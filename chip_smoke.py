@@ -1,0 +1,625 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process (a chip belongs to one process; no children), every phase in
+it, through the entry points users call:
+
+- ``device``  — ``jax.devices()``: a TPU of a kind the kernels are sized
+  for, with the jax / jaxlib / libtpu versions;
+- ``train``   — BERT-base at full width and depth (bs 32 x seq 512, bf16
+  AMP, Adam, dropout off) for ten steps on one seeded batch through
+  ``gluon.TrainLoop`` over ``Trainer.compile_step``: one fused program,
+  one trace, buffers on the chip, loss finite and falling, no compile
+  after warm-up;
+- ``kernels`` — every name in ``ops.kernels.KERNELS`` compiled by Mosaic,
+  forward and backward, at the shapes of the source-paper cells, against
+  the XLA reference the dispatch gate already falls back to;
+- ``dp``      — with more than one device: the same loop ZeRO-sharded
+  under ``make_mesh({"dp": N})``; shards on N distinct devices,
+  collectives in the compiled program, loss parity with ``train`` (the
+  kernels take the XLA tier there: Mosaic does not lower into a
+  GSPMD-partitioned program);
+- ``serve``   — ``TinyDecoder`` and ``GQADecoder`` through
+  ``serving.run_decode`` (``DecodeEngine.warmup()`` AOT-compiles every
+  ladder bucket): all requests finish and the speculative + shared-prefix
+  stream equals plain greedy token for token.
+
+Without a TPU it exits non-zero before doing any work and prints no
+result. Any failed phase makes the exit code non-zero. The LAST line of
+stdout is the verdict, one JSON object with exactly two keys:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``,
+the device as JAX reports it. The line BEFORE it is the report, one JSON
+object too: the same ``ok`` and ``device``, then ``phases`` (each with its
+status, seconds and what it found: versions, losses, kernel paths,
+collectives), the compile-cache directory with hits and misses, whether
+the native library was built in this run, and ``"claim": null``.
+
+    python3 chip_smoke.py
+
+The compile cache (``runtime.setup_compile_cache``) lives at
+``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``: a second run
+over the same directory shows hits and no misses for the first run's
+programs. tests/test_chip_smoke.py cross-lowers ``kernel_cases()`` for the
+TPU on the CPU and rehearses the phases at tiny sizes; a rehearsal is
+never a pass.
+"""
+import contextlib
+import json
+import os
+import sys
+import time
+import traceback
+from typing import Callable, NamedTuple
+
+SEED = 21
+
+# Dropout is off and the step small, so that "the loss fell" is read off
+# the optimizer and not off noise (PR 21 chip runs): with the model's
+# default dropout 0.1 the per-step loss moves +-0.09 with the mask — the
+# same trajectory to two digits under six optimizer settings — while ten
+# stable steps move it 0.02; and Adam from a random init with no warm-up
+# overshoots from lr 1e-6 up (0.70 -> 0.76 at 1e-6, -> 1.34 at 3e-6).
+# At 3e-7 the loss falls 0.705 -> 0.684 in ten steps with +-0.003 wiggle.
+TRAIN = dict(batch=32, seq=512, steps=10, vocab=1000, bert="bert_base",
+             optimizer=("adam", {"learning_rate": 3e-7}))
+
+# the accelerator sizes of bench.py's decode leg
+SERVE = dict(vocab=256, d_model=128, heads=4, requests=16,
+             ladder=(1, 2, 4, 8), page_size=16)
+
+#: max |kernel - oracle| over max |oracle|, per tensor. The oracle is the
+#: XLA reference on the same inputs upcast to f32, its dots at HIGHEST
+#: precision. A wrong mask, index or carry shows up at O(1), not here.
+#: TOL_BF16 — bf16 outputs are rounded to 8 mantissa bits (2^-9 relative,
+#: each) and the recurrence/attention kernels round p, h and c to bf16
+#: once per step or block on the way: 4 ulp of bf16 covers both (0.002 to
+#: 0.006 on the chip, PR 21). It is also the bound for f32 THROUGH THE
+#: MXU: at default precision an f32 dot is one bf16 pass, in Mosaic as in
+#: XLA, so flash attention and the RNN kernels carry bf16-sized error in
+#: f32 too (0.0013 to 0.0076, PR 21).
+#: TOL_F32 — f32 elementwise kernels (bias-GELU, LayerNorm, optimizer
+#: update): only the exp/rsqrt/pow expansions differ (at most 9e-7, PR 21).
+TOL_BF16 = 4 * 2.0 ** -8
+TOL_F32 = 1e-5
+
+#: dp-vs-one-chip loss trajectory, absolute, on a loss that starts near
+#: ln 2: the same program partitioned four ways sums gradients in
+#: another order and tiles its bf16 matmuls differently, and Adam divides
+#: by sqrt(v) — differences of a few bf16 ulps in the loss per step.
+DP_LOSS_ATOL = 2e-2
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# kernel cases — shared with the tier-1 cross-lowering test
+# ---------------------------------------------------------------------------
+
+class KernelCase(NamedTuple):
+    kernel: str              # name in ops.kernels.KERNELS
+    label: str
+    dtype: str               # of the float arguments
+    tol: float               # TOL_BF16 | TOL_F32
+    make: Callable           # RandomState -> tuple of f32/int numpy args
+    fn: Callable             # *args -> array or tuple of arrays
+    grad_argnums: tuple      # () = forward only
+
+
+def kernel_cases(tiny: bool = False):
+    """Every Pallas kernel through its public, dispatching entry point at
+    the shape of the cell it was written for (``tiny``: the same cases
+    cut down for an interpret-mode rehearsal on the CPU)."""
+    import numpy as onp
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import attention, kernels
+    from mxnet_tpu.ops import nn as ops_nn
+    from mxnet_tpu.ops.kernels import norm, rnn_scan
+
+    cases = []
+
+    def add(kernel, label, dtype, make, fn, grad_argnums=()):
+        dots = kernel in ("flash_attention", "rnn_scan", "rnn_decode_step")
+        tol = TOL_F32 if dtype == "float32" and not dots else TOL_BF16
+        cases.append(KernelCase(kernel, f"{label} {dtype}", dtype, tol,
+                                make, fn, grad_argnums))
+
+    def f32(rng, *shape, scale=1.0):
+        return (rng.standard_normal(shape) * scale).astype("float32")
+
+    def bias_gelu(x, b):
+        path, _ = kernels.dispatch("bias_gelu")
+        if path == "xla":
+            return jax.nn.gelu(x + b, approximate=False)
+        return norm.bias_gelu(x, b, interpret=path == "interpret")
+
+    def cell_args(rng, lead, n, h, g=4):
+        return (f32(rng, *lead, n, g * h, scale=0.5),
+                f32(rng, n, h, scale=0.5), f32(rng, n, h, scale=0.5),
+                f32(rng, g * h, h, scale=h ** -0.5),
+                f32(rng, g * h, scale=0.1))
+
+    for dtype in ("bfloat16", "float32"):
+        # BERT-base FFN: 32 x 512 tokens, hidden 3072
+        r, c = (64, 256) if tiny else (16384, 3072)
+        add("bias_gelu", f"bias_gelu {r}x{c}", dtype,
+            lambda rng, r=r, c=c: (f32(rng, r, c), f32(rng, c)),
+            bias_gelu, (0, 1))
+
+        # BERT-base LayerNorm over 768 units
+        shp = (4, 8, 128) if tiny else (32, 512, 768)
+        add("layernorm", f"layernorm {'x'.join(map(str, shp))}", dtype,
+            lambda rng, shp=shp: (f32(rng, *shp),
+                                  1 + f32(rng, shp[-1], scale=0.1),
+                                  f32(rng, shp[-1], scale=0.1)),
+            lambda x, g, b: ops_nn.layer_norm(x, g.astype(jnp.float32),
+                                              b.astype(jnp.float32)),
+            (0, 1, 2))
+
+        # BERT-base attention (one 512 block: the fused backward) and a
+        # 2k causal sequence (the two-kernel backward)
+        flash = (((1, 2, 64, 64), False), ((1, 2, 1024, 64), True)) \
+            if tiny else (((32, 12, 512, 64), False),
+                          ((32, 12, 512, 64), True),
+                          ((8, 12, 2048, 64), True))
+        for shp, causal in flash:
+            add("flash_attention",
+                f"flash_attention {'x'.join(map(str, shp))}"
+                f"{' causal' if causal else ''}", dtype,
+                lambda rng, shp=shp: tuple(f32(rng, *shp)
+                                           for _ in range(3)),
+                lambda q, k, v, causal=causal: attention.flash_attention(
+                    q, k, v, causal=causal), (0, 1, 2))
+
+        # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
+        # run this recurrence shape (embed = hidden = 650)
+        t, n, h = (5, 4, 50) if tiny else (35, 64, 650)
+        add("rnn_scan", f"rnn_scan lstm T{t}xN{n}xH{h}", dtype,
+            lambda rng, t=t, n=n, h=h: cell_args(rng, (t,), n, h),
+            lambda xw, h0, c0, w, b: rnn_scan.rnn_scan(
+                xw, h0, c0, w, b, "lstm"), (0, 1, 2, 3, 4))
+        add("rnn_scan", f"rnn_scan gru T{t}xN{n}xH{h}", dtype,
+            lambda rng, t=t, n=n, h=h: tuple(
+                a for i, a in enumerate(cell_args(rng, (t,), n, h, g=3))
+                if i != 2),
+            lambda xw, h0, w, b: rnn_scan.rnn_scan(
+                xw, h0, None, w, b, "gru")[:2], (0, 1, 2, 3))
+
+        # TinyDecoder's cell: 8 slots, d_model 128; verify = spec_k + 1
+        # positions
+        n, h, k = (2, 32, 3) if tiny else (8, SERVE["d_model"], 5)
+        add("rnn_decode_step", f"rnn_decode_step lstm N{n}xH{h}", dtype,
+            lambda rng, n=n, h=h: cell_args(rng, (), n, h),
+            lambda xw, h_, c_, w, b: rnn_scan.rnn_decode_step(
+                xw, h_, c_, w, b, "lstm"))
+        add("rnn_decode_step", f"rnn_verify_scan lstm K{k}xN{n}xH{h}",
+            dtype,
+            lambda rng, n=n, h=h, k=k: cell_args(rng, (k,), n, h)
+            + (rng.rand(k, n) < 0.7,),
+            lambda xw, h_, c_, w, b, valid: rnn_scan.rnn_verify_scan(
+                xw, h_, c_, w, b, "lstm", valid))
+
+    # one ZeRO unit: a BERT-base FFN weight's 1/4 shard, flat f32
+    p = 1000 if tiny else 768 * 3072 // 4
+    for name, kw, n_states in (("sgd", {"momentum": 0.9}, 1),
+                               ("adam", {}, 2)):
+        opt = mx.optimizer.create(name, learning_rate=0.01, wd=1e-4, **kw)
+        for vec in (False, True):
+            def opt_args(rng, n_states=n_states, vec=vec):
+                hp = (onp.full(p, 0.01, "float32"),
+                      onp.full(p, 1e-4, "float32"),
+                      rng.randint(1, 20, size=p).astype("int32")) \
+                    if vec else (onp.float32(0.01), onp.float32(1e-4),
+                                 onp.int32(7))
+                # second Adam moment: non-negative
+                states = tuple(onp.abs(f32(rng, p, scale=0.1))
+                               for _ in range(n_states))
+                return (f32(rng, p), f32(rng, p)) + hp + states
+
+            def update(w, g, lr, wd, t, *states, opt=opt):
+                step = opt.kernel_step_fn() or opt.fused_step_fn()
+                new_w, new_s = step((w,), (g,), (lr,), (wd,), (t,),
+                                    jnp.float32(1.0 / 32), jnp.float32(0),
+                                    (tuple(states),))
+                return (new_w[0],) + tuple(new_s[0])
+
+            add("opt_update", f"opt_update {name} "
+                f"{'vector' if vec else 'scalar'} hparams P{p}", "float32",
+                opt_args, update)
+    return cases
+
+
+def case_args(case: KernelCase, rng) -> tuple:
+    """The case's arguments on the device, floats in the case's dtype."""
+    import jax.numpy as jnp
+    return tuple(jnp.asarray(a, case.dtype if a.dtype.kind == "f" else None)
+                 for a in case.make(rng))
+
+
+@contextlib.contextmanager
+def _pallas_off():
+    """Trace under MXNET_PALLAS=off: the dispatch gate's XLA reference."""
+    prev = os.environ.get("MXNET_PALLAS")
+    os.environ["MXNET_PALLAS"] = "off"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["MXNET_PALLAS"]
+        else:
+            os.environ["MXNET_PALLAS"] = prev
+
+
+def kernel_outputs(case: KernelCase):
+    """*args -> the case's outputs as a flat tuple of arrays."""
+    def outputs(*args):
+        out = case.fn(*args)
+        return tuple(o for o in (out if isinstance(out, tuple) else (out,))
+                     if o is not None)
+    return outputs
+
+
+def kernel_program(case: KernelCase):
+    """The jittable program a case checks, ``(cots, *args) -> arrays``:
+    its outputs and, where the kernel has a VJP, the gradients of
+    sum(output * cotangent). ``cots`` are traced arguments (one f32 array
+    per output), not 50M-element constants in the program."""
+    import jax
+    import jax.numpy as jnp
+    outputs = kernel_outputs(case)
+
+    def program(cots, *args):
+        if not case.grad_argnums:
+            return outputs(*args)
+
+        def loss(*a):
+            outs = outputs(*a)
+            return sum(jnp.sum(o.astype(jnp.float32) * c)
+                       for o, c in zip(outs, cots)), outs
+        (_, outs), grads = jax.value_and_grad(
+            loss, argnums=case.grad_argnums, has_aux=True)(*args)
+        return outs + tuple(grads)
+
+    return program
+
+
+def _compiled_tier() -> str:
+    """What the dispatch gate calls a kernel that really ran: compiled on
+    a TPU; on the CPU rehearsal (MXNET_PALLAS=on) the interpreted body."""
+    import jax
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+
+
+def check_kernel(case: KernelCase) -> dict:
+    """Compile and run one case both ways; → its row of the path table."""
+    import numpy as onp
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import kernels
+
+    rng = onp.random.RandomState(SEED)
+    args = case_args(case, rng)
+    cots = [jnp.asarray(rng.standard_normal(s.shape), jnp.float32)
+            for s in jax.eval_shape(kernel_outputs(case), *args)]
+    program = kernel_program(case)
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(jax.jit(program)(cots, *args))
+    path, reason = kernels.decisions()[case.kernel]
+    row = {"kernel": case.kernel, "case": case.label, "path": path,
+           "seconds": round(time.perf_counter() - t0, 2)}
+    if path != _compiled_tier():
+        # off the tier by a static `supported` reason is a recorded
+        # retirement; switched off from outside is not a check at all
+        if path != "xla" or reason.startswith("MXNET_PALLAS"):
+            raise RuntimeError(f"{case.label}: dispatched to {path} "
+                               f"({reason})")
+        row["reason"] = reason
+        return row
+    oracle_args = tuple(a.astype(jnp.float32) if a.dtype == jnp.bfloat16
+                        else a for a in args)
+    with _pallas_off(), jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(program)(cots, *oracle_args))
+    worst = 0.0
+    for g, w in zip(got, want):
+        g, w = onp.asarray(g, "float32"), onp.asarray(w, "float32")
+        if not onp.isfinite(g).all():
+            raise RuntimeError(f"{case.label}: non-finite kernel output")
+        worst = max(worst, float(onp.abs(g - w).max()
+                                 / (onp.abs(w).max() + 1e-30)))
+    row["error"] = float(f"{worst:.3g}")
+    if worst > case.tol:
+        raise RuntimeError(
+            f"{case.label}: kernel is {worst:.3g} of the tensor scale "
+            f"away from the XLA reference (tolerance {case.tol:.3g})")
+    return row
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def phase_device() -> dict:
+    from importlib import metadata
+    import jax
+    import jaxlib
+    from mxnet_tpu.ops.kernels import SUPPORTED_DEVICE_KINDS
+    d = jax.devices()[0]
+    if d.device_kind not in SUPPORTED_DEVICE_KINDS:
+        raise RuntimeError(
+            f"device_kind {d.device_kind!r} is not one the program knows "
+            f"({', '.join(SUPPORTED_DEVICE_KINDS)}): its VMEM sizing and "
+            "peak table would be guesses")
+    return {"versions": {"jax": jax.__version__,
+                         "jaxlib": jaxlib.__version__,
+                         "libtpu": metadata.version("libtpu")}}
+
+
+def _bert_loop(cfg: dict):
+    """(net, loop, x, y): the seeded BERT classifier, its TrainLoop and
+    one seeded batch — built identically for ``train`` and ``dp`` (the
+    construction of bench.py's BERT leg, on Adam and kvstore='tpu')."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+    from mxnet_tpu.gluon.model_zoo import bert
+    mx.random.seed(SEED)
+    rng = onp.random.RandomState(SEED)
+    net = bert.BERTClassifier(
+        getattr(bert, cfg["bert"])(max_length=cfg["seq"], dropout=0.0),
+        num_classes=2, dropout=0.0)
+    net.initialize()          # every shape is declared: nothing deferred
+    trainer = mx.gluon.Trainer(net.collect_params(), *cfg["optimizer"],
+                               kvstore="tpu")
+    loop = mx.gluon.TrainLoop(net, trainer, SoftmaxCrossEntropyLoss())
+    x = mx.nd.array(rng.randint(0, cfg["vocab"],
+                                size=(cfg["batch"], cfg["seq"]))
+                    .astype("int32"))
+    y = mx.nd.array(rng.randint(0, 2, size=(cfg["batch"],))
+                    .astype("int32"))
+    return net, loop, x, y
+
+
+def _run_steps(loop, x, y, steps: int):
+    """Warm-up step, then ``steps - 1`` more with the compile cache's
+    request count held still. → per-step mean loss."""
+    import numpy as onp
+    from mxnet_tpu import runtime
+
+    def compiles():
+        s = runtime.compile_cache_stats()
+        return s["hits"] + s["misses"]
+
+    losses = [loop.step(x, y)]
+    loop.synchronize()
+    losses[0].asnumpy()
+    warm = compiles()
+    losses += [loop.step(x, y) for _ in range(steps - 1)]
+    loop.synchronize()
+    losses = [float(onp.mean(l.asnumpy(), dtype="float64")) for l in losses]
+    if compiles() != warm:
+        raise RuntimeError(f"{compiles() - warm} compile(s) after warm-up")
+    step = loop.compiled_step
+    if step.mode != "fused" or step.n_traces != 1:
+        raise RuntimeError(f"step mode={step.mode} n_traces="
+                           f"{step.n_traces}, expected fused / 1")
+    if not all(onp.isfinite(losses)):
+        raise RuntimeError(f"non-finite loss: {losses}")
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"loss did not fall: {losses}")
+    return losses
+
+
+def _log_decisions() -> dict:
+    """Print the dispatch table — with the reason for every kernel that
+    did not take the compiled tier — and return {kernel: path}."""
+    from mxnet_tpu.ops import kernels
+    table = kernels.decisions()
+    for name in kernels.KERNELS:
+        path, reason = table.get(name, ("-", "not on this model's path"))
+        log(f"  decision {name}: {path}"
+            + ("" if path == "pallas" else f" ({reason})"))
+    return {k: v[0] for k, v in table.items()}
+
+
+def phase_train(cfg: dict = TRAIN) -> dict:
+    import jax
+    import mxnet_tpu as mx
+    platform = jax.devices()[0].platform
+    net, loop, x, y = _bert_loop(cfg)
+    mx.amp.init()
+    try:
+        losses = _run_steps(loop, x, y, cfg["steps"])
+    finally:
+        mx.amp.uninit()
+    arrays = [p.data()._data for p in net.collect_params().values()]
+    placed = {d.platform for a in arrays + [x._data, y._data]
+              for d in a.devices()}
+    if placed != {platform}:
+        raise RuntimeError(f"parameters/batch live on {placed}, "
+                           f"jax.devices()[0] is {platform}")
+    paths = _log_decisions()
+    for name in ("flash_attention", "layernorm"):
+        if paths.get(name) != _compiled_tier():
+            raise RuntimeError(f"BERT dispatched {name} to {paths.get(name)}")
+    return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths}
+
+
+def phase_kernels(tiny: bool = False) -> dict:
+    """Every case runs, so one chip call names every kernel Mosaic
+    refuses; any failed case fails the phase."""
+    paths, failed = {}, []
+    for case in kernel_cases(tiny):
+        try:
+            row = check_kernel(case)
+        except Exception:
+            traceback.print_exc(file=sys.stdout)
+            row = {"kernel": case.kernel, "case": case.label,
+                   "path": "failed"}
+            failed.append(case.label)
+        log("  " + json.dumps(row))
+        paths.setdefault(case.kernel, set()).add(row["path"])
+    return {"kernel_paths": {k: "+".join(sorted(v))
+                             for k, v in paths.items()}, "failed": failed}
+
+
+def phase_dp(train_loss, cfg: dict = TRAIN) -> dict:
+    import jax
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu.parallel import make_mesh
+    n = len(jax.devices())
+    if n < 2:
+        return {"skipped": "1 device"}
+    with make_mesh({"dp": n}):
+        _, loop, x, y = _bert_loop(cfg)
+        step = loop.compiled_step
+        mx.amp.init()
+        try:
+            losses = _run_steps(loop, x, y, cfg["steps"])
+            hlo = step.lower_entry(x, y)["lowered"].compile().as_text()
+        finally:
+            mx.amp.uninit()
+        paths = _log_decisions()
+        if not step.zero_sharded:
+            raise RuntimeError("the step is not ZeRO-sharded")
+        shards = step.optimizer_state_buffers() \
+            + [step.input_placement()(x._data)]
+    for a in shards:
+        on = {s.device for s in a.addressable_shards}
+        if len(on) != n or a.addressable_shards[0].data.size * n > \
+                a.size + n:
+            raise RuntimeError(
+                f"a {a.shape} optimizer-state/batch buffer is not split "
+                f"over {n} distinct devices: {sorted(map(str, on))}")
+    collectives = {op: hlo.count(f" {op}(") + hlo.count(f" {op}-start(")
+                   for op in ("reduce-scatter", "all-gather", "all-reduce")}
+    # XLA:TPU spells a reduce-scatter as a fused `all-reduce-scatter`
+    collectives["reduce-scatter"] += hlo.count("calls=%all-reduce-scatter")
+    # the zero-dp plan: gradients reduce-scattered (or all-reduced and
+    # sliced) onto the shards, new weights all-gathered back
+    if not ((collectives["reduce-scatter"] or collectives["all-reduce"])
+            and collectives["all-gather"]):
+        raise RuntimeError(f"compiled program lacks the ZeRO "
+                           f"collectives: {collectives}")
+    gap = float(onp.abs(onp.subtract(losses, train_loss)).max())
+    if gap > DP_LOSS_ATOL:
+        raise RuntimeError(
+            f"dp loss {losses} is {gap:.3g} away from the one-device "
+            f"trajectory {train_loss} (tolerance {DP_LOSS_ATOL})")
+    return {"loss": [round(l, 4) for l in losses], "devices": n,
+            "max_loss_gap": float(f"{gap:.3g}"), "collectives": collectives,
+            "kernel_paths": paths}
+
+
+def phase_serve(cfg: dict = SERVE) -> dict:
+    import numpy as onp
+    from mxnet_tpu import serving
+    from mxnet_tpu.gluon import GQADecoder
+    rng = onp.random.RandomState(SEED)
+    ps, vocab = cfg["page_size"], cfg["vocab"]
+    # mixed lengths; every other prompt extends one shared three-page
+    # base (prefix sharing), every fourth decodes long (heavy tail)
+    base = rng.randint(0, vocab, size=3 * ps)
+    prompts, max_new = [], []
+    for i in range(cfg["requests"]):
+        tail = rng.randint(0, vocab, size=int(rng.randint(2, 12)))
+        prompts.append(onp.concatenate([base, tail]) if i % 2 else tail)
+        max_new.append(32 if i % 4 == 0 else int(rng.randint(2, 8)))
+    models = {
+        "TinyDecoder": serving.TinyDecoder(
+            vocab=vocab, d_model=cfg["d_model"], num_heads=cfg["heads"],
+            seed=0),
+        "GQADecoder": GQADecoder(
+            vocab=vocab, d_model=cfg["d_model"],
+            num_heads=cfg["heads"] * 2, num_kv_heads=cfg["heads"],
+            num_layers=2, seed=0),
+    }
+    out = {}
+    for name, model in models.items():
+        kw = dict(ladder=cfg["ladder"], page_size=ps)
+        greedy = serving.run_decode(model, prompts, max_new, spec_k=0,
+                                    prefix_share=False, **kw)
+        spec = serving.run_decode(model, prompts, max_new, spec_k=4,
+                                  prefix_share=True, **kw)
+        for run in (greedy, spec):
+            if [len(t) for t in run["token_ids"]] != max_new:
+                raise RuntimeError(
+                    f"{name}: requests emitted "
+                    f"{[len(t) for t in run['token_ids']]} tokens, "
+                    f"asked for {max_new}")
+        bad = [i for i, (a, b) in enumerate(zip(
+            greedy["token_ids"], spec["token_ids"])) if a != b]
+        if bad:
+            raise RuntimeError(
+                f"{name}: speculative + shared-prefix streams differ from "
+                f"plain greedy in requests {bad}")
+        out[name] = {"tokens": spec["tokens"], "steps": greedy["steps"],
+                     "spec_steps": spec["steps"],
+                     "prefix_hits": spec["prefix_hits"]}
+        log(f"  {name}: {out[name]}")
+    return out
+
+
+def run_phases(phases: dict) -> dict:
+    """Run every phase, each handed the report so far; a phase that
+    raises (or returns a non-empty ``failed`` list) is recorded as failed
+    with its traceback printed, and the caller exits non-zero."""
+    report = {}
+    for name, fn in phases.items():
+        log(f"[{name}]")
+        t0 = time.perf_counter()
+        try:
+            detail = fn(report) or {}
+            status = "failed" if detail.get("failed") else \
+                "skipped" if "skipped" in detail else "ok"
+        except Exception:
+            traceback.print_exc(file=sys.stdout)
+            detail, status = {}, "failed"
+        report[name] = dict(detail, status=status,
+                            seconds=round(time.perf_counter() - t0, 1))
+        log(f"[{name}] {status} {report[name]['seconds']}s")
+    return report
+
+
+def main() -> int:
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: JAX found no TPU (platform="
+              f"{devices[0].platform}); nothing was run", file=sys.stderr)
+        return 2
+    from mxnet_tpu import _native, runtime
+    report = run_phases({
+        "device": lambda _: phase_device(),
+        "train": lambda _: phase_train(),
+        # a failed train phase fails dp too: there is nothing to match.
+        # Before `kernels`, so the dispatch table dp prints is BERT's
+        "dp": lambda r: phase_dp(r["train"]["loss"]),
+        "kernels": lambda _: phase_kernels(),
+        "serve": lambda _: phase_serve(),
+    })
+    return finish(report, devices, runtime.compile_cache_stats(),
+                  _native.built_this_run())
+
+
+def finish(report: dict, devices, cache: dict, native_built: bool) -> int:
+    """Print the report line, then the verdict line — the last line of
+    stdout, exactly ``ok`` and ``device`` — and return the exit code."""
+    verdict = {
+        "ok": all(p["status"] != "failed" for p in report.values()),
+        "device": {"platform": devices[0].platform,
+                   "kind": devices[0].device_kind, "count": len(devices)},
+    }
+    print(json.dumps(dict(
+        verdict, phases=report,
+        compile_cache={k: cache[k] for k in ("dir", "hits", "misses")},
+        native_built_this_run=native_built, claim=None)), flush=True)
+    print(json.dumps(verdict), flush=True)
+    return 0 if verdict["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -22,9 +22,9 @@ from . import autograd
 from . import util
 from .util import is_np_array, set_np, reset_np, use_np
 
-# Subsystems are imported as they land in the build plan (SURVEY §7); each
-# line below is enabled once the module exists and its tests pass.
-_OPTIONAL_MODULES = [
+# Every subsystem imports or `import mxnet_tpu` fails: a dropped module
+# would otherwise surface far away as an AttributeError on `mx.<name>`.
+_SUBSYSTEMS = [
     ("initializer", None), ("init", None), ("optimizer", None),
     ("lr_scheduler", None), ("kvstore", None), ("kvstore", "kv"),
     ("gluon", None),
@@ -42,31 +42,18 @@ _OPTIONAL_MODULES = [
 ]
 import importlib as _importlib
 
-for _mod, _alias in _OPTIONAL_MODULES:
-    try:
-        _m = _importlib.import_module(f".{_mod}", __name__)
-        globals()[_alias or _mod] = _m
-    except ImportError:
-        pass
+for _mod, _alias in _SUBSYSTEMS:
+    globals()[_alias or _mod] = _importlib.import_module(f".{_mod}",
+                                                         __name__)
 
-try:
-    from .kvstore import KVStore  # noqa: F401
-except ImportError:
-    pass
+from .kvstore import KVStore  # noqa: F401,E402
 
-# MXNET_COMPILE_CACHE=<dir>: persistent XLA compilation cache — restarts
-# and repeated bench warmups load executables from disk instead of
-# recompiling (runtime.setup_compile_cache logs hits/misses).
-try:
-    from .runtime import setup_compile_cache as _setup_compile_cache
-    _setup_compile_cache()
-except Exception:   # the cache is an optimization; never block import
-    pass
+# persistent XLA compilation cache, at JAX_COMPILATION_CACHE_DIR or one
+# fixed path in the checkout: restarts load executables from disk
+# instead of recompiling (runtime.setup_compile_cache counts hits/misses)
+runtime.setup_compile_cache()  # noqa: F821 - bound by the loop above
 
-try:
-    from .attribute import AttrScope  # noqa: F401  (reference __init__:72)
-except ImportError:
-    pass
+from .attribute import AttrScope  # noqa: F401,E402  (reference __init__:72)
 
 
 def tpu_context_available() -> bool:

@@ -31,6 +31,17 @@ _load_failed = False
 _OP_FN = ctypes.CFUNCTYPE(ctypes.c_int, ctypes.c_void_p)
 
 
+#: set by get_lib(): did ``make`` (re)write the library this process
+#: loaded? False means make found build/libmxt_native.so already current
+#: with src/native/.
+_built_this_run = False
+
+
+def _lib_mtime():
+    return os.path.getmtime(_LIB_PATH) if os.path.exists(_LIB_PATH) \
+        else None
+
+
 def _build_lib() -> bool:
     try:
         r = subprocess.run(["make", "-C", _SRC_DIR],
@@ -38,6 +49,12 @@ def _build_lib() -> bool:
         return r.returncode == 0 and os.path.exists(_LIB_PATH)
     except (OSError, subprocess.TimeoutExpired):
         return False
+
+
+def built_this_run() -> bool:
+    """True when the loaded library was compiled from src/native/ by
+    THIS process (chip_smoke.py reports it)."""
+    return get_lib() is not None and _built_this_run
 
 
 def _declare(lib):
@@ -108,7 +125,7 @@ def _declare(lib):
 
 def get_lib():
     """The loaded native library, or None when unavailable."""
-    global _lib, _load_failed
+    global _lib, _load_failed, _built_this_run
     if _lib is not None or _load_failed:
         return _lib
     with _lib_lock:
@@ -118,11 +135,19 @@ def get_lib():
             _load_failed = True
             return None
         # make is a fast no-op when the .so is current, and rebuilds it
-        # when headers/sources changed (stale-symbol protection); a failed
-        # build still falls through to an existing library
-        if not _build_lib() and not os.path.exists(_LIB_PATH):
+        # when headers/sources changed (stale-symbol protection)
+        before = _lib_mtime()
+        if not _build_lib():
+            if before is not None:
+                # a library make could not vouch for is not a fallback
+                raise MXNetError(
+                    f"`make -C {_SRC_DIR}` failed but {_LIB_PATH} exists: "
+                    "refusing to load a library that may not match "
+                    "src/native/. Fix the build, delete the file, or set "
+                    "MXNET_TPU_NO_NATIVE=1 for the pure-Python paths.")
             _load_failed = True
             return None
+        _built_this_run = _lib_mtime() != before
         try:
             lib = ctypes.CDLL(_LIB_PATH)
             _declare(lib)

@@ -236,7 +236,7 @@ def _iter_eqns(jaxpr) -> Iterable:
 
 
 def _subjaxprs(v):
-    from jax.core import Jaxpr, ClosedJaxpr
+    from jax.extend.core import Jaxpr, ClosedJaxpr
     if isinstance(v, (Jaxpr, ClosedJaxpr)):
         yield v
     elif isinstance(v, (list, tuple)):

@@ -72,8 +72,14 @@ class Context:
         if self.device_type in ("cpu", "cpu_pinned"):
             cpus = [d for d in jax.local_devices() if d.platform == "cpu"]
             if not cpus:
-                # On a TPU-only runtime host staging still works via numpy;
-                # map cpu ctx onto device 0 as the reference maps pinned mem.
+                # A TPU-only runtime lists no CPU device, and mx.cpu() is
+                # the DEFAULT context: every array made without a ctx
+                # goes to local chip 0, on a four-chip host too, and
+                # COMMITTED there (``jax.default_device`` does not move
+                # it). The dp path expects exactly that — compile_step
+                # re-places params and batch on the mesh with their
+                # NamedSharding, wherever they started. The fleet does
+                # not: see FleetController._pinned_build.
                 cpus = jax.local_devices()
             return cpus[min(self.device_id, len(cpus) - 1)]
         devs = _accelerator_devices()

@@ -70,6 +70,16 @@ class _TracedSentinel:
 
 _TRACED = _TracedSentinel()
 
+#: What a loss or forward raises when it needs a CONCRETE value during
+#: a trace (``.asnumpy()``, ``float()``, ``if x > 0``, a boolean-mask
+#: index) — the one failure ``compile_step`` and ``CompiledPredictor``
+#: answer by demoting to eager. Anything else a first call raises
+#: (lowering, Mosaic/XLA compile, runtime) propagates.
+UNTRACEABLE_ERRORS = (jax.errors.ConcretizationTypeError,
+                      jax.errors.TracerArrayConversionError,
+                      jax.errors.TracerIntegerConversionError,
+                      jax.errors.NonConcreteBooleanIndexError)
+
 
 class ParamBinding:
     """Functional parameter binding for whole-graph traces.

@@ -62,6 +62,7 @@ Contracts:
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import time
@@ -79,7 +80,7 @@ from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from ..ndarray.random import next_key, push_trace_key, pop_trace_key
 from ..testing.faults import fault_point
-from .block import ParamBinding, _TRACED
+from .block import UNTRACEABLE_ERRORS, ParamBinding, _TRACED
 
 __all__ = ["CompiledTrainStep", "TrainLoop"]
 
@@ -607,13 +608,28 @@ class CompiledTrainStep:
             return self._zero.state_bytes_per_replica()
         c = _telemetry().memory.census()
         total = 0
-        for st in self._trainer._updater.states.values():
-            for s in jax.tree_util.tree_leaves(
-                    st, is_leaf=lambda x: isinstance(x, NDArray)):
-                if isinstance(s, NDArray):
-                    c.register("optimizer", s)
-                    total += _ZeroShardPlan._per_replica_bytes(s._data)
+        for s in self._state_ndarrays():
+            c.register("optimizer", s)
+            total += _ZeroShardPlan._per_replica_bytes(s._data)
         return total
+
+    def optimizer_state_buffers(self) -> list:
+        """The live device buffers :meth:`optimizer_state_bytes` counts
+        (moments + fp32 masters), as ``jax.Array``s — their
+        ``addressable_shards`` say which devices really hold the ZeRO
+        shards (chip_smoke.py's ``dp`` phase reads them)."""
+        return [s._data for s in self._state_ndarrays()]
+
+    def _state_ndarrays(self) -> list:
+        """Every optimizer-state NDArray handle: the ZeRO plan's flat
+        shards and masters, else the Updater's per-parameter states."""
+        if self._zero is not None:
+            return [s for st in self._zero.states for s in st] \
+                + list(self._zero.masters)
+        return [s for st in self._trainer._updater.states.values()
+                for s in jax.tree_util.tree_leaves(
+                    st, is_leaf=lambda x: isinstance(x, NDArray))
+                if isinstance(s, NDArray)]
 
     def memory_report(self, *args, batch_size: Optional[int] = None,
                       **kwargs):
@@ -688,14 +704,8 @@ class CompiledTrainStep:
             for p in self._all_params:
                 if p._data is not None:
                     c.register("params", p._data)
-            if self._zero is not None:
-                self._zero.state_bytes_per_replica()   # registers
-            else:
-                for st in self._trainer._updater.states.values():
-                    for s in jax.tree_util.tree_leaves(
-                            st, is_leaf=lambda x: isinstance(x, NDArray)):
-                        if isinstance(s, NDArray):
-                            c.register("optimizer", s)
+            for s in self._state_ndarrays():
+                c.register("optimizer", s)
         except Exception:        # pragma: no cover - census must never
             _LOG.debug("census registration failed", exc_info=True)
             return                  # kill a step; retry next call
@@ -769,6 +779,20 @@ class CompiledTrainStep:
             return False
         self._zero_ok = (mesh, axis)
         return True
+
+    @contextlib.contextmanager
+    def _mesh_scope(self):
+        """The step's own mesh, active for every call: a caller that
+        has left its ``with make_mesh(...)`` block still traces (jit
+        keys its cache on the mesh context) and dispatches kernels (the
+        gate reads the active mesh) as on the first call."""
+        from ..parallel.mesh import current_mesh
+        pair = self._zero_ok or self._plain_mesh
+        if pair is None or current_mesh() is pair[0]:
+            yield
+        else:
+            with pair[0]:
+                yield
 
     def _host_allreduce(self) -> bool:
         kv = self._trainer._kvstore
@@ -873,14 +897,19 @@ class CompiledTrainStep:
             # machinery sees it (telemetry/memory.py)
             with t.memory.oom_guard("CompiledTrainStep.step (compile/"
                                     "dispatch)",
-                                    step=self._steps_done + 1):
+                                    step=self._steps_done + 1), \
+                    self._mesh_scope():
                 out = self._fused_call(args, kwargs, batch_size)
-        except Exception as e:
+        except UNTRACEABLE_ERRORS as e:
+            # only a loss that cannot be TRACED demotes; a lowering,
+            # Mosaic/XLA compile or runtime error propagates — on an
+            # accelerator it would otherwise run (and be timed) as
+            # per-op eager dispatch under the fused step's name
             if self._steps_done:
                 raise   # the program is proven; this is a genuine error
             _LOG.warning(
-                "compile_step: fused trace failed (%s: %s); falling back "
-                "to the eager tape path", type(e).__name__, e)
+                "compile_step: loss is not traceable (%s: %s); falling "
+                "back to the eager tape path", type(e).__name__, e)
             opt.num_update, opt._index_update_count = \
                 snapshot[0], snapshot[1]
             self._mode = "eager"
@@ -1047,14 +1076,9 @@ class CompiledTrainStep:
             # selects it (ops/kernels/opt_update.py; bit-exact vs the
             # XLA chain, pinned by tests) — one kernel per flat unit
             # instead of a per-op elementwise chain.
-            try:
-                from ..ops.kernels.opt_update import \
-                    kernel_step_fn as _opt_kfn
-                opt_kernel_fn = _opt_kfn(self._trainer._optimizer)
-            except Exception:   # kernel layer must never kill a step
-                _LOG.debug("opt-update kernel unavailable",
-                           exc_info=True)
-                opt_kernel_fn = None
+            from ..ops.kernels.opt_update import \
+                kernel_step_fn as _opt_kfn
+            opt_kernel_fn = _opt_kfn(self._trainer._optimizer)
             if opt_kernel_fn is not None:
                 opt_fn = opt_kernel_fn
             plan = self._zero
@@ -1786,13 +1810,8 @@ class CompiledTrainStep:
         rescale = onp.float32(1.0 / batch_size)
         clip = onp.float32(0.0)
         key = next_key()
-        try:
-            exe = entry["fn"].lower(pds, sts, leaf_datas, lrs, wds, ts,
-                                    rescale, clip, key).compile()
-        except Exception as e:   # pragma: no cover - platform-dependent
-            _LOG.warning("compile_step: AOT lower/compile unavailable "
-                         "(%s); falling back to jit", type(e).__name__)
-            return None
+        exe = entry["fn"].lower(pds, sts, leaf_datas, lrs, wds, ts,
+                                rescale, clip, key).compile()
         entry["exe"] = exe
         try:
             ca = exe.cost_analysis()

@@ -100,12 +100,8 @@ class _LibOp:
 
     def kernel(self, *xs):
         """JAX-facing kernel. Eager calls run the C forward on host
-        directly (works on every platform, including PjRt plugins without
-        host-callback support). Inside a trace the op lowers to
-        ``jax.pure_callback`` — an opaque host custom-call — which requires
-        a callback-capable platform (CPU/TPU; some tunneled PjRt plugins
-        lack send/recv callbacks, in which case keep library ops outside
-        hybridized blocks)."""
+        directly. Inside a trace the op lowers to ``jax.pure_callback``
+        — an opaque host custom-call."""
         if not any(isinstance(x, jax.core.Tracer) for x in xs):
             return jnp.asarray(self.forward_host(
                 *[onp.asarray(x) for x in xs]))

@@ -39,15 +39,6 @@ __all__ = ["flash_attention", "paged_decode_attention", "ring_attention",
 _NEG_INF = -1e30  # finite mask value: keeps exp() NaN-free for masked rows
 
 
-def _PLTPU_COMPILER_PARAMS(**kwargs):
-    """pallas-tpu CompilerParams across jax versions (older releases spell
-    it TPUCompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
 def attention_reference(q, k, v, causal: bool = False,
                         sm_scale: Optional[float] = None, mask=None):
     """Unfused softmax(QK^T)V — the numeric oracle for tests and the
@@ -152,6 +143,25 @@ def _head_group(bh: int, block_q: int, block_k: int,
            <= budget):
         g *= 2
     return g
+
+
+def _vmem_limit(g: int, block_q: int, block_k: int, dp: int,
+                itemsize: int, n_blocks: int, n_acc: int,
+                n_tiles: int) -> int:
+    """``vmem_limit_bytes`` for one flash kernel, counted from what it
+    keeps in VMEM: ``n_blocks`` (G, block, dp) operand/result blocks in
+    the input dtype (Pallas double-buffers each), ``n_acc`` f32
+    (G, block, dp) scratch accumulators and ``n_tiles`` live f32
+    (G, bq, bk) score tiles, plus a quarter for Mosaic's own temporaries.
+    ``_head_group`` budgets the score tiles only; at f32 the operand
+    blocks alone double, and the BERT-shape forward asked for 16.42 MiB
+    of the 16 MiB a kernel gets without a limit. Never below that
+    default."""
+    from .kernels import VMEM_SCOPED_DEFAULT_BYTES
+    block = g * max(block_q, block_k) * dp
+    need = (2 * n_blocks * block * itemsize + n_acc * block * 4
+            + n_tiles * g * block_q * block_k * 4)
+    return max(VMEM_SCOPED_DEFAULT_BYTES, need + need // 4)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
@@ -288,8 +298,11 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
             pltpu.VMEM((g, block_q, 128), jnp.float32),
             pltpu.VMEM((g, block_q, dp), jnp.float32),
         ],
-        compiler_params=_PLTPU_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # q, k, v, o blocks; m, l, acc scratch; s and p tiles
+            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
+                                         q.dtype.itemsize, 4, 3, 2)),
         interpret=interpret,
     )(qp, kp, vp)
     return (out.reshape(b, h, sqp, dp)[:, :, :sq, :d],
@@ -484,8 +497,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             out_shape=[jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype),
                        jax.ShapeDtypeStruct((b * h, skp, dp), k.dtype),
                        jax.ShapeDtypeStruct((b * h, skp, dp), v.dtype)],
-            compiler_params=_PLTPU_COMPILER_PARAMS(
-                dimension_semantics=("parallel",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                # q, k, v, do in, dq, dk, dv out; s, p, dp, ds tiles
+                vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
+                                             q.dtype.itemsize, 7, 0, 4)),
             interpret=interpret,
         )(qp, kp, vp, dop, lsep, dl)
         return (dq.reshape(b, h, sqp, dp)[:, :, :sq, :d],
@@ -509,8 +525,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((g, block_q, dp), jnp.float32)],
-        compiler_params=_PLTPU_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # q, k, v, do in, dq out; dq scratch; s, p, dp, ds tiles
+            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
+                                         q.dtype.itemsize, 5, 1, 4)),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dl)
 
@@ -528,8 +547,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                    jax.ShapeDtypeStruct((b * h, skp, dp), v.dtype)],
         scratch_shapes=[pltpu.VMEM((g, block_k, dp), jnp.float32),
                         pltpu.VMEM((g, block_k, dp), jnp.float32)],
-        compiler_params=_PLTPU_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # q, k, v, do in, dk, dv out; dk, dv scratch; 4 score tiles
+            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
+                                         q.dtype.itemsize, 6, 2, 4)),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dl)
 
@@ -711,7 +733,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    axis_size = lax.psum(1, axis_name)
+    axis_size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, s, d = q.shape
     orig_dtype = q.dtype

@@ -18,8 +18,11 @@ Dispatch discipline (shared by every kernel in this package, and by
 ``ops.attention.flash_attention``): one ``MXNET_PALLAS`` gate with
 three tiers —
 
-- ``auto`` (default): compiled Pallas kernels on TPU backends, the XLA
-  reference implementation everywhere else;
+- ``auto`` (default): compiled Pallas kernels on TPU backends (in
+  single-device programs and ``shard_map`` bodies — a program GSPMD
+  partitions over a mesh gets the XLA reference, see
+  :func:`_gspmd_reason`), the XLA reference implementation everywhere
+  else;
 - ``on``: Pallas on TPU; on non-TPU backends the kernels run in
   ``pl.pallas_call(interpret=True)`` mode — the kernel BODY executes
   (as plain XLA ops), which is how tier-1 CPU tests exercise kernels
@@ -38,17 +41,28 @@ import os
 from typing import Dict, Optional, Tuple
 
 __all__ = ["pallas_mode", "dispatch", "decisions", "dispatch_table",
-           "KERNELS", "VMEM_TILE_BUDGET_BYTES", "VMEM_BYTES_PER_CORE",
+           "KERNELS", "SUPPORTED_DEVICE_KINDS", "VMEM_TILE_BUDGET_BYTES",
+           "VMEM_BYTES_PER_CORE", "VMEM_SCOPED_DEFAULT_BYTES",
            "vmem_tile_budget"]
 
-#: VMEM ceiling one kernel's CONCURRENT working-set tiles may claim —
-#: the budget ops.attention._head_group sizes head groups against, and
-#: the one rnn_scan sizes its timestep block against. ~16 MiB/core is
-#: the physical VMEM (v5e); 4 MiB leaves room for Mosaic's own double
-#: buffering of the streamed operands. The DEFAULT: every kernel reads
-#: the live value through :func:`vmem_tile_budget` (env/autotune
-#: overridable), never this constant directly.
-VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
+#: ``jax.Device.device_kind`` values this kernel layer is sized for
+#: (a v5e reports "TPU v5 lite"). The VMEM figures below are this
+#: chip's; chip_smoke.py refuses a kind that is not listed.
+SUPPORTED_DEVICE_KINDS = ("TPU v5 lite", "TPU v5e")
+#: Physical VMEM of one v5e TensorCore
+#: (jax.experimental.pallas.tpu.get_tpu_info). A kernel reaches past the
+#: scoped default below only by passing ``vmem_limit_bytes``; rnn_scan
+#: and the flash kernels do, counted from what they keep resident.
+VMEM_BYTES_PER_CORE = 128 * 1024 * 1024
+#: What Mosaic grants a kernel that passes no ``vmem_limit_bytes``.
+VMEM_SCOPED_DEFAULT_BYTES = 16 * 1024 * 1024
+#: VMEM one kernel's CONCURRENT working-set tiles may claim — the budget
+#: ops.attention._head_group sizes head groups against, and the one
+#: rnn_scan sizes its timestep block against. 4 MiB of the 16 MiB scoped
+#: default leaves room for Mosaic's own double buffering of the streamed
+#: operands. The DEFAULT: every kernel reads the live value through
+#: :func:`vmem_tile_budget` (env/autotune overridable), never this
+#: constant directly.
 VMEM_TILE_BUDGET_BYTES = 4 * 1024 * 1024
 
 
@@ -59,15 +73,15 @@ def vmem_tile_budget() -> int:
 
         autotune override > ``MXNET_VMEM_TILE_BUDGET`` > the default
 
-    (``tuning/space.py`` precedence), clamped to the physical
-    per-core VMEM. Hand-tuners and the autotuner turn the same knob."""
+    (``tuning/space.py`` precedence), clamped to the scoped default.
+    Hand-tuners and the autotuner turn the same knob."""
     from ...tuning import space as _tspace
     try:
         v = int(_tspace.value("kernels.vmem_tile_budget",
                               VMEM_TILE_BUDGET_BYTES))
     except (TypeError, ValueError):
         v = VMEM_TILE_BUDGET_BYTES
-    return max(64 * 1024, min(v, VMEM_BYTES_PER_CORE))
+    return max(64 * 1024, min(v, VMEM_SCOPED_DEFAULT_BYTES))
 
 
 def _register_tunables():
@@ -79,12 +93,13 @@ def _register_tunables():
         "kernels.vmem_tile_budget", default=VMEM_TILE_BUDGET_BYTES,
         grid=(1 * mib, 2 * mib, 4 * mib, 8 * mib),
         env="MXNET_VMEM_TILE_BUDGET", parse=lambda s: int(float(s)),
-        valid=lambda v, _c: 64 * 1024 <= int(v) <= VMEM_BYTES_PER_CORE,
+        valid=lambda v, _c: (64 * 1024 <= int(v)
+                             <= VMEM_SCOPED_DEFAULT_BYTES),
         seam="ops.kernels.vmem_tile_budget() -> rnn_scan block_t, "
              "attention _head_group, norm/opt_update row blocks",
         scope="train", affects_program=True,
         doc="VMEM bytes one kernel's concurrent working-set tiles may "
-            "claim (<= physical VMEM/core)"))
+            "claim (<= the scoped-VMEM default)"))
     register(Tunable(
         "kernels.rnn_block_t", default=0,
         grid=(0, 1, 2, 4, 8, 16),
@@ -122,6 +137,31 @@ def pallas_mode() -> str:
     return "auto"
 
 
+def _gspmd_reason() -> Optional[str]:
+    """Why a compiled kernel cannot be used in the trace under way, or
+    None. Mosaic lowers only into a single-device program or a
+    ``shard_map`` body; in a program GSPMD partitions it raises "Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in
+    a shard_map" (jax 0.9.0, four v5e chips, PR 21). That program is the
+    one traced while a multi-device mesh is active (``with
+    make_mesh(...)`` — ``compile_step`` traces there and keeps the mesh
+    active for its retraces) outside any manual axis."""
+    import jax
+    from ...parallel.mesh import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size < 2 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return (f"GSPMD-partitioned program over {mesh.size} devices: Mosaic "
+            "kernels are not partitioned automatically (no shard_map "
+            "around the call)")
+
+
+def _tpu_path(mode: str) -> Tuple[str, str]:
+    why = _gspmd_reason()
+    return ("xla", why) if why else ("pallas", f"MXNET_PALLAS={mode} on tpu")
+
+
 def dispatch(kernel: str, supported: bool = True,
              reason: Optional[str] = None) -> Tuple[str, str]:
     """The three-tier dispatch decision for one kernel call site.
@@ -141,7 +181,7 @@ def dispatch(kernel: str, supported: bool = True,
     else:
         backend = jax.default_backend()
         if backend == "tpu":
-            out = ("pallas", f"MXNET_PALLAS={mode} on tpu")
+            out = _tpu_path(mode)
         elif mode == "on":
             out = ("interpret",
                    f"MXNET_PALLAS=on, non-TPU backend ({backend}): "
@@ -175,7 +215,7 @@ def dispatch_table() -> Dict[str, str]:
     if mode == "off":
         path = "xla"
     elif backend == "tpu":
-        path = "pallas"
+        path = _tpu_path(mode)[0]
     elif mode == "on":
         path = "interpret"
     else:

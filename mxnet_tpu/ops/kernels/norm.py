@@ -14,7 +14,10 @@ These kernels stream a block of rows through VMEM once per pass:
   VMEM across row blocks.
 - :func:`bias_gelu` — exact (erf) GELU fused with the preceding bias
   add; the backward recomputes z = x + b and applies the closed-form
-  dGELU(z) = Φ(z) + z·φ(z).
+  dGELU(z) = Φ(z) + z·φ(z). Mosaic (jax 0.9.0) lowers neither erf nor
+  erfc, so Φ is built in-kernel from exp (:func:`_norm_cdf`; f32 GELU
+  within 1e-6 absolute of the exact form, which is also where
+  ``jax.nn.gelu`` itself sits — tolerance-level parity).
 
 Widths that are not a multiple of the 128-lane tile are zero-padded
 and the statistics masked to the true width (tolerance-level parity —
@@ -184,8 +187,8 @@ def _ln_call(x, gamma, beta, eps, interpret, bwd_dy=None):
 
 
 def _params(sem):
-    from ..attention import _PLTPU_COMPILER_PARAMS
-    return _PLTPU_COMPILER_PARAMS(dimension_semantics=(sem,))
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=(sem,))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -217,11 +220,31 @@ def layer_norm(x, gamma, beta, eps: float = 1e-5,
 # ---------------------------------------------------------------------------
 
 _INV_SQRT2PI = 1.0 / math.sqrt(2.0 * math.pi)
+_INV_SQRT2 = 1.0 / math.sqrt(2.0)
+# Abramowitz & Stegun 7.1.26: erfc(x) = poly(t)·exp(-x²) + e(x) for
+# x >= 0, t = 1/(1 + p·x), |e(x)| <= 1.5e-7
+_ERFC_P = 0.3275911
+_ERFC_A = (0.254829592, -0.284496736, 1.421413741, -1.453152027,
+           1.061405429)
+
+
+def _norm_cdf(z):
+    """Φ(z) = erfc(-z/√2)/2 in f32 from primitives Mosaic lowers (exp,
+    divide, select). Evaluating erfc at |z|/√2 and reflecting keeps the
+    negative tail free of the 1 - erf cancellation. Absolute error
+    3e-7 measured over [-12, 12] (half the A&S bound plus f32
+    rounding); tests/test_kernels.py pins GELU within 1e-6."""
+    x = jnp.abs(z) * _INV_SQRT2
+    t = 1.0 / (1.0 + _ERFC_P * x)
+    poly = t * (_ERFC_A[0] + t * (_ERFC_A[1] + t * (_ERFC_A[2] + t * (
+        _ERFC_A[3] + t * _ERFC_A[4]))))
+    half_erfc = 0.5 * poly * jnp.exp(-x * x)
+    return jnp.where(z < 0, half_erfc, 1.0 - half_erfc)
 
 
 def _bg_fwd_kernel(x_ref, b_ref, o_ref):
-    z = x_ref[...] + b_ref[...]
-    o_ref[...] = jax.nn.gelu(z, approximate=False).astype(o_ref.dtype)
+    z = (x_ref[...] + b_ref[...]).astype(jnp.float32)
+    o_ref[...] = (z * _norm_cdf(z)).astype(o_ref.dtype)
 
 
 def _bg_bwd_kernel(c, cp, x_ref, b_ref, dy_ref, dx_ref, db_ref, db_s):
@@ -235,8 +258,7 @@ def _bg_bwd_kernel(c, cp, x_ref, b_ref, dy_ref, dx_ref, db_ref, db_s):
     dy = dy_ref[...].astype(jnp.float32)
     # dGELU(z) = Phi(z) + z * phi(z) (exact-erf form)
     phi = jnp.exp(-0.5 * z * z) * _INV_SQRT2PI
-    cdf = 0.5 * (1.0 + lax.erf(z / jnp.sqrt(jnp.float32(2.0))))
-    dx = dy * (cdf + z * phi)
+    dx = dy * (_norm_cdf(z) + z * phi)
     valid = _col_valid(c, cp)
     if valid is not None:
         dx = jnp.where(valid, dx, 0.0)

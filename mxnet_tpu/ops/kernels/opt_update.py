@@ -124,7 +124,13 @@ def _opt_kernel(kind, cfg, vec, n_states, part, *refs):
     if vec:
         lr, wd, t = lr_ref[...], wd_ref[...], t_ref[...]
     else:
-        lr, wd, t = lr_ref[0, 0], wd_ref[0, 0], t_ref[0, 0]
+        # t as one lane row: Adam's beta ** t is a vector pow (the
+        # scalar core has no transcendental unit)
+        lr, wd = lr_ref[0, 0], wd_ref[0, 0]
+        t = jnp.full((1, _LANES), t_ref[0, 0])
+    # float t: Mosaic's pow takes floating operands only; XLA converts
+    # the int32 exponent the same way, so the paths stay bit-identical
+    t = t.astype(jnp.float32)
     states = tuple(s[...] for s in state_refs)
     body_cfg = dict(cfg, states=states)
     args = (w_ref[...], g_ref[...], lr, wd, t, rs_ref[0, 0],
@@ -203,8 +209,8 @@ def unit_update(kind: str, cfg: dict, w, g, lr, wd, t, rescale, clip,
 
 
 def _parallel_params():
-    from ..attention import _PLTPU_COMPILER_PARAMS
-    return _PLTPU_COMPILER_PARAMS(dimension_semantics=("parallel",))
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def opt_kernel_kind(opt) -> Optional[tuple]:

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import dispatch, vmem_tile_budget
+from . import (VMEM_BYTES_PER_CORE, VMEM_SCOPED_DEFAULT_BYTES, dispatch,
+               vmem_tile_budget)
 
 __all__ = ["rnn_scan", "rnn_decode_step", "rnn_verify_scan",
            "scan_supported"]
@@ -59,33 +60,55 @@ def _pad_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _block_t(seq: int, np_: int, g: int, hp: int, itemsize: int,
-             interpret: bool) -> int:
-    """Timesteps per grid step. On TPU: the ``kernels.rnn_block_t``
-    tunable when set (autotune override; 0 = auto), else sized so the
-    CONCURRENT per-step tiles (xw in, ys/cs out, plus the backward's
-    dys/dxw/hprev set — budgeted as ~2 gate-wide + 6 hidden-wide
-    tiles) fit the shared VMEM tile budget (``vmem_tile_budget()`` —
-    ops.attention._head_group sizes against the same accessor). In
-    interpret mode: 1, so the grid loop mirrors the lax.scan
-    reference's one-step body structure — that is what makes the fp32
-    forward BIT-identical (XLA re-fuses a multi-step unrolled body
-    differently, which costs a ulp)."""
-    if _FORCE_BLOCK_T is not None:
-        return int(min(_FORCE_BLOCK_T, max(1, seq)))
+def _vmem_plan(seq: int, np_: int, g: int, hp: int, itemsize: int,
+               interpret: bool):
+    """→ ``(block_t, vmem_limit_bytes)``, or None when the weights plus
+    ONE timestep of tiles do not fit VMEM (``scan_supported`` turns that
+    into the XLA tier with the reason).
+
+    Sized once, from the BACKWARD call — the forward streams a subset
+    of its operands, and both must agree on block_t because the saved
+    trajectories are padded to it. Counted, all resident for the whole
+    grid: ``w_hh`` in and ``dW`` out (Pallas double-buffers every
+    blocked operand, constant index map or not), the f32 ``dW``
+    scratch, the (N, H) carry blocks, and the f32 gate temporaries of
+    one unrolled step. What is left of three quarters of the physical
+    VMEM (the rest is Mosaic's own scratch) goes to the double-buffered
+    per-timestep tiles — xw in, dxw out, hprev/cprev/cs/dys, budgeted
+    as 2 gate-wide + 6 hidden-wide — capped by the shared tile budget
+    (``vmem_tile_budget()``; ops.attention._head_group sizes against
+    the same accessor) and by the ``kernels.rnn_block_t`` tunable when
+    set. In interpret mode: block 1, so the grid loop mirrors the
+    lax.scan reference's one-step body structure — that is what makes
+    the fp32 forward BIT-identical (XLA re-fuses a multi-step unrolled
+    body differently, which costs a ulp)."""
     if interpret:
-        return 1
+        bt = _FORCE_BLOCK_T if _FORCE_BLOCK_T is not None else 1
+        return int(min(bt, max(1, seq))), None
+    per_step = np_ * (2 * g * hp + 6 * hp) * itemsize
+    resident = ((4 * itemsize + 4) * g * hp * hp
+                + 16 * np_ * hp * 4 + 8 * np_ * g * hp * 4)
+    fit = (VMEM_BYTES_PER_CORE * 3 // 4 - resident) // (2 * per_step)
+    if fit < 1:
+        return None
     from ...tuning import space as _tspace
-    tuned = _tspace.value("kernels.rnn_block_t", 0)
     try:
-        tuned = int(tuned)
+        tuned = int(_tspace.value("kernels.rnn_block_t", 0))
     except (TypeError, ValueError):
         tuned = 0
-    if tuned > 0:
-        return int(min(tuned, _MAX_BLOCK_T, max(1, seq)))
-    per_step = np_ * (2 * g * hp + 6 * hp) * itemsize
-    bt = max(1, vmem_tile_budget() // max(1, per_step))
-    return int(min(bt, _MAX_BLOCK_T, max(1, seq)))
+    if _FORCE_BLOCK_T is not None:
+        tuned = _FORCE_BLOCK_T
+    bt = tuned if tuned > 0 else max(1, vmem_tile_budget() // per_step)
+    bt = int(min(bt, fit, _MAX_BLOCK_T, max(1, seq)))
+    limit = resident + 2 * bt * per_step + 4 * 1024 * 1024
+    return bt, max(VMEM_SCOPED_DEFAULT_BYTES, limit)
+
+
+def _geometry(n: int, gh: int, mode: str, dtype):
+    """(g, h, hp, np_) — the padded layout of one call."""
+    g = _GATES[mode]
+    h = gh // g
+    return g, h, _pad_to(h, 128), _pad_to(n, _sublane(dtype))
 
 
 def scan_supported(xw, h0, c0, mode: str) -> Optional[str]:
@@ -97,6 +120,12 @@ def scan_supported(xw, h0, c0, mode: str) -> Optional[str]:
         return f"dtype {xw.dtype} not kernelized (f32/bf16 only)"
     if xw.ndim != 3 or xw.shape[0] < 1:
         return "expects (T, N, G*H) with T >= 1"
+    t, n, gh = xw.shape
+    g, _, hp, np_ = _geometry(n, gh, mode, xw.dtype)
+    if _vmem_plan(t, np_, g, hp, jnp.dtype(xw.dtype).itemsize,
+                  False) is None:
+        return (f"w_hh ({g}x{hp}x{hp}) with its dW accumulators and one "
+                "timestep of tiles exceed VMEM")
     return None
 
 
@@ -207,6 +236,16 @@ def _bwd_step(mode, xw_t, h_prev, c_prev, c_new, y, hw, b, dy,
 # Pallas kernels
 # ---------------------------------------------------------------------------
 
+def _dot_f32(a, b, ca: int, cb: int):
+    """MXU dot contracting ``a`` dim ``ca`` with ``b`` dim ``cb``:
+    operands in the weight dtype (bf16 under AMP), f32 accumulation —
+    Mosaic rejects a narrower accumulator, and the gate math downstream
+    then runs in f32 and is cast once, on store. For f32 operands this
+    is the plain dot, so fp32 bit parity with the reference holds."""
+    return lax.dot_general(a.astype(b.dtype), b, (((ca,), (cb,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(mode, block_t, *refs):
     from jax.experimental import pallas as pl
     lstm = mode == "lstm"
@@ -227,12 +266,14 @@ def _fwd_kernel(mode, block_t, *refs):
     b = b_ref[...]                          # (1, G*Hp)
     for i in range(block_t):
         h = h_s[...]
-        hw = lax.dot_general(h, w, (((1,), (1,)), ((), ())))
+        hw = _dot_f32(h, w, 1, 1)
         h_new, c_new = _fwd_step(mode, xw_ref[i], h,
                                  c_s[...] if lstm else None, hw, b)
+        h_new = h_new.astype(h_s.dtype)
         h_s[...] = h_new
         ys_ref[i] = h_new
         if lstm:
+            c_new = c_new.astype(c_s.dtype)
             c_s[...] = c_new
             cs_ref[i] = c_new
 
@@ -262,7 +303,7 @@ def _bwd_kernel(mode, block_t, nt, seq, *refs):
     b = b_ref[...]
     for i in reversed(range(block_t)):
         h_prev = hp_ref[i]
-        hw = lax.dot_general(h_prev, w, (((1,), (1,)), ((), ())))
+        hw = _dot_f32(h_prev, w, 1, 1)
         dc_in = None
         if lstm:
             # c_T's cotangent seeds the reverse carry exactly at step
@@ -281,12 +322,11 @@ def _bwd_kernel(mode, block_t, nt, seq, *refs):
             dh_s[...], dc_in)
         dxw_ref[i] = dxw.astype(dxw_ref.dtype)
         # dh through the h2h matmul: dgates @ W (contract gate dim)
-        dh_mat = lax.dot_general(dhw, w, (((1,), (0,)), ((), ())))
+        dh_mat = _dot_f32(dhw, w, 1, 0)
         dh_s[...] = dh_dir + dh_mat if dh_dir is not None else dh_mat
         if lstm:
-            dc_s[...] = dc_new
-        dw_s[...] += lax.dot_general(dhw, h_prev,
-                                     (((0,), (0,)), ((), ())))
+            dc_s[...] = dc_new.astype(dc_s.dtype)
+        dw_s[...] += _dot_f32(dhw, h_prev, 0, 0)
         db_s[...] += jnp.sum(dhw, axis=0, keepdims=True)
 
     dh0_ref[...] = dh_s[...].astype(dh0_ref.dtype)
@@ -296,19 +336,17 @@ def _bwd_kernel(mode, block_t, nt, seq, *refs):
         dc0_ref[...] = dc_s[...].astype(dc0_ref.dtype)
 
 
-def _compiler_params():
-    from ..attention import _PLTPU_COMPILER_PARAMS
-    return _PLTPU_COMPILER_PARAMS(dimension_semantics=("arbitrary",))
+def _compiler_params(vmem_limit):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                vmem_limit_bytes=vmem_limit)
 
 
 def _padded_operands(xw, h0, c0, w_hh, b_hh, mode, interpret):
     t, n, gh = xw.shape
-    g = _GATES[mode]
-    h = gh // g
-    hp = _pad_to(h, 128)
-    np_ = _pad_to(n, _sublane(xw.dtype))
-    bt = _block_t(t, np_, g, hp, jnp.dtype(xw.dtype).itemsize,
-                  interpret)
+    g, h, hp, np_ = _geometry(n, gh, mode, xw.dtype)
+    bt, vmem_limit = _vmem_plan(t, np_, g, hp,
+                                jnp.dtype(xw.dtype).itemsize, interpret)
     tp = _pad_to(t, bt)
     xw_p = _pad_gated(jnp.pad(xw, ((0, tp - t), (0, np_ - n), (0, 0))),
                       g, h, hp, axis=2)
@@ -318,7 +356,8 @@ def _padded_operands(xw, h0, c0, w_hh, b_hh, mode, interpret):
     h0_p = jnp.pad(h0, ((0, np_ - n), (0, hp - h)))
     c0_p = jnp.pad(c0, ((0, np_ - n), (0, hp - h))) \
         if c0 is not None else None
-    return xw_p, h0_p, c0_p, w_p, b_p, (t, n, g, h, hp, np_, bt, tp)
+    return xw_p, h0_p, c0_p, w_p, b_p, (t, n, g, h, hp, np_, bt, tp,
+                                        vmem_limit)
 
 
 def _scan_fwd_pallas(xw, h0, c0, w_hh, b_hh, mode, interpret):
@@ -327,7 +366,7 @@ def _scan_fwd_pallas(xw, h0, c0, w_hh, b_hh, mode, interpret):
     from jax.experimental.pallas import tpu as pltpu
     xw_p, h0_p, c0_p, w_p, b_p, geo = _padded_operands(
         xw, h0, c0, w_hh, b_hh, mode, interpret)
-    t, n, g, h, hp, np_, bt, tp = geo
+    t, n, g, h, hp, np_, bt, tp, vmem_limit = geo
     lstm = mode == "lstm"
     dt = xw.dtype
 
@@ -351,7 +390,7 @@ def _scan_fwd_pallas(xw, h0, c0, w_hh, b_hh, mode, interpret):
         grid=(tp // bt,),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(*operands)
     return list(outs), geo
@@ -363,7 +402,7 @@ def _scan_bwd_pallas(res, dys, dct, mode, interpret):
     xw, h0, c0, w_hh, b_hh, ys_p, cs_p = res
     xw_p, h0_p, c0_p, w_p, b_p, geo = _padded_operands(
         xw, h0, c0, w_hh, b_hh, mode, interpret)
-    t, n, g, h, hp, np_, bt, tp = geo
+    t, n, g, h, hp, np_, bt, tp, vmem_limit = geo
     lstm = mode == "lstm"
     dt = xw.dtype
     nt = tp // bt
@@ -406,7 +445,7 @@ def _scan_bwd_pallas(res, dys, dct, mode, interpret):
         grid=(nt,),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(*operands)
     if lstm:
@@ -496,12 +535,12 @@ def _decode_kernel(mode, *refs):
     h = h0_ref[...]
     w = w_ref[...]
     b = b_ref[...]
-    hw = lax.dot_general(h, w, (((1,), (1,)), ((), ())))
+    hw = _dot_f32(h, w, 1, 1)
     h_new, c_new = _fwd_step(mode, xw_ref[...], h,
                              c0_ref[...] if lstm else None, hw, b)
-    hy_ref[...] = h_new
+    hy_ref[...] = h_new.astype(hy_ref.dtype)
     if lstm:
-        cy_ref[...] = c_new
+        cy_ref[...] = c_new.astype(cy_ref.dtype)
 
 
 def _decode_pallas(xw, h, c, w_hh, b_hh, mode, interpret):

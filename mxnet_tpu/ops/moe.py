@@ -101,9 +101,7 @@ def moe_ffn(x, gate_w, w1, w2, top_k: int = 2, capacity_factor: float = 1.25,
         out = jnp.einsum("nec,ecd->nd", combine, expert_out)
         return out, aux
 
-    # lax.psum(1, axis) == axis size on every jax version (lax.axis_size
-    # only exists in newer releases)
-    ep = lax.psum(1, axis_name)
+    ep = lax.axis_size(axis_name)
     e_local = w1.shape[0]
     e = e_local * ep
     # capacity per (expert, source shard): each source device may route up

@@ -95,7 +95,7 @@ def list_ops() -> List[str]:
 
 try:
     from jax._src.core import trace_state_clean as _trace_state_clean
-except ImportError:  # future jax relayout: annotate unconditionally
+except ImportError:  # private symbol moved: annotate unconditionally
     def _trace_state_clean():
         return False
 

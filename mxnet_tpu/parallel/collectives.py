@@ -30,24 +30,14 @@ def _get_mesh(mesh):
 
 
 def shard_map(fn, mesh, in_spec, out_spec):
-    """Version-compat ``shard_map`` with value-based replication checks
-    off (check_vma/check_rep: e.g. a tiled all_gather's output IS
-    replicated over the axis but the varying-axis inference can't prove
-    it; numerics are asserted in tests/test_parallel.py instead). Resolves
-    ``jax.shard_map`` (new jax) or ``jax.experimental.shard_map`` (<=0.4.x)
-    and whichever check kwarg that version spells. Accepts a DeviceMesh or
-    a raw jax Mesh — the supported entry point for user/example code."""
+    """``jax.shard_map`` with the varying-axis check off (e.g. a tiled
+    all_gather's output IS replicated over the axis but the inference
+    can't prove it; numerics are asserted in tests/test_parallel.py
+    instead). Accepts a DeviceMesh or a raw jax Mesh — the supported
+    entry point for user/example code."""
     raw = mesh.mesh if isinstance(mesh, DeviceMesh) else mesh
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    for check_kwarg in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return impl(fn, mesh=raw, in_specs=in_spec,
-                        out_specs=out_spec, **check_kwarg)
-        except TypeError:  # this jax spells the check kwarg differently
-            continue
-    raise MXNetError("no usable shard_map in this jax version")
+    return jax.shard_map(fn, mesh=raw, in_specs=in_spec,
+                         out_specs=out_spec, check_vma=False)
 
 
 _shard_map = shard_map  # internal alias (pre-existing call sites)

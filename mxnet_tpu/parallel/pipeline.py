@@ -50,7 +50,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, axis_name="pp",
     ``MXNET_PIPELINE_DOUBLE_BUFFER`` env default)."""
     if double_buffer is None:
         double_buffer = _double_buffer_default()
-    pp = lax.psum(1, axis_name)  # axis size (lax.axis_size needs newer jax)
+    pp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m_count = microbatches.shape[0]
     mb_shape = microbatches.shape[1:]
@@ -84,17 +84,8 @@ def pipeline_apply(stage_fn, stage_params, microbatches, axis_name="pp",
 
     def _varying(a):
         # the ring carry differs per device; mark the initial zeros as
-        # pp-varying so scan's carry types line up (JAX VMA tracking).
-        # jax versions without pcast/pvary have no VMA tracking (we run
-        # shard_map with the replication check off) — identity is correct.
-        for name, kw in (("pcast", {"to": "varying"}), ("pvary", {})):
-            fn = getattr(lax, name, None)
-            if fn is not None:
-                try:
-                    return fn(a, (axis_name,), **kw)
-                except TypeError:
-                    continue
-        return a
+        # pp-varying so scan's carry types line up (JAX VMA tracking)
+        return lax.pcast(a, (axis_name,), to="varying")
 
     init = (_varying(jnp.zeros(mb_shape, microbatches.dtype)),
             _varying(jnp.zeros(mb_shape, microbatches.dtype)),

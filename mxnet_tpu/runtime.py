@@ -18,70 +18,66 @@ _LOG = logging.getLogger("mxnet_tpu.runtime")
 # persistent-compilation-cache hit/miss census (setup_compile_cache)
 _CACHE_STATS = {"enabled": False, "dir": None, "hits": 0, "misses": 0}
 
+#: where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` does not
+#: place it: one fixed path in the checkout. The directory is part of
+#: the cache key, so a temp name, pid or time here would never hit.
+_FIXED_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
-def setup_compile_cache() -> bool:
-    """Arm JAX's persistent compilation cache behind
-    ``MXNET_COMPILE_CACHE=<dir>`` (docs/ENV_VARS.md).
 
-    Every compiled program — bench warmups, ``Trainer.compile_step``
-    shape buckets, ``hybridize()`` traces — is keyed and written to the
-    directory, so a RESTART (or the next bench leg with the same shapes)
-    loads the executable from disk instead of paying the full 10–12s
-    XLA recompile. Hits and misses are counted (via jax.monitoring's
-    ``/jax/compilation_cache/*`` events) and logged at compile time;
-    read the totals with :func:`compile_cache_stats`.
+def setup_compile_cache() -> str:
+    """Arm JAX's persistent compilation cache and return its directory.
 
-    Returns True when the cache was armed. Called once from
-    ``mxnet_tpu/__init__`` — safe to call again (idempotent).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its
+    cache there and no code sets another; unset, the cache goes to
+    ``<checkout>/.jax_cache`` (git-ignored). Every compiled program —
+    ``Trainer.compile_step`` shape buckets, serving/decode AOT warmups,
+    ``hybridize()`` traces — is keyed and written there, so a RESTART
+    (or a second process with the same shapes) loads the executable
+    from disk instead of recompiling. Hits and misses are counted
+    (jax.monitoring's ``/jax/compilation_cache/*`` events) and logged at
+    compile time; read the totals with :func:`compile_cache_stats`.
+
+    Called once from ``mxnet_tpu/__init__`` — safe to call again
+    (idempotent).
     """
-    cache_dir = os.environ.get("MXNET_COMPILE_CACHE")
-    if not cache_dir:
-        return False
     if _CACHE_STATS["enabled"]:
-        return True
+        return _CACHE_STATS["dir"]
     import jax
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _FIXED_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache EVERYTHING: the default floors (1s compile time / 4KB entry)
     # would skip exactly the many small programs eager-op dispatch and
     # tiny tests pay for repeatedly
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:       # pragma: no cover - knob renamed upstream
-            pass
-    try:
-        from jax._src import monitoring as _mon
-        from .telemetry import names as _tnames
-        from .telemetry.registry import default as _treg
-        _hits = _treg().counter(_tnames.COMPILE_CACHE_HITS)
-        _misses = _treg().counter(_tnames.COMPILE_CACHE_MISSES)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-        def _on_event(event: str, **kwargs):
-            if event == "/jax/compilation_cache/cache_hits":
-                _CACHE_STATS["hits"] += 1
-                _hits.inc()
-                _LOG.info("compile cache HIT (%d so far) [%s]",
-                          _CACHE_STATS["hits"], cache_dir)
-            elif event == "/jax/compilation_cache/cache_misses":
-                _CACHE_STATS["misses"] += 1
-                _misses.inc()
-                _LOG.info("compile cache MISS (%d so far) — compiling, "
-                          "will persist to %s",
-                          _CACHE_STATS["misses"], cache_dir)
+    from .telemetry import names as _tnames
+    from .telemetry.registry import default as _treg
+    _hits = _treg().counter(_tnames.COMPILE_CACHE_HITS)
+    _misses = _treg().counter(_tnames.COMPILE_CACHE_MISSES)
 
-        _mon.register_event_listener(_on_event)
-    except Exception:           # pragma: no cover - private API moved
-        _LOG.warning("MXNET_COMPILE_CACHE: hit/miss telemetry "
-                     "unavailable (jax.monitoring API changed); the "
-                     "cache itself is still armed")
+    def _on_event(event: str, **kwargs):
+        if event == "/jax/compilation_cache/cache_hits":
+            _CACHE_STATS["hits"] += 1
+            _hits.inc()
+            _LOG.info("compile cache HIT (%d so far) [%s]",
+                      _CACHE_STATS["hits"], cache_dir)
+        elif event == "/jax/compilation_cache/cache_misses":
+            _CACHE_STATS["misses"] += 1
+            _misses.inc()
+            _LOG.info("compile cache MISS (%d so far) — compiling, "
+                      "will persist to %s",
+                      _CACHE_STATS["misses"], cache_dir)
+
+    jax.monitoring.register_event_listener(_on_event)
     _CACHE_STATS["enabled"] = True
     _CACHE_STATS["dir"] = cache_dir
-    _LOG.info("persistent compilation cache armed at %s "
-              "(MXNET_COMPILE_CACHE)", cache_dir)
-    return True
+    _LOG.info("persistent compilation cache armed at %s", cache_dir)
+    return cache_dir
 
 
 def compile_cache_stats() -> dict:

@@ -1,7 +1,7 @@
 """mx.serving — production inference serving engine (docs/SERVING.md).
 
 The millions-of-users half of the north star: the training substrate
-(AOT lowering + ``MXNET_COMPILE_CACHE``, the dispatch window, the
+(AOT lowering + the persistent compile cache, the dispatch window, the
 telemetry catalog, the program-lint gates) turned into a serving path.
 
 - :class:`CompiledPredictor` — AOT-compiled inference executables per

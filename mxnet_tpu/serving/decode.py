@@ -11,7 +11,7 @@ STEP boundary instead:
   batch BETWEEN decode steps. The compiled step is shape-stable over a
   fixed ladder of slot-count buckets (``decode.slot_ladder`` /
   ``MXNET_DECODE_SLOTS``; AOT-compiled, warm-started from
-  ``MXNET_COMPILE_CACHE``) with a per-slot active mask; a slot freed by
+  persistent compile cache) with a per-slot active mask; a slot freed by
   EOS/max-tokens is refilled from the queue on the next iteration.
 - **Paged KV cache.** K/V history lives in :class:`~mxnet_tpu.serving
   .kvcache.PagedKVCache` pages behind a (slots, max_pages) page-table
@@ -960,7 +960,7 @@ class DecodeEngine:
     def warmup(self, buckets: Optional[Sequence[int]] = None) -> dict:
         """AOT-compile the decode + prefill program of every ladder
         bucket (``.lower().compile()``, warm-started from the
-        persistent ``MXNET_COMPILE_CACHE``) so no request ever eats a
+        persistent compile cache) so no request ever eats a
         first-iteration compile. Returns {(kind, bucket): executable}."""
         out = {}
         kinds = ("decode", "prefill", "verify") if self._spec_k > 0 \
@@ -1688,6 +1688,9 @@ def run_decode(model, prompts, max_new, *, static: bool = False,
             "kv_num_pages": eng.kv.num_pages,
             "slot_ladder": list(eng._ladder),
             "page_size": ps,
+            # what each request emitted: the speculative path's contract
+            # is token-for-token equality with plain greedy
+            "token_ids": [[int(t) for t in s.result(0)] for s in streams],
         }
         if eng._spec_k:
             st = eng.stats

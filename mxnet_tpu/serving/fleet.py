@@ -3,7 +3,7 @@
 One :class:`~mxnet_tpu.serving.ServingSupervisor` keeps one replica
 alive; this module runs a FLEET of them — one CompiledPredictor +
 DynamicBatcher + supervisor per device group, all AOT-warmed from the
-shared ``MXNET_COMPILE_CACHE`` (the first replica pays the XLA
+shared persistent compile cache (the first replica pays the XLA
 compiles; every later spawn/restart pays cache hits) — and puts a
 router in front:
 
@@ -306,7 +306,7 @@ class FleetController:
     controller wraps it in ``jax.default_device(<replica device>)`` so
     each replica's params land on its own device, and every replica
     after the first warms its AOT buckets from the shared
-    ``MXNET_COMPILE_CACHE``.
+    persistent compile cache.
 
     ``start=False`` puts every batcher in manual-drive mode (tests):
     drive :meth:`pump`, inject ``clock=``; failover restarts run
@@ -446,6 +446,14 @@ class FleetController:
         return None
 
     def _pinned_build(self, device) -> Callable:
+        """``build`` under ``jax.default_device(device)``. That moves
+        only UNCOMMITTED arrays: parameters and requests made in the
+        default context are committed to local device 0 by
+        ``Context.jax_device`` (CPU rig and TPU host alike), so today
+        every replica computes there and ``device`` is bookkeeping for
+        revocation. One replica per chip needs ``build`` to create its
+        net under ``mx.tpu(i)`` AND the router to place each request on
+        its replica's device; neither exists yet (ROADMAP Reach 7)."""
         base = self._build
         def build():
             import jax

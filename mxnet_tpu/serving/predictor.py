@@ -10,7 +10,7 @@ Julia-to-TPU work (arXiv:1810.09868) applied to the serving path:
   through the same functional ``ParamBinding`` the fused step uses, and
   the resulting program is AOT-lowered and compiled
   (:meth:`CompiledPredictor.aot_compile` / :meth:`warmup`) so the hot
-  loop never pays a jit compile. ``MXNET_COMPILE_CACHE`` warm-starts
+  loop never pays a jit compile. The persistent compile cache warm-starts
   the executables across process restarts — a restarted replica serves
   its first request from the disk cache instead of re-paying XLA.
 - **Params resident on device.** Parameters are passed by handle every
@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from .. import _tape
 from ..analysis import guard as _tguard
 from ..base import MXNetError
-from ..gluon.block import ParamBinding, _TRACED
+from ..gluon.block import UNTRACEABLE_ERRORS, ParamBinding, _TRACED
 from ..gluon.fused_step import _analysis_mode
 from ..ndarray.ndarray import NDArray
 from ..ndarray.random import next_key, push_trace_key, pop_trace_key
@@ -288,12 +288,14 @@ class CompiledPredictor:
             else:
                 try:
                     out = self._fused_call(args, kwargs)
-                except Exception as e:
+                except UNTRACEABLE_ERRORS as e:
+                    # only an untraceable forward demotes; lowering,
+                    # compile and runtime errors propagate
                     if self._requests_done:
                         raise   # proven program: a genuine error
                     _LOG.warning(
-                        "CompiledPredictor: trace failed (%s: %s); "
-                        "falling back to the eager forward",
+                        "CompiledPredictor: forward is not traceable "
+                        "(%s: %s); falling back to the eager forward",
                         type(e).__name__, e)
                     self._mode = "eager"
                     out = self._eager_call(args, kwargs)
@@ -325,8 +327,8 @@ class CompiledPredictor:
     # ---------------- AOT ----------------
     def aot_compile(self, *args, **kwargs):
         """Lower + compile this batch's bucket ahead of time and pin
-        the executable (warm-started from ``MXNET_COMPILE_CACHE`` when
-        armed); returns XLA's flop count for the program, or None where
+        the executable (warm-started from the persistent compile cache);
+        returns XLA's flop count for the program, or None where
         cost_analysis is unavailable."""
         if self._mode == "eager":
             return None
@@ -338,11 +340,6 @@ class CompiledPredictor:
         n_before = self._n_traces
         try:
             exe = entry["fn"].lower(pds, leaf_datas, next_key()).compile()
-        except Exception as e:   # pragma: no cover - platform-dependent
-            _LOG.warning("CompiledPredictor: AOT lower/compile "
-                         "unavailable (%s); falling back to jit",
-                         type(e).__name__)
-            return None
         finally:
             # an AOT lower re-runs the traced python; the live jit call
             # for the same bucket will trace once more — count ONE

@@ -27,7 +27,7 @@ compile cache (arXiv:1810.09868) making predictor rebuilds cheap:
   ``elastic.ElasticSupervisor`` — classifies failures at the dispatch
   and window-retire seams via ``elastic.detect.classify``, rebuilds
   the predictor over ``parallel.dist.available_devices()`` with AOT
-  buckets warm-started from ``MXNET_COMPILE_CACHE``, re-enqueues
+  buckets warm-started from the persistent compile cache, re-enqueues
   in-flight requests exactly once (bounded backoff retries for
   ``transient``; ``fatal``/``oom`` propagate), and drains gracefully
   on SIGTERM/:class:`~mxnet_tpu.elastic.PreemptionNotice`.
@@ -269,7 +269,7 @@ class ServingSupervisor:
     [0])`` so a rebuilt predictor's params land on a surviving device,
     and ``example`` (a tuple of one-row args) is passed to
     ``warmup()`` so every AOT bucket is re-compiled — warm-started
-    from ``MXNET_COMPILE_CACHE``, so recovery pays cache hits, not
+    from the persistent compile cache, so recovery pays cache hits, not
     fresh XLA compiles.
 
     Failure handling (the :func:`~mxnet_tpu.elastic.classify`

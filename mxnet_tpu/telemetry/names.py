@@ -255,7 +255,7 @@ CATALOG = {
         help="cumulative consumer-side wait on staged input, seconds"),
     COMPILE_CACHE_HITS: dict(
         kind="counter", label=None,
-        help="persistent compilation cache hits (MXNET_COMPILE_CACHE)"),
+        help="persistent compilation cache hits"),
     COMPILE_CACHE_MISSES: dict(
         kind="counter", label=None,
         help="persistent compilation cache misses"),
@@ -514,7 +514,7 @@ CATALOG = {
         kind="counter", label="cause",
         help="ServingSupervisor predictor rebuilds by failure cause "
              "(device_lost: re-formed over available_devices with AOT "
-             "buckets warm-started from MXNET_COMPILE_CACHE)"),
+             "buckets warm-started from the compile cache)"),
     SERVING_BREAKER_STATE: dict(
         kind="gauge", label=None,
         help="serving circuit-breaker state: 0 closed (normal), 1 "

@@ -20,7 +20,6 @@ os.environ["XLA_FLAGS"] = " ".join(
     if not f.startswith("--xla_force_host_platform_device_count"))
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as onp  # noqa: E402
 

@@ -133,8 +133,7 @@ def test_known_bad_broken_donation():
 
 def test_known_bad_accidental_f64_upcast():
     """f32 -> f64 widening is an error-severity drift, never blessed."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         jaxpr = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64).sum())(
                 jnp.ones((4,), jnp.float32))

@@ -18,7 +18,8 @@ Acceptance bar, all counter-based (never wall-clock):
   the regression test);
 - metric accumulators run sync-free on device inputs and match the host
   float64 path;
-- MXNET_COMPILE_CACHE arms jax's persistent compilation cache.
+- the persistent compilation cache is armed at import, at
+  JAX_COMPILATION_CACHE_DIR or one fixed path in the checkout.
 """
 import os
 
@@ -491,32 +492,71 @@ def test_metric_host_path_unchanged():
 
 
 # ---------------------------------------------------------------------------
-# persistent compile cache (MXNET_COMPILE_CACHE)
+# persistent compile cache (JAX_COMPILATION_CACHE_DIR, else one fixed path)
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_armed(tmp_path, monkeypatch):
+def _rearm(monkeypatch):
+    """setup_compile_cache() as a fresh import would run it, recording
+    every directory the code itself hands to jax.config."""
     import jax as _jax
     from mxnet_tpu import runtime
-    cache_dir = tmp_path / "xla-cache"
-    monkeypatch.setenv("MXNET_COMPILE_CACHE", str(cache_dir))
     monkeypatch.setitem(runtime._CACHE_STATS, "enabled", False)
-    prev_dir = _jax.config.jax_compilation_cache_dir
-    try:
-        assert runtime.setup_compile_cache() is True
-        stats = runtime.compile_cache_stats()
-        assert stats["enabled"] and stats["dir"] == str(cache_dir)
-        assert _jax.config.jax_compilation_cache_dir == str(cache_dir)
-        assert os.path.isdir(cache_dir)
-        # idempotent re-arm
-        assert runtime.setup_compile_cache() is True
-    finally:
-        # un-pollute process-global jax config for the rest of tier-1
-        _jax.config.update("jax_compilation_cache_dir", prev_dir)
-        runtime._CACHE_STATS.update(enabled=False, dir=None)
+    set_in_code = []
+    real_update = _jax.config.update
+
+    def update(name, value):
+        if name == "jax_compilation_cache_dir":
+            set_in_code.append(value)
+        else:
+            real_update(name, value)
+
+    monkeypatch.setattr(_jax.config, "update", update)
+    # the import's hit/miss listener stays the only one
+    monkeypatch.setattr(_jax.monitoring, "register_event_listener",
+                        lambda cb: None)
+    return runtime.setup_compile_cache(), set_in_code
 
 
-def test_compile_cache_off_without_env(monkeypatch):
+def test_compile_cache_placed_from_outside(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the cache is where JAX put it
+    and no code sets another directory."""
     from mxnet_tpu import runtime
-    monkeypatch.delenv("MXNET_COMPILE_CACHE", raising=False)
-    monkeypatch.setitem(runtime._CACHE_STATS, "enabled", False)
-    assert runtime.setup_compile_cache() is False
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    cache_dir, set_in_code = _rearm(monkeypatch)
+    assert cache_dir == str(tmp_path) and set_in_code == []
+    stats = runtime.compile_cache_stats()
+    assert stats["enabled"] and stats["dir"] == str(tmp_path)
+
+
+def test_compile_cache_fixed_path_without_env(monkeypatch):
+    """Unset, the cache is armed at import at ONE fixed path in the
+    checkout — the path is part of the cache key, so a temp name, pid or
+    time would never hit. Idempotent."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import runtime
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    fixed = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(mx.__file__))), ".jax_cache")
+    cache_dir, set_in_code = _rearm(monkeypatch)
+    assert cache_dir == fixed and set_in_code == [fixed]
+    assert runtime.setup_compile_cache() == fixed and len(set_in_code) == 1
+
+
+def test_compile_cache_armed_by_import_counts_hits():
+    """`import mxnet_tpu` armed the cache (floors zeroed), so a program
+    compiled twice from distinct function objects misses, then hits."""
+    import jax as _jax
+    from mxnet_tpu import runtime
+    assert runtime.compile_cache_stats()["enabled"]
+    assert _jax.config.jax_compilation_cache_dir == \
+        runtime.compile_cache_stats()["dir"]
+    salt = float(onp.random.RandomState().rand())     # a program no
+    x = onp.arange(7, dtype="float32")                # earlier run cached
+    before = runtime.compile_cache_stats()
+    _jax.jit(lambda a: a * salt + 1.5)(x).block_until_ready()
+    mid = runtime.compile_cache_stats()
+    _jax.jit(lambda a: a * salt + 1.5)(x).block_until_ready()
+    after = runtime.compile_cache_stats()
+    assert mid["misses"] == before["misses"] + 1
+    assert after["hits"] == mid["hits"] + 1
+    assert after["misses"] == mid["misses"]

@@ -1,0 +1,140 @@
+"""chip_smoke.py off the chip: what the CPU can still say about it.
+
+- every Pallas kernel LOWERS for the TPU (Mosaic lowering runs on the
+  CPU; compiling does not) at the shapes chip_smoke.py compiles them at —
+  the check that stops a kernel PR from shipping a body only the
+  interpreter accepts;
+- without an accelerator the script exits non-zero and prints no result;
+  a phase that raises makes the run fail;
+- (slow) the phases rehearse end to end at tiny sizes on the virtual
+  8-device mesh with the kernel bodies in interpret mode. A rehearsal is
+  never a pass: it keeps the script's own plumbing from rotting between
+  chip runs.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import numpy as onp
+import pytest
+
+import jax
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from mxnet_tpu.ops import kernels  # noqa: E402
+
+CASES = chip_smoke.kernel_cases()
+
+
+class _ZeroRng:
+    """RandomState's shapes without its work: lowering needs avals, and
+    the largest case is 50M elements."""
+
+    def standard_normal(self, shape):
+        return onp.zeros(shape)
+
+    def rand(self, *shape):
+        return onp.zeros(shape)
+
+    def randint(self, low, high, size):
+        return onp.full(size, low)
+
+
+def test_cases_cover_every_kernel_in_both_dtypes():
+    assert {c.kernel for c in CASES} == set(kernels.KERNELS)
+    for name in set(kernels.KERNELS) - {"opt_update"}:     # flat f32 shards
+        assert {c.dtype for c in CASES if c.kernel == name} == \
+            {"bfloat16", "float32"}
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c.label for c in CASES])
+def test_kernel_lowers_for_tpu(case, monkeypatch):
+    """Forward and backward through Mosaic lowering at the source-cell
+    shape. The dispatch gate is told the backend is a TPU, so the public
+    entry point picks the compiled (not interpreted) kernel, exactly as
+    it will on the chip."""
+    monkeypatch.delenv("MXNET_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+            for a in chip_smoke.case_args(case, _ZeroRng())]
+    cots = [jax.ShapeDtypeStruct(o.shape, "float32") for o in
+            jax.eval_shape(chip_smoke.kernel_outputs(case), *args)]
+    jax.jit(chip_smoke.kernel_program(case)).trace(cots, *args).lower(
+        lowering_platforms=("tpu",))
+    assert kernels.decisions()[case.kernel][0] == "pallas"
+
+
+def test_no_accelerator_exits_nonzero_and_prints_no_result():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        timeout=120)
+    assert proc.returncode not in (0, None)
+    assert proc.stdout == b""
+    assert b"no TPU" in proc.stderr
+
+
+def test_a_phase_that_raises_fails_the_run(capsys):
+    def planted(_):
+        raise RuntimeError("planted")
+
+    report = chip_smoke.run_phases({
+        "fine": lambda _: {"n": 1},
+        "broken": planted,
+        "partly": lambda _: {"failed": ["one"]},
+        "later": lambda r: {"skipped": r["fine"]["n"]}})
+    assert [p["status"] for p in report.values()] == \
+        ["ok", "failed", "failed", "skipped"]
+    assert "RuntimeError: planted" in capsys.readouterr().out
+
+
+def test_last_line_is_the_verdict_and_nothing_else(capsys):
+    """The driver reads the LAST stdout line and takes exactly ``ok`` and
+    ``device`` {platform, kind, count}; the report is the line before."""
+    from collections import namedtuple
+    dev = namedtuple("dev", "platform device_kind")("tpu", "TPU v5 lite")
+    cache = {"dir": "/x", "hits": 3, "misses": 0, "requests": 3}
+    phases = {"device": {"status": "ok", "seconds": 0.0},
+              "dp": {"status": "skipped", "seconds": 0.0}}
+    assert chip_smoke.finish(phases, [dev], cache, True) == 0
+    report, verdict = capsys.readouterr().out.splitlines()
+    assert json.loads(verdict) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert report.endswith('"claim": null}')
+    assert json.loads(report)["compile_cache"] == {
+        "dir": "/x", "hits": 3, "misses": 0}
+
+    phases["serve"] = {"status": "failed", "seconds": 1.0}
+    assert chip_smoke.finish(phases, [dev] * 4, cache, False) == 1
+    verdict = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert verdict == {"ok": False, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 4}}
+
+
+@pytest.mark.slow
+def test_phases_rehearse_on_cpu(monkeypatch):
+    """train -> dp (8 virtual devices) -> kernels -> serve at tiny sizes,
+    kernel bodies in interpret mode."""
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+    with pytest.raises(RuntimeError, match="not one the program knows"):
+        chip_smoke.phase_device()
+    cfg = dict(chip_smoke.TRAIN, batch=8, seq=32, steps=4, vocab=128,
+               bert="bert_small_test",
+               optimizer=("adam", {"learning_rate": 1e-3}))
+    train = chip_smoke.phase_train(cfg)
+    assert train["kernel_paths"]["flash_attention"] == "interpret"
+    dp = chip_smoke.phase_dp(train["loss"], cfg)
+    assert dp["devices"] == 8 and dp["collectives"]["all-gather"]
+    checked = chip_smoke.phase_kernels(tiny=True)
+    assert checked["failed"] == []
+    assert checked["kernel_paths"] == dict.fromkeys(kernels.KERNELS,
+                                                    "interpret")
+    serve = chip_smoke.phase_serve(dict(
+        chip_smoke.SERVE, vocab=64, d_model=32, heads=2, requests=6,
+        ladder=(1, 2, 4), page_size=8))
+    assert set(serve) == {"TinyDecoder", "GQADecoder"}
+    json.dumps([train, dp, checked, serve])

@@ -28,7 +28,7 @@ def test_two_process_smoke(tmp_path):
            "-n", "2", "--launcher", "local", "-p", str(_free_port()),
            sys.executable, worker, str(tmp_path)]
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # launch.py starts >1 local worker only on CPU
     proc = subprocess.run(cmd, cwd=REPO, env=env, timeout=120,
                           stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     out = proc.stdout.decode("utf-8", "replace")

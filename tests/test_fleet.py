@@ -47,23 +47,6 @@ IN, HIDDEN, CLASSES = 16, 32, 4
 BUCKETS = (1, 2, 4)
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _shared_compile_cache(tmp_path_factory):
-    """One MXNET_COMPILE_CACHE for the whole module: the first
-    predictor compiles each bucket once, every later build (and every
-    fleet replica — warm spawn is the product behavior) AOT-warm-starts
-    from it. Fresh dir per interpreter run (reuse across runs is the
-    known segfault trap)."""
-    path = str(tmp_path_factory.mktemp("fleet-compile-cache"))
-    old = os.environ.get("MXNET_COMPILE_CACHE")
-    os.environ["MXNET_COMPILE_CACHE"] = path
-    yield
-    if old is None:
-        os.environ.pop("MXNET_COMPILE_CACHE", None)
-    else:
-        os.environ["MXNET_COMPILE_CACHE"] = old
-
-
 @pytest.fixture(autouse=True)
 def _clean_harness():
     """Every test leaves the chaos harness disarmed, devices restored,
@@ -119,6 +102,19 @@ def pump_until_done(fleet, futs, rounds=50):
             return
         fleet.pump(force=True)
     raise AssertionError("futures did not resolve under pump()")
+
+
+def test_replicas_share_the_compile_cache():
+    """`import mxnet_tpu` armed ONE persistent compile cache (at
+    JAX_COMPILATION_CACHE_DIR, else a fixed path in the checkout): the
+    first replica compiles each bucket, every later build — warm spawn
+    is the product behavior — AOT-warm-starts from it."""
+    from mxnet_tpu import runtime
+    before = runtime.compile_cache_stats()
+    assert before["enabled"]
+    fleet = make_fleet([0.0], n=2)
+    fleet.close()
+    assert runtime.compile_cache_stats()["hits"] > before["hits"]
 
 
 # ---------------------------------------------------------------------------

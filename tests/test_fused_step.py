@@ -199,6 +199,32 @@ def test_compile_step_fallback_rolls_back_update_counts():
     _assert_params_close(net_e, net_f)
 
 
+def test_compile_step_first_call_lowering_error_raises():
+    """Only an UNTRACEABLE loss demotes. A loss that traces but cannot
+    lower or compile — here a Pallas kernel asked to compile for real on
+    a backend that only interprets, the shape of a Mosaic rejection on
+    the chip — raises on the first call: demoting would run (and time)
+    per-op eager dispatch under the fused step's name."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops.kernels import norm as knorm
+    x, y = _batch()
+    loss_blk = gloss.SoftmaxCrossEntropyLoss()
+    net = _build(with_bn=False)
+    trainer = Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1})
+
+    def unlowerable(a, b):
+        out = net(a)._data
+        ones = jnp.ones(out.shape[-1], out.dtype)
+        out = knorm.layer_norm(out, ones, 0 * ones, interpret=False)
+        return loss_blk(NDArray(out), b)
+
+    step = trainer.compile_step(unlowerable)
+    with pytest.raises(ValueError, match="interpret mode"):
+        step(x, y)
+    assert step.mode == "fused" and step._steps_done == 0
+
+
 def test_compile_step_sparse_grad_falls_back():
     """Embedding with sparse_grad takes the lazy row path — compile_step
     must route to the eager loop, and training must still work."""

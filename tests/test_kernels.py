@@ -89,6 +89,34 @@ def test_dispatch_tiers_on_cpu(monkeypatch):
     assert K.decisions()["rnn_scan"] == (path, reason)
 
 
+def test_tpu_tier_leaves_gspmd_partitioned_programs(monkeypatch):
+    """Mosaic lowers into a single-device program or a shard_map body,
+    never into one GSPMD partitions ("Mosaic kernels cannot be
+    automatically partitioned", four v5e chips, PR 21): on a TPU the
+    gate answers a trace under an active multi-device mesh with the XLA
+    tier and says why; inside shard_map, and with no mesh, it compiles."""
+    from jax.sharding import PartitionSpec as P
+    from mxnet_tpu.parallel import shard_map
+    monkeypatch.delenv("MXNET_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert K.dispatch("layernorm")[0] == "pallas"
+    seen = []
+    with make_mesh({"dp": 4}, jax.devices()[:4]) as mesh:
+        path, reason = K.dispatch("layernorm")
+        assert path == "xla" and "GSPMD" in reason and "4 devices" in reason
+        assert set(K.dispatch_table().values()) == {"xla"}
+
+        def body(x):
+            seen.append(K.dispatch("layernorm")[0])
+            return x
+
+        jax.jit(shard_map(body, mesh, (P("dp"),), P("dp"))).trace(
+            jnp.zeros(8))
+    assert seen == ["pallas"]
+    with make_mesh({"dp": 1}, jax.devices()[:1]):
+        assert K.dispatch("layernorm")[0] == "pallas"
+
+
 def test_dispatch_table_covers_all_kernels(monkeypatch):
     monkeypatch.setenv("MXNET_PALLAS", "on")
     table = K.dispatch_table()
@@ -206,17 +234,32 @@ def test_scan_grid_edge_block_t(monkeypatch, mode, T):
 
 @pytest.mark.parametrize("mode", ["lstm", "gru"])
 def test_scan_bf16_tolerance(monkeypatch, mode):
+    """bf16 operands: the kernel accumulates its dots and runs the gate
+    math in f32 (Mosaic takes no narrower accumulator) and rounds once,
+    on store; the lax.scan reference rounds every op to bf16. So the two
+    no longer agree bit for bit — the pin is that the kernel is never
+    FURTHER from the f32 oracle (same inputs upcast) than the all-bf16
+    reference is, forward and backward."""
     monkeypatch.setenv("MXNET_PALLAS", "on")
     args = _rnn_args(mode, dtype="bfloat16")
-    ys_r, h_r, c_r = rnn_ops.scan_reference(*args, mode)
-    ys_k, h_k, c_k = krnn.rnn_scan(*args, mode)
-    assert bool((ys_r == ys_k).all())      # fwd even bit-matches
+    oracle = tuple(None if a is None else a.astype(jnp.float32)
+                   for a in args)
+
+    def err(got, want):
+        return float(onp.abs(onp.asarray(got, onp.float32)
+                             - onp.asarray(want)).max())
+
+    ys_o = rnn_ops.scan_reference(*oracle, mode)[0]
+    ys_r = rnn_ops.scan_reference(*args, mode)[0]
+    ys_k = krnn.rnn_scan(*args, mode)[0]
+    assert ys_k.dtype == jnp.bfloat16
+    assert err(ys_k, ys_o) <= err(ys_r, ys_o)
+    go = _grads(rnn_ops.scan_reference, mode, False, oracle)
     gr = _grads(rnn_ops.scan_reference, mode, False, args)
     gk = _grads(krnn.rnn_scan, mode, False, args)
-    for a, b in zip(gr, gk):
-        onp.testing.assert_allclose(
-            onp.asarray(a, onp.float32), onp.asarray(b, onp.float32),
-            rtol=0.05, atol=0.5)
+    for o, r, k in zip(go, gr, gk):
+        assert k.dtype == jnp.bfloat16
+        assert err(k, o) <= err(r, o)
 
 
 def test_fused_rnn_layer_parity_through_gate(monkeypatch):
@@ -332,13 +375,21 @@ def test_layer_norm_op_dispatches(monkeypatch):
     FNN.layer_norm(x, jnp.ones(6), jnp.zeros(6), axis=0)
 
 
-def test_bias_gelu_fwd_bit_exact_and_bwd():
+def test_bias_gelu_fwd_error_bound_and_bwd():
+    """The kernel builds erfc from exp (Mosaic lowers neither erf nor
+    erfc): f32 GELU must sit within 1e-6 ABSOLUTE of the exact form
+    (float64 erfc) over the whole active range, tails included — the
+    same distance ``jax.nn.gelu`` itself keeps."""
+    import math
     r = onp.random.RandomState(3)
-    x = jnp.asarray(r.randn(4, 16, 256).astype("f4"))
+    x = jnp.asarray((4.0 * r.randn(4, 16, 256)).astype("f4"))
     b = jnp.asarray(r.randn(256).astype("f4"))
-    ref = jax.nn.gelu(x + b, approximate=False)
+    z = onp.asarray(x + b, "f8")
+    exact = z * 0.5 * onp.vectorize(math.erfc)(-z / math.sqrt(2.0))
     ker = knorm.bias_gelu(x, b, interpret=True)
-    assert bool((ref == ker).all())
+    assert onp.abs(onp.asarray(ker, "f8") - exact).max() <= 1e-6
+    assert onp.abs(onp.asarray(ker) - onp.asarray(jax.nn.gelu(
+        x + b, approximate=False))).max() <= 1e-6
     gr = jax.grad(lambda x, b: jnp.sum(jnp.cos(jax.nn.gelu(
         x + b, approximate=False))), argnums=(0, 1))(x, b)
     gk = jax.grad(lambda x, b: jnp.sum(jnp.cos(knorm.bias_gelu(

@@ -395,3 +395,18 @@ def test_png_colorspace_chunks_route_to_pil():
     assert _native_jpeg_decode(tagged, 1) is None
     pil = onp.asarray(Image.open(io.BytesIO(tagged)).convert("RGB"))
     onp.testing.assert_array_equal(imdecode(tagged).asnumpy(), pil)
+
+
+def test_failed_build_with_stale_library_is_an_error(monkeypatch):
+    """A library `make` could not vouch for is not a fallback: with
+    build/libmxt_native.so present and the build failing, get_lib()
+    raises; with no library at all it reports unavailable (the
+    pure-Python paths take over)."""
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_load_failed", False)
+    monkeypatch.setattr(_native, "_build_lib", lambda: False)
+    assert os.path.exists(_native._LIB_PATH)
+    with pytest.raises(MXNetError, match="refusing to load"):
+        _native.get_lib()
+    monkeypatch.setattr(_native, "_lib_mtime", lambda: None)
+    assert _native.get_lib() is None

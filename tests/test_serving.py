@@ -295,6 +295,41 @@ def test_guarded_serving_run_zero_unblessed_syncs(pred, monkeypatch):
         "serving hot loop performed an unblessed NDArray host sync"
 
 
+def test_first_call_lowering_error_raises_untraceable_demotes():
+    """CompiledPredictor demotes to the eager forward only for a forward
+    that cannot be TRACED; one that traces but cannot lower or compile
+    (a Pallas kernel compiled for real on a backend that only
+    interprets — the shape of a Mosaic rejection on the chip) raises on
+    the first call, from predict and from the AOT warmup alike."""
+    from mxnet_tpu.gluon import HybridBlock
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops.kernels import norm as knorm
+
+    class Unlowerable(HybridBlock):
+        def forward(self, x):
+            ones = jax.numpy.ones(x.shape[-1], x._data.dtype)
+            return NDArray(knorm.layer_norm(x._data, ones, 0 * ones,
+                                            interpret=False))
+
+    class Untraceable(HybridBlock):
+        def forward(self, x):
+            return x * float(x.asnumpy().sum())
+
+    x = mx.nd.array(rows(1))
+    p = serving.CompiledPredictor(Unlowerable(), bucket_sizes=(1,))
+    with pytest.raises(ValueError, match="interpret mode"):
+        p.predict(x)
+    assert p.mode == "fused"
+    with pytest.raises(ValueError, match="interpret mode"):
+        serving.CompiledPredictor(Unlowerable(),
+                                  bucket_sizes=(1,)).warmup(x)
+
+    p = serving.CompiledPredictor(Untraceable(), bucket_sizes=(1,))
+    out = p.predict(x).asnumpy()
+    assert p.mode == "eager"
+    onp.testing.assert_allclose(out, rows(1) * rows(1).sum(), rtol=1e-6)
+
+
 def test_guard_flushes_out_hidden_host_sync(monkeypatch):
     # a forward hiding a host materialization: the trace fails (tracer
     # has no concrete value), the eager fallback then trips the armed

@@ -432,7 +432,7 @@ def test_pipelined_telemetry_zero_unblessed_syncs(tmp_path, monkeypatch):
     assert snap["gauges"][names.WINDOW_OCCUPANCY] == 0   # drained
     assert snap["gauges"][names.WINDOW_CAPACITY] == 2
     assert names.COMPILE_CACHE_HITS in snap["counters"]
-    assert snap["gauges"][names.COMPILE_CACHE_ENABLED] == 0.0  # unarmed
+    assert snap["gauges"][names.COMPILE_CACHE_ENABLED] == 1.0  # by import
     # checkpoint-latency series observed real saves (steps 6 and 12)
     assert snap["counters"][names.CHECKPOINT_SAVES] == 2
     assert snap["histograms"][names.CHECKPOINT_CAPTURE_SECONDS][

@@ -144,7 +144,7 @@ def test_vmem_accessor_env_and_clamp(monkeypatch):
     assert kernels.vmem_tile_budget() == 8 * 1024 * 1024
     # clamped to the physical VMEM above, to 64 KiB below
     space.set_override("kernels.vmem_tile_budget", 10**12)
-    assert kernels.vmem_tile_budget() == kernels.VMEM_BYTES_PER_CORE
+    assert kernels.vmem_tile_budget() == kernels.VMEM_SCOPED_DEFAULT_BYTES
     space.set_override("kernels.vmem_tile_budget", 1)
     assert kernels.vmem_tile_budget() == 64 * 1024
 
@@ -160,12 +160,12 @@ def test_vmem_budget_feeds_all_four_kernel_sizers():
     from mxnet_tpu.ops.kernels import opt_update as kopt
     from mxnet_tpu.ops.kernels import rnn_scan as krnn
     big = (kernels.vmem_tile_budget(),
-           krnn._block_t(64, 8, 4, 128, 4, interpret=False),
+           krnn._vmem_plan(64, 8, 4, 128, 4, False)[0],
            _head_group(8, 128, 128), knorm._budget_rows(128),
            kopt._block_rows_cap())
     space.set_override("kernels.vmem_tile_budget", 64 * 1024)
     small = (kernels.vmem_tile_budget(),
-             krnn._block_t(64, 8, 4, 128, 4, interpret=False),
+             krnn._vmem_plan(64, 8, 4, 128, 4, False)[0],
              _head_group(8, 128, 128), knorm._budget_rows(128),
              kopt._block_rows_cap())
     assert small[0] < big[0]
@@ -182,12 +182,12 @@ def test_rnn_block_t_tunable_and_interpret_contract():
     numbers the parity sweep pins."""
     from mxnet_tpu.ops.kernels import rnn_scan as krnn
     args = (64, 8, 4, 128, 4)           # seq, N, gates, Hp, itemsize
-    auto = krnn._block_t(*args, interpret=False)
+    auto = krnn._vmem_plan(*args, False)[0]
     space.set_override("kernels.rnn_block_t", 8)
-    assert krnn._block_t(*args, interpret=False) == 8
-    assert krnn._block_t(*args, interpret=True) == 1
+    assert krnn._vmem_plan(*args, False)[0] == 8
+    assert krnn._vmem_plan(*args, True)[0] == 1
     space.set_override("kernels.rnn_block_t", 0)   # 0 = auto
-    assert krnn._block_t(*args, interpret=False) == auto
+    assert krnn._vmem_plan(*args, False)[0] == auto
 
 
 def test_trial_context_restores_overrides():

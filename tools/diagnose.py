@@ -149,11 +149,8 @@ def check_engine():
               "starvation", s.get("starvation_count"),
               f"input_wait {s.get('input_wait_ms', 0.0):.1f} ms")
         cc = compile_cache_stats()
-        if cc["enabled"]:
-            print("compile cache:", cc["dir"],
-                  f"hits={cc['hits']} misses={cc['misses']}")
-        else:
-            print("compile cache: off (set MXNET_COMPILE_CACHE=<dir>)")
+        print("compile cache:", cc["dir"],
+              f"hits={cc['hits']} misses={cc['misses']}")
     except Exception as e:  # pragma: no cover - env-dependent
         print("engine check failed:", repr(e))
 
@@ -861,12 +858,8 @@ def check_serving():
                   f"p99={lat.percentile(99) * 1e3:.2f} ms "
                   "(mx_serving_request_seconds)")
         cc = compile_cache_stats()
-        if cc["enabled"]:
-            print("compile cache:", cc["dir"],
-                  f"hits={cc['hits']} misses={cc['misses']}")
-        else:
-            print("compile cache: off (set MXNET_COMPILE_CACHE=<dir> "
-                  "to warm-start serving executables)")
+        print("compile cache:", cc["dir"],
+              f"hits={cc['hits']} misses={cc['misses']}")
 
         # resilience panel: one injected device revocation under a
         # small burst, served through the ServingSupervisor — breaker
