@@ -156,19 +156,17 @@ class TrainCheckpointManager:
 
     def _write(self, state: TrainState):
         t0 = time.perf_counter()
-        atomic.write_checkpoint(self._root, state.step, state.arrays,
-                                array_meta=state.array_meta,
-                                meta=state.meta)
-        self._last_saved = state.step
-        atomic.prune_checkpoints(self._root, self._keep_last)
-        t1 = time.perf_counter()
-        self._m_write.observe(t1 - t0)
+        # runs on the background writer thread for async saves (the span
+        # lands on that thread's profiler line); the timeline ring +
+        # histogram are thread-safe
+        with _telemetry().span("checkpoint", step=state.step):
+            atomic.write_checkpoint(self._root, state.step, state.arrays,
+                                    array_meta=state.array_meta,
+                                    meta=state.meta)
+            self._last_saved = state.step
+            atomic.prune_checkpoints(self._root, self._keep_last)
+        self._m_write.observe(time.perf_counter() - t0)
         self._m_saves.inc()
-        t = _telemetry()
-        if t.active():
-            # runs on the background writer thread for async saves; the
-            # timeline ring + histogram are thread-safe
-            t.timeline().record("checkpoint", t0, t1, step=state.step)
 
     def wait(self):
         """Block until the in-flight write finishes; re-raise its error."""

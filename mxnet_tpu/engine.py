@@ -250,10 +250,10 @@ class DispatchWindow:
         # chaos-harness seam: a revoked device surfaces exactly here in
         # a pipelined run — at the blocking wait on an in-flight step
         fault_point("window.retire", "before")
-        t_wait = time.perf_counter()
         with _tguard.allow_transfers("dispatch-window retire"):
             try:
-                self._sync(payload)
+                with _telemetry().span("retire", step=tag):
+                    self._sync(payload)
             except MXNetError as e:
                 with self._mu:
                     self.stats["errors"] += 1
@@ -287,12 +287,14 @@ class DispatchWindow:
             # still inside the blessed retire region: the watchdog's
             # NaN peek at the (already completed) payload is the one
             # designed device->host read telemetry adds
-            self._observe_retire(tag, payload, aux, t_push, t_wait)
+            self._observe_retire(tag, payload, aux, t_push)
         fault_point("window.retire", "after")
 
-    def _observe_retire(self, tag, payload, aux, t_push, t_wait):
-        """Step-timeline spans + watchdog feed for one retire — gated on
-        MXNET_TELEMETRY / an active profiler; must never kill a run.
+    def _observe_retire(self, tag, payload, aux, t_push):
+        """The ``window`` span (push -> done; the ``retire`` span is the
+        blocking sync itself, recorded around it) + watchdog feed for
+        one retire — gated on MXNET_TELEMETRY / an active profiler; must
+        never kill a run.
         The numerics aux (when the step was compiled with numerics
         instrumentation) is consumed FIRST and regardless of the
         telemetry gate — MXNET_NUMERICS is its own opt-in."""
@@ -304,9 +306,7 @@ class DispatchWindow:
                 self._last_retire_t = None
                 return
             t_done = time.perf_counter()
-            tl = t.timeline()
-            tl.record("window", t_push, t_done, step=tag)
-            tl.record("retire", t_wait, t_done, step=tag)
+            t.timeline().record("window", t_push, t_done, step=tag)
             dt = None if self._last_retire_t is None \
                 else t_done - self._last_retire_t
             self._last_retire_t = t_done

@@ -174,22 +174,21 @@ class DevicePrefetcher:
         return batch
 
     # ---------------- telemetry ----------------
-    def _record_fetch(self, ordinal, t0, t1):
-        """batch_fetch span (source pull + device staging) — producer
-        side; ordinal is this prefetcher's batch number, the closest
-        step attribution the data layer has."""
-        t = _telemetry()
-        if t.active():
-            t.timeline().record("batch_fetch", t0, t1, step=ordinal)
+    def _fetch(self, it, ordinal):
+        """One batch pulled from the source and staged, under the
+        ``batch_fetch`` span (producer side). ``ordinal`` is this
+        prefetcher's batch number: the 0-based index of the step the
+        batch feeds, the closest step attribution the data layer has.
+        ``StopIteration`` passes through and leaves no span."""
+        with _telemetry().span("batch_fetch", step=ordinal):
+            return self._stage_batch(next(it), ordinal)
 
-    def _record_wait(self, ordinal, t0, t1):
-        """h2d_wait span (consumer blocked on staged input)."""
+    def _count_wait(self, seconds):
+        """The operator's always-on view of the consumer's wait; the
+        ``h2d_wait`` span around the same region is the gated one."""
         with self._stats_mu:
-            self.stats["input_wait_ms"] += (t1 - t0) * 1e3
-        self._m_wait.inc(t1 - t0)
-        t = _telemetry()
-        if t.active():
-            t.timeline().record("h2d_wait", t0, t1, step=ordinal)
+            self.stats["input_wait_ms"] += seconds * 1e3
+        self._m_wait.inc(seconds)
 
     # ---------------- iteration ----------------
     def __iter__(self):
@@ -197,13 +196,10 @@ class DevicePrefetcher:
             it = iter(self._source)
             n = 0
             while True:
-                t0 = time.perf_counter()
                 try:
-                    batch = next(it)
+                    staged = self._fetch(it, n)
                 except StopIteration:
                     return
-                staged = self._stage_batch(batch, n)
-                self._record_fetch(n, t0, time.perf_counter())
                 with self._stats_mu:
                     self.stats["prefetch_batches"] += 1
                 self._m_batches.inc()
@@ -220,13 +216,10 @@ class DevicePrefetcher:
                 it = iter(self._source)
                 n = 0
                 while True:
-                    t0 = time.perf_counter()
                     try:
-                        batch = next(it)
+                        staged = self._fetch(it, n)
                     except StopIteration:
                         break
-                    staged = self._stage_batch(batch, n)
-                    self._record_fetch(n, t0, time.perf_counter())
                     n += 1
                     while not stop.is_set():
                         try:
@@ -258,12 +251,13 @@ class DevicePrefetcher:
                     self._m_starved.inc()
                 t0 = time.perf_counter()
                 try:
-                    item = q.get(timeout=self._timeout)
+                    with _telemetry().span("h2d_wait", step=n):
+                        item = q.get(timeout=self._timeout)
                 except queue.Empty:
                     raise MXNetError(
                         f"DevicePrefetcher produced no batch within "
                         f"timeout={self._timeout}s") from None
-                self._record_wait(n, t0, time.perf_counter())
+                self._count_wait(time.perf_counter() - t0)
                 if item is _DONE:
                     return
                 if isinstance(item, _Raised):

@@ -65,7 +65,6 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-import time
 from collections import OrderedDict
 from typing import Any, Callable, Optional
 
@@ -85,6 +84,17 @@ from .block import UNTRACEABLE_ERRORS, ParamBinding, _TRACED
 __all__ = ["CompiledTrainStep", "TrainLoop"]
 
 _LOG = logging.getLogger("mxnet_tpu.fused_step")
+
+#: the ``jax.named_scope`` every op of a step program sits under, so a
+#: device trace splits into forward+backward / update / ZeRO packing and
+#: collectives / numerics aux (docs/OBSERVABILITY.md "Phase scopes").
+#: Trace-time metadata only: what runs is the same program.
+PHASE_LOSS_AND_GRAD = "loss_and_grad"
+PHASE_OPTIMIZER_UPDATE = "optimizer_update"
+PHASE_GRAD_REDUCE = "grad_reduce"
+PHASE_NUMERICS = "numerics"
+PHASE_SCOPES = (PHASE_LOSS_AND_GRAD, PHASE_OPTIMIZER_UPDATE, PHASE_GRAD_REDUCE,
+                PHASE_NUMERICS)
 
 _TELEM = None
 
@@ -1060,8 +1070,9 @@ class CompiledTrainStep:
             return jnp.sum(l), (l, binding.state)
 
         def grad_part(pds, traced_leaves, key):
-            (_, (l, state)), grads = jax.value_and_grad(
-                run_loss, has_aux=True)(tuple(pds), traced_leaves, key)
+            with jax.named_scope(PHASE_LOSS_AND_GRAD):
+                (_, (l, state)), grads = jax.value_and_grad(
+                    run_loss, has_aux=True)(tuple(pds), traced_leaves, key)
             gs = tuple(grads[i] for i in t_pos)
             return l, state, gs
 
@@ -1184,18 +1195,22 @@ class CompiledTrainStep:
             def zero_fused(pds, sts, masters, traced_leaves, ulrs, uwds,
                            uts, rescale, clip, key):
                 step_self._n_traces += 1
-                (_, (l, state)), grad_bufs = jax.value_and_grad(
-                    run_loss_bufs, has_aux=True)(
-                        pack_buckets(pds), pds, traced_leaves, key)
+                with jax.named_scope(PHASE_GRAD_REDUCE):
+                    packed = pack_buckets(pds)
+                with jax.named_scope(PHASE_LOSS_AND_GRAD):
+                    (_, (l, state)), grad_bufs = jax.value_and_grad(
+                        run_loss_bufs, has_aux=True)(
+                            packed, pds, traced_leaves, key)
                 n_units = len(units)
                 ws_u = [None] * n_units
                 for k, u in enumerate(units):
                     if u["mp"]:
                         wflat = masters[mslot[k]]   # persistent fp32 shard
                     else:
-                        wflat = wsc(_padded(_flat_cat(
-                            [pds[t_pos[j]] for j in u["members"]]),
-                            u["padded"]), shard)
+                        with jax.named_scope(PHASE_GRAD_REDUCE):
+                            wflat = wsc(_padded(_flat_cat(
+                                [pds[t_pos[j]] for j in u["members"]]),
+                                u["padded"]), shard)
                     ws_u[k] = wflat
                 gs_u = [None] * n_units
                 new_ws = [None] * n_units
@@ -1209,26 +1224,28 @@ class CompiledTrainStep:
                     # shards slice out comm-free
                     gbuf = grad_bufs[bi]
                     upd = units[idx[0]]["upd_dtype"]
-                    if gbuf.dtype != upd:
-                        gbuf = gbuf.astype(upd)
                     # the constraint is applied to the FLAT view (row d
                     # of the interleaved layout = contiguous slice d of
                     # the flat buffer): GSPMD lowers a 1-D P(axis) pin
                     # on a pending sum as the clean reduce-scatter /
                     # all-reduce + partition-id-slice pattern the
                     # zero-dp program checks assert on
-                    gbuf = wsc(gbuf.reshape(-1), shard).reshape(
-                        nsh, -1)
-                    b_gs = _unpack_bucket(gbuf, idx)
+                    with jax.named_scope(PHASE_GRAD_REDUCE):
+                        if gbuf.dtype != upd:
+                            gbuf = gbuf.astype(upd)
+                        gbuf = wsc(gbuf.reshape(-1), shard).reshape(
+                            nsh, -1)
+                        b_gs = _unpack_bucket(gbuf, idx)
                     for k, g in zip(idx, b_gs):
                         gs_u[k] = g
-                    bw, bst = opt_fn(
-                        tuple(ws_u[k] for k in idx), tuple(b_gs),
-                        tuple(ulrs[k] for k in idx),
-                        tuple(uwds[k] for k in idx),
-                        tuple(uts[k] for k in idx),
-                        rescale, clip,
-                        tuple(sts[k] for k in idx))
+                    with jax.named_scope(PHASE_OPTIMIZER_UPDATE):
+                        bw, bst = opt_fn(
+                            tuple(ws_u[k] for k in idx), tuple(b_gs),
+                            tuple(ulrs[k] for k in idx),
+                            tuple(uwds[k] for k in idx),
+                            tuple(uts[k] for k in idx),
+                            rescale, clip,
+                            tuple(sts[k] for k in idx))
                     for k, w, st in zip(idx, bw, bst):
                         new_ws[k] = w
                         new_sts_u[k] = st
@@ -1238,45 +1255,50 @@ class CompiledTrainStep:
                     # without it GSPMD propagates `repl` into the
                     # update's last elementwise op and all-gathers both
                     # of its operands instead
-                    b_fulls = allgather_bucketed(
-                        list(bw), nsh,
-                        constrain=lambda b: wsc(wsc(b, shard2d), repl))
+                    with jax.named_scope(PHASE_GRAD_REDUCE):
+                        b_fulls = allgather_bucketed(
+                            list(bw), nsh,
+                            constrain=lambda b: wsc(wsc(b, shard2d),
+                                                    repl))
                     for k, f in zip(idx, b_fulls):
                         fulls[k] = f
                 new_pds = list(state)
                 new_masters = [None] * len(mslot)
-                for k, u in enumerate(units):
-                    full = fulls[k]
-                    off = 0
-                    for j, shp, n, dt in zip(u["members"], u["shapes"],
-                                             u["sizes"], u["dtypes"]):
-                        new_pds[t_pos[j]] = \
-                            full[off:off + n].reshape(shp).astype(dt)
-                        off += n
-                    if u["mp"]:
-                        new_masters[mslot[k]] = wsc(new_ws[k], shard)
-                # pin the state outputs to the sharded layout: the
-                # replicated all-gather consumer above must not make
-                # GSPMD replicate the persistent buffers on the way out
-                new_sts = tuple(tuple(wsc(s, shard) for s in st)
-                                for st in new_sts_u)
+                with jax.named_scope(PHASE_GRAD_REDUCE):
+                    for k, u in enumerate(units):
+                        full = fulls[k]
+                        off = 0
+                        for j, shp, n, dt in zip(u["members"], u["shapes"],
+                                                 u["sizes"], u["dtypes"]):
+                            new_pds[t_pos[j]] = \
+                                full[off:off + n].reshape(shp).astype(dt)
+                            off += n
+                        if u["mp"]:
+                            new_masters[mslot[k]] = wsc(new_ws[k], shard)
+                    # pin the state outputs to the sharded layout: the
+                    # replicated all-gather consumer above must not make
+                    # GSPMD replicate the persistent buffers on the way
+                    # out
+                    new_sts = tuple(tuple(wsc(s, shard) for s in st)
+                                    for st in new_sts_u)
                 out = (tuple(new_pds), new_sts, tuple(new_masters), l)
                 if numerics:
-                    gs_log = ()
-                    if numerics == "per_layer":
-                        # logical per-param grads, sliced back out of
-                        # the packed pre-scatter buffers (materializes
-                        # the full gradient — the documented per-layer
-                        # cost)
-                        gs_log = [None] * len(t_pos)
-                        for bi, idx in enumerate(buckets):
-                            for k, flat in zip(
-                                    idx, _unpack_bucket(grad_bufs[bi],
-                                                        idx)):
-                                _scatter_members(gs_log, k, flat,
-                                                 to_pds=False)
-                    out = out + (zero_aux(ws_u, gs_u, new_ws, gs_log,
-                                          rescale),)
+                    with jax.named_scope(PHASE_NUMERICS):
+                        gs_log = ()
+                        if numerics == "per_layer":
+                            # logical per-param grads, sliced back out
+                            # of the packed pre-scatter buffers
+                            # (materializes the full gradient — the
+                            # documented per-layer cost)
+                            gs_log = [None] * len(t_pos)
+                            for bi, idx in enumerate(buckets):
+                                for k, flat in zip(
+                                        idx, _unpack_bucket(grad_bufs[bi],
+                                                            idx)):
+                                    _scatter_members(gs_log, k, flat,
+                                                     to_pds=False)
+                        out = out + (zero_aux(ws_u, gs_u, new_ws, gs_log,
+                                              rescale),)
                 return out
 
             def zero_aux(ws_u, gs_u, new_ws, gs, rescale):
@@ -1334,7 +1356,9 @@ class CompiledTrainStep:
 
             def update(ws, sts, lrs, wds, ts, rescale, clip, gs):
                 step_self._n_traces += 1
-                return opt_fn(ws, gs, lrs, wds, ts, rescale, clip, sts)
+                with jax.named_scope(PHASE_OPTIMIZER_UPDATE):
+                    return opt_fn(ws, gs, lrs, wds, ts, rescale, clip,
+                                  sts)
 
             return {"kind": "split", "grad": grad_fn,
                     "update": jax.jit(update, donate_argnums=donate),
@@ -1364,23 +1388,32 @@ class CompiledTrainStep:
                 aux["layer_grad_sq"] = jnp.stack([r2 * s for s in gsq])
             return aux
 
-        def fused(pds, sts, traced_leaves, lrs, wds, ts, rescale, clip,
-                  key):
+        # NOT named ``fused`` as before PR 26: the persistent compile
+        # cache keys a program by its ops and its module's name
+        # (``jit_<this function>``) and leaves op names out, so under the
+        # old name a step with no Pallas call in it (the LSTM LM) was
+        # served the executable an unscoped build had cached, whose HLO
+        # text — what a trace's attribution is read from — carries the
+        # old op_names and no phase (PERF.md section 6, PR 26)
+        def fused_step(pds, sts, traced_leaves, lrs, wds, ts, rescale,
+                       clip, key):
             step_self._n_traces += 1
             l, state, gs = grad_part(pds, traced_leaves, key)
             ws = tuple(pds[i] for i in t_pos)
-            new_ws, new_sts = opt_fn(ws, gs, lrs, wds, ts, rescale, clip,
-                                     sts)
+            with jax.named_scope(PHASE_OPTIMIZER_UPDATE):
+                new_ws, new_sts = opt_fn(ws, gs, lrs, wds, ts, rescale,
+                                         clip, sts)
             new_pds = list(state)   # BN-stat rebinds + identity for rest
             for j, i in enumerate(t_pos):
                 new_pds[i] = new_ws[j]
             out = (tuple(new_pds), new_sts, l)
             if numerics:
-                out = out + (fused_aux(ws, gs, new_ws, rescale),)
+                with jax.named_scope(PHASE_NUMERICS):
+                    out = out + (fused_aux(ws, gs, new_ws, rescale),)
             return out
 
         return {"kind": "fused",
-                "fn": jax.jit(fused, donate_argnums=donate),
+                "fn": jax.jit(fused_step, donate_argnums=donate),
                 "exe": None, "flops": None, "numerics": numerics,
                 "probe": grad_part}
 
@@ -1975,16 +2008,16 @@ class TrainLoop:
             t = _telemetry()
             step_no = self._global_step + 1
             if t.active():
-                # dispatch span + the XProf bridge: StepTraceAnnotation
-                # groups this step's device kernels under the same step
-                # number the host spans carry, so the merged trace
+                # dispatch span + the XProf bridge: the span holds this
+                # StepTraceAnnotation (not a second ``mx:dispatch`` one),
+                # which groups the step's device kernels under the same
+                # step number the host spans carry, so the merged trace
                 # aligns host phases with XLA execution
-                t0 = time.perf_counter()
-                with jax.profiler.StepTraceAnnotation(
-                        "mx_train_step", step_num=step_no):
+                with t.timeline().span(
+                        "dispatch", step=step_no,
+                        annotation=jax.profiler.StepTraceAnnotation(
+                            "mx_train_step", step_num=step_no)):
                     loss = self._step(*batch, batch_size=batch_size)
-                t.timeline().record("dispatch", t0,
-                                    time.perf_counter(), step=step_no)
             else:
                 loss = self._step(*batch, batch_size=batch_size)
             self._global_step = step_no

@@ -9,14 +9,60 @@ from __future__ import annotations
 import collections
 import logging
 import os
+import threading
+import time
 
 __all__ = ["Feature", "Features", "feature_list", "setup_compile_cache",
-           "compile_cache_stats"]
+           "compile_cache_stats", "compile_log"]
 
 _LOG = logging.getLogger("mxnet_tpu.runtime")
 
 # persistent-compilation-cache hit/miss census (setup_compile_cache)
 _CACHE_STATS = {"enabled": False, "dir": None, "hits": 0, "misses": 0}
+
+#: jax.monitoring duration event -> the compile log's ``phase``
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+
+
+class _CompileLog:
+    """Bounded ring of what JAX compiled, when, and for how long.
+
+    Sized from the chip: a whole run of the benchmark's BERT cell (set-up,
+    window and its float32 reference) is 17,403 events, nearly all of
+    them sub-millisecond ``trace`` events of the ``jit``-wrapped
+    ``jax.numpy`` functions inside the step's own tracing; the LSTM
+    cell's is 1,966 (PERF.md section 6, PR 26). A session that outgrows
+    the ring loses its OLDEST events and ``dropped`` says how many."""
+
+    def __init__(self, capacity: int = 65536):
+        self._events: "collections.deque[tuple]" = collections.deque(
+            maxlen=capacity)
+        self._dropped = 0
+        # bare on purpose: telemetry substrate, held for one append
+        self._lock = threading.Lock()  # mx-lint: allow=MXA009
+
+    def append(self, phase: str, fun_name: str, duration: float):
+        # the event fires as the timed region closes: its end is now, on
+        # the clock the step timeline's spans use
+        t1 = time.perf_counter()
+        with self._lock:
+            if len(self._events) == self._events.maxlen:
+                self._dropped += 1
+            self._events.append((phase, fun_name, t1 - duration, t1))
+
+    def read(self) -> dict:
+        with self._lock:
+            events, dropped = list(self._events), self._dropped
+        return {"events": [dict(zip(("phase", "fun_name", "t0", "t1"), e))
+                           for e in events], "dropped": dropped}
+
+
+_COMPILE_LOG = _CompileLog()
 
 #: where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` does not
 #: place it: one fixed path in the checkout. The directory is part of
@@ -73,7 +119,21 @@ def setup_compile_cache() -> str:
                       "will persist to %s",
                       _CACHE_STATS["misses"], cache_dir)
 
+    _seconds = _treg().counter(_tnames.COMPILE_SECONDS, label_key="phase")
+    _programs = _treg().counter(_tnames.COMPILE_PROGRAMS)
+
+    def _on_duration(event: str, duration: float, **kwargs):
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        _COMPILE_LOG.append(phase, str(kwargs.get("fun_name", "")),
+                            duration)
+        _seconds.inc(max(0.0, duration), label=phase)
+        if phase == "backend_compile":
+            _programs.inc()
+
     jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _CACHE_STATS["enabled"] = True
     _CACHE_STATS["dir"] = cache_dir
     _LOG.info("persistent compilation cache armed at %s", cache_dir)
@@ -84,6 +144,23 @@ def compile_cache_stats() -> dict:
     """{'enabled', 'dir', 'hits', 'misses'} for the persistent
     compilation cache (tools/diagnose.py prints this)."""
     return dict(_CACHE_STATS)
+
+
+def compile_log() -> dict:
+    """``{"events": [...], "dropped": n}``: one entry per phase of every
+    program JAX built in this process, oldest first, each
+    ``{"phase": "trace" | "lower" | "backend_compile" | "cache_read",
+    "fun_name", "t0", "t1"}`` with ``time.perf_counter()`` stamps (the
+    step timeline's clock). ``trace`` is Python tracing to a jaxpr (an
+    inner ``jit``'s nests inside its caller's), ``lower`` the jaxpr to an
+    MLIR module, ``backend_compile`` XLA's compile OR, on a persistent-
+    cache hit, the read that replaced it (the ``cache_read`` entry just
+    before it, inside its interval; ``fun_name`` empty). The listeners
+    fire only when JAX compiles: a warm step adds nothing. Which step
+    recompiled: the entry whose time falls inside a ``dispatch`` span of
+    ``telemetry.timeline()``, which carries the step number
+    (docs/OBSERVABILITY.md "Compile log")."""
+    return _COMPILE_LOG.read()
 
 
 def _cache_collector(reg):

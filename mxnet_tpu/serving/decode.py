@@ -78,6 +78,7 @@ copy-on-write page copy (kvcache.py has the lifecycle).
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -105,6 +106,8 @@ __all__ = ["DecodeEngine", "DecodeStream", "TinyDecoder", "run_decode",
            "slot_ladder", "kv_page_size", "prefill_chunk", "spec_k",
            "prefix_share", "DECODE_SLOT_LADDER", "PREFILL_CHUNK",
            "SPEC_K", "PREFIX_SHARE"]
+
+_LOG = logging.getLogger("mxnet_tpu.serving.decode")
 
 #: shipped slot-count ladder (``decode.slot_ladder`` / ``MXNET_DECODE_SLOTS``)
 DECODE_SLOT_LADDER = (1, 2, 4, 8)
@@ -857,6 +860,7 @@ class DecodeEngine:
                                        label_key="reason")
         self._m_drafted = reg.counter(t.names.DECODE_SPEC_DRAFTED)
         self._m_accepted = reg.counter(t.names.DECODE_SPEC_ACCEPTED)
+        self._m_aot_fallback = reg.counter(t.names.DECODE_AOT_FALLBACK)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         if start:
@@ -870,7 +874,7 @@ class DecodeEngine:
         key = (kind, bucket)
         entry = self._programs.get(key)
         if entry is None:
-            entry = {"fn": self._shared_program(kind),
+            entry = {"key": key, "fn": self._shared_program(kind),
                      "exe": None, "analysis": None}
             self._programs[key] = entry
         return entry
@@ -982,10 +986,18 @@ class DecodeEngine:
         fn = entry["exe"] if entry["exe"] is not None else entry["fn"]
         try:
             return fn(*args)
-        except (TypeError, ValueError):
+        except (TypeError, ValueError) as e:
             if entry["exe"] is None:
                 raise
-            entry["exe"] = None       # AOT signature drifted: re-jit
+            # AOT signature drifted: drop the executable and re-jit.
+            # Counted and logged because the next call compiles inside
+            # a request; once per entry, since the executable is gone
+            entry["exe"] = None
+            self._m_aot_fallback.inc()
+            _LOG.warning(
+                "decode engine: AOT %s executable of slot bucket %d "
+                "rejected its arguments (%s: %s); dropped, re-jitting "
+                "inside this call", *entry["key"], type(e).__name__, e)
             return entry["fn"](*args)
 
     # ---------------- static analysis ----------------

@@ -35,6 +35,7 @@ inside the already-blessed retire sync.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Optional
 
@@ -58,7 +59,7 @@ __all__ = ["names", "registry", "MetricsRegistry", "Counter", "Gauge",
            "prometheus_text", "write_prometheus", "prometheus_file",
            "Heartbeat", "start_heartbeat", "stop_heartbeat",
            "heartbeat_interval", "SCHEMA_VERSION", "enabled", "enable",
-           "value", "reset", "memory", "census", "BufferCensus",
+           "span", "value", "reset", "memory", "census", "BufferCensus",
            "MemoryReport", "numerics", "NumericsMonitor",
            "StepNumerics"]
 
@@ -95,6 +96,19 @@ def active() -> bool:
     from ..profiler import Profiler
     prof = Profiler.get()
     return prof.running and not prof.paused
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(phase: str, step: Optional[int] = None):
+    """The gated span of an instrumentation point: ``timeline().span``
+    while :func:`active`, else a shared do-nothing context — with
+    telemetry off a site pays this one branch, stamps no clock and
+    enters no profiler annotation."""
+    if active():
+        return timeline().span(phase, step)
+    return _NO_SPAN
 
 
 def value(name: str, label: Optional[str] = None):

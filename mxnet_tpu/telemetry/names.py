@@ -68,6 +68,8 @@ COMPILE_CACHE_HITS = "mx_compile_cache_hits_total"
 COMPILE_CACHE_MISSES = "mx_compile_cache_misses_total"
 COMPILE_CACHE_ENABLED = "mx_compile_cache_enabled"
 COMPILE_RETRACES = "mx_compile_retraces_total"
+COMPILE_SECONDS = "mx_compile_seconds_total"
+COMPILE_PROGRAMS = "mx_compile_programs_total"
 
 # ---------------------------------------------------------------------------
 # checkpoint (checkpoint/manager.py)
@@ -191,6 +193,7 @@ DECODE_SPEC_DRAFTED = "mx_decode_spec_drafted_total"
 DECODE_SPEC_ACCEPTED = "mx_decode_spec_accepted_total"
 DECODE_PREFIX_HITS = "mx_decode_prefix_hits_total"
 DECODE_COW_COPIES = "mx_decode_cow_copies_total"
+DECODE_AOT_FALLBACK = "mx_decode_aot_fallback_total"
 
 # ---------------------------------------------------------------------------
 # serving fleet controller (serving/fleet.py)
@@ -265,6 +268,18 @@ CATALOG = {
     COMPILE_RETRACES: dict(
         kind="counter", label=None,
         help="new compiled shape buckets built by Trainer.compile_step"),
+    COMPILE_SECONDS: dict(
+        kind="counter", label="phase",
+        help="seconds JAX spent building programs, by phase (trace = "
+             "Python tracing to a jaxpr, inner jits counted again "
+             "inside their callers; lower = jaxpr to MLIR; "
+             "backend_compile = XLA compile or the cache read that "
+             "replaced it; cache_read = that read alone); "
+             "runtime.compile_log() holds the events"),
+    COMPILE_PROGRAMS: dict(
+        kind="counter", label=None,
+        help="programs handed to the backend (compiled or read from "
+             "the persistent cache)"),
     CHECKPOINT_SAVES: dict(
         kind="counter", label=None,
         help="checkpoints committed by TrainCheckpointManager"),
@@ -568,6 +583,12 @@ CATALOG = {
         kind="counter", label=None,
         help="copy-on-write page copies: a writer diverging on a "
              "shared KV page got a private copy before the write"),
+    DECODE_AOT_FALLBACK: dict(
+        kind="counter", label=None,
+        help="warmed-up (AOT) decode executables dropped because their "
+             "arguments no longer matched; the call re-jits, so the "
+             "next step of that bucket pays a trace and maybe a compile "
+             "inside a request"),
     FLEET_REPLICAS: dict(
         kind="gauge", label="state",
         help="fleet replicas by lifecycle state (serving = in "

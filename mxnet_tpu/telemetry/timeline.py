@@ -10,7 +10,9 @@ One pipelined train step passes through five host-observable phases —
 
 plus ``checkpoint`` for snapshot captures. Each instrumentation point
 (engine.DispatchWindow, gluon.data.DevicePrefetcher, gluon.TrainLoop,
-checkpoint.TrainCheckpointManager) records its span here; the timeline
+checkpoint.TrainCheckpointManager) wraps its region in
+:meth:`StepTimeline.span` (``window``, which opens in one call and closes
+in another, calls :meth:`StepTimeline.record`); the timeline
 
 - feeds the ``mx_step_phase_seconds{phase=}`` histogram in the metrics
   registry (always),
@@ -19,8 +21,15 @@ checkpoint.TrainCheckpointManager) records its span here; the timeline
 - when the host profiler is running, emits each span into the SAME
   Chrome-trace stream as the per-op events (``cat: "step"``, args
   carrying the step number and phase) — so host ops and step phases land
-  on one chrome://tracing / Perfetto timeline. Device kernels align via
-  the ``jax.profiler`` step annotation the TrainLoop wraps dispatch in.
+  on one chrome://tracing / Perfetto timeline.
+
+While a :meth:`~StepTimeline.span` is open it also holds a
+``jax.profiler.TraceAnnotation`` named ``mx:<phase>`` (``dispatch`` holds
+the TrainLoop's ``StepTraceAnnotation("mx_train_step")`` instead, which
+XProf groups device kernels by): under ``jax.profiler.start_trace`` the
+span sits on the xplane host line of the thread it ran on, on the same
+clock as the device's events. With no trace running the annotation is a
+no-op of the runtime's.
 
 Span recording is gated by :func:`active` at the call sites: on when
 ``MXNET_TELEMETRY`` is set (``mx.telemetry.enable()``) or when the host
@@ -29,19 +38,60 @@ profiler is running; the registry counters stay always-on regardless.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..base import MXNetError
 from . import names
 from .registry import default as _default_registry
 
-__all__ = ["PHASES", "StepTimeline", "timeline"]
+__all__ = ["PHASES", "ANNOTATION_PREFIX", "StepTimeline", "timeline"]
 
 #: the span vocabulary — documented in docs/OBSERVABILITY.md; record()
 #: rejects anything else so the phase label stays bounded
 PHASES = ("batch_fetch", "h2d_wait", "dispatch", "window", "retire",
           "checkpoint")
+
+
+def _check_phase(phase: str):
+    if phase not in PHASES:
+        raise MXNetError(
+            f"unknown step phase {phase!r}; the span vocabulary is "
+            f"{PHASES} (docs/OBSERVABILITY.md)")
+
+
+#: a span's name on the profiler's host line is this + its phase
+ANNOTATION_PREFIX = "mx:"
+
+
+class _Span:
+    """One open span (:meth:`StepTimeline.span`)."""
+
+    __slots__ = ("_timeline", "_phase", "_step", "_annotation", "_t0")
+
+    def __init__(self, timeline, phase, step, annotation):
+        self._timeline, self._phase, self._step = timeline, phase, step
+        self._annotation = annotation
+
+    def __enter__(self):
+        if self._annotation is None:
+            kw = {} if self._step is None else {"step": self._step}
+            self._annotation = TraceAnnotation(
+                ANNOTATION_PREFIX + self._phase, **kw)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is None:    # a region that raised is not a span
+            self._timeline.record(self._phase, self._t0, t1,
+                                  step=self._step)
+        return False
 
 
 class StepTimeline:
@@ -55,6 +105,17 @@ class StepTimeline:
             names.STEP_PHASE_SECONDS, label_key="phase")
 
     # ---------------- recording ----------------
+    def span(self, phase: str, step: Optional[int] = None,
+             annotation=None):
+        """Context manager around one region: ``perf_counter`` stamps on
+        entry and exit, :meth:`record` on a clean exit, and for as long
+        as it is open a ``TraceAnnotation("mx:<phase>", step=step)`` on
+        the calling thread's profiler line — or ``annotation``, where the
+        site has one of its own to hold (the TrainLoop's step
+        annotation). Call sites gate it on ``telemetry.active()``."""
+        _check_phase(phase)
+        return _Span(self, phase, step, annotation)
+
     def record(self, phase: str, t0: float, t1: float,
                step: Optional[int] = None):
         """Record one span: ``t0``/``t1`` are ``time.perf_counter()``
@@ -62,10 +123,7 @@ class StepTimeline:
         instrumentation point knows it (prefetcher spans use their own
         batch ordinal). Also mirrors the span into the profiler's
         Chrome-trace stream when it is running."""
-        if phase not in PHASES:
-            raise MXNetError(
-                f"unknown step phase {phase!r}; the span vocabulary is "
-                f"{PHASES} (docs/OBSERVABILITY.md)")
+        _check_phase(phase)
         dur = max(0.0, t1 - t0)
         self._hist.observe(dur, label=phase)
         with self._lock:

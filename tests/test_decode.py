@@ -477,6 +477,41 @@ def test_warmup_means_zero_live_traces(model):
         eng.close()
 
 
+def test_aot_drift_is_counted_logged_and_still_served(model, caplog):
+    """A warmed-up executable whose signature no longer matches its
+    arguments (here: bucket 2's executable under bucket 1's key) is
+    dropped and the call re-jits — once per entry, counted and logged
+    with the bucket, the stream unchanged."""
+    ref = make_engine(model, ladder=(1, 2))
+    try:
+        s = ref.submit(prompt(70, 3), max_new=4)
+        drive(ref)
+        want = s.result(0)
+    finally:
+        ref.close()
+    eng = make_engine(model, ladder=(1, 2))
+    try:
+        exes = eng.warmup()
+        entry = eng._entry("decode", 1)
+        entry["exe"] = exes[("decode", 2)]
+        before = telemetry.value(telemetry.names.DECODE_AOT_FALLBACK)
+        with caplog.at_level("WARNING", logger="mxnet_tpu.serving.decode"):
+            s = eng.submit(prompt(70, 3), max_new=4)
+            drive(eng)
+        assert s.result(0) == want
+        assert entry["exe"] is None
+        assert telemetry.value(telemetry.names.DECODE_AOT_FALLBACK) \
+            - before == 1
+        warned = [r.getMessage() for r in caplog.records
+                  if "AOT" in r.getMessage()]
+        assert len(warned) == 1 and "decode" in warned[0] \
+            and "slot bucket 1" in warned[0]
+        # the prefill bucket never drifted: its executable still serves
+        assert eng._entry("prefill", 1)["exe"] is not None
+    finally:
+        eng.close()
+
+
 # ---------------------------------------------------------------------------
 # static analysis + telemetry
 # ---------------------------------------------------------------------------
