@@ -173,6 +173,20 @@ def kernel_cases(tiny: bool = False):
                                            for _ in range(3)),
                 lambda q, k, v, causal=causal: attention.flash_attention(
                     q, k, v, causal=causal), (0, 1, 2))
+        # the same two at the model's own layout, (B, S, H*D): the
+        # benchmark cell's attention layer, and the 2k causal sequence
+        flash = (((1, 64, 128), 2, False), ((1, 1024, 128), 2, True)) \
+            if tiny else (((32, 512, 768), 12, False),
+                          ((8, 2048, 768), 12, True))
+        for shp, heads, causal in flash:
+            add("flash_attention",
+                f"flash_attention bsh {'x'.join(map(str, shp))} h{heads}"
+                f"{' causal' if causal else ''}", dtype,
+                lambda rng, shp=shp: tuple(f32(rng, *shp)
+                                           for _ in range(3)),
+                lambda q, k, v, heads=heads, causal=causal:
+                attention.flash_attention_bsh(q, k, v, heads, causal=causal),
+                (0, 1, 2))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -419,9 +433,17 @@ def _log_decisions() -> dict:
     table = kernels.decisions()
     for name in kernels.KERNELS:
         path, reason = table.get(name, ("-", "not on this model's path"))
-        log(f"  decision {name}: {path}"
-            + ("" if path == "pallas" else f" ({reason})"))
+        log(f"  decision {name}: {path} ({reason})")
     return {k: v[0] for k, v in table.items()}
+
+
+def _flash_layouts() -> dict:
+    """``mx_flash_attention_layout_total`` as {layout: calls so far}."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.attention import FLASH_LAYOUTS
+    from mxnet_tpu.telemetry import names
+    return {lay: int(telemetry.value(names.FLASH_ATTENTION_LAYOUT, lay) or 0)
+            for lay in FLASH_LAYOUTS}
 
 
 def phase_train(cfg: dict = TRAIN) -> dict:
@@ -429,11 +451,13 @@ def phase_train(cfg: dict = TRAIN) -> dict:
     import mxnet_tpu as mx
     platform = jax.devices()[0].platform
     net, loop, x, y = _bert_loop(cfg)
+    before = _flash_layouts()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
     finally:
         mx.amp.uninit()
+    layouts = {k: n - before[k] for k, n in _flash_layouts().items()}
     arrays = [p.data()._data for p in net.collect_params().values()]
     placed = {d.platform for a in arrays + [x._data, y._data]
               for d in a.devices()}
@@ -444,7 +468,13 @@ def phase_train(cfg: dict = TRAIN) -> dict:
     for name in ("flash_attention", "layernorm"):
         if paths.get(name) != _compiled_tier():
             raise RuntimeError(f"BERT dispatched {name} to {paths.get(name)}")
-    return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths}
+    # one traced call a layer; BERT-base's 12 heads of 64 read the
+    # projections where they lie ("packed"), and no BERT here pads a head
+    if layouts["padded"] or not any(layouts.values()):
+        raise RuntimeError(f"BERT's attention layers took {layouts}, "
+                           "expected one call a layer and none padded")
+    return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths,
+            "flash_layouts": layouts}
 
 
 def phase_kernels(tiny: bool = False) -> dict:

@@ -193,7 +193,11 @@ def _operand_dims(op: HloOp, mod: Optional[HloModule],
 
 
 def _flash_fwd_flops(op: HloOp, mod=None) -> int:
-    # q (BH, Sq, D), k (BH, Sk, D): two (Sq x Sk x D) matmuls
+    # two (Sq x Sk x D) matmuls a head, from q and k as the kernel
+    # addresses them (ops/attention.py _Tiles): (B*H, S, D) at the
+    # head's own width, or the projections' (B, S, H*D): rows x last
+    # dimension is B*H*D either way, so one product counts both. Only a
+    # head width padded in HBM (80 -> 128) still counts its zero lanes.
     q = _operand_dims(op, mod, 0)
     k = _operand_dims(op, mod, 1)
     if len(q) < 3 or len(k) < 3:

@@ -81,11 +81,18 @@ class MultiHeadAttention(HybridBlock):
     def forward(self, q, k=None, v=None, mask=None, valid_length=None):
         k = q if k is None else k
         v = k if v is None else v
-        qh = self._split(self.query_proj(q))
-        kh = self._split(self.key_proj(k))
-        vh = self._split(self.value_proj(v))
+        qp, kp, vp = self.query_proj(q), self.key_proj(k), self.value_proj(v)
         d = self._units // self._num_heads
         scale = 1.0 / math.sqrt(d)
+        if mask is None and valid_length is None:
+            # the flash kernels read the projections where they lie and
+            # write what out_proj reads: no head transpose on either side
+            fn = functools.partial(ATT.flash_attention_bsh,
+                                   num_heads=self._num_heads,
+                                   causal=self._causal, sm_scale=scale)
+            out = invoke_raw("flash_attention", fn, [qp, kp, vp])
+            return self.dropout(self.out_proj(out))
+        qh, kh, vh = self._split(qp), self._split(kp), self._split(vp)
         if mask is not None:
             inputs = [qh, kh, vh, mask if isinstance(mask, NDArray)
                       else NDArray(jnp.asarray(mask))]
@@ -103,7 +110,7 @@ class MultiHeadAttention(HybridBlock):
                 fn = functools.partial(_masked_attention, sm_scale=scale,
                                        causal=self._causal)
             out = invoke_raw("masked_attention", fn, inputs)
-        elif valid_length is not None:
+        else:
             def fn(q_, k_, v_, vl_):
                 return ATT.flash_attention(q_, k_, v_, causal=self._causal,
                                            sm_scale=scale, valid_length=vl_)
@@ -112,10 +119,6 @@ class MultiHeadAttention(HybridBlock):
             # float32: integer tape inputs would get float0 cotangents
             vl = NDArray(jnp.asarray(vl_data, jnp.float32))
             out = invoke_raw("flash_attention_vl", fn, [qh, kh, vh, vl])
-        else:
-            fn = functools.partial(ATT.flash_attention, causal=self._causal,
-                                   sm_scale=scale)
-            out = invoke_raw("flash_attention", fn, [qh, kh, vh])
         b, _, s, _ = out.shape
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         (b, s, self._units))

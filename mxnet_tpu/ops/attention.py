@@ -12,20 +12,26 @@ batch_dot+softmax. This module is the TPU-idiomatic superset the build plan
   dk/dv kernels reconstruct softmax blocks from it — no S×S residual is
   ever materialized). Elsewhere a blockwise ``lax.scan`` XLA implementation
   with identical math and a recompute-based backward.
+- ``flash_attention_bsh``: the same op on (batch, seq, heads*head_dim),
+  what a projection emits and the output projection eats. The kernels
+  address those arrays where they lie, at the head's own width (``_Tiles``:
+  128 // D heads share one 128-lane block and are told apart by lane
+  masks), so no transpose, pad or slice surrounds the calls;
+  ``MultiHeadAttention`` calls this form.
 - ``ring_attention``: context parallelism over a mesh axis. Each device
   holds a sequence shard of Q/K/V; K/V blocks rotate around the ring via
   ``lax.ppermute`` (ICI neighbor exchange) while online-softmax accumulators
   merge partial results — sequence length scales with the number of chips.
 
-Math convention: inputs are (batch, heads, seq, head_dim); softmax scale
-defaults to head_dim**-0.5; masking uses a large negative finite value so
+Math convention: inputs are (batch, heads, seq, head_dim) unless a
+function says otherwise; softmax scale defaults to head_dim**-0.5; masking uses a large negative finite value so
 fully-masked rows stay NaN-free through exp/renormalization.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +39,8 @@ from jax import lax
 
 from ..base import MXNetError
 
-__all__ = ["flash_attention", "paged_decode_attention", "ring_attention",
+__all__ = ["flash_attention", "flash_attention_bsh",
+           "paged_decode_attention", "ring_attention",
            "ring_attention_sharded", "attention_reference"]
 
 _NEG_INF = -1e30  # finite mask value: keeps exp() NaN-free for masked rows
@@ -119,57 +126,229 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU forward kernel
+# Pallas TPU kernels: how they address the caller's arrays
 # ---------------------------------------------------------------------------
 
+#: the layouts ``_Tiles`` knows, as ``mx_flash_attention_layout_total``
+#: labels them
+FLASH_LAYOUTS = ("packed", "unpadded", "padded")
+#: the blocks every kernel starts from; ``_tiles`` clamps them to the
+#: sequence
+_BLOCK_Q = _BLOCK_K = 512
+#: narrowest head the kernels take without padding
+_MIN_LANES = 8
+
+
+class _Tiles(NamedTuple):
+    """How the three flash kernels address q, k, v, o and their
+    gradients: as ``(rows, seq, col_tiles * width)`` arrays cut into
+    ``(g, block, width)`` blocks at ``(row block, seq block, column
+    tile)``, each block holding ``heads`` heads side by side on ``width
+    // heads`` lanes apiece. The per-row statistics (log-sum-exp, delta) are
+    ``(rows, col_tiles * heads, seq, 8)`` f32 (8 lanes: the narrowest
+    block the TPU tiling takes for a row vector).
+
+    - ``packed``: the projections' own (B, S, H*D). rows = B, width =
+      128 lanes holding 128 // D heads (or D lanes and one head when D
+      is a multiple of 128); nothing is moved around the call.
+    - ``unpadded``: (B, H, S, D) folded to (B*H, S, D), one column tile
+      of width D (a block's last dimension may be the array's own).
+    - ``padded``: the same fold with D zero-padded to a multiple of 128
+      in HBM: head widths that neither divide 128 nor are a multiple
+      (80, 96). Zero lanes cancel in QK^T and are sliced off after.
+    """
+    layout: str
+    batch: int
+    num_heads: int
+    head_dim: int
+    seq_q: int
+    seq_k: int
+    rows: int
+    col_tiles: int
+    width: int
+    heads: int          # heads side by side in one block
+    block_q: int
+    block_k: int
+    sqp: int
+    skp: int
+    nq: int
+    nk: int
+
+    @property
+    def reason(self) -> str:
+        """The layout as the dispatch decision words it."""
+        if self.layout == "packed":
+            return (f"packed: {self.heads} head{'s' * (self.heads > 1)} "
+                    f"per {self.width} lanes, no pad")
+        if self.layout == "unpadded":
+            return f"unpadded: D={self.head_dim}, one head per block"
+        return f"padded: D={self.head_dim} → {self.width}"
+
+
+def _tiles(q_shape, k_shape, block_q: int, block_k: int,
+           num_heads: Optional[int]) -> _Tiles:
+    """The tiling for one call, from the shapes alone. ``num_heads``
+    None: q, k, v are (B, H, S, D); else they are (B, S, H*D)."""
+    if num_heads is None:
+        b, h, sq, d = q_shape
+        sk = k_shape[2]
+    else:
+        b, sq, hd = q_shape
+        h, d, sk = num_heads, hd // num_heads, k_shape[1]
+    block_q = min(block_q, max(sq, 8))
+    block_k = min(block_k, max(sk, 8))
+    sqp = -(-sq // block_q) * block_q
+    skp = -(-sk // block_k) * block_k
+    fits = d % 128 == 0 or (128 % d == 0 and d >= _MIN_LANES)
+    if num_heads is not None and fits and (h * d) % 128 == 0:
+        width = max(d, 128)
+        layout, rows, cols, heads = "packed", b, h * d // width, width // d
+    elif fits:
+        layout, rows, cols, width, heads = "unpadded", b * h, 1, d, 1
+    else:
+        layout, rows, cols, width, heads = \
+            "padded", b * h, 1, -(-d // 128) * 128, 1
+    return _Tiles(layout, b, h, d, sq, sk, rows, cols, width, heads,
+                  block_q, block_k, sqp, skp, sqp // block_q, skp // block_k)
+
+
+def _split_heads(x, num_heads: int):
+    """(B, S, H*D) -> (B, H, S, D)."""
+    b, s, hd = x.shape
+    return x.reshape(b, s, num_heads, hd // num_heads).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    """(B, H, S, D) -> (B, S, H*D)."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None):
+    """Shared fwd/bwd tiling preamble: clamp block sizes, choose the
+    layout (:class:`_Tiles`) and bring q, k, v into it. Nothing is
+    padded that the kernels can take as it is: the sequence only when
+    it is not a multiple of its block, the head dimension only in the
+    ``padded`` layout. The backward's exp(s - lse) recompute is only
+    correct when it uses EXACTLY these conventions — keep this the
+    single source. Returns ``(qt, kt, vt, to_tiles, from_tiles,
+    tiles)``; ``to_tiles(x, seq_to)`` lays any further (…q- or k-shaped)
+    array out the same way and ``from_tiles(y, seq)`` is its inverse."""
+    t = _tiles(q.shape, k.shape, block_q, block_k, num_heads)
+    b, h, d = t.batch, t.num_heads, t.head_dim
+
+    def to_tiles(x, seq_to):
+        if t.layout == "packed":
+            pad = seq_to - x.shape[1]
+            return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+        if num_heads is not None:
+            x = _split_heads(x, h)
+        pad_s, pad_d = seq_to - x.shape[2], t.width - d
+        if pad_s or pad_d:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_s), (0, pad_d)))
+        return x.reshape(b * h, seq_to, t.width)
+
+    def from_tiles(y, seq):
+        if t.layout == "packed":
+            return y if y.shape[1] == seq else y[:, :seq]
+        y = y.reshape(b, h, y.shape[1], t.width)
+        if y.shape[2] != seq or t.width != d:
+            y = y[:, :, :seq, :d]
+        return y if num_heads is None else _merge_heads(y)
+
+    return (to_tiles(q, t.sqp), to_tiles(k, t.skp), to_tiles(v, t.skp),
+            to_tiles, from_tiles, t)
+
+
 def _head_group(bh: int, block_q: int, block_k: int,
-                n_tiles: int = 1) -> int:
-    """Heads per Pallas program. Per-program fixed overhead (~2-3 µs:
-    launch + DMA setup) dominates short-seq attention when the grid has
-    one program per (batch, head) — 384 programs for BERT-base bs=32.
-    Batch G heads per program, bounded by the CONCURRENT (G, bq, bk) f32
-    tiles' VMEM footprint (~16 MiB/core on v5e; the shared tile budget
-    lives in ops/kernels — the rnn_scan timestep-block sizer accounts
-    against the same number). ``n_tiles`` is how many such score-shaped
-    tiles the kernel holds live at once: 1 for the forward (s; p
-    overwrites it), 4 for the fused backward (s, p, dp, ds) — budgeting
-    the backward as a single tile oversizes G and fails Mosaic lowering
-    at large blocks."""
+                n_tiles: int = 1, heads_per_block: int = 1) -> int:
+    """Rows of the kernels' grid per Pallas program; a row is one block
+    of ``heads_per_block`` heads (one head of one sample in the
+    (B*H, S, D) layouts, the 128 // D heads that share a lane tile in
+    the packed one), so a program holds g x heads_per_block heads.
+    Per-program fixed overhead (~2-3 µs: launch + DMA setup) dominates
+    short-seq attention when the grid has one program per (batch, head)
+    — 384 programs for BERT-base bs=32. Batch up to 8 heads per program,
+    bounded by the CONCURRENT (heads, bq, bk) f32 tiles' VMEM footprint
+    (~16 MiB/core on v5e; the shared tile budget lives in ops/kernels —
+    the rnn_scan timestep-block sizer accounts against the same
+    number). ``n_tiles`` is how many such score-shaped tiles the kernel
+    holds live at once: 1 for the forward (s; p overwrites it), 4 for
+    the fused backward (s, p, dp, ds) — budgeting the backward as a
+    single tile oversizes G and fails Mosaic lowering at large
+    blocks."""
     from .kernels import vmem_tile_budget
     budget = vmem_tile_budget()
     g = 1
-    while (g * 2 <= 8 and bh % (g * 2) == 0
-           and g * 2 * block_q * block_k * 4 * n_tiles
+    while (g * 2 * heads_per_block <= 8 and bh % (g * 2) == 0
+           and g * 2 * heads_per_block * block_q * block_k * 4 * n_tiles
            <= budget):
         g *= 2
     return g
 
 
-def _vmem_limit(g: int, block_q: int, block_k: int, dp: int,
-                itemsize: int, n_blocks: int, n_acc: int,
-                n_tiles: int) -> int:
+def _vmem_limit(g: int, t: _Tiles, itemsize: int, n_blocks: int,
+                n_acc: int, n_tiles: int, n_rows: int) -> int:
     """``vmem_limit_bytes`` for one flash kernel, counted from what it
-    keeps in VMEM: ``n_blocks`` (G, block, dp) operand/result blocks in
-    the input dtype (Pallas double-buffers each), ``n_acc`` f32
-    (G, block, dp) scratch accumulators and ``n_tiles`` live f32
-    (G, bq, bk) score tiles, plus a quarter for Mosaic's own temporaries.
-    ``_head_group`` budgets the score tiles only; at f32 the operand
-    blocks alone double, and the BERT-shape forward asked for 16.42 MiB
-    of the 16 MiB a kernel gets without a limit. Never below that
-    default."""
+    keeps in VMEM: ``n_blocks`` (g, block, width) operand/result blocks
+    in the input dtype (Pallas double-buffers each; a block narrower
+    than 128 lanes still fills whole lane tiles there), ``n_acc`` f32
+    values of that shape (scratch accumulators, the per-head statistics
+    and partial results), ``n_tiles`` live f32 (g, bq, bk) score tiles
+    for each head of the block (Mosaic gives every head of the unrolled
+    loop its own) and ``n_rows`` (g, heads, bq, 8 -> 128 lanes) f32
+    statistic blocks, plus a quarter for Mosaic's own temporaries.
+    ``_head_group`` budgets
+    the score tiles only; at f32 the operand blocks alone double, and
+    the BERT-shape forward asked for 16.42 MiB of the 16 MiB a kernel
+    gets without a limit. Never below that default."""
     from .kernels import VMEM_SCOPED_DEFAULT_BYTES
-    block = g * max(block_q, block_k) * dp
+    block = g * max(t.block_q, t.block_k) * (-(-t.width // 128) * 128)
     need = (2 * n_blocks * block * itemsize + n_acc * block * 4
-            + n_tiles * g * block_q * block_k * 4)
+            + n_tiles * g * t.heads * t.block_q * t.block_k * 4
+            + 2 * n_rows * g * t.heads * t.block_q * 128 * 4)
     return max(VMEM_SCOPED_DEFAULT_BYTES, need + need // 4)
 
 
+def _head_masks(shape, heads: int):
+    """One lane mask per head of a (g, rows, width) block: True on the
+    head's own width // heads lanes. ``(None,)`` when one head fills
+    the block."""
+    if heads == 1:
+        return (None,)
+    d = shape[2] // heads
+    lane = lax.broadcasted_iota(jnp.int32, shape, 2)
+    return tuple((lane >= i * d) & (lane < (i + 1) * d)
+                 for i in range(heads))
+
+
+def _only(x, mask):
+    """``x`` with the other heads' lanes zeroed: contracting it over the
+    block's whole width then contracts over this head alone."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _by_head(parts, masks):
+    """One (g, rows, width) value from one per head, each taken on its
+    own lanes (the parts are per-row scalars (g, rows, 1), or products
+    over the block's whole width of which only the head's lanes are
+    this head's)."""
+    out = parts[0]
+    for part, mask in zip(parts[1:], masks[1:]):
+        out = jnp.where(mask, part, out)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU forward kernel
+# ---------------------------------------------------------------------------
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                   sm_scale, causal, block_q, block_k, nk, seq_q, seq_k,
-                  need_mask):
+                  need_mask, heads):
     from jax.experimental import pallas as pl
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
@@ -188,10 +367,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         # dots take the INPUT dtype (bf16 under AMP) with f32
         # accumulation — an astype(f32) here would push the MXU onto its
         # ~6x slower f32 passes
-        q = q_ref[...]                            # (G, block_q, d)
-        k = k_ref[...]                            # (G, block_k, d)
-        s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * sm_scale
+        q = q_ref[...]                            # (G, block_q, width)
+        k = k_ref[...]                            # (G, block_k, width)
+        v = v_ref[...]
+        masks = _head_masks(q.shape, heads)
+        valid = None
         if need_mask or causal:
             # masking is real VPU work on a (bq, bk) tile — emitted only
             # when there is padding to hide or a causal wedge to cut
@@ -202,111 +382,104 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                 q_pos = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0) + diag_off
                 valid = valid & (k_pos <= q_pos)
-            s = jnp.where(valid[None], s, _NEG_INF)
-
-        m_prev = m_s[:, :, :1]                    # (G, block_q, 1)
-        m_cur = s.max(axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_s[:, :, :1] * alpha + p.sum(axis=2, keepdims=True)
-        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
-        acc_s[...] = acc_s[...] * alpha + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        alphas, pvs = [], []
+        for i, mask in enumerate(masks):          # static: heads <= 16
+            s = lax.dot_general(_only(q, mask), k,
+                                (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * sm_scale
+            if valid is not None:
+                s = jnp.where(valid[None], s, _NEG_INF)
+            m_prev = m_s[i, :, :, :1]             # (G, block_q, 1)
+            m_cur = s.max(axis=2, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_s[i, :, :, :1] * alpha + p.sum(axis=2, keepdims=True)
+            m_s[i] = jnp.broadcast_to(m_new, m_s.shape[1:])
+            l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
+            alphas.append(alpha)
+            pvs.append(lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32))
+        acc_s[...] = acc_s[...] * _by_head(alphas, masks) \
+            + _by_head(pvs, masks)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_s[:, :, :1], 1e-30)
-        out = acc_s[...] / l
+        masks = _head_masks(acc_s.shape, heads)
+        ms = [m_s[i, :, :, :1] for i in range(heads)]
+        ls = [jnp.maximum(l_s[i, :, :, :1], 1e-30) for i in range(heads)]
+        out = acc_s[...] / _by_head(ls, masks)
         # rows that never saw a valid key (m still at init) output zero —
         # the shared convention across every path in this module
-        out = jnp.where(m_s[:, :, :1] > _NEG_INF / 2, out, 0.0)
+        out = jnp.where(_by_head(ms, masks) > _NEG_INF / 2, out, 0.0)
         o_ref[...] = out.astype(o_ref.dtype)
-        # log-sum-exp per row: the residual the backward kernels need
-        # (p = exp(s - lse) reconstructs softmax without the S×S matrix)
-        lse = jnp.where(m_s[:, :, :1] > _NEG_INF / 2,
-                        m_s[:, :, :1] + jnp.log(l), _NEG_INF)
-        # 8-lane replication: narrowest layout the TPU tiling rules allow
-        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
-
-
-def _pad_for_blocks(q, k, v, block_q, block_k):
-    """Shared fwd/bwd tiling preamble: clamp block sizes, pad seq dims to
-    block multiples and head_dim to the 128-lane tile, fold (B, H) →
-    batch-of-heads. The backward's exp(s - lse) recompute is only correct
-    when it uses EXACTLY these conventions — keep this the single source."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    block_q = min(block_q, max(sq, 8))
-    block_k = min(block_k, max(sk, 8))
-    dp = max(128, -(-d // 128) * 128)
-    sqp = -(-sq // block_q) * block_q
-    skp = -(-sk // block_k) * block_k
-
-    def pad3(x, s_to, d_to):
-        return jnp.pad(x, ((0, 0), (0, 0), (0, s_to - x.shape[2]),
-                           (0, d_to - x.shape[3])))
-
-    qp = pad3(q, sqp, dp).reshape(b * h, sqp, dp)
-    kp = pad3(k, skp, dp).reshape(b * h, skp, dp)
-    vp = pad3(v, skp, dp).reshape(b * h, skp, dp)
-    return (qp, kp, vp, pad3, block_q, block_k, dp, sqp, skp,
-            sqp // block_q, skp // block_k)
+        for i, (m, l) in enumerate(zip(ms, ls)):
+            # log-sum-exp per row: the residual the backward kernels need
+            # (p = exp(s - lse) reconstructs softmax without the S×S
+            # matrix)
+            lse = jnp.where(m > _NEG_INF / 2, m + jnp.log(l), _NEG_INF)
+            # 8-lane replication: narrowest layout the TPU tiling allows
+            lse_ref[:, i] = jnp.broadcast_to(
+                lse, (lse.shape[0], lse.shape[1], lse_ref.shape[-1]))
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
-                      block_q: int = 512, block_k: int = 512,
-                      interpret: bool = False):
-    # 512x512 blocks measured 2.2x faster than 128x128 on one TPU chip
-    # (8x12x2048x64 causal: 4.5ms vs 13ms; XLA blockwise scan: 9.7ms)
-    """Pallas flash attention forward → (out, lse). Padding/tiling via
-    _pad_for_blocks; zero-padded head dims cancel in QK^T and are sliced
-    off the output."""
+                      block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
+                      interpret: bool = False, num_heads=None):
+    # 512x512 blocks measured 2.2x faster than 128x128 on an earlier
+    # chip (8x12x2048x64 causal: 4.5ms vs 13ms; XLA blockwise scan:
+    # 9.7ms). The same shape on a v5e, bf16, ms a call (PR 27): forward
+    # 2.33 with heads padded to 128 lanes in HBM, 2.28 at the head's own
+    # width, 2.25 from (B, S, H*D); forward + backward 8.25, 7.80, 6.17.
+    """Pallas flash attention forward → (out, lse). q, k, v are
+    (B, H, S, D), or (B, S, H*D) with ``num_heads``; ``out`` comes back
+    in the same form and ``lse`` as the kernel wrote it, (rows, heads,
+    padded seq, 8) f32, for ``_flash_bwd_pallas`` alone. Tiling via
+    ``_pad_for_blocks``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    (qp, kp, vp, _, block_q, block_k, dp, sqp, skp, nq, nk) = \
-        _pad_for_blocks(q, k, v, block_q, block_k)
-    g = _head_group(b * h, block_q, block_k)
+    qt, kt, vt, _, from_tiles, t = _pad_for_blocks(
+        q, k, v, block_q, block_k, num_heads)
+    g = _head_group(t.rows, t.block_q, t.block_k, heads_per_block=t.heads)
+    w = t.width
 
     kernel = functools.partial(
-        _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk, seq_q=sq, seq_k=sk,
-        need_mask=(skp != sk))
+        _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=t.block_q,
+        block_k=t.block_k, nk=t.nk, seq_q=t.seq_q, seq_k=t.seq_k,
+        need_mask=(t.skp != t.seq_k), heads=t.heads)
+    q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, qi, ki: (r, qi, c))
+    k_spec = pl.BlockSpec((g, t.block_k, w), lambda r, c, qi, ki: (r, ki, c))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h // g, nq, nk),
-        in_specs=[
-            pl.BlockSpec((g, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((g, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((g, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
+        grid=(t.rows // g, t.col_tiles, t.nq, t.nk),
+        in_specs=[q_spec, k_spec, k_spec],
         out_specs=[
-            pl.BlockSpec((g, block_q, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((g, block_q, 8), lambda bh, qi, ki: (bh, qi, 0)),
+            q_spec,
+            pl.BlockSpec((g, t.heads, t.block_q, 8),
+                         lambda r, c, qi, ki: (r, c, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sqp, 8), jnp.float32),
+            jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            jax.ShapeDtypeStruct(
+                (t.rows, t.col_tiles * t.heads, t.sqp, 8), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, block_q, 128), jnp.float32),
-            pltpu.VMEM((g, block_q, 128), jnp.float32),
-            pltpu.VMEM((g, block_q, dp), jnp.float32),
+            pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
+            pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
+            pltpu.VMEM((g, t.block_q, w), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # q, k, v, o blocks; m, l, acc scratch; s and p tiles
-            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
-                                         q.dtype.itemsize, 4, 3, 2)),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            # q, k, v, o blocks; acc scratch, m and l and one product a
+            # head; s and p tiles; the lse block
+            vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 4,
+                                         1 + 3 * t.heads, 2, 1)),
         interpret=interpret,
-    )(qp, kp, vp)
-    return (out.reshape(b, h, sqp, dp)[:, :, :sq, :d],
-            lse[:, :, 0].reshape(b, h, sqp)[:, :, :sq])
+    )(qt, kt, vt)
+    return from_tiles(out, t.seq_q), lse
 
 
 # ---------------------------------------------------------------------------
@@ -332,12 +505,30 @@ def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k):
     return valid
 
 
+def _bwd_head(q, k, v, do, lse, delta, qmask, valid, sm_scale):
+    """One head's softmax block rebuilt from its log-sum-exp, and the
+    score gradient: (p, ds), both f32 (G, bq, bk). ``qmask`` picks the
+    head's lanes of the q-side operands (q, do); k and v need none, the
+    zeroed lanes of the other side cancel theirs."""
+    # operands keep the input dtype (bf16 under AMP), f32 accumulate
+    # — see the forward kernel's MXU-pass note
+    s = lax.dot_general(_only(q, qmask), k, (((2,), (2,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32) * sm_scale
+    p = jnp.exp(s - lse)                            # (G, bq, bk)
+    if valid is not None:
+        p = jnp.where(valid[None], p, 0.0)
+    dp = lax.dot_general(_only(do, qmask), v, (((2,), (2,)), ((0,), (0,))),
+                         preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, causal,
-                          block_q, block_k, nq, seq_q, seq_k, need_mask):
+                          block_q, block_k, nq, seq_q, seq_k, need_mask,
+                          heads):
     from jax.experimental import pallas as pl
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -350,30 +541,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _compute():
-        # operands keep the input dtype (bf16 under AMP), f32 accumulate
-        # — see the forward kernel's MXU-pass note
-        q = q_ref[...]                              # (G, bq, d)
-        k = k_ref[...]                              # (G, bk, d)
+        q = q_ref[...]                              # (G, bq, width)
+        k = k_ref[...]                              # (G, bk, width)
         v = v_ref[...]
-        do = do_ref[...]                            # (G, bq, d)
-        lse = lse_ref[...][:, :, :1]                # (G, bq, 1)
-        delta = delta_ref[...][:, :, :1]            # (G, bq, 1)
-        s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.exp(s - lse)                        # (G, bq, bk)
-        if need_mask or causal:
-            valid = _bwd_mask(qi, ki, block_q, block_k, causal,
-                              seq_q, seq_k)
-            p = jnp.where(valid[None], p, 0.0)
-        dv_s[...] += lax.dot_general(p.astype(do.dtype), do,
-                                     (((1,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale)
-        dk_s[...] += lax.dot_general(ds.astype(q.dtype), q,
-                                     (((1,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
+        do = do_ref[...]                            # (G, bq, width)
+        qmasks = _head_masks(q.shape, heads)
+        kmasks = _head_masks(k.shape, heads)
+        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k) \
+            if need_mask or causal else None
+        dks, dvs = [], []
+        for i, qmask in enumerate(qmasks):
+            p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
+                              delta_ref[:, i][:, :, :1], qmask, valid,
+                              sm_scale)
+            dvs.append(lax.dot_general(p.astype(do.dtype), do,
+                                       (((1,), (1,)), ((0,), (0,))),
+                                       preferred_element_type=jnp.float32))
+            dks.append(lax.dot_general(ds.astype(q.dtype), q,
+                                       (((1,), (1,)), ((0,), (0,))),
+                                       preferred_element_type=jnp.float32))
+        dv_s[...] += _by_head(dvs, kmasks)
+        dk_s[...] += _by_head(dks, kmasks)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -381,46 +569,49 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                             dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                            block_q, block_k, seq_q, seq_k, need_mask):
+                            block_q, block_k, seq_q, seq_k, need_mask,
+                            heads):
     """Single-block backward (nq == nk == 1, the short-seq fast path):
     one program computes dq, dk AND dv, reconstructing the softmax block
     ONCE — the two-kernel general path pays the s = qk^T + exp recompute
-    twice, and that VPU work dominates short-seq attention (r5)."""
-    q = q_ref[...]                                  # (G, bq, d)
-    k = k_ref[...]                                  # (G, bk, d)
+    twice, and that VPU work dominates short-seq attention (r5). delta_i
+    = rowsum(dO_i * O_i) is taken here from the o block, per head, so no
+    XLA pass over dO and O precedes the call."""
+    q = q_ref[...]                                  # (G, bq, width)
+    k = k_ref[...]                                  # (G, bk, width)
     v = v_ref[...]
     do = do_ref[...]
-    lse = lse_ref[...][:, :, :1]
-    delta = delta_ref[...][:, :, :1]
-    s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32) * sm_scale
-    p = jnp.exp(s - lse)                            # (G, bq, bk)
-    if need_mask or causal:
-        valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k)
-        p = jnp.where(valid[None], p, 0.0)
-    pb = p.astype(do.dtype)
-    dv_ref[...] = lax.dot_general(
-        pb, do, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
-                         preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-    dq_ref[...] = lax.dot_general(
-        ds, k, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[...] = lax.dot_general(
-        ds, q, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+    do_o = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
+    qmasks = _head_masks(q.shape, heads)
+    kmasks = _head_masks(k.shape, heads)
+    valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k) \
+        if need_mask or causal else None
+    dqs, dks, dvs = [], [], []
+    for i, qmask in enumerate(qmasks):
+        delta = _only(do_o, qmask).sum(axis=2, keepdims=True)
+        p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1], delta,
+                          qmask, valid, sm_scale)
+        ds = ds.astype(k.dtype)
+        dvs.append(lax.dot_general(p.astype(do.dtype), do,
+                                   (((1,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+        dqs.append(lax.dot_general(ds, k, (((2,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+        dks.append(lax.dot_general(ds, q, (((1,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+    dq_ref[...] = _by_head(dqs, qmasks).astype(dq_ref.dtype)
+    dk_ref[...] = _by_head(dks, kmasks).astype(dk_ref.dtype)
+    dv_ref[...] = _by_head(dvs, kmasks).astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_s, *, sm_scale, causal, block_q,
-                         block_k, nk, seq_q, seq_k, need_mask):
+                         block_k, nk, seq_q, seq_k, need_mask, heads):
     from jax.experimental import pallas as pl
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
@@ -432,26 +623,22 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _compute():
-        # operands keep the input dtype (bf16 under AMP), f32 accumulate
-        q = q_ref[...]                              # (G, bq, d)
-        k = k_ref[...]                              # (G, bk, d)
+        q = q_ref[...]                              # (G, bq, width)
+        k = k_ref[...]                              # (G, bk, width)
         v = v_ref[...]
         do = do_ref[...]
-        lse = lse_ref[...][:, :, :1]
-        delta = delta_ref[...][:, :, :1]
-        s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.exp(s - lse)
-        if need_mask or causal:
-            valid = _bwd_mask(qi, ki, block_q, block_k, causal,
-                              seq_q, seq_k)
-            p = jnp.where(valid[None], p, 0.0)
-        dp = lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_s[...] += lax.dot_general(ds.astype(k.dtype), k,
-                                     (((2,), (1,)), ((0,), (0,))),
-                                     preferred_element_type=jnp.float32)
+        qmasks = _head_masks(q.shape, heads)
+        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k) \
+            if need_mask or causal else None
+        dqs = []
+        for i, qmask in enumerate(qmasks):
+            _, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
+                              delta_ref[:, i][:, :, :1], qmask, valid,
+                              sm_scale)
+            dqs.append(lax.dot_general(ds.astype(k.dtype), k,
+                                       (((2,), (1,)), ((0,), (0,))),
+                                       preferred_element_type=jnp.float32))
+        dq_s[...] += _by_head(dqs, qmasks)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -459,123 +646,131 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
-                      block_q: int = 512, block_k: int = 512,
-                      interpret: bool = False):
-    """Pallas flash attention backward: dq via a (q-parallel, k-inner)
-    kernel, dk/dv via a (k-parallel, q-inner) kernel."""
+                      block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
+                      interpret: bool = False, num_heads=None):
+    """Pallas flash attention backward: one fused kernel when the whole
+    sequence is one block, else dq via a (q-parallel, k-inner) kernel
+    and dk/dv via a (k-parallel, q-inner) kernel. ``lse`` is what
+    ``_flash_fwd_pallas`` returned for the same blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    (qp, kp, vp, pad3, block_q, block_k, dp, sqp, skp, nq, nk) = \
-        _pad_for_blocks(q, k, v, block_q, block_k)
-    dop = pad3(do.astype(q.dtype), sqp, dp).reshape(b * h, sqp, dp)
-    # delta_i = rowsum(dO_i * O_i) (cheap; XLA fuses into the pad)
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
-    dl = jnp.pad(delta.reshape(b * h, sq), ((0, 0), (0, sqp - sq)))
-    lsep = jnp.pad(lse.reshape(b * h, sq), ((0, 0), (0, sqp - sq)))
-    # 8-lane replication (TPU block tiling minimum for a row vector)
-    dl = jnp.broadcast_to(dl[..., None], dl.shape + (8,))
-    lsep = jnp.broadcast_to(lsep[..., None], lsep.shape + (8,))
-    g = _head_group(b * h, block_q, block_k, n_tiles=4)
-    need_mask = (skp != sk) or (sqp != sq)
+    qt, kt, vt, to_tiles, from_tiles, t = _pad_for_blocks(
+        q, k, v, block_q, block_k, num_heads)
+    sq, sk = t.seq_q, t.seq_k
+    do = do.astype(q.dtype)
+    dot = to_tiles(do, t.sqp)
+    g = _head_group(t.rows, t.block_q, t.block_k, n_tiles=4,
+                    heads_per_block=t.heads)
+    need_mask = (t.skp != sk) or (t.sqp != sq)
+    w, heads = t.width, t.heads
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=t.block_q,
+                  block_k=t.block_k, seq_q=sq, seq_k=sk,
+                  need_mask=need_mask, heads=heads)
+    q_shape = jax.ShapeDtypeStruct(qt.shape, q.dtype)
+    k_shape = jax.ShapeDtypeStruct(kt.shape, k.dtype)
 
-    if nq == 1 and nk == 1:
-        bspec = lambda blk: pl.BlockSpec((g, blk, dp),
-                                         lambda bh: (bh, 0, 0))
-        rspec = pl.BlockSpec((g, block_q, 8), lambda bh: (bh, 0, 0))
+    if t.nq == 1 and t.nk == 1:
+        bspec = lambda blk: pl.BlockSpec((g, blk, w),
+                                         lambda r, c: (r, 0, c))
+        rspec = pl.BlockSpec((g, heads, t.block_q, 8),
+                             lambda r, c: (r, c, 0, 0))
         dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _flash_bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k, seq_q=sq, seq_k=sk,
-                need_mask=need_mask),
-            grid=(b * h // g,),
-            in_specs=[bspec(block_q), bspec(block_k), bspec(block_k),
-                      bspec(block_q), rspec, rspec],
-            out_specs=[bspec(block_q), bspec(block_k), bspec(block_k)],
-            out_shape=[jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype),
-                       jax.ShapeDtypeStruct((b * h, skp, dp), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, skp, dp), v.dtype)],
+            functools.partial(_flash_bwd_fused_kernel, **static),
+            grid=(t.rows // g, t.col_tiles),
+            in_specs=[bspec(t.block_q), bspec(t.block_k), bspec(t.block_k),
+                      bspec(t.block_q), bspec(t.block_q), rspec],
+            out_specs=[bspec(t.block_q), bspec(t.block_k),
+                       bspec(t.block_k)],
+            out_shape=[q_shape, k_shape, k_shape],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",),
-                # q, k, v, do in, dq, dk, dv out; s, p, dp, ds tiles
-                vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
-                                             q.dtype.itemsize, 7, 0, 4)),
+                dimension_semantics=("parallel", "parallel"),
+                # q, k, v, o, do in, dq, dk, dv out; dO*O and three
+                # products a head; s, p, dp, ds tiles; the lse block
+                vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 8,
+                                             1 + 3 * heads, 4, 1)),
             interpret=interpret,
-        )(qp, kp, vp, dop, lsep, dl)
-        return (dq.reshape(b, h, sqp, dp)[:, :, :sq, :d],
-                dk.reshape(b, h, skp, dp)[:, :, :sk, :d],
-                dv.reshape(b, h, skp, dp)[:, :, :sk, :d])
+        )(qt, kt, vt, to_tiles(o, t.sqp), dot, lse)
+        return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
 
-    q_spec = pl.BlockSpec((g, block_q, dp), lambda bh, a, c: (bh, a, 0))
-    row_spec = pl.BlockSpec((g, block_q, 8), lambda bh, a, c: (bh, a, 0))
+    # delta_i = rowsum(dO_i * O_i), per head, laid out as the lse is
+    # (cheap; XLA fuses it into one pass over dO and O)
+    delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if num_heads is None:
+        delta = delta.sum(-1)
+    else:
+        # reduce first: the transpose then moves one number a head
+        delta = delta.reshape(t.batch, sq, t.num_heads,
+                              t.head_dim).sum(-1).transpose(0, 2, 1)
+    delta = delta.reshape(lse.shape[0], lse.shape[1], sq)
+    if t.sqp != sq:
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t.sqp - sq)))
+    # 8-lane replication (TPU block tiling minimum for a row vector)
+    delta = jnp.broadcast_to(delta[..., None], lse.shape)
+
+    q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, a, b_: (r, a, c))
+    row_spec = pl.BlockSpec((g, heads, t.block_q, 8),
+                            lambda r, c, a, b_: (r, c, a, 0))
+    k_in = pl.BlockSpec((g, t.block_k, w), lambda r, c, qi, ki: (r, ki, c))
 
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          nk=nk, seq_q=sq, seq_k=sk, need_mask=need_mask),
-        grid=(b * h // g, nq, nk),
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((g, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((g, block_k, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            q_spec, row_spec, row_spec,
-        ],
+        functools.partial(_flash_bwd_dq_kernel, nk=t.nk, **static),
+        grid=(t.rows // g, t.col_tiles, t.nq, t.nk),
+        in_specs=[q_spec, k_in, k_in, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, sqp, dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((g, block_q, dp), jnp.float32)],
+        out_shape=q_shape,
+        scratch_shapes=[pltpu.VMEM((g, t.block_q, w), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # q, k, v, do in, dq out; dq scratch; s, p, dp, ds tiles
-            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
-                                         q.dtype.itemsize, 5, 1, 4)),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            # q, k, v, do in, dq out; dq scratch and one product a head;
+            # s, p, dp, ds tiles; lse and delta blocks
+            vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 5,
+                                         1 + heads, 4, 2)),
         interpret=interpret,
-    )(qp, kp, vp, dop, lsep, dl)
+    )(qt, kt, vt, dot, lse, delta)
 
-    k_spec = pl.BlockSpec((g, block_k, dp), lambda bh, ki, qi: (bh, ki, 0))
-    qrow = pl.BlockSpec((g, block_q, dp), lambda bh, ki, qi: (bh, qi, 0))
-    rrow = pl.BlockSpec((g, block_q, 8), lambda bh, ki, qi: (bh, qi, 0))
+    k_spec = pl.BlockSpec((g, t.block_k, w), lambda r, c, ki, qi: (r, ki, c))
+    qrow = pl.BlockSpec((g, t.block_q, w), lambda r, c, ki, qi: (r, qi, c))
+    rrow = pl.BlockSpec((g, heads, t.block_q, 8),
+                        lambda r, c, ki, qi: (r, c, qi, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          nq=nq, seq_q=sq, seq_k=sk, need_mask=need_mask),
-        grid=(b * h // g, nk, nq),
+        functools.partial(_flash_bwd_dkv_kernel, nq=t.nq, **static),
+        grid=(t.rows // g, t.col_tiles, t.nk, t.nq),
         in_specs=[qrow, k_spec, k_spec, qrow, rrow, rrow],
         out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, skp, dp), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, skp, dp), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((g, block_k, dp), jnp.float32),
-                        pltpu.VMEM((g, block_k, dp), jnp.float32)],
+        out_shape=[k_shape, k_shape],
+        scratch_shapes=[pltpu.VMEM((g, t.block_k, w), jnp.float32),
+                        pltpu.VMEM((g, t.block_k, w), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # q, k, v, do in, dk, dv out; dk, dv scratch; 4 score tiles
-            vmem_limit_bytes=_vmem_limit(g, block_q, block_k, dp,
-                                         q.dtype.itemsize, 6, 2, 4)),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            # q, k, v, do in, dk, dv out; dk, dv scratch and two
+            # products a head; 4 score tiles; lse and delta blocks
+            vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 6,
+                                         2 + 2 * heads, 4, 2)),
         interpret=interpret,
-    )(qp, kp, vp, dop, lsep, dl)
+    )(qt, kt, vt, dot, lse, delta)
 
-    return (dq.reshape(b, h, sqp, dp)[:, :, :sq, :d],
-            dk.reshape(b, h, skp, dp)[:, :, :sk, :d],
-            dv.reshape(b, h, skp, dp)[:, :, :sk, :d])
+    return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_tpu(q, k, v, causal, sm_scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_tpu(q, k, v, causal, sm_scale, interpret, num_heads=None):
     return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                             interpret=interpret)[0]
+                             interpret=interpret, num_heads=num_heads)[0]
 
 
-def _flash_tpu_fwd(q, k, v, causal, sm_scale, interpret):
+def _flash_tpu_fwd(q, k, v, causal, sm_scale, interpret, num_heads):
     o, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                               interpret=interpret)
+                               interpret=interpret, num_heads=num_heads)
     return o, (q, k, v, o, lse)
 
 
-def _flash_tpu_bwd(causal, sm_scale, interpret, res, g):
+def _flash_tpu_bwd(causal, sm_scale, interpret, num_heads, res, g):
     q, k, v, o, lse = res
     return _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
-                             interpret=interpret)
+                             interpret=interpret, num_heads=num_heads)
 
 
 _flash_tpu.defvjp(_flash_tpu_fwd, _flash_tpu_bwd)
@@ -629,6 +824,32 @@ def _flash_vl_bwd(causal, sm_scale, res, g):
 _flash_vl.defvjp(_flash_vl_fwd, _flash_vl_bwd)
 
 
+def _kernel_tier(q, k, num_heads, use_pallas) -> Optional[bool]:
+    """Which tier takes this call: None for the XLA reference, else
+    whether the kernel bodies run interpreted. ``use_pallas`` None asks
+    the shared MXNET_PALLAS three-tier gate (ops/kernels): compiled
+    kernels on TPU, interpret-mode bodies when forced on other backends,
+    blockwise-XLA reference otherwise. A call the kernels take is
+    counted by the layout its shapes gave it, and the gate's recorded
+    reason says which."""
+    tiles = _tiles(q.shape, k.shape, _BLOCK_Q, _BLOCK_K, num_heads)
+    if use_pallas is None:
+        from .kernels import dispatch as _kdispatch
+        path, _ = _kdispatch("flash_attention", detail=tiles.reason)
+    else:
+        path = "pallas" if use_pallas else "xla"
+    if path == "xla":
+        return None
+    try:
+        from ..telemetry import names as tn
+        from ..telemetry import registry as treg
+        treg().counter(tn.FLASH_ATTENTION_LAYOUT,
+                       label_key="layout").inc(label=tiles.layout)
+    except Exception:   # telemetry must never fail a kernel call
+        pass
+    return path == "interpret"
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     use_pallas: Optional[bool] = None,
@@ -649,21 +870,42 @@ def flash_attention(q, k, v, causal: bool = False,
     if valid_length is not None:
         vl = jnp.asarray(valid_length, jnp.float32)
         return _flash_vl(q, k, v, vl, causal, float(sm_scale))
-    if use_pallas is None:
-        # the shared MXNET_PALLAS three-tier gate (ops/kernels):
-        # compiled kernels on TPU, interpret-mode bodies when forced
-        # on other backends, blockwise-XLA reference otherwise
-        from .kernels import dispatch as _kdispatch
-        path, _ = _kdispatch("flash_attention")
-        if path != "xla":
-            return _flash_tpu(q, k, v, causal, float(sm_scale),
-                              path == "interpret")
+    interpret = _kernel_tier(q, k, None, use_pallas)
+    if interpret is None:
         return _flash(q, k, v, causal, float(sm_scale))
-    if use_pallas:
-        # full-Pallas path: flash forward AND FlashAttention-2-style
-        # backward kernels (dq + dkv) off the saved log-sum-exp
-        return _flash_tpu(q, k, v, causal, float(sm_scale), False)
-    return _flash(q, k, v, causal, float(sm_scale))
+    # full-Pallas path: flash forward AND FlashAttention-2-style
+    # backward kernels off the saved log-sum-exp
+    return _flash_tpu(q, k, v, causal, float(sm_scale), interpret)
+
+
+def flash_attention_bsh(q, k, v, num_heads: int, causal: bool = False,
+                        sm_scale: Optional[float] = None):
+    """:func:`flash_attention` on (B, S, H*D) tensors, heads side by
+    side on the last axis as a projection emits them and as the output
+    projection eats them; returns (B, S, H*D).
+
+    When the head width divides 128 (and H*D is a multiple of 128) or
+    is a multiple of it, the Pallas kernels read and write these arrays
+    where they lie: no head transpose, no padding (``_Tiles``,
+    "packed"). Any other width, and the XLA tier, transposes to
+    (B, H, S, D) here, inside the op, and back.
+    """
+    if q.ndim != 3 or k.ndim != 3 or v.ndim != 3:
+        raise MXNetError("flash_attention_bsh expects (batch, seq, "
+                         "heads * dim)")
+    if q.shape[-1] % num_heads:
+        raise MXNetError(f"flash_attention_bsh: width {q.shape[-1]} not "
+                         f"divisible by heads {num_heads}")
+    d = q.shape[-1] // num_heads
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    interpret = _kernel_tier(q, k, num_heads, None)
+    if interpret is not None:
+        return _flash_tpu(q, k, v, causal, float(sm_scale), interpret,
+                          num_heads)
+    return _merge_heads(_flash(*(_split_heads(x, num_heads)
+                                 for x in (q, k, v)),
+                               causal, float(sm_scale)))
 
 
 # ---------------------------------------------------------------------------

@@ -163,7 +163,8 @@ def _tpu_path(mode: str) -> Tuple[str, str]:
 
 
 def dispatch(kernel: str, supported: bool = True,
-             reason: Optional[str] = None) -> Tuple[str, str]:
+             reason: Optional[str] = None,
+             detail: Optional[str] = None) -> Tuple[str, str]:
     """The three-tier dispatch decision for one kernel call site.
 
     Returns ``(path, reason)`` with path one of ``'pallas'`` (compiled
@@ -171,7 +172,9 @@ def dispatch(kernel: str, supported: bool = True,
     ``pallas_call(interpret=True)``), ``'xla'`` (reference
     implementation). ``supported=False`` forces the XLA tier with the
     caller's ``reason`` (shape/mode the kernel does not cover) — the
-    fallback is automatic, never an error."""
+    fallback is automatic, never an error. ``detail`` is what the caller
+    can say of a call the kernel takes (flash attention: the layout its
+    shapes gave it); it joins the recorded reason."""
     import jax
     mode = pallas_mode()
     if not supported:
@@ -189,6 +192,8 @@ def dispatch(kernel: str, supported: bool = True,
         else:
             out = ("xla", f"MXNET_PALLAS=auto, non-TPU backend "
                           f"({backend}): XLA reference")
+    if detail and out[0] != "xla":
+        out = (out[0], f"{out[1]}; {detail}")
     _DECISIONS[kernel] = out
     try:
         from ...telemetry import names as tn

@@ -152,6 +152,7 @@ OVERLAP_FRACTION = "mx_overlap_fraction"
 # Pallas kernel layer (ops/kernels dispatch gate)
 # ---------------------------------------------------------------------------
 KERNEL_DISPATCH = "mx_kernel_dispatch_total"
+FLASH_ATTENTION_LAYOUT = "mx_flash_attention_layout_total"
 
 # ---------------------------------------------------------------------------
 # self-tuning performance autopilot (tuning/)
@@ -465,6 +466,14 @@ CATALOG = {
              "(pallas = compiled TPU kernel, interpret = kernel body "
              "under pallas interpret mode, xla = reference fallback; "
              "MXNET_PALLAS gate, docs/PERF_NOTES.md)"),
+    FLASH_ATTENTION_LAYOUT: dict(
+        kind="counter", label="layout",
+        help="flash-attention calls the Pallas kernels took, by how "
+             "they address q, k, v (packed = the projections' own "
+             "(B, S, H*D) at the head's width, unpadded = (B*H, S, D) "
+             "at the head's width, padded = head width zero-padded to "
+             "128 lanes in HBM; ops/attention.py _Tiles); one count a "
+             "traced call"),
     AUTOTUNE_TRIALS: dict(
         kind="counter", label="backend",
         help="autotune candidate measurements by backend (timed = "

@@ -257,3 +257,147 @@ def test_flash_pallas_bf16_interpret():
     for g in (dq, dk, dv):
         assert g.dtype == jnp.bfloat16
         assert onp.isfinite(onp.asarray(g, "float32")).all()
+
+
+# ---------------------------------------------------------------------------
+# (B, S, H*D): the kernels at the model's own layout and head width
+# ---------------------------------------------------------------------------
+
+# head width -> the layout its shapes give the (B, S, H*D) entry
+_BSH_LAYOUT = {32: "packed", 64: "packed", 128: "packed", 80: "padded"}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seqs", [(64, 64), (1024, 1024), (128, 256)],
+                         ids=["s64-one-block", "s1024-multi-block",
+                              "s128x256-cross"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("d", [32, 64, 128, 80])
+def test_flash_attention_bsh_parity(d, causal, seqs, dtype, monkeypatch):
+    """Forward and the three gradients of the (B, S, H*D) entry against
+    the unfused reference, kernel bodies interpreted: heads sharing a
+    lane tile (32, 64), a head filling its own (128), a width that keeps
+    the padded path (80); one block, several, and cross-length."""
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+    sq, sk = seqs
+    h = 128 // d if d < 128 else 2          # one 128-lane tile, or two
+    if d == 80:
+        h = 2
+    rng = onp.random.RandomState(d + sq)
+    q, k, v, do = (jnp.asarray(rng.randn(1, s, h * d), dtype)
+                   for s in (sq, sk, sk, sq))
+
+    def ref(q_, k_, v_):
+        return A._merge_heads(A.attention_reference(
+            *(A._split_heads(x.astype(jnp.float32), h)
+              for x in (q_, k_, v_)),
+            causal=causal))
+
+    out, vjp = jax.vjp(lambda *a: A.flash_attention_bsh(*a, h, causal=causal),
+                       q, k, v)
+    want, ref_vjp = jax.vjp(ref, q, k, v)
+    assert A._tiles(q.shape, k.shape, 512, 512, h).layout == _BSH_LAYOUT[d]
+    tol = 2e-4 if dtype == "float32" else 4e-2
+    for got, exp in zip((out,) + vjp(do),
+                        (want,) + ref_vjp(do.astype(jnp.float32))):
+        assert got.shape == exp.shape and got.dtype == q.dtype
+        scale = float(jnp.max(jnp.abs(exp)))
+        onp.testing.assert_allclose(onp.asarray(got, "float32") / scale,
+                                    onp.asarray(exp, "float32") / scale,
+                                    rtol=0, atol=tol)
+
+
+def _walk_eqns(jaxpr, from_pallas=()):
+    """(eqn, an operand is a pallas_call's result) over a jaxpr and the
+    jaxprs its calls hold; a kernel's own body is not entered."""
+    from jax.extend.core import Var
+    from mxnet_tpu.analysis.program import _subjaxprs
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    tainted = set(from_pallas)
+
+    def is_tainted(v):
+        return isinstance(v, Var) and v in tainted
+
+    for eqn in jaxpr.eqns:
+        hit = any(map(is_tainted, eqn.invars))
+        yield eqn, hit
+        if eqn.primitive.name == "pallas_call":
+            tainted.update(eqn.outvars)
+            continue
+        if hit and eqn.primitive.name in ("reshape", "transpose",
+                                          "convert_element_type"):
+            tainted.update(eqn.outvars)     # the same values, re-viewed
+        for sub in (s for p in eqn.params.values() for s in _subjaxprs(p)):
+            sub = getattr(sub, "jaxpr", sub)
+            inner = [iv for iv, ov in zip(sub.invars, eqn.invars)
+                     if is_tainted(ov)] \
+                if len(sub.invars) == len(eqn.invars) else ()
+            yield from _walk_eqns(sub, inner)
+
+
+def test_multi_head_attention_program_moves_no_layout(monkeypatch):
+    """BERT-base's attention layer, forward and backward, kernels on: the
+    program holds the three Pallas calls and nothing that re-lays their
+    operands out: no pad, no head transpose, no slice of a kernel's
+    result."""
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.gluon.block import ParamBinding
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+    mha = nn.MultiHeadAttention(units=768, num_heads=12)
+    mha.initialize()
+    params = list(mha.collect_params().values())
+    datas = [p.data()._data for p in params]
+
+    def loss(datas_, x_):
+        with ParamBinding(params, datas_):
+            out = mha(NDArray(x_))
+        return jnp.sum(out._data ** 2)
+
+    x = jnp.zeros((2, 512, 768), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(datas, x)
+    seen = list(_walk_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e, _ in seen]
+    assert names.count("pallas_call") == 2          # forward, fused backward
+    assert "pad" not in names
+    assert not [e for e, _ in seen if e.primitive.name == "transpose"
+                and e.invars[0].aval.ndim >= 4]
+    assert not [e for e, from_kernel in seen if from_kernel
+                and e.primitive.name in ("slice", "dynamic_slice", "gather")]
+
+
+def test_flash_layout_is_recorded_and_counted(monkeypatch):
+    """The layout a call's shapes gave it is in the dispatch decision's
+    reason and in ``mx_flash_attention_layout_total``: D = 80 keeps the
+    padded path, D = 64 packs two heads into a lane tile, and the
+    (B, H, S, D) form goes unpadded."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import kernels
+    from mxnet_tpu.telemetry import names as tnames
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+
+    def counts():
+        return {lay: telemetry.value(tnames.FLASH_ATTENTION_LAYOUT, lay) or 0
+                for lay in A.FLASH_LAYOUTS}
+
+    x80 = jnp.ones((1, 16, 2 * 80), jnp.float32)
+    before = counts()
+    out = A.flash_attention_bsh(x80, x80, x80, 2)
+    assert out.shape == x80.shape
+    path, reason = kernels.decisions()["flash_attention"]
+    assert path == "interpret" and "padded: D=80 → 128" in reason
+    assert counts() == dict(before, padded=before["padded"] + 1)
+
+    x64 = jnp.ones((1, 16, 2 * 64), jnp.float32)
+    A.flash_attention_bsh(x64, x64, x64, 2)
+    assert "packed: 2 heads per 128 lanes, no pad" in \
+        kernels.decisions()["flash_attention"][1]
+    A.flash_attention(*(A._split_heads(x64, 2),) * 3)
+    assert "unpadded: D=64" in kernels.decisions()["flash_attention"][1]
+    assert counts() == {"packed": before["packed"] + 1,
+                        "unpadded": before["unpadded"] + 1,
+                        "padded": before["padded"] + 1}
+    # the XLA tier takes no layout: nothing is counted
+    monkeypatch.setenv("MXNET_PALLAS", "off")
+    A.flash_attention_bsh(x64, x64, x64, 2)
+    assert counts()["packed"] == before["packed"] + 1

@@ -690,6 +690,35 @@ def test_custom_call_flops_builtin_estimators():
     assert fr.compute_bound_pct > 0
 
 
+@pytest.mark.parametrize("q_dims,k_dims,kernel,want", [
+    # BERT-base's layer, 32 x 12 heads of 64 over 512 tokens: the same
+    # count whether the kernel reads (B*H, S, D) or (B, S, H*D)
+    ((384, 512, 64), (384, 512, 64), "_flash_kernel",
+     4 * 32 * 12 * 512 * 512 * 64),
+    ((32, 512, 768), (32, 512, 768), "_flash_kernel",
+     4 * 32 * 12 * 512 * 512 * 64),
+    ((32, 512, 768), (32, 512, 768), "_flash_bwd_fused_kernel",
+     10 * 32 * 12 * 512 * 512 * 64),
+    # cross-length, packed: Sq 128 against Sk 2048
+    ((8, 128, 768), (8, 2048, 768), "_flash_bwd_dkv_kernel",
+     8 * 8 * 12 * 128 * 2048 * 64),
+], ids=["fwd-bh_s_d", "fwd-b_s_hd", "bwd-fused-b_s_hd", "bwd-dkv-cross"])
+def test_flash_flops_count_both_layouts(q_dims, k_dims, kernel, want):
+    from mxnet_tpu.analysis.hlo import parse_hlo
+    shape = lambda dims: "bf16[%s]{2,1,0}" % ",".join(map(str, dims))
+    mod = parse_hlo(f"""\
+HloModule flash_layouts
+
+ENTRY %main {{
+  %q = {shape(q_dims)} parameter(0)
+  %k = {shape(k_dims)} parameter(1)
+  %v = {shape(k_dims)} parameter(2)
+  ROOT %cc = {shape(q_dims)} custom-call(%q, %k, %v), custom_call_target="tpu_custom_call", metadata={{op_name="jit(step)/flash_attention/{kernel}"}}
+}}
+""")
+    assert afusion.op_flops(mod.ops["cc"], mod) == want
+
+
 def test_register_custom_call_flops_hook():
     """The public hook: a registered estimator applies by substring
     match, re-registering a name replaces it, and an estimator that
