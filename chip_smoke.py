@@ -63,6 +63,21 @@ SEED = 21
 TRAIN = dict(batch=32, seq=512, steps=10, vocab=1000, bert="bert_base",
              optimizer=("adam", {"learning_rate": 3e-7}))
 
+# a small SmallThinkerLM for the train phase: head width and layer pattern
+# of the benchmark's configuration (benchmark/grid/configs/
+# smallthinker-21b-a3b.json), everything else cut down; the fixed batch is
+# memorised, so Adam at 1e-3 makes the loss fall
+SPARSE = dict(batch=2, seq=512, steps=8,
+              optimizer=("adam", {"learning_rate": 1e-3}),
+              model=dict(hidden_size=256, head_dim=128,
+                         num_attention_heads=4, num_key_value_heads=2,
+                         num_hidden_layers=4, rope_layout=[0, 1, 1, 1],
+                         sliding_window_layout=[0, 1, 1, 1],
+                         sliding_window_size=128, rope_theta=1.5e6,
+                         rms_norm_eps=1e-6, moe_ffn_hidden_size=256,
+                         moe_router_width=8, moe_num_primary_experts=4,
+                         moe_num_active_primary_experts=2, vocab_size=512))
+
 # the accelerator sizes of bench.py's decode leg
 SERVE = dict(vocab=256, d_model=128, heads=4, requests=16,
              ladder=(1, 2, 4, 8), page_size=16)
@@ -187,6 +202,26 @@ def kernel_cases(tiny: bool = False):
                 lambda q, k, v, heads=heads, causal=causal:
                 attention.flash_attention_bsh(q, k, v, heads, causal=causal),
                 (0, 1, 2))
+
+        # the SmallThinker cell's attention: 28 query heads over 4
+        # key/value heads of 128, causal, with and without the window;
+        # one block at 512, eight at 8192 (there one group, 7 over 1: the
+        # oracle's float32 scores of all 28 heads do not fit the chip)
+        flash = ((64, 2, 1, 24), (1024, 2, 1, None), (1024, 2, 1, 600)) \
+            if tiny else ((512, 28, 4, 256), (8192, 7, 1, None),
+                          (8192, 7, 1, 4096))
+        for seq, heads, kv_heads, window in flash:
+            add("flash_attention",
+                f"flash_attention bsh 1x{seq} h{heads}/{kv_heads}x128 "
+                f"causal window {window}", dtype,
+                lambda rng, seq=seq, heads=heads, kv_heads=kv_heads: (
+                    f32(rng, 1, seq, heads * 128),
+                    f32(rng, 1, seq, kv_heads * 128),
+                    f32(rng, 1, seq, kv_heads * 128)),
+                lambda q, k, v, heads=heads, kv_heads=kv_heads,
+                window=window: attention.flash_attention_bsh(
+                    q, k, v, heads, causal=True, num_kv_heads=kv_heads,
+                    window=window), (0, 1, 2))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -446,7 +481,59 @@ def _flash_layouts() -> dict:
             for lay in FLASH_LAYOUTS}
 
 
-def phase_train(cfg: dict = TRAIN) -> dict:
+def _counter(name: str) -> dict:
+    """A labelled counter of the program as {label: count so far}."""
+    from mxnet_tpu import telemetry
+    return {k: int(v) for k, v in
+            telemetry.registry().counter(name).values().items()}
+
+
+def _sparse_lm(cfg: dict) -> dict:
+    """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
+    fused, traced once, loss falling; → its losses and what the program
+    counted while tracing it (``mx_moe_dispatch_total``,
+    ``mx_attention_mask_total``)."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+    from mxnet_tpu.gluon.model_zoo.smallthinker import SmallThinkerLM
+    from mxnet_tpu.telemetry import names
+    mx.random.seed(SEED)
+    rng = onp.random.RandomState(SEED)
+    net = SmallThinkerLM(cfg["model"])
+    net.initialize(mx.init.Normal(0.02))
+    for name, p in net.collect_params().items():
+        if name.endswith("gamma"):
+            p.set_data(mx.nd.ones(p.shape))
+    trainer = mx.gluon.Trainer(net.collect_params(), *cfg["optimizer"],
+                               kvstore="tpu")
+    loop = mx.gluon.TrainLoop(net, trainer, SoftmaxCrossEntropyLoss())
+    shape = (cfg["batch"], cfg["seq"])
+    x, y = (mx.nd.array(rng.randint(0, cfg["model"]["vocab_size"],
+                                    size=shape).astype("int32"))
+            for _ in range(2))
+    before = {n: _counter(n) for n in (names.MOE_DISPATCH,
+                                       names.ATTENTION_MASK)}
+    mx.amp.init()
+    try:
+        losses = _run_steps(loop, x, y, cfg["steps"])
+    finally:
+        mx.amp.uninit()
+    counted = {n: {k: v - before[n].get(k, 0)
+                   for k, v in _counter(n).items()
+                   if v > before[n].get(k, 0)} for n in before}
+    layers = cfg["model"]["num_hidden_layers"]
+    windowed = sum(cfg["model"]["sliding_window_layout"][:layers])
+    if counted[names.MOE_DISPATCH] != {"grouped": layers} or \
+            counted[names.ATTENTION_MASK].get("window") != windowed:
+        raise RuntimeError(f"SmallThinkerLM traced {counted}, expected "
+                           f"{layers} grouped expert layers, {windowed} "
+                           "of them behind a window")
+    log(f"  SmallThinkerLM: {counted}")
+    return {"loss": [round(l, 4) for l in losses], **counted}
+
+
+def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE) -> dict:
     import jax
     import mxnet_tpu as mx
     platform = jax.devices()[0].platform
@@ -474,7 +561,7 @@ def phase_train(cfg: dict = TRAIN) -> dict:
         raise RuntimeError(f"BERT's attention layers took {layouts}, "
                            "expected one call a layer and none padded")
     return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths,
-            "flash_layouts": layouts}
+            "flash_layouts": layouts, "sparse_lm": _sparse_lm(sparse)}
 
 
 def phase_kernels(tiny: bool = False) -> dict:

@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from .base import MXNetError
 
@@ -151,6 +152,11 @@ def record_op(name: str, fn: Callable, inputs: Sequence[Any],
 
 
 def _zeros_like_aval(aval):
+    """The cotangent of an output nobody differentiated through. An
+    integer or boolean output (the sort indices and group sizes a router
+    hands the expert layer) takes jax's ``float0``, as ``jax.vjp`` asks."""
+    if not jnp.issubdtype(aval.dtype, jnp.inexact):
+        return onp.zeros(aval.shape, jax.dtypes.float0)
     return jnp.zeros(aval.shape, aval.dtype)
 
 
@@ -290,7 +296,11 @@ def backward(heads, head_grads=None, retain_graph=False, create_graph=False,
 def _scatter_input_cts(node, in_cts, ct, leaf_grads, var_ids):
     # zip with snapshotted entries (handle duplicates positionally)
     for pos, g in enumerate(in_cts):
-        if g is None:
+        # an integer input's cotangent is float0 (a numpy array; a lazy
+        # row_sparse cotangent must not be asked its dtype: that
+        # materializes its dense mirror): nothing to carry back
+        if g is None or (isinstance(g, onp.ndarray)
+                         and g.dtype == jax.dtypes.float0):
             continue
         x = node.inputs[pos]
         ent = node.input_entries[pos]

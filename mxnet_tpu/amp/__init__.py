@@ -46,14 +46,14 @@ __all__ = ["init", "uninit", "is_enabled", "init_trainer", "scale_loss",
 TARGET_DTYPE_OPS = {
     "fully_connected", "convolution", "deconvolution", "dot", "batch_dot",
     "linalg_gemm2", "flash_attention", "flash_attention_vl",
-    "masked_attention", "bert_decoder_proj", "moe_ffn",
+    "masked_attention", "bert_decoder_proj", "moe_ffn", "moe_experts",
     "Correlation", "DeformableConvolution",
 }
 
 # Norm ops: f32-pinned only for true fp16 (their kernels already compute
 # statistics in f32 internally — ops/nn.py _stat_dtype — so bf16 may flow).
 NORM_OPS = {
-    "batch_norm", "layer_norm", "group_norm", "instance_norm",
+    "batch_norm", "layer_norm", "rms_norm", "group_norm", "instance_norm",
     "SyncBatchNorm",
 }
 
@@ -63,6 +63,9 @@ NORM_OPS = {
 FP32_OPS = NORM_OPS | {
     "softmax", "log_softmax", "softmax_cross_entropy", "norm", "moments",
     "exp", "log", "l2_normalization", "lrn",
+    # the router of the dropless expert layer: logits, top-k and the
+    # softmax of the chosen logits (a bf16 logit flips near-tied choices)
+    "moe_route",
 }
 
 _state = {"enabled": False, "dtype": None, "wrapper": None}

@@ -23,7 +23,8 @@ from ..block import Block, HybridBlock
 from ..parameter import Parameter
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Embedding",
-           "BatchNorm", "BatchNormReLU", "SyncBatchNorm", "LayerNorm", "GroupNorm",
+           "BatchNorm", "BatchNormReLU", "SyncBatchNorm", "LayerNorm", "RMSNorm",
+           "GroupNorm",
            "InstanceNorm", "Flatten", "Activation", "LeakyReLU", "PReLU",
            "ELU", "SELU", "GELU", "Swish", "SiLU", "Lambda", "HybridLambda",
            "Identity", "Concatenate", "HybridConcatenate"]
@@ -273,6 +274,25 @@ class LayerNorm(HybridBlock):
         self._infer(x)
         return FNN.LayerNorm(x, self.gamma.data(), self.beta.data(),
                              axis=self._axis, eps=self._eps)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square norm over the last axis with a learned gain
+    (``gamma``) and no bias: ``x / sqrt(mean(x^2) + epsilon) * gamma``."""
+
+    def __init__(self, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._eps = epsilon
+        self.gamma = Parameter("gamma", shape=(in_channels,),
+                               init=gamma_initializer)
+
+    def forward(self, x):
+        if self.gamma._data is None:
+            self.gamma.shape = (x.shape[-1],)
+            if self.gamma._deferred_init_args is not None:
+                self.gamma._finish_deferred_init()
+        return FNN.RMSNorm(x, self.gamma.data(), eps=self._eps)
 
 
 class GroupNorm(HybridBlock):

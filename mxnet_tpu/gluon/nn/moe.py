@@ -1,19 +1,29 @@
-"""Mixture-of-Experts Gluon layer.
+"""Mixture-of-Experts Gluon layers.
 
 No reference analog (the reference has no MoE — SURVEY §2.3 lists expert
-parallelism as absent); TPU-native extension backed by ``ops/moe.py``
-(GShard/Switch-style capacity-bounded router + batched expert einsums, with
-an expert-parallel all-to-all path for mesh execution).
+parallelism as absent); TPU-native extensions backed by ``ops/moe.py``:
+
+- ``MoE``: the GShard/Switch layer, a capacity-bounded router (tokens past
+  an expert's capacity are DROPPED) over batched two-matrix expert
+  einsums;
+- ``SparseMoE``: the dropless layer of today's sparse-expert decoders:
+  top-k of the router's logits, a softmax over the chosen ones, gated
+  (ReGLU) experts, no capacity and no dropped token, and a layer that is
+  told which experts it holds (``held``).
 """
 from __future__ import annotations
 
+import functools
+
+from ...base import MXNetError
 from ...ndarray.ndarray import NDArray
 from ...ops.registry import invoke_raw
 from ...ops import moe as moe_ops
+from ...ops.kernels import count_traced
 from ..block import HybridBlock
 from ..parameter import Parameter
 
-__all__ = ["MoE"]
+__all__ = ["MoE", "SparseMoE"]
 
 
 class MoE(HybridBlock):
@@ -22,9 +32,10 @@ class MoE(HybridBlock):
     x (..., units) is flattened to tokens; each token routes to ``top_k`` of
     ``num_experts`` expert FFNs (units -> hidden -> units). ``aux`` is the
     load-balance loss (≈1 when balanced) to add to the training objective.
-    For expert-parallel execution shard the expert dimension of
-    ``w1/w2`` over an 'ep' mesh axis and call ``ops.moe.moe_ffn`` with
-    ``axis_name`` inside shard_map (see __graft_entry__ dryrun)."""
+    ``ops.moe.moe_ffn`` with ``axis_name`` (inside shard_map, the expert
+    dimension of ``w1/w2`` sharded over an 'ep' mesh axis) is the same
+    capacity-bound layer with an all-to-all on either side; it is not how
+    ``SparseMoE`` is shared out (see ``held`` there)."""
 
     def __init__(self, units, hidden, num_experts, top_k=2,
                  capacity_factor=1.25, dtype="float32", **kwargs):
@@ -48,9 +59,89 @@ class MoE(HybridBlock):
                                        capacity_factor=self._cf)
             return out.reshape(shape), aux
 
+        count_traced("MOE_DISPATCH", "path", "capacity")
         out, aux = invoke_raw(
             "moe_ffn", fn,
             [x if isinstance(x, NDArray) else NDArray(x),
              self.gate.data(), self.w1.data(), self.w2.data()],
             n_outputs=2)
         return out, aux
+
+
+class SparseMoE(HybridBlock):
+    """Dropless sparse expert layer: ``out = moe(x)``, x (..., units).
+
+    The router (``router_weight``, (num_experts, units)) scores every
+    token against ALL ``num_experts`` experts; each token takes its
+    ``top_k`` best and weighs them by the softmax of those k logits, in
+    float32. Expert e is ``W_down (relu(W_gate x) * (W_up x))`` (sparse
+    ReGLU, width ``hidden``, no bias).
+
+    ``held = (first, count)`` says which experts this layer holds:
+    ``(0, num_experts)`` is the whole layer, ``(8 * j, 8)`` chip j's part
+    when eight chips share a 64-expert layer. The layer computes ITS
+    experts' part of each token's sum and nothing else: pairs whose expert
+    is held elsewhere are neither gathered nor multiplied, and nothing
+    stands in for the other chips. The parts of all the shares add up to
+    the whole layer's output (tests/test_smallthinker.py).
+
+    ``route(u)`` is the router alone, for a model whose router reads
+    another tensor than the experts do (before attention); ``forward(x,
+    routing)`` then takes what it returned.
+    """
+
+    def __init__(self, units, hidden, num_experts, top_k, held=None,
+                 dtype="float32", **kwargs):
+        super().__init__(**kwargs)
+        first, count = (0, num_experts) if held is None else held
+        if not (0 <= first and count >= 1 and first + count <= num_experts):
+            raise MXNetError(f"held={held!r} is no range of "
+                             f"{num_experts} experts")
+        if top_k > num_experts:
+            raise MXNetError(f"top_k {top_k} > {num_experts} experts")
+        self._units, self._k = units, top_k
+        self._held = (int(first), int(count))
+        self.router_weight = Parameter(
+            "router_weight", shape=(num_experts, units), dtype=dtype)
+        self.gate_weight = Parameter(
+            "gate_weight", shape=(count, hidden, units), dtype=dtype)
+        self.up_weight = Parameter(
+            "up_weight", shape=(count, hidden, units), dtype=dtype)
+        self.down_weight = Parameter(
+            "down_weight", shape=(count, units, hidden), dtype=dtype)
+
+    def _tokens(self, x):
+        x = x if isinstance(x, NDArray) else NDArray(x)
+        return x.reshape((-1, self._units))
+
+    def route(self, u):
+        """``(weights, order, place, sizes)`` of ``ops.moe.moe_route``
+        for the tokens of ``u``."""
+        fn = functools.partial(moe_ops.moe_route, top_k=self._k,
+                               held=self._held)
+        return invoke_raw("moe_route", fn,
+                          [self._tokens(u), self.router_weight.data()],
+                          n_outputs=4)
+
+    def forward(self, x, routing=None):
+        shape = x.shape
+        tokens = self._tokens(x)
+        weights, order, place, sizes = routing or self.route(x)
+        count_traced("MOE_DISPATCH", "path", "grouped")
+        y = invoke_raw("moe_experts", moe_ops.moe_experts,
+                       [tokens, order, place, sizes, self.gate_weight.data(),
+                        self.up_weight.data(), self.down_weight.data()])
+        out = invoke_raw("moe_combine", moe_ops.moe_combine,
+                         [y, weights, order, place, sizes])
+        return out.reshape(shape)
+
+    def routing_stats(self, x):
+        """Eager, outside any step: ``{"pairs": pairs each held expert is
+        given for the tokens of x, "held_share": their share of all
+        tokens x top_k pairs}`` as numpy / float."""
+        import numpy as onp
+        sizes, share = moe_ops.routing_counts(
+            self._tokens(x)._data, self.router_weight.data()._data,
+            self._k, self._held)
+        return {"pairs": onp.asarray(sizes), "held_share": float(share)}
+

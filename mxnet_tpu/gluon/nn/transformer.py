@@ -51,47 +51,89 @@ class MultiHeadAttention(HybridBlock):
     ``mask`` is an arbitrary additive float mask broadcastable to
     (batch, heads, seq_q, seq_k) (0 keep / -inf drop) — that path is
     unfused; prefer valid_length for plain padding.
+
+    Decoder options, all on the fused flash path and on no other:
+    ``head_dim`` (default units // num_heads; the projections are then
+    units -> heads * head_dim and back), ``num_kv_heads`` fewer key/value
+    heads than query heads (query head n reads head n // group; k and v
+    are projected to that many heads and never repeated), ``window`` (with
+    ``causal``: key j is seen by query i iff 0 <= i - j < window),
+    ``rope_theta`` (rotary position embedding of q and k in front of the
+    call; None: no position signal at all).
     """
 
     def __init__(self, units: int, num_heads: int, dropout: float = 0.0,
-                 use_bias: bool = True, causal: bool = False, **kwargs):
+                 use_bias: bool = True, causal: bool = False,
+                 head_dim: Optional[int] = None,
+                 num_kv_heads: Optional[int] = None,
+                 window: Optional[int] = None,
+                 rope_theta: Optional[float] = None, **kwargs):
         super().__init__(**kwargs)
-        if units % num_heads:
-            raise MXNetError(f"units {units} not divisible by heads {num_heads}")
+        if head_dim is None:
+            if units % num_heads:
+                raise MXNetError(
+                    f"units {units} not divisible by heads {num_heads}")
+            head_dim = units // num_heads
+        kv_heads = num_heads if num_kv_heads is None else num_kv_heads
+        if kv_heads < 1 or num_heads % kv_heads:
+            raise MXNetError(f"heads {num_heads} not a multiple of K/V "
+                             f"heads {kv_heads}")
+        if window is not None and not causal:
+            raise MXNetError("a window needs causal=True")
         self._units = units
         self._num_heads = num_heads
+        self._kv_heads = kv_heads
+        self._head_dim = head_dim
         self._causal = causal
-        self.query_proj = Dense(units, use_bias=use_bias, flatten=False,
+        self._window = window
+        self._rope_theta = rope_theta
+        width = num_heads * head_dim
+        self.query_proj = Dense(width, use_bias=use_bias, flatten=False,
                                 in_units=units)
-        self.key_proj = Dense(units, use_bias=use_bias, flatten=False,
-                              in_units=units)
-        self.value_proj = Dense(units, use_bias=use_bias, flatten=False,
-                                in_units=units)
+        self.key_proj = Dense(kv_heads * head_dim, use_bias=use_bias,
+                              flatten=False, in_units=units)
+        self.value_proj = Dense(kv_heads * head_dim, use_bias=use_bias,
+                                flatten=False, in_units=units)
         self.out_proj = Dense(units, use_bias=use_bias, flatten=False,
-                              in_units=units)
+                              in_units=width)
         self.dropout = Dropout(dropout)
 
     def _split(self, x):
         b, s, _ = x.shape
         return F.transpose(
-            F.reshape(x, (b, s, self._num_heads,
-                          self._units // self._num_heads)),
+            F.reshape(x, (b, s, self._num_heads, self._head_dim)),
             axes=(0, 2, 1, 3))
+
+    def _rope(self, x, heads):
+        fn = functools.partial(ATT.rope, num_heads=heads,
+                               theta=self._rope_theta)
+        return invoke_raw("rope", fn, [x])
 
     def forward(self, q, k=None, v=None, mask=None, valid_length=None):
         k = q if k is None else k
         v = k if v is None else v
         qp, kp, vp = self.query_proj(q), self.key_proj(k), self.value_proj(v)
-        d = self._units // self._num_heads
+        d = self._head_dim
         scale = 1.0 / math.sqrt(d)
         if mask is None and valid_length is None:
+            if self._rope_theta is not None:
+                qp = self._rope(qp, self._num_heads)
+                kp = self._rope(kp, self._kv_heads)
             # the flash kernels read the projections where they lie and
             # write what out_proj reads: no head transpose on either side
             fn = functools.partial(ATT.flash_attention_bsh,
                                    num_heads=self._num_heads,
                                    causal=self._causal, sm_scale=scale)
+            if self._kv_heads != self._num_heads or self._window is not None:
+                fn = functools.partial(fn, num_kv_heads=self._kv_heads,
+                                       window=self._window)
             out = invoke_raw("flash_attention", fn, [qp, kp, vp])
             return self.dropout(self.out_proj(out))
+        if self._kv_heads != self._num_heads or self._window is not None \
+                or self._rope_theta is not None:
+            raise MXNetError("MultiHeadAttention: grouped K/V heads, a "
+                             "window and rope go with neither mask nor "
+                             "valid_length")
         qh, kh, vh = self._split(qp), self._split(kp), self._split(vp)
         if mask is not None:
             inputs = [qh, kh, vh, mask if isinstance(mask, NDArray)
@@ -121,7 +163,7 @@ class MultiHeadAttention(HybridBlock):
             out = invoke_raw("flash_attention_vl", fn, [qh, kh, vh, vl])
         b, _, s, _ = out.shape
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
-                        (b, s, self._units))
+                        (b, s, self._num_heads * d))
         return self.dropout(self.out_proj(out))
 
 

@@ -16,7 +16,8 @@ from ..ops.registry import invoke_raw
 from .ndarray import NDArray
 
 __all__ = ["Convolution", "Deconvolution", "Pooling", "BatchNorm",
-           "LayerNorm", "GroupNorm", "InstanceNorm", "L2Normalization",
+           "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm",
+           "L2Normalization",
            "LRN", "UpSampling", "BilinearResize2D", "RNN"]
 
 
@@ -113,6 +114,11 @@ def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, **_ignored):
         "layer_norm",
         lambda x, g, b: K.layer_norm(x, g, b, axis, eps),
         [_wrap(data), _wrap(gamma), _wrap(beta)])
+
+
+def RMSNorm(data, gamma, eps=1e-6, **_ignored):
+    return invoke_raw("rms_norm", lambda x, g: K.rms_norm(x, g, eps),
+                      [_wrap(data), _wrap(gamma)])
 
 
 def GroupNorm(data, gamma, beta, num_groups=1, eps=1e-5, **_ignored):

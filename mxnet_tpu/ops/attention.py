@@ -39,22 +39,52 @@ from jax import lax
 
 from ..base import MXNetError
 
-__all__ = ["flash_attention", "flash_attention_bsh",
+__all__ = ["flash_attention", "flash_attention_bsh", "rope",
            "paged_decode_attention", "ring_attention",
            "ring_attention_sharded", "attention_reference"]
 
 _NEG_INF = -1e30  # finite mask value: keeps exp() NaN-free for masked rows
 
 
+def _window_of(window, causal: bool) -> Optional[int]:
+    """The sliding window as every path takes it: None, or a positive
+    number of keys a query sees, itself included. A window is causal by
+    definition here (key j is seen by query i iff 0 <= i - j < window)."""
+    if window is None:
+        return None
+    if not causal:
+        raise MXNetError("attention: window needs causal=True")
+    if int(window) < 1:
+        raise MXNetError(f"attention: window {window} < 1")
+    return int(window)
+
+
+def _kv_group(num_heads: int, num_kv_heads: int) -> int:
+    """Query heads per key/value head (grouped-query attention): query
+    head n reads key/value head n // group."""
+    if num_kv_heads < 1 or num_heads % num_kv_heads:
+        raise MXNetError(
+            f"attention: query heads {num_heads} not a multiple of "
+            f"K/V heads {num_kv_heads} (GQA needs integer groups)")
+    return num_heads // num_kv_heads
+
+
 def attention_reference(q, k, v, causal: bool = False,
-                        sm_scale: Optional[float] = None, mask=None):
+                        sm_scale: Optional[float] = None, mask=None,
+                        window: Optional[int] = None):
     """Unfused softmax(QK^T)V — the numeric oracle for tests and the
     arbitrary-additive-mask path (XLA fuses the softmax). ``mask`` is an
-    additive float mask broadcastable to (B, H, Sq, Sk). Convention shared
+    additive float mask broadcastable to (B, H, Sq, Sk). ``window`` hides
+    key j from query i unless 0 <= i - j < window. k and v may hold fewer
+    heads than q (query head n reads head n // group). Convention shared
     by every attention path in this module: a query row with NO valid key
     outputs exactly zero (the flash-kernel convention)."""
+    window = _window_of(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:
+        group = _kv_group(q.shape[1], k.shape[1])
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     if mask is not None:
@@ -62,6 +92,9 @@ def attention_reference(q, k, v, causal: bool = False,
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         tri = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            tri = tri & ~jnp.tril(jnp.ones((sq, sk), bool),
+                                  k=sk - sq - window)
         s = jnp.where(tri, s, _NEG_INF)
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -71,19 +104,45 @@ def attention_reference(q, k, v, causal: bool = False,
     return out.astype(q.dtype)
 
 
+def rope(x, num_heads: int, theta: float = 10000.0):
+    """Rotary position embedding of (B, S, H*D) queries or keys, in
+    front of the attention call: position p turns the pair (x[i],
+    x[i + D/2]) of each head by the angle p * theta^(-2i/D)
+    ("rotate-half" over the head's whole width). Angles and the turn in
+    float32, the result in the input's dtype."""
+    b, s, hd = x.shape
+    d = hd // num_heads
+    if hd % num_heads or d % 2:
+        raise MXNetError(f"rope: width {hd} over {num_heads} heads gives "
+                         f"no even head width")
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]          # (1, S, 1, D/2)
+    sin = jnp.sin(angle)[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(b, s, num_heads, d)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(b, s, hd).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Blockwise XLA implementation (fallback forward + backward recompute target)
 # ---------------------------------------------------------------------------
 
 def _attention_xla(q, k, v, causal: bool, sm_scale: float,
-                   block_k: int = 512, valid_length=None):
+                   block_k: int = 512, valid_length=None, window=None):
     """Online-softmax attention scanning over K/V blocks: O(Sq·block_k)
     live memory instead of O(Sq·Sk). Pure lax.scan — XLA pipelines the
     blocks and keeps the matmuls on the MXU. ``valid_length`` is an
-    optional (B,) per-sample key length (padding mask)."""
+    optional (B,) per-sample key length (padding mask); ``window`` the
+    sliding window. k, v may hold fewer heads than q: the query heads of
+    a group are folded into the query rows, so nothing is repeated."""
     orig_dtype = q.dtype
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+    b, hq, sq, d = q.shape
+    h, sk = k.shape[1], k.shape[2]
+    group = _kv_group(hq, h)
+    if group > 1:       # (B, Hkv, group * Sq, D): row r is query r % Sq
+        q = q.reshape(b, h, group * sq, d)
     block_k = min(block_k, sk)
     nk = -(-sk // block_k)
     pad = nk * block_k - sk
@@ -93,7 +152,9 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
     qf = q.astype(jnp.float32) * sm_scale
     kb = jnp.moveaxis(k.reshape(b, h, nk, block_k, d), 2, 0)
     vb = jnp.moveaxis(v.reshape(b, h, nk, block_k, d), 2, 0)
-    q_pos = jnp.arange(sq) + (sk - sq)  # align causal diagonal to the end
+    # align causal diagonal to the end
+    q_pos = jnp.tile(jnp.arange(sq) + (sk - sq), group)
+    sq = group * sq
 
     def body(carry, inp):
         acc, m, l = carry
@@ -103,6 +164,8 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
         valid = (k_pos < sk)[None, None, None, :]
         if causal:
             valid = valid & (k_pos[None, :] <= q_pos[:, None])
+        if window is not None:
+            valid = valid & (k_pos[None, :] > q_pos[:, None] - window)
         if valid_length is not None:
             valid = valid & (k_pos[None, None, None, :]
                              < valid_length[:, None, None, None])
@@ -122,7 +185,7 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
                               (kb, vb, jnp.arange(nk)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = jnp.where((m > _NEG_INF / 2)[..., None], out, 0.0)  # no-key rows
-    return out.astype(orig_dtype)
+    return out.reshape(b, hq, sq // group, d).astype(orig_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +198,18 @@ FLASH_LAYOUTS = ("packed", "unpadded", "padded")
 #: the blocks every kernel starts from; ``_tiles`` clamps them to the
 #: sequence
 _BLOCK_Q = _BLOCK_K = 512
+
+
+def _default_block(head_dim: int) -> int:
+    """The q and k block a call starts from when it names none. Heads
+    that share a lane tile (D < 128) take 512: a program then works on
+    128 // D heads' (bq, bk) score tiles. A head that fills a tile alone
+    has one such tile a grid step, and at 512 the step's fixed cost is a
+    large part of it: 1024 (v5e, bf16, 1 x 8192 causal, 28 q / 4 kv heads
+    of 128, ms a call at 512 -> 1024, PR 28: forward 10.51 -> 5.21,
+    backward 17.86 -> 15.68; behind a 4096 window 9.14 -> 4.83 and
+    16.71 -> 14.59)."""
+    return 1024 if head_dim >= 128 else _BLOCK_Q
 #: narrowest head the kernels take without padding
 _MIN_LANES = 8
 
@@ -156,6 +231,13 @@ class _Tiles(NamedTuple):
     - ``padded``: the same fold with D zero-padded to a multiple of 128
       in HBM: head widths that neither divide 128 nor are a multiple
       (80, 96). Zero lanes cancel in QK^T and are sliced off after.
+
+    With fewer key/value heads than query heads (``group`` query heads
+    read one of them) k, v and their gradients are the narrower arrays,
+    never repeated in HBM: the block a query block meets is found by
+    dividing its column tile (``packed``, which then takes one head a
+    block) or its row (the folded layouts) by ``group``. ``window`` is
+    the sliding window, None for none.
     """
     layout: str
     batch: int
@@ -167,6 +249,8 @@ class _Tiles(NamedTuple):
     col_tiles: int
     width: int
     heads: int          # heads side by side in one block
+    group: int          # query heads per key/value head
+    window: Optional[int]
     block_q: int
     block_k: int
     sqp: int
@@ -184,23 +268,39 @@ class _Tiles(NamedTuple):
             return f"unpadded: D={self.head_dim}, one head per block"
         return f"padded: D={self.head_dim} → {self.width}"
 
+    @property
+    def row_group(self) -> int:
+        """Query rows per key/value row (the folded layouts)."""
+        return 1 if self.layout == "packed" else self.group
+
+    @property
+    def col_group(self) -> int:
+        """Query column tiles per key/value column tile (``packed``)."""
+        return self.group if self.layout == "packed" else 1
+
 
 def _tiles(q_shape, k_shape, block_q: int, block_k: int,
-           num_heads: Optional[int]) -> _Tiles:
+           num_heads: Optional[int], window: Optional[int] = None) -> _Tiles:
     """The tiling for one call, from the shapes alone. ``num_heads``
-    None: q, k, v are (B, H, S, D); else they are (B, S, H*D)."""
+    None: q, k, v are (B, H, S, D); else they are (B, S, H*D). The
+    key/value head count is read off k's shape."""
     if num_heads is None:
         b, h, sq, d = q_shape
-        sk = k_shape[2]
+        hkv, sk = k_shape[1], k_shape[2]
     else:
         b, sq, hd = q_shape
         h, d, sk = num_heads, hd // num_heads, k_shape[1]
+        hkv = k_shape[2] // d
+    group = _kv_group(h, hkv)
     block_q = min(block_q, max(sq, 8))
     block_k = min(block_k, max(sk, 8))
     sqp = -(-sq // block_q) * block_q
     skp = -(-sk // block_k) * block_k
     fits = d % 128 == 0 or (128 % d == 0 and d >= _MIN_LANES)
-    if num_heads is not None and fits and (h * d) % 128 == 0:
+    # heads that share a lane tile share its key/value tile too: with
+    # groups, packed is for heads that fill a tile alone
+    if num_heads is not None and fits and (h * d) % 128 == 0 \
+            and (group == 1 or d % 128 == 0):
         width = max(d, 128)
         layout, rows, cols, heads = "packed", b, h * d // width, width // d
     elif fits:
@@ -208,8 +308,9 @@ def _tiles(q_shape, k_shape, block_q: int, block_k: int,
     else:
         layout, rows, cols, width, heads = \
             "padded", b * h, 1, -(-d // 128) * 128, 1
-    return _Tiles(layout, b, h, d, sq, sk, rows, cols, width, heads,
-                  block_q, block_k, sqp, skp, sqp // block_q, skp // block_k)
+    return _Tiles(layout, b, h, d, sq, sk, rows, cols, width, heads, group,
+                  window, block_q, block_k, sqp, skp, sqp // block_q,
+                  skp // block_k)
 
 
 def _split_heads(x, num_heads: int):
@@ -224,7 +325,8 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None):
+def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None,
+                    window=None):
     """Shared fwd/bwd tiling preamble: clamp block sizes, choose the
     layout (:class:`_Tiles`) and bring q, k, v into it. Nothing is
     padded that the kernels can take as it is: the sequence only when
@@ -234,24 +336,26 @@ def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None):
     single source. Returns ``(qt, kt, vt, to_tiles, from_tiles,
     tiles)``; ``to_tiles(x, seq_to)`` lays any further (…q- or k-shaped)
     array out the same way and ``from_tiles(y, seq)`` is its inverse."""
-    t = _tiles(q.shape, k.shape, block_q, block_k, num_heads)
-    b, h, d = t.batch, t.num_heads, t.head_dim
+    default = _default_block(q.shape[-1] // (num_heads or 1))
+    t = _tiles(q.shape, k.shape, block_q or default, block_k or default,
+               num_heads, window)
+    b, d = t.batch, t.head_dim
 
     def to_tiles(x, seq_to):
         if t.layout == "packed":
             pad = seq_to - x.shape[1]
             return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
         if num_heads is not None:
-            x = _split_heads(x, h)
+            x = _split_heads(x, x.shape[-1] // d)
         pad_s, pad_d = seq_to - x.shape[2], t.width - d
         if pad_s or pad_d:
             x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_s), (0, pad_d)))
-        return x.reshape(b * h, seq_to, t.width)
+        return x.reshape(b * x.shape[1], seq_to, t.width)
 
     def from_tiles(y, seq):
         if t.layout == "packed":
             return y if y.shape[1] == seq else y[:, :seq]
-        y = y.reshape(b, h, y.shape[1], t.width)
+        y = y.reshape(b, y.shape[0] // b, y.shape[1], t.width)
         if y.shape[2] != seq or t.width != d:
             y = y[:, :, :seq, :d]
         return y if num_heads is None else _merge_heads(y)
@@ -261,7 +365,8 @@ def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None):
 
 
 def _head_group(bh: int, block_q: int, block_k: int,
-                n_tiles: int = 1, heads_per_block: int = 1) -> int:
+                n_tiles: int = 1, heads_per_block: int = 1,
+                row_group: int = 1) -> int:
     """Rows of the kernels' grid per Pallas program; a row is one block
     of ``heads_per_block`` heads (one head of one sample in the
     (B*H, S, D) layouts, the 128 // D heads that share a lane tile in
@@ -276,8 +381,12 @@ def _head_group(bh: int, block_q: int, block_k: int,
     holds live at once: 1 for the forward (s; p overwrites it), 4 for
     the fused backward (s, p, dp, ds) — budgeting the backward as a
     single tile oversizes G and fails Mosaic lowering at large
-    blocks."""
+    blocks. ``row_group`` > 1 (fewer key/value rows than query rows):
+    one row a program, so that a program's rows read one key/value
+    row."""
     from .kernels import vmem_tile_budget
+    if row_group > 1:
+        return 1
     budget = vmem_tile_budget()
     g = 1
     while (g * 2 * heads_per_block <= 8 and bh % (g * 2) == 0
@@ -339,13 +448,24 @@ def _by_head(parts, masks):
     return out
 
 
+def _kv_index(t: _Tiles):
+    """Index map of a key/value block on the (row, column tile, q block,
+    k block) grid of the forward and dq kernels: the query block's own
+    row and column tile, or with fewer key/value heads the ones its
+    group reads."""
+    if t.group == 1:
+        return lambda r, c, qi, ki: (r, ki, c)
+    rg, cg = t.row_group, t.col_group
+    return lambda r, c, qi, ki: (r // rg, ki, c // cg)
+
+
 # ---------------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ---------------------------------------------------------------------------
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                   sm_scale, causal, block_q, block_k, nk, seq_q, seq_k,
-                  need_mask, heads):
+                  need_mask, heads, window=None):
     from jax.experimental import pallas as pl
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -356,11 +476,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    # Causal: skip blocks strictly above the diagonal (no valid entries).
+    # Causal: skip blocks strictly above the diagonal (no valid entries),
+    # and with a window those wholly behind it.
     diag_off = seq_k - seq_q
     run = True
     if causal:
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k)
+        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
+                                 window)
 
     @pl.when(run)
     def _compute():
@@ -382,6 +504,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
                 q_pos = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0) + diag_off
                 valid = valid & (k_pos <= q_pos)
+                if window is not None:
+                    valid = valid & (k_pos > q_pos - window)
         alphas, pvs = [], []
         for i, mask in enumerate(masks):          # static: heads <= 16
             s = lax.dot_general(_only(q, mask), k,
@@ -425,8 +549,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
-                      block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
-                      interpret: bool = False, num_heads=None):
+                      block_q: int = None, block_k: int = None,
+                      interpret: bool = False, num_heads=None, window=None):
     # 512x512 blocks measured 2.2x faster than 128x128 on an earlier
     # chip (8x12x2048x64 causal: 4.5ms vs 13ms; XLA blockwise scan:
     # 9.7ms). The same shape on a v5e, bf16, ms a call (PR 27): forward
@@ -441,16 +565,17 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
     from jax.experimental.pallas import tpu as pltpu
 
     qt, kt, vt, _, from_tiles, t = _pad_for_blocks(
-        q, k, v, block_q, block_k, num_heads)
-    g = _head_group(t.rows, t.block_q, t.block_k, heads_per_block=t.heads)
+        q, k, v, block_q, block_k, num_heads, window)
+    g = _head_group(t.rows, t.block_q, t.block_k, heads_per_block=t.heads,
+                    row_group=t.row_group)
     w = t.width
 
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=t.block_q,
         block_k=t.block_k, nk=t.nk, seq_q=t.seq_q, seq_k=t.seq_k,
-        need_mask=(t.skp != t.seq_k), heads=t.heads)
+        need_mask=(t.skp != t.seq_k), heads=t.heads, window=t.window)
     q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, qi, ki: (r, qi, c))
-    k_spec = pl.BlockSpec((g, t.block_k, w), lambda r, c, qi, ki: (r, ki, c))
+    k_spec = pl.BlockSpec((g, t.block_k, w), _kv_index(t))
     out, lse = pl.pallas_call(
         kernel,
         grid=(t.rows // g, t.col_tiles, t.nq, t.nk),
@@ -487,14 +612,21 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
 # saved per-row log-sum-exp; no S×S residual is ever materialized)
 # ---------------------------------------------------------------------------
 
-def _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k):
+def _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
+                       window=None):
     """True iff block (qi, ki) holds ANY valid causal entry — the shared
     skip predicate for the forward and both backward kernels (a divergence
-    here would desynchronize forward and backward masking)."""
-    return ki * block_k <= qi * block_q + block_q - 1 + (seq_k - seq_q)
+    here would desynchronize forward and backward masking). With a
+    ``window`` the block's last key must also be seen by its first
+    query."""
+    first_q = qi * block_q + (seq_k - seq_q)
+    run = ki * block_k <= first_q + block_q - 1
+    if window is not None:
+        run = run & (ki * block_k + block_k - 1 > first_q - window)
+    return run
 
 
-def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k):
+def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k, window=None):
     k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
                                                 (block_q, block_k), 1)
     q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
@@ -502,6 +634,8 @@ def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k):
     valid = (k_pos < seq_k) & (q_pos < seq_q)
     if causal:
         valid = valid & (k_pos <= q_pos + (seq_k - seq_q))
+    if window is not None:
+        valid = valid & (k_pos > q_pos + (seq_k - seq_q) - window)
     return valid
 
 
@@ -525,19 +659,23 @@ def _bwd_head(q, k, v, do, lse, delta, qmask, valid, sm_scale):
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, causal,
                           block_q, block_k, nq, seq_q, seq_k, need_mask,
-                          heads):
+                          heads, window=None, group=1):
     from jax.experimental import pallas as pl
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    # the inner axis walks the q blocks of every query head that reads
+    # this key/value head, one head after another
+    step = pl.program_id(3)
+    qi = step if group == 1 else step % nq
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
     run = True
     if causal:  # this k block only touches q rows at/after the diagonal
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k)
+        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
+                                 window)
 
     @pl.when(run)
     def _compute():
@@ -547,8 +685,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[...]                            # (G, bq, width)
         qmasks = _head_masks(q.shape, heads)
         kmasks = _head_masks(k.shape, heads)
-        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k) \
-            if need_mask or causal else None
+        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
+                          window) if need_mask or causal else None
         dks, dvs = [], []
         for i, qmask in enumerate(qmasks):
             p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
@@ -563,7 +701,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_s[...] += _by_head(dvs, kmasks)
         dk_s[...] += _by_head(dks, kmasks)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finalize():
         dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
@@ -572,7 +710,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                             dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
                             block_q, block_k, seq_q, seq_k, need_mask,
-                            heads):
+                            heads, window=None):
     """Single-block backward (nq == nk == 1, the short-seq fast path):
     one program computes dq, dk AND dv, reconstructing the softmax block
     ONCE — the two-kernel general path pays the s = qk^T + exp recompute
@@ -586,8 +724,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     do_o = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
     qmasks = _head_masks(q.shape, heads)
     kmasks = _head_masks(k.shape, heads)
-    valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k) \
-        if need_mask or causal else None
+    valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k,
+                      window) if need_mask or causal else None
     dqs, dks, dvs = [], [], []
     for i, qmask in enumerate(qmasks):
         delta = _only(do_o, qmask).sum(axis=2, keepdims=True)
@@ -608,7 +746,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_s, *, sm_scale, causal, block_q,
-                         block_k, nk, seq_q, seq_k, need_mask, heads):
+                         block_k, nk, seq_q, seq_k, need_mask, heads,
+                         window=None):
     from jax.experimental import pallas as pl
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -619,7 +758,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     run = True
     if causal:
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k)
+        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
+                                 window)
 
     @pl.when(run)
     def _compute():
@@ -628,8 +768,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[...]
         do = do_ref[...]
         qmasks = _head_masks(q.shape, heads)
-        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k) \
-            if need_mask or causal else None
+        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
+                          window) if need_mask or causal else None
         dqs = []
         for i, qmask in enumerate(qmasks):
             _, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
@@ -646,31 +786,35 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
-                      block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
-                      interpret: bool = False, num_heads=None):
+                      block_q: int = None, block_k: int = None,
+                      interpret: bool = False, num_heads=None, window=None):
     """Pallas flash attention backward: one fused kernel when the whole
     sequence is one block, else dq via a (q-parallel, k-inner) kernel
     and dk/dv via a (k-parallel, q-inner) kernel. ``lse`` is what
-    ``_flash_fwd_pallas`` returned for the same blocks."""
+    ``_flash_fwd_pallas`` returned for the same blocks. With fewer
+    key/value heads than query heads the dk/dv kernel's inner axis walks
+    the q blocks of each query head of the group in turn, so a key/value
+    head's gradient is summed where it is made, and the one-block case
+    takes the two kernels too."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     qt, kt, vt, to_tiles, from_tiles, t = _pad_for_blocks(
-        q, k, v, block_q, block_k, num_heads)
+        q, k, v, block_q, block_k, num_heads, window)
     sq, sk = t.seq_q, t.seq_k
     do = do.astype(q.dtype)
     dot = to_tiles(do, t.sqp)
     g = _head_group(t.rows, t.block_q, t.block_k, n_tiles=4,
-                    heads_per_block=t.heads)
+                    heads_per_block=t.heads, row_group=t.row_group)
     need_mask = (t.skp != sk) or (t.sqp != sq)
     w, heads = t.width, t.heads
     static = dict(sm_scale=sm_scale, causal=causal, block_q=t.block_q,
                   block_k=t.block_k, seq_q=sq, seq_k=sk,
-                  need_mask=need_mask, heads=heads)
+                  need_mask=need_mask, heads=heads, window=t.window)
     q_shape = jax.ShapeDtypeStruct(qt.shape, q.dtype)
     k_shape = jax.ShapeDtypeStruct(kt.shape, k.dtype)
 
-    if t.nq == 1 and t.nk == 1:
+    if t.nq == 1 and t.nk == 1 and t.group == 1:
         bspec = lambda blk: pl.BlockSpec((g, blk, w),
                                          lambda r, c: (r, 0, c))
         rspec = pl.BlockSpec((g, heads, t.block_q, 8),
@@ -711,7 +855,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, a, b_: (r, a, c))
     row_spec = pl.BlockSpec((g, heads, t.block_q, 8),
                             lambda r, c, a, b_: (r, c, a, 0))
-    k_in = pl.BlockSpec((g, t.block_k, w), lambda r, c, qi, ki: (r, ki, c))
+    k_in = pl.BlockSpec((g, t.block_k, w), _kv_index(t))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, nk=t.nk, **static),
@@ -731,12 +875,25 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     )(qt, kt, vt, dot, lse, delta)
 
     k_spec = pl.BlockSpec((g, t.block_k, w), lambda r, c, ki, qi: (r, ki, c))
-    qrow = pl.BlockSpec((g, t.block_q, w), lambda r, c, ki, qi: (r, qi, c))
-    rrow = pl.BlockSpec((g, heads, t.block_q, 8),
-                        lambda r, c, ki, qi: (r, c, qi, 0))
+    if t.group == 1:
+        q_at = lambda r, c, ki, qi: (r, qi, c)
+    else:
+        # the grid is over key/value rows and column tiles; inner step
+        # s reads q block s % nq of the group's query head s // nq
+        nq, rg, cg = t.nq, t.row_group, t.col_group
+        q_at = lambda r, c, ki, s: (r * rg + (s // nq if rg > 1 else 0),
+                                    s % nq,
+                                    c * cg + (s // nq if cg > 1 else 0))
+
+    def stat_at(r, c, ki, s):
+        row, qi, col = q_at(r, c, ki, s)
+        return row, col, qi, 0
+    qrow = pl.BlockSpec((g, t.block_q, w), q_at)
+    rrow = pl.BlockSpec((g, heads, t.block_q, 8), stat_at)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq=t.nq, **static),
-        grid=(t.rows // g, t.col_tiles, t.nk, t.nq),
+        functools.partial(_flash_bwd_dkv_kernel, nq=t.nq, group=t.group,
+                          **static),
+        grid=(kt.shape[0] // g, kt.shape[2] // w, t.nk, t.group * t.nq),
         in_specs=[qrow, k_spec, k_spec, qrow, rrow, rrow],
         out_specs=[k_spec, k_spec],
         out_shape=[k_shape, k_shape],
@@ -755,22 +912,25 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_tpu(q, k, v, causal, sm_scale, interpret, num_heads=None):
-    return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                             interpret=interpret, num_heads=num_heads)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_tpu(q, k, v, causal, sm_scale, interpret, num_heads=None,
+               window=None):
+    return _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=interpret,
+                             num_heads=num_heads, window=window)[0]
 
 
-def _flash_tpu_fwd(q, k, v, causal, sm_scale, interpret, num_heads):
+def _flash_tpu_fwd(q, k, v, causal, sm_scale, interpret, num_heads, window):
     o, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                               interpret=interpret, num_heads=num_heads)
+                               interpret=interpret, num_heads=num_heads,
+                               window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_tpu_bwd(causal, sm_scale, interpret, num_heads, res, g):
+def _flash_tpu_bwd(causal, sm_scale, interpret, num_heads, window, res, g):
     q, k, v, o, lse = res
     return _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
-                             interpret=interpret, num_heads=num_heads)
+                             interpret=interpret, num_heads=num_heads,
+                             window=window)
 
 
 _flash_tpu.defvjp(_flash_tpu_fwd, _flash_tpu_bwd)
@@ -780,22 +940,23 @@ _flash_tpu.defvjp(_flash_tpu_fwd, _flash_tpu_bwd)
 # Public flash_attention with recompute backward
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, sm_scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, sm_scale, window=None):
     """XLA (non-Pallas) flash path: blockwise scan forward, recompute
     backward. The TPU default goes through _flash_tpu instead."""
-    return _attention_xla(q, k, v, causal, sm_scale)
+    return _attention_xla(q, k, v, causal, sm_scale, window=window)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale):
-    return _flash(q, k, v, causal, sm_scale), (q, k, v)
+def _flash_fwd(q, k, v, causal, sm_scale, window):
+    return _flash(q, k, v, causal, sm_scale, window), (q, k, v)
 
 
-def _flash_bwd(causal, sm_scale, res, g):
+def _flash_bwd(causal, sm_scale, window, res, g):
     q, k, v = res
     # Flash-style backward: recompute attention blockwise (no S×S residual).
     _, vjp = jax.vjp(
-        lambda q_, k_, v_: _attention_xla(q_, k_, v_, causal, sm_scale),
+        lambda q_, k_, v_: _attention_xla(q_, k_, v_, causal, sm_scale,
+                                          window=window),
         q, k, v)
     return vjp(g)
 
@@ -824,7 +985,7 @@ def _flash_vl_bwd(causal, sm_scale, res, g):
 _flash_vl.defvjp(_flash_vl_fwd, _flash_vl_bwd)
 
 
-def _kernel_tier(q, k, num_heads, use_pallas) -> Optional[bool]:
+def _kernel_tier(q, k, num_heads, use_pallas, window=None) -> Optional[bool]:
     """Which tier takes this call: None for the XLA reference, else
     whether the kernel bodies run interpreted. ``use_pallas`` None asks
     the shared MXNET_PALLAS three-tier gate (ops/kernels): compiled
@@ -832,7 +993,7 @@ def _kernel_tier(q, k, num_heads, use_pallas) -> Optional[bool]:
     blockwise-XLA reference otherwise. A call the kernels take is
     counted by the layout its shapes gave it, and the gate's recorded
     reason says which."""
-    tiles = _tiles(q.shape, k.shape, _BLOCK_Q, _BLOCK_K, num_heads)
+    tiles = _tiles(q.shape, k.shape, _BLOCK_Q, _BLOCK_K, num_heads, window)
     if use_pallas is None:
         from .kernels import dispatch as _kdispatch
         path, _ = _kdispatch("flash_attention", detail=tiles.reason)
@@ -840,20 +1001,31 @@ def _kernel_tier(q, k, num_heads, use_pallas) -> Optional[bool]:
         path = "pallas" if use_pallas else "xla"
     if path == "xla":
         return None
-    try:
-        from ..telemetry import names as tn
-        from ..telemetry import registry as treg
-        treg().counter(tn.FLASH_ATTENTION_LAYOUT,
-                       label_key="layout").inc(label=tiles.layout)
-    except Exception:   # telemetry must never fail a kernel call
-        pass
+    from .kernels import count_traced
+    count_traced("FLASH_ATTENTION_LAYOUT", "layout", tiles.layout)
     return path == "interpret"
+
+
+def _count_mask(causal: bool, window) -> None:
+    """``mx_attention_mask_total{kind}``: which mask a traced attention
+    call asked for, whatever tier then took it."""
+    from .kernels import count_traced
+    count_traced("ATTENTION_MASK", "kind",
+                 "window" if window is not None else
+                 "causal" if causal else "full")
+
+
+def _check_kv_heads(num_kv_heads, found: int) -> None:
+    if num_kv_heads is not None and num_kv_heads != found:
+        raise MXNetError(f"attention: num_kv_heads={num_kv_heads} but k "
+                         f"holds {found} heads")
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     use_pallas: Optional[bool] = None,
-                    valid_length=None):
+                    valid_length=None, num_kv_heads: Optional[int] = None,
+                    window: Optional[int] = None):
     """Fused memory-efficient attention on (B, H, S, D) tensors.
 
     On TPU forward and backward run as Pallas kernels (_flash_tpu:
@@ -862,33 +1034,50 @@ def flash_attention(q, k, v, causal: bool = False,
     and a recompute-based backward. ``valid_length`` (B,) masks padded
     keys; that path uses the blockwise implementation (still O(S·block)
     memory, never an S×S score matrix).
+
+    k and v may hold fewer heads than q (``num_kv_heads``, read off their
+    shape when not given): query head n reads head n // (H // Hkv), and
+    no tier repeats k or v in memory. ``window`` (with ``causal``) hides
+    key j from query i unless 0 <= i - j < window; the kernels skip the
+    blocks wholly outside it as they skip those above the diagonal.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise MXNetError("flash_attention expects (batch, heads, seq, dim)")
+    _check_kv_heads(num_kv_heads, k.shape[1])
+    window = _window_of(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    _count_mask(causal, window)
     if valid_length is not None:
+        if window is not None or k.shape[1] != q.shape[1]:
+            raise MXNetError("flash_attention: valid_length goes with "
+                             "neither a window nor grouped K/V heads")
         vl = jnp.asarray(valid_length, jnp.float32)
         return _flash_vl(q, k, v, vl, causal, float(sm_scale))
-    interpret = _kernel_tier(q, k, None, use_pallas)
+    interpret = _kernel_tier(q, k, None, use_pallas, window)
     if interpret is None:
-        return _flash(q, k, v, causal, float(sm_scale))
+        return _flash(q, k, v, causal, float(sm_scale), window)
     # full-Pallas path: flash forward AND FlashAttention-2-style
     # backward kernels off the saved log-sum-exp
-    return _flash_tpu(q, k, v, causal, float(sm_scale), interpret)
+    return _flash_tpu(q, k, v, causal, float(sm_scale), interpret, None,
+                      window)
 
 
 def flash_attention_bsh(q, k, v, num_heads: int, causal: bool = False,
-                        sm_scale: Optional[float] = None):
+                        sm_scale: Optional[float] = None,
+                        num_kv_heads: Optional[int] = None,
+                        window: Optional[int] = None):
     """:func:`flash_attention` on (B, S, H*D) tensors, heads side by
     side on the last axis as a projection emits them and as the output
-    projection eats them; returns (B, S, H*D).
+    projection eats them; returns (B, S, H*D). k and v are
+    (B, S, Hkv*D), Hkv = ``num_kv_heads`` (``num_heads`` when None).
 
     When the head width divides 128 (and H*D is a multiple of 128) or
     is a multiple of it, the Pallas kernels read and write these arrays
     where they lie: no head transpose, no padding (``_Tiles``,
-    "packed"). Any other width, and the XLA tier, transposes to
-    (B, H, S, D) here, inside the op, and back.
+    "packed"; with Hkv < H only heads that fill a lane tile alone). Any
+    other width, and the XLA tier, transposes to (B, H, S, D) here,
+    inside the op, and back.
     """
     if q.ndim != 3 or k.ndim != 3 or v.ndim != 3:
         raise MXNetError("flash_attention_bsh expects (batch, seq, "
@@ -897,15 +1086,23 @@ def flash_attention_bsh(q, k, v, num_heads: int, causal: bool = False,
         raise MXNetError(f"flash_attention_bsh: width {q.shape[-1]} not "
                          f"divisible by heads {num_heads}")
     d = q.shape[-1] // num_heads
+    if k.shape[-1] % d:
+        raise MXNetError(f"flash_attention_bsh: K/V width {k.shape[-1]} "
+                         f"not a multiple of the head width {d}")
+    kv_heads = k.shape[-1] // d
+    _check_kv_heads(num_kv_heads, kv_heads)
+    window = _window_of(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    interpret = _kernel_tier(q, k, num_heads, None)
+    _count_mask(causal, window)
+    interpret = _kernel_tier(q, k, num_heads, None, window)
     if interpret is not None:
         return _flash_tpu(q, k, v, causal, float(sm_scale), interpret,
-                          num_heads)
-    return _merge_heads(_flash(*(_split_heads(x, num_heads)
-                                 for x in (q, k, v)),
-                               causal, float(sm_scale)))
+                          num_heads, window)
+    return _merge_heads(_flash(_split_heads(q, num_heads),
+                               _split_heads(k, kv_heads),
+                               _split_heads(v, kv_heads),
+                               causal, float(sm_scale), window))
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,8 @@ import os
 from typing import Dict, Optional, Tuple
 
 __all__ = ["pallas_mode", "dispatch", "decisions", "dispatch_table",
-           "KERNELS", "SUPPORTED_DEVICE_KINDS", "VMEM_TILE_BUDGET_BYTES",
+           "KERNELS", "count_traced", "SUPPORTED_DEVICE_KINDS",
+           "VMEM_TILE_BUDGET_BYTES",
            "VMEM_BYTES_PER_CORE", "VMEM_SCOPED_DEFAULT_BYTES",
            "vmem_tile_budget"]
 
@@ -195,14 +196,22 @@ def dispatch(kernel: str, supported: bool = True,
     if detail and out[0] != "xla":
         out = (out[0], f"{out[1]}; {detail}")
     _DECISIONS[kernel] = out
+    count_traced("KERNEL_DISPATCH", "path", out[0])
+    return out
+
+
+def count_traced(metric: str, label_key: str, label: str) -> None:
+    """One more under ``label`` of the labelled counter
+    ``telemetry.names.<metric>``: what the op layer counts while a call
+    is traced (dispatch path, flash layout, attention mask, expert
+    dispatch). Telemetry must never fail a kernel call."""
     try:
         from ...telemetry import names as tn
         from ...telemetry import registry as treg
-        treg().counter(tn.KERNEL_DISPATCH,
-                       label_key="path").inc(label=out[0])
-    except Exception:   # telemetry must never fail a kernel call
+        treg().counter(getattr(tn, metric),
+                       label_key=label_key).inc(label=label)
+    except Exception:
         pass
-    return out
 
 
 def decisions() -> Dict[str, Tuple[str, str]]:

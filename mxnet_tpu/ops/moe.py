@@ -21,6 +21,30 @@ Design (the standard TPU MoE recipe — GShard/Switch style):
 
 Everything is differentiable (einsums + where), so jax.grad flows through
 router and experts.
+
+The DROPLESS layer (``moe_route`` / ``moe_experts`` / ``moe_combine``,
+behind ``gluon.nn.SparseMoE``) is a different layer, not a second path of
+the one above: no capacity and no dropped token at any imbalance, a
+softmax over the chosen logits only, gated (ReGLU) experts, and a layer
+that is told WHICH experts it holds. It routes over all ``E`` experts and
+computes its own part of the sum:
+
+- ``moe_route``: router logits, top-k and the softmax of the chosen
+  logits in float32; the token-expert pairs whose expert is held, sorted
+  by expert (a stable argsort; pairs of experts held elsewhere sort behind
+  them), each pair's place in that order, and the held experts' group
+  sizes;
+- ``moe_experts``: the sorted pairs' tokens gathered, one grouped (ragged)
+  matrix product a projection over the experts held, ReGLU between. The
+  product is ``lax.ragged_dot``, told the group sizes, so rows past the
+  last group are never multiplied;
+- ``moe_combine``: each token's chosen outputs weighted and summed, as a
+  gather through the pairs' places (the sort's inverse), in float32.
+
+Shapes are static: the sorted list has ``N * min(top_k, held)`` rows, the
+most pairs the held experts can be given. The gathers in front of and
+behind the experts run over all those rows (the products do not); both
+have gather-only gradients (``_dispatch``, ``_combine``).
 """
 from __future__ import annotations
 
@@ -28,7 +52,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["moe_gating", "moe_ffn"]
+__all__ = ["moe_gating", "moe_ffn", "moe_route", "moe_experts",
+           "moe_combine", "routing_counts"]
 
 
 def moe_gating(x, gate_w, num_experts: int, top_k: int = 2,
@@ -132,6 +157,143 @@ def moe_ffn(x, gate_w, w1, w2, top_k: int = 2, capacity_factor: float = 1.25,
     # aux is computed from local stats; average across shards
     aux = lax.pmean(aux, axis_name)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer: route, grouped experts, combine
+# ---------------------------------------------------------------------------
+
+def moe_route(x, router_w, top_k: int, held):
+    """Router of the dropless layer. ``x`` (N, d); ``router_w`` (E, d),
+    all E experts whichever are held; ``held = (first, count)``.
+
+    Returns ``(weights, order, place, sizes)``: ``weights`` (N, k) f32,
+    the softmax over each token's k chosen logits; ``order`` (rows,)
+    int32, the pairs (token * k + choice) of held experts sorted by
+    expert, rows = N * min(k, count); ``place`` (N, k) int32, where each
+    pair stands in that order (pairs of experts held elsewhere stand at
+    or past the held total); ``sizes`` (count,) int32, the pairs each
+    held expert was given. Everything in float32 at ``highest``: a logit
+    rounded to bf16 moves the top-k choice on near-ties."""
+    n = x.shape[0]
+    first, count = held
+    logits = jnp.einsum("nd,ed->ne", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    top_vals, top_idx = lax.top_k(logits, top_k)
+    weights = jax.nn.softmax(top_vals, axis=-1)
+    # each pair's sort key: its expert's index among those held, or
+    # ``count`` for an expert held elsewhere
+    local = top_idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    # a pair's place = its group's start + its rank among the group's
+    # pairs, by pair index as the stable sort leaves them
+    onehot = (key[:, None] == jnp.arange(count + 1)[None, :]) \
+        .astype(jnp.int32)
+    totals = onehot.sum(axis=0)
+    starts = jnp.cumsum(totals) - totals
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), key[:, None],
+                               axis=1)[:, 0] - 1
+    place = (starts[key] + rank).reshape(n, top_k).astype(jnp.int32)
+    return weights, order[:n * min(top_k, count)], place, totals[:count]
+
+
+def routing_counts(x, router_w, top_k: int, held):
+    """``(pairs per held expert (count,), held share of all N*k pairs)``
+    of one routing, for tests and PERF.md; not part of any step."""
+    _, _, _, sizes = moe_route(x, router_w, top_k, held)
+    return sizes, sizes.sum() / (x.shape[0] * top_k)
+
+
+def _valid_rows(x, sizes):
+    """``x`` with the rows past the last group zeroed."""
+    keep = jnp.arange(x.shape[0]) < sizes.sum()
+    return jnp.where(keep[:, None], x, jnp.zeros_like(x))
+
+
+def _grouped_dot(lhs, rhs, sizes):
+    """One grouped product: row r of ``lhs`` (rows, k) against
+    ``rhs[g]`` (n, k) for the group g that holds r; zero past the last
+    group. ``lax.ragged_dot`` in every dtype and on every backend: XLA
+    is told the group sizes and multiplies no row past them."""
+    return lax.ragged_dot(lhs, rhs.swapaxes(1, 2), sizes,
+                          preferred_element_type=lhs.dtype)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, place, sizes):
+    """``x[order // k]``: the sorted pairs' tokens. Its gradient is a
+    gather too (each token sums its pairs' rows through ``place``), where
+    autodiff would scatter-add with repeated indices."""
+    return x[order // place.shape[1]]
+
+
+def _dispatch_fwd(x, order, place, sizes):
+    return _dispatch(x, order, place, sizes), (place, sizes)
+
+
+def _pair_rows(rows, place, sizes):
+    """(N, k, d): each pair's row of the sorted list, zero for a pair
+    whose expert is held elsewhere."""
+    held = place < sizes.sum()
+    got = rows[jnp.minimum(place, rows.shape[0] - 1)]
+    return jnp.where(held[..., None], got, jnp.zeros_like(got))
+
+
+def _dispatch_bwd(res, g):
+    place, sizes = res
+    dx = _pair_rows(g, place, sizes).astype(jnp.float32).sum(axis=1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def moe_experts(x, order, place, sizes, w_gate, w_up, w_down):
+    """The held experts on the sorted pairs. ``x`` (N, d); ``order``,
+    ``place``, ``sizes`` from :func:`moe_route`; ``w_gate``, ``w_up``
+    (count, f, d) and ``w_down`` (count, d, f), expert e's
+    ``y = W_down (relu(W_gate x) * (W_up x))``. Returns (rows, d), row p
+    the output for pair ``order[p]``; rows past the last group are
+    zero (``moe_combine`` never reads them)."""
+    xs = _dispatch(x, order, place, sizes)
+    gate = _grouped_dot(xs, w_gate, sizes)
+    up = _grouped_dot(xs, w_up, sizes)
+    return _grouped_dot(jax.nn.relu(gate) * up, w_down, sizes)
+
+
+@jax.custom_vjp
+def moe_combine(y, weights, order, place, sizes):
+    """Each token's chosen outputs, weighted and summed in float32:
+    ``out[n] = sum_j weights[n, j] * y[place[n, j]]`` over the pairs whose
+    expert is held. ``y`` (rows, d) from :func:`moe_experts`. Returns
+    (N, d) float32. Gathers forward and backward."""
+    picked = _pair_rows(y, place, sizes).astype(jnp.float32)
+    return jnp.einsum("nk,nkd->nd", weights, picked)
+
+
+def _combine_fwd(y, weights, order, place, sizes):
+    return (moe_combine(y, weights, order, place, sizes),
+            (y, weights, order, place, sizes))
+
+
+def _combine_bwd(res, g):
+    y, weights, order, place, sizes = res
+    k = place.shape[1]
+    g_pair = g[order // k]                      # (rows, d): each pair's dout
+    dy = _valid_rows((g_pair * weights.reshape(-1)[order][:, None])
+                     .astype(y.dtype), sizes)
+    # d weights[n, j] = <y[place[n, j]], dout[n]>: taken pair by pair in
+    # the sorted list, where dout is gathered already, then one number a
+    # pair carried back through ``place``
+    dw_pair = jnp.sum(y.astype(jnp.float32) * g_pair, axis=-1)
+    dw = jnp.where(place < sizes.sum(),
+                   dw_pair[jnp.minimum(place, y.shape[0] - 1)], 0.0)
+    return dy, dw.astype(weights.dtype), None, None, None
+
+
+moe_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # ---------------------------------------------------------------------------

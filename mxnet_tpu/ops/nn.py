@@ -254,6 +254,16 @@ def layer_norm(x, gamma, beta, axis: int = -1, eps: float = 1e-5):
             + beta.astype(dt).reshape(shape)).astype(x.dtype)
 
 
+def rms_norm(x, gamma, eps: float = 1e-6):
+    """Root-mean-square norm over the last axis: ``x / sqrt(mean(x^2) +
+    eps) * gamma``, no mean taken out and no bias. f32 stats,
+    activation-dtype output, as the other norms."""
+    dt = _stat_dtype(x)
+    xf = x.astype(dt)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(ms + eps) * gamma.astype(dt)).astype(x.dtype)
+
+
 def group_norm(x, gamma, beta, num_groups: int, eps: float = 1e-5):
     """Reference GroupNorm (src/operator/nn/group_norm.cc). x: (N, C, ...).
     f32 stats, activation-dtype output."""

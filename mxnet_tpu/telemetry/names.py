@@ -153,6 +153,8 @@ OVERLAP_FRACTION = "mx_overlap_fraction"
 # ---------------------------------------------------------------------------
 KERNEL_DISPATCH = "mx_kernel_dispatch_total"
 FLASH_ATTENTION_LAYOUT = "mx_flash_attention_layout_total"
+ATTENTION_MASK = "mx_attention_mask_total"
+MOE_DISPATCH = "mx_moe_dispatch_total"
 
 # ---------------------------------------------------------------------------
 # self-tuning performance autopilot (tuning/)
@@ -474,6 +476,20 @@ CATALOG = {
              "at the head's width, padded = head width zero-padded to "
              "128 lanes in HBM; ops/attention.py _Tiles); one count a "
              "traced call"),
+    ATTENTION_MASK: dict(
+        kind="counter", label="kind",
+        help="flash-attention calls by the mask they asked for, whatever "
+             "tier took them (full = every key, causal = keys up to the "
+             "query, window = causal and the last `window` keys only; "
+             "ops/attention.py); one count a traced call"),
+    MOE_DISPATCH: dict(
+        kind="counter", label="path",
+        help="sparse expert layers by how tokens reach their experts "
+             "(grouped = pairs sorted by expert, gathered, one grouped "
+             "product a projection, scatter-added back: no capacity, no "
+             "dropped token; capacity = the GShard one-hot dispatch of "
+             "ops/moe.py moe_ffn, which drops past capacity); one count "
+             "a traced layer"),
     AUTOTUNE_TRIALS: dict(
         kind="counter", label="backend",
         help="autotune candidate measurements by backend (timed = "

@@ -125,8 +125,14 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     cfg = dict(chip_smoke.TRAIN, batch=8, seq=32, steps=4, vocab=128,
                bert="bert_small_test",
                optimizer=("adam", {"learning_rate": 1e-3}))
-    train = chip_smoke.phase_train(cfg)
+    sparse = dict(chip_smoke.SPARSE, batch=2, seq=32, steps=4, model=dict(
+        chip_smoke.SPARSE["model"], hidden_size=64, head_dim=16,
+        moe_ffn_hidden_size=32, sliding_window_size=8, vocab_size=128))
+    train = chip_smoke.phase_train(cfg, sparse)
     assert train["kernel_paths"]["flash_attention"] == "interpret"
+    assert train["sparse_lm"]["mx_moe_dispatch_total"] == {"grouped": 4}
+    assert train["sparse_lm"]["mx_attention_mask_total"] == {
+        "causal": 1, "window": 3}
     dp = chip_smoke.phase_dp(train["loss"], cfg)
     assert dp["devices"] == 8 and dp["collectives"]["all-gather"]
     checked = chip_smoke.phase_kernels(tiny=True)
