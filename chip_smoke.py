@@ -481,6 +481,21 @@ def _flash_layouts() -> dict:
             for lay in FLASH_LAYOUTS}
 
 
+def _grid_steps(before: dict = None) -> dict:
+    """``mx_flash_attention_grid_steps_total`` as {kind: steps since
+    ``before``}; a dead step (in a flash kernel's grid, its body
+    skipped) fails the phase."""
+    from mxnet_tpu.telemetry import names
+    now = {"live": 0, "dead": 0, **_counter(names.FLASH_ATTENTION_GRID_STEPS)}
+    if before is None:
+        return now
+    steps = {k: n - before[k] for k, n in now.items()}
+    if steps["dead"] or not steps["live"]:
+        raise RuntimeError(f"the flash kernels' grids ran {steps}, "
+                           "expected live steps and none dead")
+    return steps
+
+
 def _counter(name: str) -> dict:
     """A labelled counter of the program as {label: count so far}."""
     from mxnet_tpu import telemetry
@@ -492,7 +507,7 @@ def _sparse_lm(cfg: dict) -> dict:
     """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
     fused, traced once, loss falling; → its losses and what the program
     counted while tracing it (``mx_moe_dispatch_total``,
-    ``mx_attention_mask_total``)."""
+    ``mx_attention_mask_total``, ``mx_flash_attention_grid_steps_total``)."""
     import numpy as onp
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
@@ -514,6 +529,7 @@ def _sparse_lm(cfg: dict) -> dict:
             for _ in range(2))
     before = {n: _counter(n) for n in (names.MOE_DISPATCH,
                                        names.ATTENTION_MASK)}
+    steps_before = _grid_steps()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
@@ -529,6 +545,7 @@ def _sparse_lm(cfg: dict) -> dict:
         raise RuntimeError(f"SmallThinkerLM traced {counted}, expected "
                            f"{layers} grouped expert layers, {windowed} "
                            "of them behind a window")
+    counted[names.FLASH_ATTENTION_GRID_STEPS] = _grid_steps(steps_before)
     log(f"  SmallThinkerLM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}
 
@@ -538,13 +555,14 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE) -> dict:
     import mxnet_tpu as mx
     platform = jax.devices()[0].platform
     net, loop, x, y = _bert_loop(cfg)
-    before = _flash_layouts()
+    before, steps_before = _flash_layouts(), _grid_steps()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
     finally:
         mx.amp.uninit()
     layouts = {k: n - before[k] for k, n in _flash_layouts().items()}
+    grid_steps = _grid_steps(steps_before)
     arrays = [p.data()._data for p in net.collect_params().values()]
     placed = {d.platform for a in arrays + [x._data, y._data]
               for d in a.devices()}
@@ -561,7 +579,8 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE) -> dict:
         raise RuntimeError(f"BERT's attention layers took {layouts}, "
                            "expected one call a layer and none padded")
     return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths,
-            "flash_layouts": layouts, "sparse_lm": _sparse_lm(sparse)}
+            "flash_layouts": layouts, "flash_grid_steps": grid_steps,
+            "sparse_lm": _sparse_lm(sparse)}
 
 
 def phase_kernels(tiny: bool = False) -> dict:

@@ -31,10 +31,11 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 from jax import lax
 
 from ..base import MXNetError
@@ -208,7 +209,8 @@ def _default_block(head_dim: int) -> int:
     large part of it: 1024 (v5e, bf16, 1 x 8192 causal, 28 q / 4 kv heads
     of 128, ms a call at 512 -> 1024, PR 28: forward 10.51 -> 5.21,
     backward 17.86 -> 15.68; behind a 4096 window 9.14 -> 4.83 and
-    16.71 -> 14.59)."""
+    16.71 -> 14.59; on PR 29's grids of live blocks 9.16 -> 4.57 and
+    14.04 -> 13.61, behind the window 7.36 -> 3.96 and 11.76 -> 12.11)."""
     return 1024 if head_dim >= 128 else _BLOCK_Q
 #: narrowest head the kernels take without padding
 _MIN_LANES = 8
@@ -237,7 +239,9 @@ class _Tiles(NamedTuple):
     never repeated in HBM: the block a query block meets is found by
     dividing its column tile (``packed``, which then takes one head a
     block) or its row (the folded layouts) by ``group``. ``window`` is
-    the sliding window, None for none.
+    the sliding window, None for none. Which (q block, k block) pairs
+    the multi-block kernels visit is ``_walk``'s to say, from these
+    fields: the grids hold the blocks with a valid pair alone.
     """
     layout: str
     batch: int
@@ -448,87 +452,202 @@ def _by_head(parts, masks):
     return out
 
 
-def _kv_index(t: _Tiles):
-    """Index map of a key/value block on the (row, column tile, q block,
-    k block) grid of the forward and dq kernels: the query block's own
-    row and column tile, or with fewer key/value heads the ones its
-    group reads."""
+def _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
+                       window=None):
+    """True iff block (qi, ki) holds ANY valid causal entry — the ONE
+    predicate by which the forward and both backward kernels leave a
+    block out of their grids (a divergence here would desynchronize
+    forward and backward masking). With a ``window`` the block's last
+    key must also be seen by its first query. Exact for padded tails
+    too: a block's first row and first key are always real."""
+    first_q = qi * block_q + (seq_k - seq_q)
+    run = ki * block_k <= first_q + block_q - 1
+    if window is not None:
+        run = run & (ki * block_k + block_k - 1 > first_q - window)
+    return run
+
+
+#: ``edge`` bits of a grid step: the first / the last of its row of blocks
+_FIRST, _LAST = 1, 2
+
+
+def _live_blocks(t: _Tiles, causal: bool):
+    """(nq, nk) bool, on the host: the blocks that hold a valid pair
+    under the call's mask. Without ``causal`` every one does."""
+    live = True
+    if causal:
+        live = _causal_block_skip(onp.arange(t.nq)[:, None],
+                                  onp.arange(t.nk)[None, :], t.block_q,
+                                  t.block_k, t.seq_q, t.seq_k, t.window)
+    return onp.broadcast_to(live, (t.nq, t.nk))
+
+
+def _live_steps(live, group: int = 1):
+    """The kernels' innermost grid axis: one step for each live block,
+    and none for any other. ``live`` is (rows, blocks a row) bool, a
+    row being what a kernel accumulates over: a q block's k blocks
+    (forward, dq: ``_live_blocks``), or a k block's q blocks (dk/dv:
+    its transpose) walked once for each of the ``group`` query heads
+    that read the key/value head, head after head. Returns int32
+    vectors ``(row, head, block, edge)``, row-major and ascending
+    within a row as the dense grid ran them; ``edge`` has ``_FIRST`` on
+    a row's first step (zero the scratch) and ``_LAST`` on its last
+    (write the block out). A row with no live block keeps one step, on
+    block 0: every pair of it is masked, so the row's result is written
+    as zeros (the module's convention)."""
+    steps = []
+    for row, blocks in enumerate(live):
+        walk = [(row, head, int(b), 0) for head in range(group)
+                for b in onp.flatnonzero(blocks)] or [(row, 0, 0, 0)]
+        walk[0] = walk[0][:3] + (_FIRST,)
+        walk[-1] = walk[-1][:3] + (walk[-1][3] | _LAST,)
+        steps += walk
+    return tuple(onp.asarray(col, onp.int32) for col in zip(*steps))
+
+
+class _Walk(NamedTuple):
+    """How a multi-block kernel's grid walks its blocks, after the (row,
+    column tile) axes: ``axes`` the grid's further axes, ``tables`` the
+    scalar-prefetch operands the index maps and the kernel read, and
+    ``row``, ``head``, ``block``, each of which turns what an index map
+    is handed after (row, column tile) into the step's row of blocks,
+    its query head of the group, or its block."""
+    axes: tuple
+    tables: tuple
+    row: Callable
+    head: Callable
+    block: Callable
+
+
+def _walk(live, group: int = 1) -> _Walk:
+    """The walk over ``live`` (see ``_live_steps``), chosen by what the
+    mask leaves: where a block holds no pair, one axis over the table of
+    live steps; where every block is live (no mask, or one that cuts no
+    whole block) the dense (rows, group * blocks) grid in the table's
+    own order, which needs none: a step then reads no index from SMEM
+    (v5e, 28 heads of 128 at 1 x 8192 unmasked, the table against the
+    dense grid, PR 29: forward +2 %, backward +2 to +7 %)."""
+    n_rows, n_blocks = live.shape
+    if not live.all():
+        tables = _live_steps(live, group)
+        return _Walk((len(tables[0]),), tables,
+                     lambda s, rows, head, blocks, edge: rows[s],
+                     lambda s, rows, head, blocks, edge: head[s],
+                     lambda s, rows, head, blocks, edge: blocks[s])
+    if group == 1:
+        return _Walk((n_rows, n_blocks), (), lambda row, s: row,
+                     lambda row, s: 0, lambda row, s: s)
+    return _Walk((n_rows, group * n_blocks), (), lambda row, s: row,
+                 lambda row, s: s // n_blocks, lambda row, s: s % n_blocks)
+
+
+def _grid_step(tables, blocks_a_head=None):
+    """Inside a kernel, this grid step as (row of blocks, block, first
+    of its row, last of its row), from ``_walk``'s tables or, on the
+    dense grid, from the grid's own indices (``blocks_a_head``: the
+    row's blocks when the inner axis walks them once a head)."""
+    from jax.experimental import pallas as pl
+    if tables:
+        rows, _, blocks, edge = tables
+        s = pl.program_id(2)
+        return (rows[s], blocks[s], (edge[s] & _FIRST) != 0,
+                (edge[s] & _LAST) != 0)
+    row, s = pl.program_id(2), pl.program_id(3)
+    block = s if blocks_a_head is None else s % blocks_a_head
+    return row, block, s == 0, s == pl.num_programs(3) - 1
+
+
+def _count_grid_steps(grid) -> None:
+    """``mx_flash_attention_grid_steps_total{kind}`` for one traced
+    Pallas flash call. Every step of ``_walk``'s grids (and of the
+    one-block backward's) computes, so all of ``grid`` counts as
+    ``live``; ``dead``, the steps whose body is skipped, as the dense
+    grids' were above the diagonal and behind the window, gets its 0 so
+    that the series is there to read."""
+    from .kernels import count_traced
+    count_traced("FLASH_ATTENTION_GRID_STEPS", "kind", "live",
+                 math.prod(grid))
+    count_traced("FLASH_ATTENTION_GRID_STEPS", "kind", "dead", 0)
+
+
+def _semantics(grid) -> tuple:
+    """``dimension_semantics`` of a flash kernel's grid: its last axis
+    accumulates, the others are independent."""
+    return ("parallel",) * (len(grid) - 1) + ("arbitrary",)
+
+
+def _kv_index(t: _Tiles, walk: _Walk):
+    """Index map of a key/value block on the forward's and the dq
+    kernel's grid (row, column tile, then ``walk``'s axes): the query
+    block's own row and column tile, or with fewer key/value heads the
+    ones its group reads."""
     if t.group == 1:
-        return lambda r, c, qi, ki: (r, ki, c)
+        return lambda r, c, *at: (r, walk.block(*at), c)
     rg, cg = t.row_group, t.col_group
-    return lambda r, c, qi, ki: (r // rg, ki, c // cg)
+    return lambda r, c, *at: (r // rg, walk.block(*at), c // cg)
 
 
 # ---------------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-                  sm_scale, causal, block_q, block_k, nk, seq_q, seq_k,
+def _flash_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q, seq_k,
                   need_mask, heads, window=None):
     from jax.experimental import pallas as pl
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    *tables, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
+    # the grid walks the blocks that hold a pair alone (_walk): every
+    # step computes
+    qi, ki, first, last = _grid_step(tables)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    # Causal: skip blocks strictly above the diagonal (no valid entries),
-    # and with a window those wholly behind it.
-    diag_off = seq_k - seq_q
-    run = True
-    if causal:
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
-                                 window)
+    # dots take the INPUT dtype (bf16 under AMP) with f32 accumulation —
+    # an astype(f32) here would push the MXU onto its ~6x slower f32
+    # passes
+    q = q_ref[...]                                # (G, block_q, width)
+    k = k_ref[...]                                # (G, block_k, width)
+    v = v_ref[...]
+    masks = _head_masks(q.shape, heads)
+    valid = None
+    if need_mask or causal:
+        # masking is real VPU work on a (bq, bk) tile — emitted only
+        # when there is padding to hide or a causal wedge to cut
+        k_pos = ki * block_k + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        valid = k_pos < seq_k
+        if causal:
+            q_pos = qi * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0) + (seq_k - seq_q)
+            valid = valid & (k_pos <= q_pos)
+            if window is not None:
+                valid = valid & (k_pos > q_pos - window)
+    alphas, pvs = [], []
+    for i, mask in enumerate(masks):              # static: heads <= 16
+        s = lax.dot_general(_only(q, mask), k,
+                            (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * sm_scale
+        if valid is not None:
+            s = jnp.where(valid[None], s, _NEG_INF)
+        m_prev = m_s[i, :, :, :1]                 # (G, block_q, 1)
+        m_cur = s.max(axis=2, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_s[i, :, :, :1] * alpha + p.sum(axis=2, keepdims=True)
+        m_s[i] = jnp.broadcast_to(m_new, m_s.shape[1:])
+        l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
+        alphas.append(alpha)
+        pvs.append(lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+    acc_s[...] = acc_s[...] * _by_head(alphas, masks) \
+        + _by_head(pvs, masks)
 
-    @pl.when(run)
-    def _compute():
-        # dots take the INPUT dtype (bf16 under AMP) with f32
-        # accumulation — an astype(f32) here would push the MXU onto its
-        # ~6x slower f32 passes
-        q = q_ref[...]                            # (G, block_q, width)
-        k = k_ref[...]                            # (G, block_k, width)
-        v = v_ref[...]
-        masks = _head_masks(q.shape, heads)
-        valid = None
-        if need_mask or causal:
-            # masking is real VPU work on a (bq, bk) tile — emitted only
-            # when there is padding to hide or a causal wedge to cut
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < seq_k
-            if causal:
-                q_pos = qi * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0) + diag_off
-                valid = valid & (k_pos <= q_pos)
-                if window is not None:
-                    valid = valid & (k_pos > q_pos - window)
-        alphas, pvs = [], []
-        for i, mask in enumerate(masks):          # static: heads <= 16
-            s = lax.dot_general(_only(q, mask), k,
-                                (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * sm_scale
-            if valid is not None:
-                s = jnp.where(valid[None], s, _NEG_INF)
-            m_prev = m_s[i, :, :, :1]             # (G, block_q, 1)
-            m_cur = s.max(axis=2, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_s[i, :, :, :1] * alpha + p.sum(axis=2, keepdims=True)
-            m_s[i] = jnp.broadcast_to(m_new, m_s.shape[1:])
-            l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
-            alphas.append(alpha)
-            pvs.append(lax.dot_general(
-                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32))
-        acc_s[...] = acc_s[...] * _by_head(alphas, masks) \
-            + _by_head(pvs, masks)
-
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
         masks = _head_masks(acc_s.shape, heads)
         ms = [m_s[i, :, :, :1] for i in range(heads)]
@@ -570,40 +689,45 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
                     row_group=t.row_group)
     w = t.width
 
+    walk = _walk(_live_blocks(t, causal))
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=t.block_q,
-        block_k=t.block_k, nk=t.nk, seq_q=t.seq_q, seq_k=t.seq_k,
+        block_k=t.block_k, seq_q=t.seq_q, seq_k=t.seq_k,
         need_mask=(t.skp != t.seq_k), heads=t.heads, window=t.window)
-    q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, qi, ki: (r, qi, c))
-    k_spec = pl.BlockSpec((g, t.block_k, w), _kv_index(t))
+    grid = (t.rows // g, t.col_tiles) + walk.axes
+    _count_grid_steps(grid)
+    q_spec = pl.BlockSpec((g, t.block_q, w),
+                          lambda r, c, *at: (r, walk.row(*at), c))
+    k_spec = pl.BlockSpec((g, t.block_k, w), _kv_index(t, walk))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(t.rows // g, t.col_tiles, t.nq, t.nk),
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((g, t.heads, t.block_q, 8),
-                         lambda r, c, qi, ki: (r, c, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.tables),
+            grid=grid,
+            in_specs=[q_spec, k_spec, k_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((g, t.heads, t.block_q, 8),
+                             lambda r, c, *at: (r, c, walk.row(*at), 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
+                pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
+                pltpu.VMEM((g, t.block_q, w), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(qt.shape, q.dtype),
             jax.ShapeDtypeStruct(
                 (t.rows, t.col_tiles * t.heads, t.sqp, 8), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
-            pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
-            pltpu.VMEM((g, t.block_q, w), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=_semantics(grid),
             # q, k, v, o blocks; acc scratch, m and l and one product a
             # head; s and p tiles; the lse block
             vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 4,
                                          1 + 3 * t.heads, 2, 1)),
         interpret=interpret,
-    )(qt, kt, vt)
+    )(*walk.tables, qt, kt, vt)
     return from_tiles(out, t.seq_q), lse
 
 
@@ -611,20 +735,6 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
 # Pallas TPU backward kernels (FlashAttention-2 style: recompute p from the
 # saved per-row log-sum-exp; no S×S residual is ever materialized)
 # ---------------------------------------------------------------------------
-
-def _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
-                       window=None):
-    """True iff block (qi, ki) holds ANY valid causal entry — the shared
-    skip predicate for the forward and both backward kernels (a divergence
-    here would desynchronize forward and backward masking). With a
-    ``window`` the block's last key must also be seen by its first
-    query."""
-    first_q = qi * block_q + (seq_k - seq_q)
-    run = ki * block_k <= first_q + block_q - 1
-    if window is not None:
-        run = run & (ki * block_k + block_k - 1 > first_q - window)
-    return run
-
 
 def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k, window=None):
     k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
@@ -656,52 +766,45 @@ def _bwd_head(q, k, v, do, lse, delta, qmask, valid, sm_scale):
     return p, p * (dp - delta) * sm_scale
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_s, dv_s, *, sm_scale, causal,
-                          block_q, block_k, nq, seq_q, seq_k, need_mask,
-                          heads, window=None, group=1):
+def _flash_bwd_dkv_kernel(*refs, blocks_a_head, sm_scale, causal, block_q,
+                          block_k, seq_q, seq_k, need_mask, heads,
+                          window=None):
     from jax.experimental import pallas as pl
-    ki = pl.program_id(2)
-    # the inner axis walks the q blocks of every query head that reads
-    # this key/value head, one head after another
-    step = pl.program_id(3)
-    qi = step if group == 1 else step % nq
+    (*tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+     dv_ref, dk_s, dv_s) = refs
+    # the grid walks, k block after k block, the q blocks that hold a
+    # pair with it, once for every query head that reads this key/value
+    # head, one head after another (_walk)
+    ki, qi, first, last = _grid_step(tables, blocks_a_head)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    run = True
-    if causal:  # this k block only touches q rows at/after the diagonal
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
-                                 window)
+    q = q_ref[...]                                  # (G, bq, width)
+    k = k_ref[...]                                  # (G, bk, width)
+    v = v_ref[...]
+    do = do_ref[...]                                # (G, bq, width)
+    qmasks = _head_masks(q.shape, heads)
+    kmasks = _head_masks(k.shape, heads)
+    valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
+                      window) if need_mask or causal else None
+    dks, dvs = [], []
+    for i, qmask in enumerate(qmasks):
+        p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
+                          delta_ref[:, i][:, :, :1], qmask, valid,
+                          sm_scale)
+        dvs.append(lax.dot_general(p.astype(do.dtype), do,
+                                   (((1,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+        dks.append(lax.dot_general(ds.astype(q.dtype), q,
+                                   (((1,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+    dv_s[...] += _by_head(dvs, kmasks)
+    dk_s[...] += _by_head(dks, kmasks)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[...]                              # (G, bq, width)
-        k = k_ref[...]                              # (G, bk, width)
-        v = v_ref[...]
-        do = do_ref[...]                            # (G, bq, width)
-        qmasks = _head_masks(q.shape, heads)
-        kmasks = _head_masks(k.shape, heads)
-        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
-                          window) if need_mask or causal else None
-        dks, dvs = [], []
-        for i, qmask in enumerate(qmasks):
-            p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
-                              delta_ref[:, i][:, :, :1], qmask, valid,
-                              sm_scale)
-            dvs.append(lax.dot_general(p.astype(do.dtype), do,
-                                       (((1,), (1,)), ((0,), (0,))),
-                                       preferred_element_type=jnp.float32))
-            dks.append(lax.dot_general(ds.astype(q.dtype), q,
-                                       (((1,), (1,)), ((0,), (0,))),
-                                       preferred_element_type=jnp.float32))
-        dv_s[...] += _by_head(dvs, kmasks)
-        dk_s[...] += _by_head(dks, kmasks)
-
-    @pl.when(step == group * nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
@@ -744,43 +847,35 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     dv_ref[...] = _by_head(dvs, kmasks).astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_s, *, sm_scale, causal, block_q,
-                         block_k, nk, seq_q, seq_k, need_mask, heads,
-                         window=None):
+def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q,
+                         seq_k, need_mask, heads, window=None):
     from jax.experimental import pallas as pl
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    (*tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+     dq_s) = refs
+    qi, ki, first, last = _grid_step(tables)        # as the forward
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    run = True
-    if causal:
-        run = _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
-                                 window)
+    q = q_ref[...]                                  # (G, bq, width)
+    k = k_ref[...]                                  # (G, bk, width)
+    v = v_ref[...]
+    do = do_ref[...]
+    qmasks = _head_masks(q.shape, heads)
+    valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
+                      window) if need_mask or causal else None
+    dqs = []
+    for i, qmask in enumerate(qmasks):
+        _, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
+                          delta_ref[:, i][:, :, :1], qmask, valid,
+                          sm_scale)
+        dqs.append(lax.dot_general(ds.astype(k.dtype), k,
+                                   (((2,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32))
+    dq_s[...] += _by_head(dqs, qmasks)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[...]                              # (G, bq, width)
-        k = k_ref[...]                              # (G, bk, width)
-        v = v_ref[...]
-        do = do_ref[...]
-        qmasks = _head_masks(q.shape, heads)
-        valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
-                          window) if need_mask or causal else None
-        dqs = []
-        for i, qmask in enumerate(qmasks):
-            _, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
-                              delta_ref[:, i][:, :, :1], qmask, valid,
-                              sm_scale)
-            dqs.append(lax.dot_general(ds.astype(k.dtype), k,
-                                       (((2,), (1,)), ((0,), (0,))),
-                                       preferred_element_type=jnp.float32))
-        dq_s[...] += _by_head(dqs, qmasks)
-
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
 
@@ -789,13 +884,14 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                       block_q: int = None, block_k: int = None,
                       interpret: bool = False, num_heads=None, window=None):
     """Pallas flash attention backward: one fused kernel when the whole
-    sequence is one block, else dq via a (q-parallel, k-inner) kernel
-    and dk/dv via a (k-parallel, q-inner) kernel. ``lse`` is what
-    ``_flash_fwd_pallas`` returned for the same blocks. With fewer
-    key/value heads than query heads the dk/dv kernel's inner axis walks
-    the q blocks of each query head of the group in turn, so a key/value
-    head's gradient is summed where it is made, and the one-block case
-    takes the two kernels too."""
+    sequence is one block, else dq via a kernel that walks each q
+    block's live k blocks and dk/dv via one that walks each k block's
+    live q blocks (``_walk``: a block with no valid pair is not in
+    either grid). ``lse`` is what ``_flash_fwd_pallas`` returned for the
+    same blocks. With fewer key/value heads than query heads the dk/dv
+    kernel's inner axis walks the q blocks of each query head of the
+    group in turn, so a key/value head's gradient is summed where it is
+    made, and the one-block case takes the two kernels too."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -819,6 +915,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                                          lambda r, c: (r, 0, c))
         rspec = pl.BlockSpec((g, heads, t.block_q, 8),
                              lambda r, c: (r, c, 0, 0))
+        _count_grid_steps((t.rows // g, t.col_tiles))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_fused_kernel, **static),
             grid=(t.rows // g, t.col_tiles),
@@ -852,62 +949,71 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     # 8-lane replication (TPU block tiling minimum for a row vector)
     delta = jnp.broadcast_to(delta[..., None], lse.shape)
 
-    q_spec = pl.BlockSpec((g, t.block_q, w), lambda r, c, a, b_: (r, a, c))
+    live = _live_blocks(t, causal)
+    walk = _walk(live)
+    grid = (t.rows // g, t.col_tiles) + walk.axes
+    _count_grid_steps(grid)
+    q_spec = pl.BlockSpec((g, t.block_q, w),
+                          lambda r, c, *at: (r, walk.row(*at), c))
     row_spec = pl.BlockSpec((g, heads, t.block_q, 8),
-                            lambda r, c, a, b_: (r, c, a, 0))
-    k_in = pl.BlockSpec((g, t.block_k, w), _kv_index(t))
+                            lambda r, c, *at: (r, c, walk.row(*at), 0))
+    k_in = pl.BlockSpec((g, t.block_k, w), _kv_index(t, walk))
 
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, nk=t.nk, **static),
-        grid=(t.rows // g, t.col_tiles, t.nq, t.nk),
-        in_specs=[q_spec, k_in, k_in, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        functools.partial(_flash_bwd_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.tables),
+            grid=grid,
+            in_specs=[q_spec, k_in, k_in, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((g, t.block_q, w), jnp.float32)]),
         out_shape=q_shape,
-        scratch_shapes=[pltpu.VMEM((g, t.block_q, w), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=_semantics(grid),
             # q, k, v, do in, dq out; dq scratch and one product a head;
             # s, p, dp, ds tiles; lse and delta blocks
             vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 5,
                                          1 + heads, 4, 2)),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )(*walk.tables, qt, kt, vt, dot, lse, delta)
 
-    k_spec = pl.BlockSpec((g, t.block_k, w), lambda r, c, ki, qi: (r, ki, c))
-    if t.group == 1:
-        q_at = lambda r, c, ki, qi: (r, qi, c)
-    else:
-        # the grid is over key/value rows and column tiles; inner step
-        # s reads q block s % nq of the group's query head s // nq
-        nq, rg, cg = t.nq, t.row_group, t.col_group
-        q_at = lambda r, c, ki, s: (r * rg + (s // nq if rg > 1 else 0),
-                                    s % nq,
-                                    c * cg + (s // nq if cg > 1 else 0))
+    # the dk/dv grid is over key/value rows and column tiles; a step
+    # reads a q block of the query head of the group that the walk names
+    walk = _walk(live.T, t.group)
+    grid = (kt.shape[0] // g, kt.shape[2] // w) + walk.axes
+    _count_grid_steps(grid)
+    rg, cg = t.row_group, t.col_group
 
-    def stat_at(r, c, ki, s):
-        row, qi, col = q_at(r, c, ki, s)
+    def q_at(r, c, *at):
+        return (r * rg + (walk.head(*at) if rg > 1 else 0), walk.block(*at),
+                c * cg + (walk.head(*at) if cg > 1 else 0))
+
+    def stat_at(*at):
+        row, qi, col = q_at(*at)
         return row, col, qi, 0
+    k_spec = pl.BlockSpec((g, t.block_k, w),
+                          lambda r, c, *at: (r, walk.row(*at), c))
     qrow = pl.BlockSpec((g, t.block_q, w), q_at)
     rrow = pl.BlockSpec((g, heads, t.block_q, 8), stat_at)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq=t.nq, group=t.group,
-                          **static),
-        grid=(kt.shape[0] // g, kt.shape[2] // w, t.nk, t.group * t.nq),
-        in_specs=[qrow, k_spec, k_spec, qrow, rrow, rrow],
-        out_specs=[k_spec, k_spec],
+        functools.partial(_flash_bwd_dkv_kernel, **static,
+                          blocks_a_head=t.nq if t.group > 1 else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.tables),
+            grid=grid,
+            in_specs=[qrow, k_spec, k_spec, qrow, rrow, rrow],
+            out_specs=[k_spec, k_spec],
+            scratch_shapes=[pltpu.VMEM((g, t.block_k, w), jnp.float32),
+                            pltpu.VMEM((g, t.block_k, w), jnp.float32)]),
         out_shape=[k_shape, k_shape],
-        scratch_shapes=[pltpu.VMEM((g, t.block_k, w), jnp.float32),
-                        pltpu.VMEM((g, t.block_k, w), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=_semantics(grid),
             # q, k, v, do in, dk, dv out; dk, dv scratch and two
             # products a head; 4 score tiles; lse and delta blocks
             vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 6,
                                          2 + 2 * heads, 4, 2)),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )(*walk.tables, qt, kt, vt, dot, lse, delta)
 
     return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
 
@@ -1038,8 +1144,9 @@ def flash_attention(q, k, v, causal: bool = False,
     k and v may hold fewer heads than q (``num_kv_heads``, read off their
     shape when not given): query head n reads head n // (H // Hkv), and
     no tier repeats k or v in memory. ``window`` (with ``causal``) hides
-    key j from query i unless 0 <= i - j < window; the kernels skip the
-    blocks wholly outside it as they skip those above the diagonal.
+    key j from query i unless 0 <= i - j < window; the kernels' grids
+    hold neither the blocks wholly outside it nor those above the
+    diagonal (``_walk``).
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise MXNetError("flash_attention expects (batch, heads, seq, dim)")

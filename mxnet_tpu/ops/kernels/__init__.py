@@ -200,16 +200,18 @@ def dispatch(kernel: str, supported: bool = True,
     return out
 
 
-def count_traced(metric: str, label_key: str, label: str) -> None:
-    """One more under ``label`` of the labelled counter
-    ``telemetry.names.<metric>``: what the op layer counts while a call
-    is traced (dispatch path, flash layout, attention mask, expert
-    dispatch). Telemetry must never fail a kernel call."""
+def count_traced(metric: str, label_key: str, label: str,
+                 n: int = 1) -> None:
+    """``n`` more (one, unless said) under ``label`` of the labelled
+    counter ``telemetry.names.<metric>``: what the op layer counts while
+    a call is traced (dispatch path, flash layout and grid steps,
+    attention mask, expert dispatch). Telemetry must never fail a kernel
+    call."""
     try:
         from ...telemetry import names as tn
         from ...telemetry import registry as treg
         treg().counter(getattr(tn, metric),
-                       label_key=label_key).inc(label=label)
+                       label_key=label_key).inc(n, label=label)
     except Exception:
         pass
 

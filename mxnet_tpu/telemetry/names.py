@@ -153,6 +153,7 @@ OVERLAP_FRACTION = "mx_overlap_fraction"
 # ---------------------------------------------------------------------------
 KERNEL_DISPATCH = "mx_kernel_dispatch_total"
 FLASH_ATTENTION_LAYOUT = "mx_flash_attention_layout_total"
+FLASH_ATTENTION_GRID_STEPS = "mx_flash_attention_grid_steps_total"
 ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
 
@@ -476,6 +477,15 @@ CATALOG = {
              "at the head's width, padded = head width zero-padded to "
              "128 lanes in HBM; ops/attention.py _Tiles); one count a "
              "traced call"),
+    FLASH_ATTENTION_GRID_STEPS: dict(
+        kind="counter", label="kind",
+        help="grid steps of the Pallas flash-attention calls (forward, "
+             "dq, dk/dv, the one-block fused backward), summed over a "
+             "call's programs: live = steps whose body computes a block, "
+             "dead = steps in the grid whose body is skipped. The grids "
+             "hold the blocks with a valid pair under the mask and no "
+             "other (ops/attention.py _walk), so dead reads 0; counted "
+             "while a call is traced"),
     ATTENTION_MASK: dict(
         kind="counter", label="kind",
         help="flash-attention calls by the mask they asked for, whatever "

@@ -212,29 +212,207 @@ def test_flash_attention_pallas_backward_cross_length():
                                     rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_pallas_backward_multiblock(causal):
-    # Small explicit blocks force a 3x4 grid: exercises cross-block
-    # accumulator init/+=/finalize and the causal block-skip predicate in
-    # both backward kernels (not reachable with default 512 blocks on CI
-    # sizes).
-    q, k, v = _rand_qkv(b=1, h=2, s=48, d=8, seed=5)
-    k = k[:, :, :64] if k.shape[2] >= 64 else k
-    sm = 8 ** -0.5
+# multi-block cases of the three flash kernels, interpret mode: heads
+# (q, k/v), head width, (seq_q, seq_k), (block_q, block_k), causal, window,
+# and whether the call comes as (B, S, H*D) (packed at D = 128, folded
+# below it) or as (B, H, S, D)
+_MULTIBLOCK = {
+    # 3x3 blocks: cross-block accumulator init / += / finalize
+    "full": (2, 2, 8, (48, 48), (16, 16), False, None, False),
+    "causal": (2, 2, 8, (48, 48), (16, 16), True, None, False),
+    "causal-blocks-32x16": (2, 2, 8, (64, 64), (32, 16), True, None, False),
+    "causal-blocks-16x32": (2, 2, 8, (64, 64), (16, 32), True, None, False),
+    "window-inside-a-block": (2, 2, 8, (64, 64), (16, 16), True, 5, False),
+    "window-of-two-blocks": (2, 2, 8, (64, 64), (16, 16), True, 32, False),
+    "window-cuts-a-block": (2, 2, 8, (64, 64), (16, 16), True, 20, False),
+    "window-over-the-sequence": (2, 2, 8, (64, 64), (16, 16), True, 100,
+                                 False),
+    # a padded tail: 40 = 2.5 blocks
+    "causal-ragged-tail": (2, 2, 8, (40, 40), (16, 16), True, None, False),
+    "window-ragged-tail": (2, 2, 8, (40, 40), (16, 16), True, 12, False),
+    "full-ragged-tail": (2, 2, 8, (40, 56), (16, 16), False, None, False),
+    # k blocks no query sees (their dk, dv are zeros) and q rows that see
+    # no key (their o, dq are zeros)
+    "window-longer-k": (2, 2, 8, (24, 64), (16, 16), True, 8, False),
+    "causal-longer-q": (2, 2, 8, (64, 24), (16, 16), True, None, False),
+    # grouped key/value heads, the projections' own layout
+    "grouped-packed": (4, 2, 128, (48, 48), (16, 16), True, None, True),
+    "grouped-packed-window": (4, 2, 128, (56, 56), (16, 16), True, 20, True),
+    "grouped-folded": (4, 2, 32, (48, 48), (16, 16), True, None, True),
+    "grouped-folded-window": (4, 2, 32, (56, 56), (16, 16), True, 20, True),
+    "packed-two-heads-a-tile-window": (4, 4, 64, (64, 64), (16, 16), True,
+                                       24, True),
+}
 
-    o, lse = A._flash_fwd_pallas(q, k, v, causal, sm, block_q=16,
-                                 block_k=16, interpret=True)
-    rng = onp.random.RandomState(9)
-    do = jnp.asarray(rng.randn(*o.shape).astype("float32"))
-    dq, dk, dv = A._flash_bwd_pallas(q, k, v, o, lse, do, causal, sm,
-                                     block_q=16, block_k=16, interpret=True)
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: A.attention_reference(q_, k_, v_, causal=causal,
-                                                 sm_scale=sm), q, k, v)
-    rq, rk, rv = vjp(do)
-    for a, b in zip((dq, dk, dv), (rq, rk, rv)):
+
+@pytest.mark.parametrize("case", sorted(_MULTIBLOCK))
+def test_flash_attention_pallas_backward_multiblock(case):
+    # Small explicit blocks force a multi-block grid: exercises the
+    # accumulators across a row of live blocks and the table of live
+    # blocks in the forward and both backward kernels (not reachable
+    # with default 512 blocks on CI sizes), against the unfused oracle.
+    hq, hkv, d, (sq, sk), (bq, bk), causal, window, bsh = _MULTIBLOCK[case]
+    rng = onp.random.RandomState(5)
+    q, k, v, do = (jnp.asarray(rng.randn(1, h, s, d).astype("float32"))
+                   for h, s in ((hq, sq), (hkv, sk), (hkv, sk), (hq, sq)))
+    sm = d ** -0.5
+    want, vjp = jax.vjp(
+        lambda q_, k_, v_: A.attention_reference(
+            q_, k_, v_, causal=causal, sm_scale=sm, window=window), q, k, v)
+    to = A._merge_heads if bsh else (lambda x: x)
+    back = (lambda x, h: A._split_heads(x, h)) if bsh else (lambda x, h: x)
+    heads = hq if bsh else None
+    o, lse = A._flash_fwd_pallas(to(q), to(k), to(v), causal, sm, bq, bk,
+                                 True, heads, window)
+    dq, dk, dv = A._flash_bwd_pallas(to(q), to(k), to(v), o, lse, to(do),
+                                     causal, sm, bq, bk, True, heads, window)
+    t = A._tiles(to(q).shape, to(k).shape, bq, bk, heads, window)
+    assert t.nq > 1 and t.nk > 1
+    if bsh:
+        assert t.layout == ("packed" if d >= 64 else "unpadded")
+    got = (back(o, hq), back(dq, hq), back(dk, hkv), back(dv, hkv))
+    for a, b in zip(got, (want,) + vjp(do)):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the table of live blocks: pure NumPy, no kernel
+# ---------------------------------------------------------------------------
+
+def _brute_live(t, causal):
+    """(nq, nk) bool from the (seq_q, seq_k) element mask itself."""
+    i = onp.arange(t.seq_q)[:, None] + (t.seq_k - t.seq_q)
+    j = onp.arange(t.seq_k)[None, :]
+    ok = onp.ones((t.seq_q, t.seq_k), bool)
+    if causal:
+        ok &= j <= i
+    if t.window is not None:
+        ok &= j > i - t.window
+    padded = onp.zeros((t.sqp, t.skp), bool)
+    padded[:t.seq_q, :t.seq_k] = ok
+    return padded.reshape(t.nq, t.block_q, t.nk, t.block_k).any((1, 3))
+
+
+def _check_rows(rows, heads, blocks, edge, live, group):
+    """One walk of ``_live_steps`` against the live map it was built
+    from: row-major; within a row head after head and blocks ascending;
+    exactly the live blocks, once for each head; one first and one last
+    step a row; a row with no live block keeps one step on block 0."""
+    assert sorted(set(rows.tolist())) == list(range(len(live)))
+    assert (onp.diff(rows) >= 0).all()
+    for row, want in enumerate(live):
+        at = onp.flatnonzero(rows == row)
+        assert (edge[at] & A._FIRST != 0).tolist() == \
+            [True] + [False] * (len(at) - 1)
+        assert (edge[at] & A._LAST != 0).tolist() == \
+            [False] * (len(at) - 1) + [True]
+        steps = list(zip(heads[at].tolist(), blocks[at].tolist()))
+        if not want.any():
+            assert steps == [(0, 0)]
+            continue
+        assert steps == [(h, int(b)) for h in range(group)
+                         for b in onp.flatnonzero(want)]
+
+
+@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32)],
+                         ids=["16x16", "32x16", "16x32"])
+@pytest.mark.parametrize("seqs", [(96, 96), (96, 160), (160, 96), (90, 90),
+                                  (70, 121)],
+                         ids=["square", "longer-k", "longer-q", "ragged",
+                              "ragged-longer-k"])
+@pytest.mark.parametrize("mask", ["full", "causal", "window-1", "window-5",
+                                  "window-17", "window-32", "window-40",
+                                  "window-1000"])
+def test_live_block_table_is_the_brute_force_mask(mask, seqs, blocks, group):
+    """The flash kernels' grids list, block for block, the blocks in
+    which the element mask has a true entry: forward and dq q-major,
+    dk/dv k-major with every query head of the group in turn."""
+    causal = mask != "full"
+    window = int(mask.split("-")[1]) if "-" in mask else None
+    (sq, sk), (bq, bk) = seqs, blocks
+    t = A._tiles((1, sq, group * 128), (1, sk, 128), bq, bk, group, window)
+    assert (t.group, t.seq_q, t.seq_k) == (group, sq, sk)
+    live = A._live_blocks(t, causal)
+    want = _brute_live(t, causal)
+    assert live.shape == (t.nq, t.nk) and (live == want).all()
+    qi, head, ki, edge = A._live_steps(live)
+    assert all(x.dtype == onp.int32 for x in (qi, head, ki, edge))
+    assert not head.any()
+    _check_rows(qi, head, ki, edge, want, 1)
+    ki, head, qi, edge = A._live_steps(live.T, group)
+    _check_rows(ki, head, qi, edge, want.T, group)
+    empty_q = int((~want.any(1)).sum())
+    assert len(edge) == group * want.sum() + int((~want.any(0)).sum())
+    if mask == "causal" and sq > sk:
+        assert empty_q > 0           # rows the table must still write
+    # the grid a kernel takes: the table where a block is dead, and the
+    # dense grid, which is the table's own order, where none is
+    for walk, lv, g in ((A._walk(live), live, 1),
+                        (A._walk(live.T, group), live.T, group)):
+        if mask != "full":
+            assert not want.all() and len(walk.tables) == 4
+            assert walk.axes == (len(walk.tables[0]),)
+            at = [f(3, *walk.tables) for f in walk[2:]]
+            assert at == [x[3] for x in walk.tables[:3]]
+            continue
+        assert empty_q == 0 and walk.tables == ()
+        n_rows, n_blocks = lv.shape
+        assert walk.axes == (n_rows, g * n_blocks)
+        dense = [tuple(f(r, s) for f in walk[2:]) for r in range(n_rows)
+                 for s in range(g * n_blocks)]
+        assert dense == list(zip(*(x.tolist()
+                                   for x in A._live_steps(lv, g)[:3])))
+
+
+def test_live_steps_of_the_smallthinker_cell():
+    """28 q / 4 kv heads of 128 at 1 x 8192, 1024 blocks: 36 live blocks
+    a head under the causal mask and 30 behind the 4096 window, of 64."""
+    for window, n in ((None, 36), (4096, 30)):
+        t = A._tiles((1, 8192, 28 * 128), (1, 8192, 4 * 128), 1024, 1024,
+                     28, window)
+        live = A._live_blocks(t, True)
+        assert (t.nq, t.nk, int(live.sum())) == (8, 8, n)
+        assert len(A._live_steps(live)[0]) == n
+        assert len(A._live_steps(live.T, t.group)[0]) == 7 * n
+
+
+def test_flash_grid_steps_are_counted_and_none_is_dead(monkeypatch):
+    """``mx_flash_attention_grid_steps_total``: SmallThinker's attention
+    shapes at half the sequence and half the window (one causal layer and
+    three behind the window, traced only), then a one-block BERT call."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.telemetry import names as tnames
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+
+    def steps():
+        return {kind: telemetry.value(tnames.FLASH_ATTENTION_GRID_STEPS,
+                                      kind) or 0 for kind in ("live", "dead")}
+
+    def trace(q, k, heads, **mask):
+        before = steps()
+        jax.eval_shape(
+            lambda q_, k_, v_, do: jax.vjp(
+                lambda *a: A.flash_attention_bsh(*a, heads, **mask),
+                q_, k_, v_)[1](do), q, k, k, q)
+        return {kind: n - before[kind] for kind, n in steps().items()}
+
+    q = jax.ShapeDtypeStruct((1, 4096, 28 * 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 4096, 4 * 128), jnp.bfloat16)
+    # 4 x 4 blocks of 1024 a head: 10 live when causal, 9 behind 2048;
+    # forward, dq and dk/dv each walk them once for each of 28 heads
+    assert trace(q, k, 28, causal=True) == {"live": 3 * 28 * 10, "dead": 0}
+    counted = [trace(q, k, 28, causal=True, window=2048) for _ in range(3)]
+    assert counted == [{"live": 3 * 28 * 9, "dead": 0}] * 3
+    # BERT-base, 32 x 512: one block a sequence, two heads a lane tile
+    # (6 column tiles), a few rows a program: one step a program in the
+    # forward and in the fused backward
+    x = jax.ShapeDtypeStruct((32, 512, 768), jnp.bfloat16)
+    programs = sum(32 // A._head_group(32, 512, 512, n_tiles=n,
+                                       heads_per_block=2) * 6
+                   for n in (1, 4))
+    assert trace(x, x, 12) == {"live": programs, "dead": 0}
 
 
 def test_flash_pallas_bf16_interpret():

@@ -133,6 +133,12 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     assert train["sparse_lm"]["mx_moe_dispatch_total"] == {"grouped": 4}
     assert train["sparse_lm"]["mx_attention_mask_total"] == {
         "causal": 1, "window": 3}
+    # 32 positions in blocks of 32: a step a program, none dead; the
+    # sparse LM's grouped heads take the two-kernel backward
+    assert train["flash_grid_steps"]["dead"] == 0 < \
+        train["flash_grid_steps"]["live"]
+    sparse_steps = train["sparse_lm"]["mx_flash_attention_grid_steps_total"]
+    assert sparse_steps["dead"] == 0 and sparse_steps["live"] % 4 == 0
     dp = chip_smoke.phase_dp(train["loss"], cfg)
     assert dp["devices"] == 8 and dp["collectives"]["all-gather"]
     checked = chip_smoke.phase_kernels(tiny=True)
