@@ -35,7 +35,7 @@ _SUBSYSTEMS = [
     ("operator", None), ("rtc", None), ("contrib", None),
     ("subgraph", None), ("checkpoint", None), ("testing", None),
     ("analysis", None), ("telemetry", None), ("elastic", None),
-    ("serving", None), ("tuning", None), ("library", None),
+    ("serving", None), ("library", None),
     ("inspector", None), ("visualization", None), ("visualization", "viz"),
     ("name", None), ("attribute", None), ("error", None), ("log", None),
     ("registry", None),

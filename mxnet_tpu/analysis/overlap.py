@@ -128,10 +128,6 @@ class OverlapReport:
     #: merely approximates the schedule)
     scheduled: bool = False
     profile: str = "cpu"
-    #: active ``zero.bucket_bytes`` at census time (None outside the
-    #: fused-step context) — rides along so bench legs/autotuner trials
-    #: record which bucketing produced this posture
-    zero_bucket_bytes: Optional[int] = None
     findings: List[Finding] = field(default_factory=list)
 
     @property
@@ -151,8 +147,7 @@ class OverlapReport:
                 "total_comm_s": self.total_comm_s,
                 "overlap_fraction": self.overlap_fraction,
                 "n_collectives": self.n_collectives,
-                "n_async": self.n_async,
-                "zero_bucket_bytes": self.zero_bucket_bytes}
+                "n_async": self.n_async}
 
     def to_dict(self) -> Dict[str, Any]:
         d = self.brief()
@@ -295,14 +290,6 @@ def _tainted_in_window(mod: HloModule, op: HloOp, order: List[str],
     return tainted
 
 
-def _active_bucket_bytes() -> Optional[int]:
-    try:
-        from ..gluon.fused_step import _zero_bucket_bytes
-        return int(_zero_bucket_bytes())
-    except Exception:            # pragma: no cover - defensive
-        return None
-
-
 def overlap_census(hlo_text: str, mesh=None,
                    num_devices: Optional[int] = None,
                    profile=None) -> OverlapReport:
@@ -323,7 +310,6 @@ def overlap_census(hlo_text: str, mesh=None,
                 if jmesh is not None else 1
         profile = profile or _sharding.bandwidth_profile()
         report.profile = profile.name
-        report.zero_bucket_bytes = _active_bucket_bytes()
         mod = parse_hlo(hlo_text, num_devices=num_devices)
         report.scheduled = mod.is_scheduled
         census = _program.collective_census(

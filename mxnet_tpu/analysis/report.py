@@ -5,10 +5,9 @@ Every checker in ``mxnet_tpu.analysis`` speaks one vocabulary: a
 ``ProgramReport`` aggregates one compiled train-step's census numbers
 (collectives, donation, host transfers, dtype drift, retraces) plus the
 findings derived from them. The report is the machine-checkable contract
-tier-1 asserts on (tests/test_fused_step.py, tests/test_zero_shard.py)
-and the structural diff bench.py attaches to its BENCH json — numerics
-tests prove the step computes the right thing, the report proves the
-program IS the right program (docs/ANALYSIS.md).
+tier-1 asserts on (tests/test_fused_step.py, tests/test_zero_shard.py):
+numerics tests prove the step computes the right thing, the report
+proves the program IS the right program (docs/ANALYSIS.md).
 """
 from __future__ import annotations
 

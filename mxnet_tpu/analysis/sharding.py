@@ -1108,7 +1108,7 @@ class ShardingAudit:
         return sum(r.wire_bytes for r in self.reshards)
 
     def brief(self) -> Dict[str, Any]:
-        """The headline numbers bench.py attaches per leg."""
+        """The headline numbers, as one flat dict."""
         return {"implicit_reshards": len(self.reshards),
                 "reshard_bytes": self.reshard_bytes,
                 "comm_cost_est_s": self.cost.total_s if self.cost

@@ -121,44 +121,16 @@ _live_windows: "weakref.WeakSet" = weakref.WeakSet()
 def inflight_steps(default: int = 2) -> int:
     """The bounded dispatch-window size: how many train-step futures
     the host may keep outstanding before it blocks on the oldest.
-    Resolved autotune override > ``MXNET_INFLIGHT_STEPS`` > ``default``
-    (the ``engine.inflight_steps`` tunable — tuning/space.py).
+    ``MXNET_INFLIGHT_STEPS`` when set, else ``default``; a value that
+    does not parse gives ``default``, a negative one 0.
     ``NaiveEngine`` forces 0 — every step retires synchronously, the
     race-free oracle mode."""
     if get().is_naive:
         return 0
-    from .tuning import space as _tspace
-    found, v = _tspace.get_override("engine.inflight_steps")
-    if not found:
-        v = get_env("MXNET_INFLIGHT_STEPS", str(default))
     try:
-        return max(0, int(v))
+        return max(0, int(get_env("MXNET_INFLIGHT_STEPS", str(default))))
     except (TypeError, ValueError):
         return default
-
-
-def _register_tunables():
-    """The window-depth tunable, declared next to the constant it makes
-    sweepable (docs/PERF_NOTES.md \"Autotuner\"). Window depth never
-    changes numerics — losses are bit-exact at any W (pinned since
-    PR 5) — only how much host dispatch overlap the device gets."""
-    from .tuning.space import Tunable, register
-    register(Tunable(
-        "engine.inflight_steps", default=2, grid=(0, 1, 2, 3, 4, 6, 8),
-        env="MXNET_INFLIGHT_STEPS", parse=int,
-        valid=lambda v, _c: int(v) >= 0,
-        seam="engine.inflight_steps() -> DispatchWindow max_inflight",
-        scope="train",
-        doc="async step futures outstanding before the host blocks on "
-            "the oldest"))
-
-
-try:
-    _register_tunables()
-except Exception:    # pragma: no cover - tuning must never break engine
-    import logging
-    logging.getLogger("mxnet_tpu.tuning").debug(
-        "engine tunable registration failed", exc_info=True)
 
 
 class DispatchWindow:

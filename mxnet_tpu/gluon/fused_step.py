@@ -131,32 +131,29 @@ def _place_on_mesh(mesh, axis: str, d):
 
 
 def _zero_min_size() -> int:
-    """ZeRO bucket floor (elements): autotune override >
-    ``MXNET_ZERO_SHARD_MIN_SIZE`` > 2048 (the ``zero.shard_min_size``
-    tunable — tuning/space.py)."""
-    from ..tuning import space as _tspace
-    found, v = _tspace.get_override("zero.shard_min_size")
-    if not found:
-        v = os.environ.get("MXNET_ZERO_SHARD_MIN_SIZE", "2048")
+    """ZeRO bucket floor (elements): ``MXNET_ZERO_SHARD_MIN_SIZE`` when
+    set and parseable, else 2048; at least 1.  A param of fewer
+    elements joins the fused small-param bucket instead of getting its
+    own reduce-scatter + all-gather pair; any packing is numerically
+    identical (the update is elementwise over the flat shards)."""
     try:
-        return int(v)
-    except (TypeError, ValueError):
+        return max(1, int(os.environ.get("MXNET_ZERO_SHARD_MIN_SIZE",
+                                         "2048")))
+    except ValueError:
         return 2048
 
 
 def _zero_bucket_bytes() -> int:
-    """ZeRO gradient communication bucket size (bytes): autotune
-    override > ``MXNET_ZERO_BUCKET_BYTES`` > 4 MiB.  ``<= 0`` selects
-    the monolithic serial baseline (one collective payload over every
-    unit: backward -> reduce-scatter -> update -> all-gather with no
-    independent compute left to hide the wire time)."""
-    from ..tuning import space as _tspace
-    found, v = _tspace.get_override("zero.bucket_bytes")
-    if not found:
-        v = os.environ.get("MXNET_ZERO_BUCKET_BYTES", str(4 << 20))
+    """ZeRO gradient communication bucket size (bytes):
+    ``MXNET_ZERO_BUCKET_BYTES`` when set and parseable, else 4 MiB.
+    0 (or less) selects the monolithic serial baseline (one collective
+    payload over every unit: backward -> reduce-scatter -> update ->
+    all-gather with no independent compute left to hide the wire
+    time)."""
     try:
-        return int(v)
-    except (TypeError, ValueError):
+        return max(0, int(os.environ.get("MXNET_ZERO_BUCKET_BYTES",
+                                         str(4 << 20))))
+    except ValueError:
         return 4 << 20
 
 
@@ -200,45 +197,6 @@ def zero_bucket_schedule(units, bucket_bytes: int):
     if cur:
         buckets.append(cur)
     return buckets
-
-
-def _register_tunables():
-    """The ZeRO bucket-floor tunable, declared next to the constant it
-    makes sweepable: the floor trades collective COUNT (every solo
-    param is one reduce-scatter + one all-gather) against update-fusion
-    granularity. Any packing is numerically identical — the update is
-    elementwise over the flat shards — so the knob is pure speed."""
-    from ..tuning.space import Tunable, register
-    register(Tunable(
-        "zero.shard_min_size", default=2048,
-        grid=(512, 2048, 8192, 32768),
-        env="MXNET_ZERO_SHARD_MIN_SIZE", parse=int,
-        valid=lambda v, _c: int(v) >= 1,
-        seam="gluon.fused_step._zero_min_size() -> _ZeroShardPlan "
-             "solo-vs-bucketed unit split",
-        scope="train", affects_program=True,
-        doc="element floor for a param to get its own RS/AG pair "
-            "under the ZeRO-1 sharded update"))
-    register(Tunable(
-        "zero.bucket_bytes", default=4 << 20,
-        grid=(0, 1 << 20, 4 << 20, 16 << 20),
-        env="MXNET_ZERO_BUCKET_BYTES", parse=int,
-        valid=lambda v, _c: int(v) >= 0,
-        seam="gluon.fused_step._zero_bucket_bytes() -> "
-             "zero_bucket_schedule comm bucketing (0 = monolithic "
-             "serial baseline)",
-        scope="train", affects_program=True,
-        doc="byte bound per ZeRO gradient communication bucket — "
-            "smaller buckets expose more collectives to latency "
-            "hiding, larger ones amortize per-collective latency; "
-            "the analytical autotuner scores both against modeled "
-            "exposed comm seconds (analysis/overlap.py)"))
-
-
-try:
-    _register_tunables()
-except Exception:    # pragma: no cover - tuning must never break steps
-    _LOG.debug("fused_step tunable registration failed", exc_info=True)
 
 
 def _analysis_mode(requested: Optional[str]) -> Optional[str]:
@@ -451,20 +409,11 @@ class CompiledTrainStep:
                  train_mode: bool = True, zero_shard: Optional[bool] = None,
                  zero_axis: str = "dp", mesh=None,
                  analyze: Optional[str] = None,
-                 numerics: Optional[str] = None,
-                 autotune: Optional[str] = None):
+                 numerics: Optional[str] = None):
         self._trainer = trainer
         self._loss_fn = loss_fn
         self._donate = donate
         self._train = train_mode
-        # self-tuning autopilot (docs/PERF_NOTES.md "Autotuner"):
-        # None = the MXNET_AUTOTUNE env gate; 'off'|'cached'|'on'
-        # explicit. Tuning runs ONCE, on the first step call (a real
-        # batch pins the shape bucket), BEFORE the live program builds
-        # so the winning config governs it.
-        self._autotune = autotune
-        self._autotune_done = False
-        self._autotune_outcome = None
         self._mode: Optional[str] = None   # None→undecided, 'fused'|'eager'
         self._lru: "OrderedDict[Any, dict]" = OrderedDict()
         self._trace_signatures: set = set()
@@ -834,51 +783,7 @@ class CompiledTrainStep:
             self._run_analysis(args, kwargs, batch_size)
         return out
 
-    @property
-    def autotune_result(self):
-        """The :class:`~mxnet_tpu.tuning.AutotuneOutcome` of this
-        step's tuning pass (None until the first call; mode 'off'
-        produces an off-outcome stub). The bench legs attach its
-        ``bench_dict()`` next to the kernel/fusion posture."""
-        return self._autotune_outcome
-
-    def autotune(self, *args, batch_size: Optional[int] = None,
-                 mode: Optional[str] = None, **kwargs):
-        """Explicitly tune this step for the shape bucket ``args`` pin
-        (normally implicit on the first call when
-        ``compile_step(autotune=)``/``MXNET_AUTOTUNE`` arms it).
-        Returns the outcome; winners apply as tuned overrides and,
-        after a search, persist to ``MXNET_AUTOTUNE_CACHE``."""
-        from .. import tuning as _tuning
-        self._autotune_done = True
-        self._autotune_outcome = _tuning.tune_step(
-            self, args, kwargs, batch_size=batch_size,
-            mode=mode if mode is not None else self._autotune)
-        return self._autotune_outcome
-
-    def _maybe_autotune(self, args, kwargs, batch_size):
-        """First-call tuning hook. Never kills a step — a tuner bug
-        costs the tuned config, not the run. Runs under
-        ``allow_transfers``: tuning is a designed offline measurement
-        phase, not a hot-loop sync."""
-        self._autotune_done = True
-        from .. import tuning as _tuning
-        if _tuning.autotune_mode(self._autotune) == "off":
-            self._autotune_outcome = _tuning.AutotuneOutcome(
-                "off", "off")
-            return
-        try:
-            with _tguard.allow_transfers("autotune measurement"):
-                self._autotune_outcome = _tuning.tune_step(
-                    self, args, kwargs, batch_size=batch_size,
-                    mode=self._autotune)
-        except Exception as e:   # pragma: no cover - defensive
-            _LOG.warning("compile_step: autotune failed (%s: %s); "
-                         "running with defaults", type(e).__name__, e)
-
     def _guarded_call(self, args, kwargs, batch_size):
-        if not self._autotune_done and not self._steps_done:
-            self._maybe_autotune(args, kwargs, batch_size)
         if self._mode is None:
             self._mode = self._decide_mode()
         t = _telemetry()
@@ -1811,7 +1716,7 @@ class CompiledTrainStep:
         except Exception:            # pragma: no cover - defensive
             return None
 
-    # ---------------- AOT (bench integration) ----------------
+    # ---------------- AOT ----------------
     def aot_compile(self, *args, batch_size: Optional[int] = None,
                     **kwargs):
         """Lower + compile the step for this batch's shape bucket ahead

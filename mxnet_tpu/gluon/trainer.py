@@ -81,8 +81,7 @@ class Trainer:
                      zero_shard: Optional[bool] = None,
                      zero_axis: str = "dp", mesh=None,
                      analyze: Optional[str] = None,
-                     numerics: Optional[str] = None,
-                     autotune: Optional[str] = None):
+                     numerics: Optional[str] = None):
         """Compile the ENTIRE training step — forward, backward, gradient
         reduction, optimizer update — into one donated-buffer XLA program
         per input-shape bucket (gluon/fused_step.py)::
@@ -144,23 +143,13 @@ class Trainer:
         forensics plus an atomic post-mortem dump
         (``MXNET_NUMERICS_DUMP_DIR``). Default comes from
         ``MXNET_NUMERICS``.
-
-        **Self-tuning autopilot** (``autotune=`` — docs/PERF_NOTES.md
-        "Autotuner"): on the step's FIRST call (a real batch pins the
-        shape bucket), replay this program's cached tuned config with
-        zero trials (``'cached'``), or measure-and-search the
-        registered tunable space and persist the winner
-        (``'on'``; budget ``MXNET_AUTOTUNE_BUDGET_TRIALS``, DB
-        ``MXNET_AUTOTUNE_CACHE``). Tunables never change numerics —
-        only speed. Default comes from ``MXNET_AUTOTUNE`` (off).
         """
         from .fused_step import CompiledTrainStep
         return CompiledTrainStep(self, loss_fn, donate=donate,
                                  train_mode=train_mode,
                                  zero_shard=zero_shard,
                                  zero_axis=zero_axis, mesh=mesh,
-                                 analyze=analyze, numerics=numerics,
-                                 autotune=autotune)
+                                 analyze=analyze, numerics=numerics)
 
     # ---------------- compiled-step registry ----------------
     def _register_compiled(self, step):

@@ -7,8 +7,7 @@ Members (each joins the flash-attention kernels in ops/attention.py):
 - :mod:`.rnn_scan` — time-fused LSTM/GRU/vanilla-RNN recurrence: the
   hidden-to-hidden matmul, gate nonlinearities and carry update of a
   whole timestep block live in ONE kernel with h/c pinned in VMEM,
-  killing the per-step HBM round-trips that made LSTM the worst-MFU
-  BENCH leg (0.17).
+  killing the per-step HBM round-trips of the XLA ``while`` loop.
 - :mod:`.opt_update` — fused elementwise optimizer update (SGD-mom,
   Adam) over the ZeRO flat padded 1/N shards of gluon/fused_step.py.
 - :mod:`.norm` — LayerNorm and bias-GELU forward+backward kernels for
@@ -31,17 +30,16 @@ three tiers —
   for attribution and the escape hatch for a miscompiling kernel.
 
 Every decision is recorded (``decisions()``, ``tools/diagnose.py
---kernels``) and counted (``mx_kernel_dispatch_total{path}``), and the
-per-leg BENCH json attaches ``dispatch_table()`` next to the fusion
-posture so a throughput number always names the path that produced it.
+--kernels``; the benchmark and ``chip_smoke.py`` log it beside their
+numbers) and counted (``mx_kernel_dispatch_total{path}``).
 """
 from __future__ import annotations
 
 import os
 from typing import Dict, Optional, Tuple
 
-__all__ = ["pallas_mode", "dispatch", "decisions", "dispatch_table",
-           "KERNELS", "count_traced", "SUPPORTED_DEVICE_KINDS",
+__all__ = ["pallas_mode", "dispatch", "decisions", "KERNELS",
+           "count_traced", "SUPPORTED_DEVICE_KINDS",
            "VMEM_TILE_BUDGET_BYTES",
            "VMEM_BYTES_PER_CORE", "VMEM_SCOPED_DEFAULT_BYTES",
            "vmem_tile_budget"]
@@ -62,7 +60,7 @@ VMEM_SCOPED_DEFAULT_BYTES = 16 * 1024 * 1024
 #: rnn_scan sizes its timestep block against. 4 MiB of the 16 MiB scoped
 #: default leaves room for Mosaic's own double buffering of the streamed
 #: operands. The DEFAULT: every kernel reads the live value through
-#: :func:`vmem_tile_budget` (env/autotune overridable), never this
+#: :func:`vmem_tile_budget` (env overridable), never this
 #: constant directly.
 VMEM_TILE_BUDGET_BYTES = 4 * 1024 * 1024
 
@@ -70,55 +68,17 @@ VMEM_TILE_BUDGET_BYTES = 4 * 1024 * 1024
 def vmem_tile_budget() -> int:
     """THE tile-budget accessor — rnn_scan's timestep-block sizer,
     attention's ``_head_group``, and the norm/opt_update row-block caps
-    all size against this one number, resolved as
-
-        autotune override > ``MXNET_VMEM_TILE_BUDGET`` > the default
-
-    (``tuning/space.py`` precedence), clamped to the scoped default.
-    Hand-tuners and the autotuner turn the same knob."""
-    from ...tuning import space as _tspace
+    all size against this one number: ``MXNET_VMEM_TILE_BUDGET`` (bytes)
+    when set and parseable, else the default, clamped to
+    [64 KiB, the scoped default]."""
     try:
-        v = int(_tspace.value("kernels.vmem_tile_budget",
-                              VMEM_TILE_BUDGET_BYTES))
-    except (TypeError, ValueError):
+        v = int(float(os.environ["MXNET_VMEM_TILE_BUDGET"]))
+    except (KeyError, ValueError, OverflowError):
         v = VMEM_TILE_BUDGET_BYTES
     return max(64 * 1024, min(v, VMEM_SCOPED_DEFAULT_BYTES))
 
 
-def _register_tunables():
-    """Kernel-layer tunables, declared next to the constants they make
-    sweepable (docs/PERF_NOTES.md \"Autotuner\")."""
-    from ...tuning.space import Tunable, register
-    mib = 1024 * 1024
-    register(Tunable(
-        "kernels.vmem_tile_budget", default=VMEM_TILE_BUDGET_BYTES,
-        grid=(1 * mib, 2 * mib, 4 * mib, 8 * mib),
-        env="MXNET_VMEM_TILE_BUDGET", parse=lambda s: int(float(s)),
-        valid=lambda v, _c: (64 * 1024 <= int(v)
-                             <= VMEM_SCOPED_DEFAULT_BYTES),
-        seam="ops.kernels.vmem_tile_budget() -> rnn_scan block_t, "
-             "attention _head_group, norm/opt_update row blocks",
-        scope="train", affects_program=True,
-        doc="VMEM bytes one kernel's concurrent working-set tiles may "
-            "claim (<= the scoped-VMEM default)"))
-    register(Tunable(
-        "kernels.rnn_block_t", default=0,
-        grid=(0, 1, 2, 4, 8, 16),
-        valid=lambda v, _c: 0 <= int(v) <= 16,
-        seam="ops.kernels.rnn_scan._block_t() timesteps per grid step "
-             "(0 = auto-size against the VMEM budget)",
-        scope="train", affects_program=True,
-        doc="timesteps one Pallas rnn_scan grid step walks"))
-
-
-try:
-    _register_tunables()
-except Exception:    # pragma: no cover - tuning must never break ops
-    import logging
-    logging.getLogger("mxnet_tpu.tuning").debug(
-        "kernel tunable registration failed", exc_info=True)
-
-#: the kernel names the dispatch gate knows (bench/diagnose vocabulary)
+#: the kernel names the dispatch gate knows (diagnose/chip_smoke vocabulary)
 KERNELS = ("rnn_scan", "rnn_decode_step", "opt_update", "layernorm",
            "bias_gelu", "flash_attention")
 
@@ -219,21 +179,3 @@ def count_traced(metric: str, label_key: str, label: str,
 def decisions() -> Dict[str, Tuple[str, str]]:
     """Last dispatch decision per kernel: {name: (path, reason)}."""
     return dict(_DECISIONS)
-
-
-def dispatch_table() -> Dict[str, str]:
-    """Current {kernel: path} for every known kernel under the live
-    env/backend — the BENCH json's per-leg ``kernel_path`` field (no
-    decision is recorded; this is a pure read)."""
-    import jax
-    mode = pallas_mode()
-    backend = jax.default_backend()
-    if mode == "off":
-        path = "xla"
-    elif backend == "tpu":
-        path = _tpu_path(mode)[0]
-    elif mode == "on":
-        path = "interpret"
-    else:
-        path = "xla"
-    return {k: path for k in KERNELS}

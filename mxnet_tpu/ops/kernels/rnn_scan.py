@@ -77,11 +77,10 @@ def _vmem_plan(seq: int, np_: int, g: int, hp: int, itemsize: int,
     per-timestep tiles — xw in, dxw out, hprev/cprev/cs/dys, budgeted
     as 2 gate-wide + 6 hidden-wide — capped by the shared tile budget
     (``vmem_tile_budget()``; ops.attention._head_group sizes against
-    the same accessor) and by the ``kernels.rnn_block_t`` tunable when
-    set. In interpret mode: block 1, so the grid loop mirrors the
-    lax.scan reference's one-step body structure — that is what makes
-    the fp32 forward BIT-identical (XLA re-fuses a multi-step unrolled
-    body differently, which costs a ulp)."""
+    the same accessor). In interpret mode: block 1, so the grid loop
+    mirrors the lax.scan reference's one-step body structure — that is
+    what makes the fp32 forward BIT-identical (XLA re-fuses a multi-step
+    unrolled body differently, which costs a ulp)."""
     if interpret:
         bt = _FORCE_BLOCK_T if _FORCE_BLOCK_T is not None else 1
         return int(min(bt, max(1, seq))), None
@@ -91,14 +90,8 @@ def _vmem_plan(seq: int, np_: int, g: int, hp: int, itemsize: int,
     fit = (VMEM_BYTES_PER_CORE * 3 // 4 - resident) // (2 * per_step)
     if fit < 1:
         return None
-    from ...tuning import space as _tspace
-    try:
-        tuned = int(_tspace.value("kernels.rnn_block_t", 0))
-    except (TypeError, ValueError):
-        tuned = 0
-    if _FORCE_BLOCK_T is not None:
-        tuned = _FORCE_BLOCK_T
-    bt = tuned if tuned > 0 else max(1, vmem_tile_budget() // per_step)
+    bt = (_FORCE_BLOCK_T if _FORCE_BLOCK_T is not None
+          else max(1, vmem_tile_budget() // per_step))
     bt = int(min(bt, fit, _MAX_BLOCK_T, max(1, seq)))
     limit = resident + 2 * bt * per_step + 4 * 1024 * 1024
     return bt, max(VMEM_SCOPED_DEFAULT_BYTES, limit)

@@ -36,7 +36,7 @@ telemetry catalog, the program-lint gates) turned into a serving path.
   existing AMP and post-training-quantization paths.
 - :mod:`.loadgen` — closed-/open-loop load generation with per-request
   outcome census {ok, rejected, deadline_missed, error}, goodput vs
-  raw QPS, and exact p50/p99 (the ``serving`` bench leg in bench.py).
+  raw QPS, and exact p50/p99.
 
 Observability: ``mx_serving_*`` series in the telemetry catalog —
 queue depth, in-flight micro-batches, batch occupancy, request-latency

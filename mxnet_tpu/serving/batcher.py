@@ -96,68 +96,28 @@ def _telemetry():
 
 
 def max_batch_rows(default: int = 32) -> int:
-    """Max coalesced rows per dispatch: autotune override >
-    ``MXNET_SERVING_MAX_BATCH`` > ``default`` (the
-    ``serving.max_batch`` tunable — tuning/space.py)."""
-    from ..tuning import space as _tspace
-    found, v = _tspace.get_override("serving.max_batch")
-    if not found:
-        v = os.environ.get("MXNET_SERVING_MAX_BATCH", str(default))
+    """``MXNET_SERVING_MAX_BATCH``: max coalesced rows per dispatch
+    (at least 1; ``default`` when unset or unparseable).  It trades
+    occupancy against padding waste and must fit the predictor's bucket
+    ladder; per-request results are bit-identical at any setting."""
     try:
-        return max(1, int(v))
-    except (TypeError, ValueError):
+        return max(1, int(os.environ.get("MXNET_SERVING_MAX_BATCH",
+                                         str(default))))
+    except ValueError:
         return default
 
 
 def batch_timeout_s(default_ms: float = 2.0) -> float:
     """How long the oldest waiting request may age before a partial
-    batch flushes, as SECONDS: autotune override >
-    ``MXNET_SERVING_BATCH_TIMEOUT_MS`` (milliseconds) > ``default_ms``
-    (the ``serving.batch_timeout_ms`` tunable — tuning/space.py)."""
-    from ..tuning import space as _tspace
-    found, v = _tspace.get_override("serving.batch_timeout_ms")
-    if not found:
-        v = os.environ.get("MXNET_SERVING_BATCH_TIMEOUT_MS",
-                           str(default_ms))
+    batch flushes, as SECONDS: ``MXNET_SERVING_BATCH_TIMEOUT_MS``
+    (milliseconds, at least 0; ``default_ms`` when unset or
+    unparseable)."""
     try:
-        v = float(v)
-    except (TypeError, ValueError):
+        v = float(os.environ.get("MXNET_SERVING_BATCH_TIMEOUT_MS",
+                                 str(default_ms)))
+    except ValueError:
         v = default_ms
     return max(0.0, v) / 1e3
-
-
-def _register_tunables():
-    """Serving coalescing tunables, declared next to the env knobs they
-    share a seam with: the batch cap trades occupancy against padding
-    waste, the linger trades batching delay against fill. Both are
-    dispatch policy — per-request RESULTS are bit-identical at any
-    setting (batched-vs-single parity is pinned in tests) — so the
-    autotuner may sweep them freely."""
-    from ..tuning.space import Tunable, register
-    register(Tunable(
-        "serving.max_batch", default=32, grid=(8, 16, 32, 64),
-        env="MXNET_SERVING_MAX_BATCH", parse=int,
-        valid=lambda v, _c: int(v) >= 1,
-        seam="serving.batcher.max_batch_rows() -> DynamicBatcher "
-             "coalescing cap (must fit the predictor's bucket ladder)",
-        scope="serving",
-        doc="max coalesced request rows per serving micro-batch"))
-    register(Tunable(
-        "serving.batch_timeout_ms", default=2.0,
-        grid=(0.5, 1.0, 2.0, 5.0, 10.0),
-        env="MXNET_SERVING_BATCH_TIMEOUT_MS", parse=float,
-        valid=lambda v, _c: float(v) >= 0.0,
-        seam="serving.batcher.batch_timeout_s() -> oldest-request "
-             "linger before a partial flush",
-        scope="serving",
-        doc="max age (ms) of the oldest waiting request before a "
-            "partial micro-batch flushes"))
-
-
-try:
-    _register_tunables()
-except Exception:    # pragma: no cover - tuning must never break serving
-    _LOG.debug("serving tunable registration failed", exc_info=True)
 
 
 def queue_depth(default: int = 1024) -> int:

@@ -79,6 +79,7 @@ copy-on-write page copy (kvcache.py has the lifecycle).
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 from collections import deque
@@ -135,174 +136,51 @@ def _parse_ladder(v) -> Tuple[int, ...]:
 
 
 def slot_ladder() -> Tuple[int, ...]:
-    """THE slot-ladder accessor: autotune override >
-    ``MXNET_DECODE_SLOTS`` > the default (tuning/space.py precedence)."""
-    from ..tuning import space as _tspace
-    v = _tspace.value("decode.slot_ladder",
-                      ",".join(str(x) for x in DECODE_SLOT_LADDER))
+    """THE slot-ladder accessor: ``MXNET_DECODE_SLOTS`` ('1,2,4,8')
+    when set and a valid ladder, else the default."""
     try:
-        return _parse_ladder(v)
-    except (TypeError, ValueError):
+        return _parse_ladder(os.environ["MXNET_DECODE_SLOTS"])
+    except (KeyError, ValueError):
         return DECODE_SLOT_LADDER
 
 
-def kv_page_size() -> int:
-    """Tokens per KV page — autotune override >
-    ``MXNET_DECODE_KV_PAGE_SIZE`` > ``kvcache.KV_PAGE_SIZE``."""
-    from ..tuning import space as _tspace
+def _env_int(name: str, default: int, lo: int, hi: int) -> int:
+    """``int(os.environ[name])`` clamped to [lo, hi]; ``default`` when
+    unset or unparseable."""
     try:
-        return max(1, int(_tspace.value("decode.kv_page_size",
-                                        KV_PAGE_SIZE)))
-    except (TypeError, ValueError):
-        return KV_PAGE_SIZE
+        return max(lo, min(int(os.environ[name]), hi))
+    except (KeyError, ValueError):
+        return default
+
+
+def kv_page_size() -> int:
+    """Tokens per KV page: ``MXNET_DECODE_KV_PAGE_SIZE`` (1..4096),
+    else ``kvcache.KV_PAGE_SIZE``."""
+    return _env_int("MXNET_DECODE_KV_PAGE_SIZE", KV_PAGE_SIZE, 1, 4096)
 
 
 def prefill_chunk() -> int:
-    """Prompt tokens one prefill iteration consumes — autotune override
-    > ``MXNET_DECODE_PREFILL_CHUNK`` > the default."""
-    from ..tuning import space as _tspace
-    try:
-        return max(1, int(_tspace.value("decode.prefill_chunk",
-                                        PREFILL_CHUNK)))
-    except (TypeError, ValueError):
-        return PREFILL_CHUNK
+    """Prompt tokens one prefill iteration consumes:
+    ``MXNET_DECODE_PREFILL_CHUNK`` (1..4096), else the default.
+    Smaller = better decode-batch latency, larger = better prefill
+    throughput."""
+    return _env_int("MXNET_DECODE_PREFILL_CHUNK", PREFILL_CHUNK, 1, 4096)
 
 
 def spec_k() -> int:
-    """Max draft tokens per speculative-decode step (0 disables) —
-    autotune override > ``MXNET_DECODE_SPEC_K`` > the default."""
-    from ..tuning import space as _tspace
-    try:
-        return max(0, int(_tspace.value("decode.spec_k", SPEC_K)))
-    except (TypeError, ValueError):
-        return SPEC_K
+    """Max draft tokens per speculative-decode step (0 disables):
+    ``MXNET_DECODE_SPEC_K`` (0..64), else the default."""
+    return _env_int("MXNET_DECODE_SPEC_K", SPEC_K, 0, 64)
 
 
 def prefix_share() -> bool:
-    """Whether the engine shares prefix-cache pages across requests —
-    autotune override > ``MXNET_DECODE_PREFIX_SHARE`` > the default."""
-    from ..tuning import space as _tspace
+    """Whether the engine shares prefix-cache pages across requests:
+    ``MXNET_DECODE_PREFIX_SHARE`` (0 = off, any other integer on),
+    else the default."""
     try:
-        return bool(int(_tspace.value("decode.prefix_share",
-                                      PREFIX_SHARE)))
-    except (TypeError, ValueError):
+        return int(os.environ["MXNET_DECODE_PREFIX_SHARE"]) != 0
+    except (KeyError, ValueError):
         return bool(PREFIX_SHARE)
-
-
-def _page_size_valid(v, _config) -> bool:
-    """A candidate page size is valid when a nominal full cache (the
-    shipped ladder's worst slot count at a 256-token context, f32,
-    2 heads x 16 dims x 1 layer) stays inside ``MXNET_MEMORY_BUDGET``
-    — engines re-check their REAL geometry at construction."""
-    try:
-        v = int(v)
-    except (TypeError, ValueError):
-        return False
-    if not 1 <= v <= 4096:
-        return False
-    try:
-        from ..telemetry.memory import memory_budget
-        budget = memory_budget()
-    except Exception:           # pragma: no cover - defensive
-        return True
-    if budget is None:
-        return True
-    slots = DECODE_SLOT_LADDER[-1]
-    page_bytes = 2 * 1 * v * 2 * 16 * 4       # K+V, 1 layer, 2x16 f32
-    pages = 1 + slots * pages_needed(256, v)
-    return pages * page_bytes <= budget
-
-
-def _spec_k_valid(v, _config) -> bool:
-    """A candidate draft width is valid when the speculative overrun
-    slack (up to ``spec_k`` uncommitted KV positions per slot) still
-    fits ``MXNET_MEMORY_BUDGET`` at the same nominal geometry
-    ``_page_size_valid`` prices — engines re-check their REAL geometry
-    at construction."""
-    try:
-        v = int(v)
-    except (TypeError, ValueError):
-        return False
-    if not 0 <= v <= 64:
-        return False
-    try:
-        from ..telemetry.memory import memory_budget
-        budget = memory_budget()
-    except Exception:           # pragma: no cover - defensive
-        return True
-    if budget is None or v == 0:
-        return True
-    slots = DECODE_SLOT_LADDER[-1]
-    ps = KV_PAGE_SIZE
-    page_bytes = 2 * 1 * ps * 2 * 16 * 4       # K+V, 1 layer, 2x16 f32
-    pages = 1 + slots * pages_needed(256 + v, ps)
-    return pages * page_bytes <= budget
-
-
-def _register_tunables():
-    """Decode-engine tunables, declared next to the constants they make
-    sweepable (docs/PERF_NOTES.md "Autotuner")."""
-    from ..tuning.space import Tunable, register
-    register(Tunable(
-        "decode.slot_ladder",
-        default=",".join(str(x) for x in DECODE_SLOT_LADDER),
-        grid=("1,2,4", "1,2,4,8", "1,2,4,8,16", "1,4,16"),
-        env="MXNET_DECODE_SLOTS", parse=str,
-        valid=lambda v, _c: bool(_parse_ladder(v)),
-        seam="serving.decode.slot_ladder() -> DecodeEngine AOT "
-             "slot-count buckets",
-        scope="serving", affects_program=True,
-        doc="slot-count buckets the decode step is compiled for "
-            "(comma list; largest = physical slots)"))
-    register(Tunable(
-        "decode.kv_page_size", default=KV_PAGE_SIZE,
-        grid=(8, 16, 32, 64),
-        env="MXNET_DECODE_KV_PAGE_SIZE", parse=int,
-        valid=_page_size_valid,
-        seam="serving.decode.kv_page_size() -> PagedKVCache page "
-             "geometry + page-table width",
-        scope="serving", affects_program=True,
-        doc="tokens per KV page (pages x page_bytes must fit "
-            "MXNET_MEMORY_BUDGET)"))
-    register(Tunable(
-        "decode.prefill_chunk", default=PREFILL_CHUNK,
-        grid=(8, 16, 32, 64, 128),
-        env="MXNET_DECODE_PREFILL_CHUNK", parse=int,
-        valid=lambda v, _c: 1 <= int(v) <= 4096,
-        seam="serving.decode.prefill_chunk() -> chunked-prefill "
-             "program width",
-        scope="serving", affects_program=True,
-        doc="prompt tokens one prefill iteration consumes (smaller = "
-            "better decode-batch latency, larger = better prefill "
-            "throughput)"))
-    register(Tunable(
-        "decode.spec_k", default=SPEC_K,
-        grid=(0, 2, 4, 8),
-        env="MXNET_DECODE_SPEC_K", parse=int,
-        valid=_spec_k_valid,
-        seam="serving.decode.spec_k() -> DecodeEngine draft->verify "
-             "width (verify-program token dim = spec_k + 1)",
-        scope="serving", affects_program=True,
-        doc="max draft tokens the drafter proposes per speculative "
-            "step (0 = off; overrun slack must fit the KV budget)"))
-    register(Tunable(
-        "decode.prefix_share", default=PREFIX_SHARE,
-        grid=(0, 1),
-        env="MXNET_DECODE_PREFIX_SHARE", parse=int,
-        valid=lambda v, _c: int(v) in (0, 1),
-        seam="serving.decode.prefix_share() -> PagedKVCache prefix "
-             "registry + COW sharing",
-        scope="serving", affects_program=False,
-        doc="share committed prompt-prefix KV pages across requests "
-            "(refcounted, copy-on-write on divergence)"))
-
-
-try:
-    _register_tunables()
-except Exception:    # pragma: no cover - tuning must never break serving
-    import logging
-    logging.getLogger("mxnet_tpu.tuning").debug(
-        "decode tunable registration failed", exc_info=True)
 
 
 def _telemetry():
@@ -1655,7 +1533,7 @@ def run_decode(model, prompts, max_new, *, static: bool = False,
                prefix_share: Optional[bool] = None,
                drafter=None) -> dict:
     """Submit every request up front and drive the engine to
-    completion — the bench ``decode`` leg's harness. ``static``
+    completion (``chip_smoke.py``'s decode phase drives it). ``static``
     selects the whole-batch baseline policy; everything else (model,
     compiled programs, kernels, page geometry) is identical, so the
     delta is pure scheduling."""

@@ -68,10 +68,9 @@ from ..ndarray.ndarray import NDArray
 __all__ = ["PagedKVCache", "KV_PAGE_SIZE", "pages_needed",
            "prefix_hash"]
 
-#: tokens per KV page — the shipped default behind the
-#: ``decode.kv_page_size`` tunable / ``MXNET_DECODE_KV_PAGE_SIZE``
-#: (consumers read the live value through ``serving.decode
-#: .kv_page_size()``, never this constant directly)
+#: tokens per KV page — the shipped default behind
+#: ``MXNET_DECODE_KV_PAGE_SIZE`` (consumers read the live value through
+#: ``serving.decode.kv_page_size()``, never this constant directly)
 KV_PAGE_SIZE = 16
 
 

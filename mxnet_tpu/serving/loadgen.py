@@ -22,8 +22,7 @@ capacity number; raw QPS flatters a service that answers late.
 
 Reports carry QPS, goodput_qps, reject_rate, deadline_miss_rate, and
 exact p50/p99 latency computed from the raw per-request samples of the
-``ok`` population (no histogram interpolation — bench.py puts these
-next to the training legs in the BENCH json;
+``ok`` population (no histogram interpolation;
 ``mx_serving_request_seconds`` carries the live-histogram view).
 
 Fleet targets: :func:`fleet_issue` / :func:`fleet_submit` adapt a

@@ -139,7 +139,6 @@ class CompiledPredictor:
         self._lru: "OrderedDict[Any, dict]" = OrderedDict()
         self._n_traces = 0
         self._requests_done = 0
-        self._autotune_outcome = None
         self._analyze = _analysis_mode(analyze)
         self._analysis_report = None
         # measured per-micro-batch service time from the warmup()
@@ -357,35 +356,11 @@ class CompiledPredictor:
             entry["flops"] = None
         return entry["flops"]
 
-    @property
-    def autotune_result(self):
-        """The :class:`~mxnet_tpu.tuning.AutotuneOutcome` of the last
-        ``warmup(autotune=)`` pass (None before warmup / mode off)."""
-        return self._autotune_outcome
-
-    def warmup(self, *example, buckets: Optional[Sequence[int]] = None,
-               autotune: Optional[str] = None):
+    def warmup(self, *example, buckets: Optional[Sequence[int]] = None):
         """AOT-compile every shape bucket from one example request
         (a 1-row batch): each bucket's program is lowered + compiled
         before traffic arrives, so no live request ever pays a compile.
-        Returns ``{bucket_size: flops}``.
-
-        ``autotune`` (default: the ``MXNET_AUTOTUNE`` gate — docs/
-        PERF_NOTES.md "Autotuner"): before compiling, replay or search
-        this deployment's serving tunables (``serving.max_batch``,
-        ``serving.batch_timeout_ms``); the tuned overrides govern any
-        :class:`~mxnet_tpu.serving.DynamicBatcher` constructed AFTER
-        warmup. Per-request results are bit-identical at any setting
-        (the knobs are dispatch policy, not math)."""
-        from .. import tuning as _tuning
-        if _tuning.autotune_mode(autotune) != "off":
-            try:
-                self._autotune_outcome = _tuning.tune_predictor(
-                    self, example, mode=autotune)
-            except Exception as e:   # pragma: no cover - defensive
-                _LOG.warning("CompiledPredictor: autotune failed "
-                             "(%s: %s); serving with defaults",
-                             type(e).__name__, e)
+        Returns ``{bucket_size: flops}``."""
         out = {}
         last_padded = None
         for b in (buckets or self.bucket_sizes):

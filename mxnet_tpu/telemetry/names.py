@@ -158,14 +158,6 @@ ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
 
 # ---------------------------------------------------------------------------
-# self-tuning performance autopilot (tuning/)
-# ---------------------------------------------------------------------------
-AUTOTUNE_TRIALS = "mx_autotune_trials_total"
-AUTOTUNE_CACHE_HITS = "mx_autotune_cache_hits_total"
-AUTOTUNE_CACHE_MISSES = "mx_autotune_cache_misses_total"
-AUTOTUNE_ACTIVE = "mx_autotune_active_config"
-
-# ---------------------------------------------------------------------------
 # inference serving engine (serving/batcher.py)
 # ---------------------------------------------------------------------------
 SERVING_REQUESTS = "mx_serving_requests_total"
@@ -500,25 +492,6 @@ CATALOG = {
              "dropped token; capacity = the GShard one-hot dispatch of "
              "ops/moe.py moe_ffn, which drops past capacity); one count "
              "a traced layer"),
-    AUTOTUNE_TRIALS: dict(
-        kind="counter", label="backend",
-        help="autotune candidate measurements by backend (timed = "
-             "live warmup+measured executions, analytical = "
-             "cost_analysis/memory model scoring; docs/PERF_NOTES.md "
-             "\"Autotuner\")"),
-    AUTOTUNE_CACHE_HITS: dict(
-        kind="counter", label=None,
-        help="autotune config-DB hits: a persisted winner replayed "
-             "with zero trials (MXNET_AUTOTUNE_CACHE)"),
-    AUTOTUNE_CACHE_MISSES: dict(
-        kind="counter", label=None,
-        help="autotune config-DB misses (mode=on searches; "
-             "mode=cached falls back to the shipped defaults)"),
-    AUTOTUNE_ACTIVE: dict(
-        kind="gauge", label="tunable",
-        help="active tuned-config info gauge: one series per applied "
-             "tunable override (numeric values verbatim, choice "
-             "values as their grid index)"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),

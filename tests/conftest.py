@@ -42,6 +42,31 @@ def program_report():
     return make
 
 
+@pytest.fixture
+def rows_match():
+    """``rows_match(got, want)``: a request's rows served in one shape
+    bucket against the same rows served in another (a micro-batch of 2,
+    4 or 8 against a single dispatch). Different programs: XLA:CPU picks
+    each bucket's matmul (a matrix-vector product at 1 row) and its
+    accumulation order by the shape, so the sums may round differently.
+    Measured (PR 30, this XLA:CPU): 3.7e-9 on outputs up to 0.079, half
+    an ulp of the largest (22 ulp of the smallest element, where the
+    terms cancel), in the batcher's, the supervisor's and the fleet's
+    tests alike. Held to 4 ulp of the largest output, which still tells
+    two weight versions apart.
+    Where the bucket is fixed, one program against itself, ``==`` stays
+    (test_decode.py: a request alone against the same request in a
+    continuous batch, through one compiled step)."""
+
+    def match(got, want):
+        got, want = onp.asarray(got), onp.asarray(want)
+        atol = 4 * 2.0 ** -23 * float(onp.abs(want).max())
+        return got.shape == want.shape and \
+            bool((onp.abs(got - want) <= atol).all())
+
+    return match
+
+
 @pytest.fixture(scope="session")
 def lint_allowlist():
     """The checked-in blessed-violation list for the source-lint sweep

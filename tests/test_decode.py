@@ -75,7 +75,7 @@ def prompt(seed: int, n: int):
 
 
 # ---------------------------------------------------------------------------
-# accessors + tunables
+# accessors
 # ---------------------------------------------------------------------------
 
 def test_slot_ladder_env_override(monkeypatch):
@@ -92,26 +92,18 @@ def test_page_size_and_chunk_env_overrides(monkeypatch):
     assert serving.prefill_chunk() == 32
 
 
-def test_decode_tunables_registered():
-    from mxnet_tpu.tuning import space
-    names = {t["name"]: t for t in space.table()}
-    for name in ("decode.slot_ladder", "decode.kv_page_size",
-                 "decode.prefill_chunk"):
-        assert name in names, name
-        assert names[name]["scope"] == "serving"
-        assert "decode" in names[name]["seam"]
-    assert names["decode.kv_page_size"]["grid"] == (8, 16, 32, 64)
-
-
-def test_kv_page_size_validity_respects_memory_budget(monkeypatch):
-    from mxnet_tpu.serving.decode import _page_size_valid
-    assert _page_size_valid(16, None)
-    assert not _page_size_valid(0, None)
-    assert not _page_size_valid("x", None)
-    # a 16 KiB budget cannot hold the nominal full cache at ANY page
-    # size, so every candidate is invalid under it
-    monkeypatch.setenv("MXNET_MEMORY_BUDGET", str(16 * 1024))
-    assert not _page_size_valid(16, None)
+def test_page_size_and_chunk_range_is_clamped(monkeypatch):
+    """Both widths are input from outside the program: 1..4096, the
+    default when the value does not parse."""
+    for var, accessor, default in (
+            ("MXNET_DECODE_KV_PAGE_SIZE", serving.kv_page_size,
+             serving.kvcache.KV_PAGE_SIZE),
+            ("MXNET_DECODE_PREFILL_CHUNK", serving.prefill_chunk,
+             serving.decode.PREFILL_CHUNK)):
+        for raw, want in (("0", 1), ("-8", 1), ("4096", 4096),
+                          ("4097", 4096), ("x", default)):
+            monkeypatch.setenv(var, raw)
+            assert accessor() == want, (var, raw)
 
 
 # ---------------------------------------------------------------------------

@@ -34,7 +34,6 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serving import (DecodeEngine, NgramDrafter, PagedKVCache,
                                TinyDecoder, pages_needed)
 from mxnet_tpu.serving import kvcache as kvcache_mod
-from mxnet_tpu.serving.decode import _spec_k_valid
 
 VOCAB = 48
 
@@ -263,18 +262,8 @@ def test_spec_accounting_and_accept_hist(model):
 
 
 # ---------------------------------------------------------------------------
-# tunables
+# knobs
 # ---------------------------------------------------------------------------
-
-def test_spec_tunables_registered():
-    from mxnet_tpu.tuning import space
-    names = {t["name"]: t for t in space.table()}
-    assert tuple(names["decode.spec_k"]["grid"]) == (0, 2, 4, 8)
-    assert names["decode.spec_k"]["scope"] == "serving"
-    assert space.get("decode.spec_k").affects_program is True
-    assert tuple(names["decode.prefix_share"]["grid"]) == (0, 1)
-    assert space.get("decode.prefix_share").affects_program is False
-
 
 def test_spec_env_overrides(monkeypatch):
     monkeypatch.setenv("MXNET_DECODE_SPEC_K", "6")
@@ -285,16 +274,12 @@ def test_spec_env_overrides(monkeypatch):
     assert serving.spec_k() == serving.decode.SPEC_K
 
 
-def test_spec_k_validity_respects_memory_budget(monkeypatch):
-    assert _spec_k_valid(0, None)
-    assert _spec_k_valid(8, None)
-    assert not _spec_k_valid(-1, None)
-    assert not _spec_k_valid(65, None)
-    assert not _spec_k_valid("x", None)
-    monkeypatch.setenv("MXNET_MEMORY_BUDGET", str(16 * 1024))
-    assert not _spec_k_valid(8, None), \
-        "speculative slack must be priced against the KV budget"
-    assert _spec_k_valid(0, None), "off is always affordable"
+def test_spec_k_range_is_clamped(monkeypatch):
+    """The draft width is input from outside the program: 0..64."""
+    for raw, want in (("-1", 0), ("0", 0), ("8", 8), ("64", 64),
+                      ("65", 64)):
+        monkeypatch.setenv("MXNET_DECODE_SPEC_K", raw)
+        assert serving.spec_k() == want, raw
 
 
 def test_engine_reads_spec_env(monkeypatch, model):

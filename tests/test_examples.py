@@ -78,32 +78,6 @@ def test_example_bert():
     _run("train_bert_classifier.py")
 
 
-def test_opbench_runs_and_reports():
-    """benchmark/opbench.py (reference benchmark/opperf analog): runs a
-    filtered sweep and emits valid JSON with usec + gflops fields."""
-    import json
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + \
-        " --xla_force_host_platform_device_count=1"
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "opbench.py"),
-         "--iters", "3", "--warmup", "1", "--ops", "dot,relu"],
-        cwd=REPO, env=env, timeout=300,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-    out = proc.stdout.decode()
-    assert proc.returncode == 0, out[-2000:]
-    lines = [json.loads(l) for l in out.splitlines()
-             if l.startswith("{")]
-    summary = lines[-1]
-    assert summary["summary"] and summary["ops_measured"] >= 3
-    per_op = lines[:-1]
-    assert any(r["op"].startswith("dot_") and r["gflops"] > 0
-               for r in per_op)
-
-
 def test_example_pipeline_trainer():
     _run("pipeline_trainer.py", ("x", "--steps", "12", "--width", "16"))
 

@@ -9,9 +9,8 @@ ZeRO gradient path it measures: reverse-topological bucket schedules,
 the bucketed reduce-scatter/all-gather routing with non-divisible
 tails, bit-exact loss/param parity of bucketed vs monolithic updates,
 the per-payload-byte comm-cost invariant (N buckets of B bytes cost
-one collective of N*B bytes), the double-buffered pipeline permute,
-the transfer-guard-armed pipelined run, and the autotuner's
-exposed-comm scoring term.
+one collective of N*B bytes), the double-buffered pipeline permute, and
+the transfer-guard-armed pipelined run.
 
 Acceptance bar of ISSUE 17: the bucketed zero program on the virtual
 dp=8 mesh measures overlap_fraction > 0 where the serial monolithic
@@ -19,6 +18,7 @@ baseline measures ~0 (zero at metric resolution: the only residual
 hider is the nanoseconds-scale loss tail the scheduler may park after
 the weight all-gather).
 """
+import contextlib
 import json
 import math
 import os
@@ -45,7 +45,6 @@ from mxnet_tpu.parallel import make_mesh, shard_batch
 from mxnet_tpu.parallel.collectives import (allgather_bucketed,
                                             reduce_scatter_bucketed)
 from mxnet_tpu.telemetry import names as tn
-from mxnet_tpu.tuning import space as tspace
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
@@ -55,6 +54,17 @@ needs_mesh = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device virtual mesh")
 
 DP = 4
+
+
+@contextlib.contextmanager
+def zero_knobs(bucket_bytes=None, min_size=None):
+    """The two ZeRO packing knobs, set in the environment for the body."""
+    with pytest.MonkeyPatch.context() as mp:
+        if bucket_bytes is not None:
+            mp.setenv("MXNET_ZERO_BUCKET_BYTES", str(bucket_bytes))
+        if min_size is not None:
+            mp.setenv("MXNET_ZERO_SHARD_MIN_SIZE", str(min_size))
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -179,7 +189,7 @@ def test_report_brief_and_table():
     rep = aoverlap.overlap_census(_CANNED_ROOT_ESCAPE, num_devices=8)
     b = rep.brief()
     for k in ("exposed_comm_s", "total_comm_s", "overlap_fraction",
-              "n_collectives", "n_async", "zero_bucket_bytes"):
+              "n_collectives", "n_async"):
         assert k in b
     d = rep.to_dict()
     assert d["scheduled"] is True and d["windows"]
@@ -390,8 +400,7 @@ def _acceptance_census(bucket_bytes):
     trainer = Trainer(net.collect_params(), "adam",
                       {"learning_rate": 0.01})
     step = trainer.compile_step(lambda a, b: loss(net(a), b))
-    with tspace.trial({"zero.shard_min_size": 1,
-                       "zero.bucket_bytes": bucket_bytes}):
+    with zero_knobs(bucket_bytes=bucket_bytes, min_size=1):
         with make_mesh({"dp": 8}, jax.devices()[:8]) as m:
             xs, ys = shard_batch(x, m), shard_batch(y, m)
             step(xs, ys)
@@ -424,7 +433,6 @@ def test_serial_baseline_measures_zero_overlap(serial_census):
     assert rep.total_comm_s > 0
     assert rep.overlap_fraction < 1e-3, rep.summary_line()
     assert rep.exposed_comm_s >= 0.99 * rep.total_comm_s
-    assert rep.zero_bucket_bytes == 0
     assert "dp" in rep.per_axis_total_s
 
 
@@ -438,7 +446,6 @@ def test_bucketed_step_overlaps_collectives(bucketed_census,
     assert rep.overlap_fraction > 5e-3, rep.summary_line()
     assert rep.overlap_fraction > serial_census.overlap_fraction
     assert rep.n_collectives >= serial_census.n_collectives
-    assert rep.zero_bucket_bytes == 16384
     hidden = [w for w in rep.windows
               if w.kind == "all_gather" and w.n_hiders > 0]
     assert hidden, rep.table_str()
@@ -491,15 +498,13 @@ def _toy_step(seed=3):
 @needs_mesh
 def test_program_report_carries_overlap_brief():
     _, step, x, y = _toy_step()
-    with tspace.trial({"zero.shard_min_size": 1,
-                       "zero.bucket_bytes": 16384}):
+    with zero_knobs(bucket_bytes=16384, min_size=1):
         with make_mesh({"dp": DP}, jax.devices()[:DP]) as mesh:
             xs, ys = shard_batch(x, mesh), shard_batch(y, mesh)
             step(xs, ys)
             rep = step.analyze(xs, ys)
     assert rep.overlap is not None
     assert rep.overlap.total_comm_s > 0
-    assert rep.overlap.zero_bucket_bytes == 16384
     d = rep.to_dict()
     assert d["overlap"]["n_collectives"] == rep.overlap.n_collectives
     assert "overlap" in rep.summary()
@@ -544,11 +549,8 @@ def _parity_run(opt, kwargs, bucket_bytes, min_size=None, steps=3):
     rng = onp.random.RandomState(0)
     x = nd.array(rng.randn(8, 4).astype("float32"))
     y = nd.array(rng.randint(0, 3, size=(8,)).astype("int32"))
-    overrides = {"zero.bucket_bytes": bucket_bytes}
-    if min_size is not None:
-        overrides["zero.shard_min_size"] = min_size
     losses = []
-    with tspace.trial(overrides):
+    with zero_knobs(bucket_bytes=bucket_bytes, min_size=min_size):
         with make_mesh({"dp": DP}, jax.devices()[:DP]) as mesh:
             xs, ys = shard_batch(x, mesh), shard_batch(y, mesh)
             for _ in range(steps):
@@ -612,8 +614,7 @@ def test_bucketed_pipelined_loop_zero_unblessed_syncs(monkeypatch):
     rng = onp.random.RandomState(7)
     x = nd.array(rng.randn(8, 4).astype("float32"))
     y = nd.array(rng.randint(0, 3, size=(8,)).astype("int32"))
-    with tspace.trial({"zero.bucket_bytes": 16384,
-                       "zero.shard_min_size": 1}):
+    with zero_knobs(bucket_bytes=16384, min_size=1):
         with make_mesh({"dp": DP}, jax.devices()[:DP]):
             tguard.reset_sync_counts()
             tguard.clear_events()
@@ -664,27 +665,3 @@ def test_double_buffer_env_default(monkeypatch):
     for v in ("0", "false", "off", ""):
         monkeypatch.setenv("MXNET_PIPELINE_DOUBLE_BUFFER", v)
         assert pmod._double_buffer_default() is False
-
-
-# ---------------------------------------------------------------------------
-# autotuner scoring: exposed comm is a first-class term
-# ---------------------------------------------------------------------------
-
-@needs_mesh
-def test_analytical_backend_scores_exposed_comm():
-    from mxnet_tpu.tuning.measure import AnalyticalStepBackend
-    _, step, x, y = _toy_step(seed=9)
-    with make_mesh({"dp": DP}, jax.devices()[:DP]) as mesh:
-        xs, ys = shard_batch(x, mesh), shard_batch(y, mesh)
-        step(xs, ys)
-        backend = AnalyticalStepBackend(step, (xs, ys))
-        res = backend.measure({"zero.bucket_bytes": 16384,
-                               "zero.shard_min_size": 1})
-    assert res.feasible
-    for k in ("exposed_comm_s", "overlap_fraction",
-              "zero_bucket_bytes"):
-        assert k in res.detail, res.detail
-    assert res.detail["zero_bucket_bytes"] == 16384
-    assert 0.0 <= res.detail["overlap_fraction"] <= 1.0
-    # the exposed term is additive in the score
-    assert res.score >= res.detail["exposed_comm_s"]

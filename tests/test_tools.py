@@ -172,31 +172,6 @@ def test_diagnose_kernels_section(capsys):
     assert out.count("bit-exact") == 2
 
 
-def test_diagnose_autotune_section(capsys):
-    """--autotune: the registered tunable table (every knob with its
-    default, grid and consumer seam), then the 3-trial analytical
-    sweep on the tiny MLP shown twice against a scratch DB — first run
-    a cache MISS that searches, second run a HIT that replays with
-    zero trials."""
-    from mxnet_tpu.tuning import space
-    before = space.overrides()
-    diagnose = _load("tools/diagnose.py", "diagnose_at")
-    assert diagnose.main(["--autotune"]) == 0
-    out = capsys.readouterr().out
-    assert "Self-Tuning Autopilot" in out
-    assert "MXNET_AUTOTUNE=" in out
-    for name in ("engine.inflight_steps", "kernels.vmem_tile_budget",
-                 "kernels.rnn_block_t", "zero.shard_min_size",
-                 "serving.max_batch", "serving.batch_timeout_ms"):
-        assert name in out
-    assert "-> engine.inflight_steps() -> DispatchWindow" in out
-    assert "cache MISS -> searched + persisted  trials=3" in out
-    assert "cache HIT (replayed, 0 trials)  trials=0" in out
-    assert "winning config:" in out
-    # the section restores the process overrides it found
-    assert space.overrides() == before
-
-
 def test_diagnose_numerics_section(capsys, tmp_path, monkeypatch):
     """--numerics: the 10-step norm table prints with finite values and
     the simulated-divergence demo produces exactly one anomaly plus a

@@ -4,10 +4,8 @@
 Reference analog: tools/bandwidth/measure.py — same experiment (push a
 network's gradient set through a kvstore, pull it back, report GB/s and
 the error vs a serial reduction), re-targeted at this framework's
-kvstore types ('local', 'tpu', 'dist*') instead of GPU device lists.
-The dist cross-process path has its own artifact-producing rig in
-benchmark/dist_kvbench.py; this tool is the interactive single-process
-view of the same transfer path.
+kvstore types ('local', 'tpu', 'dist*') instead of GPU device lists,
+in a single process.
 """
 import argparse
 import logging

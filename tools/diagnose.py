@@ -479,10 +479,9 @@ def _fusion_leg(title, step, x, y):
 def check_fusion():
     """Fusion-census health (docs/ANALYSIS.md "Fusion census"): audit
     XLA's fusion decisions for two canonical legs — a tiny MLP and the
-    LSTM-LM architecture of examples/train_lstm_lm.py (the worst-MFU
-    BENCH leg) — printing each kernel's kind/ops/FLOPs/boundary bytes
-    and bound class, plus any stranded ops the ideal-fusion diff of
-    arXiv:2301.13062 flags."""
+    LSTM-LM architecture of examples/train_lstm_lm.py — printing each
+    kernel's kind/ops/FLOPs/boundary bytes and bound class, plus any
+    stranded ops the ideal-fusion diff of arXiv:2301.13062 flags."""
     print("----------Fusion Census----------")
     try:
         import numpy as onp
@@ -608,13 +607,14 @@ def check_sharding():
 def check_overlap():
     """Exposed-communication posture (docs/PERF_NOTES.md "Communication
     overlap"): compile the zero-sharded adam MLP on the virtual dp mesh
-    twice — monolithic serial baseline (zero.bucket_bytes=0) vs
+    twice — monolithic serial baseline (MXNET_ZERO_BUCKET_BYTES=0) vs
     bucketed (16 KiB) — and print each schedule's per-collective
     overlap windows. The bucketed program should show a positive
     overlap fraction (bucket k's all-gather hides behind bucket k+1's
     update) where the serial baseline measures ~0."""
     print("----------Communication Overlap----------")
     try:
+        from unittest import mock
         import numpy as onp
         import jax
         import mxnet_tpu as mx
@@ -622,7 +622,6 @@ def check_overlap():
         from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
         from mxnet_tpu.parallel import make_mesh, shard_batch
         from mxnet_tpu.analysis.overlap import overlap_census
-        from mxnet_tpu.tuning import space as tspace
 
         ndev = min(8, len(jax.devices()))
         if ndev < 2:
@@ -646,8 +645,9 @@ def check_overlap():
             trainer = Trainer(net.collect_params(), "adam",
                               {"learning_rate": 0.01}, kvstore=None)
             step = trainer.compile_step(lambda a, b: loss(net(a), b))
-            with tspace.trial({"zero.shard_min_size": 1,
-                               "zero.bucket_bytes": bucket_bytes}):
+            with mock.patch.dict(os.environ, {
+                    "MXNET_ZERO_SHARD_MIN_SIZE": "1",
+                    "MXNET_ZERO_BUCKET_BYTES": str(bucket_bytes)}):
                 with make_mesh({"dp": ndev}, jax.devices()[:ndev]) as m:
                     xs, ys = shard_batch(x, m), shard_batch(y, m)
                     step(xs, ys)
@@ -722,76 +722,6 @@ def check_kernels():
               f"  ({'bit-exact' if d == 0.0 else 'nonzero'})")
     except Exception as e:  # pragma: no cover - env-dependent
         print("kernel check failed:", repr(e))
-
-
-def check_autotune():
-    """Self-tuning autopilot health (docs/PERF_NOTES.md "Autotuner"):
-    the registered tunable table (name, default, grid, consumer seam),
-    then a 3-trial analytical sweep over a tiny MLP train step — shown
-    twice against a scratch config DB so the report demonstrates BOTH
-    halves of the loop: the cache MISS that searches + persists, and
-    the cache HIT that replays the winner with zero trials."""
-    print("----------Self-Tuning Autopilot----------")
-    import tempfile
-    try:
-        import numpy as onp
-        import mxnet_tpu as mx
-        from mxnet_tpu import tuning
-        from mxnet_tpu.gluon import Trainer, nn
-        from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
-
-        tuning.space.ensure_registered()
-        print(f"MXNET_AUTOTUNE={tuning.autotune_mode()}  "
-              f"backend={tuning.measure.backend_mode()}  "
-              f"budget={tuning.budget_trials()}  "
-              f"cache={tuning.cache_path() or '<memory>'}")
-        print(f"{'tunable':<26s}{'default':>10s}  grid / seam")
-        for row in tuning.space.table():
-            print(f"{row['name']:<26s}{str(row['default']):>10s}  "
-                  f"{list(row['grid'])}")
-            print(f"{'':<38s}-> {row['seam']}")
-
-        def build_step():
-            onp.random.seed(0)
-            net = nn.HybridSequential()
-            net.add(nn.Dense(32, activation="relu"), nn.Dense(8))
-            net.initialize()
-            x = mx.nd.array(onp.random.randn(8, 16).astype("float32"))
-            y = mx.nd.array(onp.random.randint(0, 8, size=(8,))
-                            .astype("int32"))
-            net(x)
-            loss = SoftmaxCrossEntropyLoss()
-            trainer = Trainer(net.collect_params(), "sgd",
-                              {"learning_rate": 0.1, "momentum": 0.9},
-                              kvstore=None)
-            step = trainer.compile_step(lambda a, b: loss(net(a), b))
-            return step, x, y
-
-        db = tuning.AutotuneCache(
-            os.path.join(tempfile.mkdtemp(prefix="mx_autotune_"),
-                         "autotune.json"))
-        saved = tuning.space.overrides()
-        try:
-            backend = None
-            for label in ("first run ", "second run"):
-                step, x, y = build_step()
-                out = tuning.tune_step(step, (x, y), mode="on",
-                                       budget=3, db=db)
-                backend = out.backend or backend
-                hitmiss = ("HIT (replayed, 0 trials)"
-                           if out.source == "cache"
-                           else "MISS -> searched + persisted")
-                print(f"{label}: cache {hitmiss}  trials={out.trials}"
-                      f"  config={out.config or '{defaults}'}"
-                      + (f"  delta={out.delta_pct}%"
-                         if out.delta_pct is not None else ""))
-            print(f"winning config: {out.config or '{defaults}'} "
-                  f"(backend={backend}, 3-trial budget)")
-        finally:
-            tuning.space.clear_overrides()
-            tuning.space.apply_config(saved)
-    except Exception as e:  # pragma: no cover - env-dependent
-        print("autotune check failed:", repr(e))
 
 
 def check_serving():
@@ -1334,11 +1264,6 @@ def main(argv=None):
                         "interpret/xla + reason) and an interpret-vs-"
                         "xla parity probe for a tiny LSTM scan and "
                         "LayerNorm")
-    parser.add_argument("--autotune", action="store_true",
-                        help="also print the registered tunable table "
-                        "and run a 3-trial analytical autotune sweep "
-                        "on a tiny MLP, showing the winning config and "
-                        "the cache miss->hit round trip")
     parser.add_argument("--serving", action="store_true",
                         help="also AOT-compile a tiny bucketed "
                         "predictor, run a concurrent burst through the "
@@ -1391,8 +1316,6 @@ def main(argv=None):
         check_overlap()
     if args.kernels:
         check_kernels()
-    if args.autotune:
-        check_autotune()
     if args.serving:
         check_serving()
     if args.decode:
