@@ -131,6 +131,7 @@ def kernel_cases(tiny: bool = False):
     import jax.numpy as jnp
     import mxnet_tpu as mx
     from mxnet_tpu.ops import attention, kernels
+    from mxnet_tpu.ops import moe as ops_moe
     from mxnet_tpu.ops import nn as ops_nn
     from mxnet_tpu.ops.kernels import norm, rnn_scan
 
@@ -156,6 +157,25 @@ def kernel_cases(tiny: bool = False):
                 f32(rng, n, h, scale=0.5), f32(rng, n, h, scale=0.5),
                 f32(rng, g * h, h, scale=h ** -0.5),
                 f32(rng, g * h, scale=0.1))
+
+    def sparse_experts(top_k, held):
+        # float32 products at HIGHEST in the kernel tier too, so that both
+        # sides multiply alike and the comparison reads the rows moved:
+        # at the default precision a float32 product is one bf16 pass,
+        # gate values within its rounding of zero change sign, and ReLU's
+        # mask then moves whole rows of the gate weights' gradient (0.69
+        # of its scale at these widths, whatever multiplies: PR 31).
+        # bf16 products stay as they are: they are exact in float32, and
+        # XLA's own bf16 ragged-dot kernel refuses HIGHEST ("Bad lhs type")
+        def layer(x, router_w, w_gate, w_up, w_down):
+            with jax.default_matmul_precision(
+                    "highest" if x.dtype == jnp.float32 else "default"):
+                weights, order, place, sizes = ops_moe.moe_route(
+                    x, router_w, top_k, held)
+                y = ops_moe.moe_experts(x, order, place, sizes, w_gate,
+                                        w_up, w_down)
+                return ops_moe.moe_combine(y, weights, order, place, sizes)
+        return layer
 
     for dtype in ("bfloat16", "float32"):
         # BERT-base FFN: 32 x 512 tokens, hidden 3072
@@ -222,6 +242,22 @@ def kernel_cases(tiny: bool = False):
                 window=window: attention.flash_attention_bsh(
                     q, k, v, heads, causal=True, num_kv_heads=kv_heads,
                     window=window), (0, 1, 2))
+
+        # the SmallThinker cell's expert layer, one chip's share: 8192
+        # tokens of 2560, top-6 of 64 experts of width 768, 8 held, so an
+        # eighth of the 49,152 list rows are live. The row movers behind
+        # lax.ragged_dot, forward and every gradient (the router's too:
+        # it is the weights' gradient that reaches it)
+        n, d, f, e, k, held = (128, 128, 64, 8, 2, (2, 4)) if tiny \
+            else (8192, 2560, 768, 64, 6, (0, 8))
+        add("moe_rows", f"moe_rows {n}x{d} f{f} top{k}/{e} held {held[1]}",
+            dtype,
+            lambda rng, n=n, d=d, f=f, e=e, c=held[1]: (
+                f32(rng, n, d), f32(rng, e, d),
+                f32(rng, c, f, d, scale=d ** -0.5),
+                f32(rng, c, f, d, scale=d ** -0.5),
+                f32(rng, c, d, f, scale=f ** -0.5)),
+            sparse_experts(k, held), (0, 1, 2, 3, 4))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -507,7 +543,8 @@ def _sparse_lm(cfg: dict) -> dict:
     """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
     fused, traced once, loss falling; → its losses and what the program
     counted while tracing it (``mx_moe_dispatch_total``,
-    ``mx_attention_mask_total``, ``mx_flash_attention_grid_steps_total``)."""
+    ``mx_attention_mask_total``, ``mx_moe_row_mover_total``,
+    ``mx_flash_attention_grid_steps_total``)."""
     import numpy as onp
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
@@ -528,7 +565,8 @@ def _sparse_lm(cfg: dict) -> dict:
                                     size=shape).astype("int32"))
             for _ in range(2))
     before = {n: _counter(n) for n in (names.MOE_DISPATCH,
-                                       names.ATTENTION_MASK)}
+                                       names.ATTENTION_MASK,
+                                       names.MOE_ROW_MOVER)}
     steps_before = _grid_steps()
     mx.amp.init()
     try:
@@ -545,6 +583,15 @@ def _sparse_lm(cfg: dict) -> dict:
         raise RuntimeError(f"SmallThinkerLM traced {counted}, expected "
                            f"{layers} grouped expert layers, {windowed} "
                            "of them behind a window")
+    # three row movements a layer (the weighted sum back, its gradient,
+    # the gradient of the tokens' gather), each traced at least once, all
+    # by the kernels
+    movers = counted[names.MOE_ROW_MOVER]
+    if set(movers) != {_compiled_tier()} or \
+            movers[_compiled_tier()] < 3 * layers:
+        raise RuntimeError(f"SmallThinkerLM's row movers took {movers}, "
+                           f"expected {_compiled_tier()} alone, {3 * layers} "
+                           "or more")
     counted[names.FLASH_ATTENTION_GRID_STEPS] = _grid_steps(steps_before)
     log(f"  SmallThinkerLM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}

@@ -81,9 +81,12 @@ class SparseMoE(HybridBlock):
     ``(0, num_experts)`` is the whole layer, ``(8 * j, 8)`` chip j's part
     when eight chips share a 64-expert layer. The layer computes ITS
     experts' part of each token's sum and nothing else: pairs whose expert
-    is held elsewhere are neither gathered nor multiplied, and nothing
-    stands in for the other chips. The parts of all the shares add up to
-    the whole layer's output (tests/test_smallthinker.py).
+    is held elsewhere are not multiplied, and on a TPU chip not moved
+    back either (behind the products the row movers of
+    ``ops/kernels/moe_rows.py`` walk the held pairs; the XLA tier's
+    gathers run over the whole static list, masked), and nothing stands
+    in for the other chips. The parts of all the shares
+    add up to the whole layer's output (tests/test_smallthinker.py).
 
     ``route(u)`` is the router alone, for a model whose router reads
     another tensor than the experts do (before attention); ``forward(x,

@@ -12,6 +12,9 @@ Members (each joins the flash-attention kernels in ops/attention.py):
   Adam) over the ZeRO flat padded 1/N shards of gluon/fused_step.py.
 - :mod:`.norm` — LayerNorm and bias-GELU forward+backward kernels for
   the transformer/BERT leg.
+- :mod:`.moe_rows` — the row movers around the dropless expert layer's
+  grouped products (ops/moe.py), whose work follows the pairs a layer
+  holds and not the static length of its sorted list.
 
 Dispatch discipline (shared by every kernel in this package, and by
 ``ops.attention.flash_attention``): one ``MXNET_PALLAS`` gate with
@@ -80,7 +83,7 @@ def vmem_tile_budget() -> int:
 
 #: the kernel names the dispatch gate knows (diagnose/chip_smoke vocabulary)
 KERNELS = ("rnn_scan", "rnn_decode_step", "opt_update", "layernorm",
-           "bias_gelu", "flash_attention")
+           "bias_gelu", "flash_attention", "moe_rows")
 
 # last decision per kernel name: {kernel: (path, reason)}
 _DECISIONS: Dict[str, Tuple[str, str]] = {}
@@ -165,8 +168,8 @@ def count_traced(metric: str, label_key: str, label: str,
     """``n`` more (one, unless said) under ``label`` of the labelled
     counter ``telemetry.names.<metric>``: what the op layer counts while
     a call is traced (dispatch path, flash layout and grid steps,
-    attention mask, expert dispatch). Telemetry must never fail a kernel
-    call."""
+    attention mask, expert dispatch and row movers). Telemetry must never
+    fail a kernel call."""
     try:
         from ...telemetry import names as tn
         from ...telemetry import registry as treg

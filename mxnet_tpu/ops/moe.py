@@ -38,13 +38,31 @@ computes its own part of the sum:
   matrix product a projection over the experts held, ReGLU between. The
   product is ``lax.ragged_dot``, told the group sizes, so rows past the
   last group are never multiplied;
-- ``moe_combine``: each token's chosen outputs weighted and summed, as a
-  gather through the pairs' places (the sort's inverse), in float32.
+- ``moe_combine``: each token's chosen outputs weighted and summed in
+  float32, the sort's inverse.
 
 Shapes are static: the sorted list has ``N * min(top_k, held)`` rows, the
-most pairs the held experts can be given. The gathers in front of and
-behind the experts run over all those rows (the products do not); both
-have gather-only gradients (``_dispatch``, ``_combine``).
+most pairs the held experts can be given; the pairs they ARE given stand
+in its first ``sizes.sum()`` rows. Nothing may count on what the list
+holds past them. The gather in front of the products (``_dispatch``,
+``x[order // k]``) runs over all those rows. The three passes behind them
+(the weighted sum back in ``moe_combine``, its gradient, and the gradient
+of ``_dispatch``, which autodiff would scatter-add) have two tiers behind
+the kernel layer's gate (``ops/kernels`` ``dispatch("moe_rows")``, from
+platform and shapes):
+
+- on one TPU chip the kernels of ``ops/kernels/moe_rows.py``, which read
+  the held total on the device and move the held pairs' rows only: a sum
+  of the live rows into their tokens (``moe_combine``, ``_dispatch_bwd``)
+  and a gather that walks the live prefix of the list (``_combine_bwd``,
+  with each pair's weight gradient in the same pass); the rest of the
+  list is zero or not written at all;
+- everywhere else, under a multi-device mesh, and as the oracle: the XLA
+  forms below, gathers through ``order`` and ``place`` over all the rows
+  of the list, masked to the held pairs.
+
+``mx_moe_row_mover_total{tier}`` counts which tier a traced call site
+took.
 """
 from __future__ import annotations
 
@@ -221,6 +239,20 @@ def _grouped_dot(lhs, rhs, sizes):
                           preferred_element_type=lhs.dtype)
 
 
+def _row_movers(n, rows, k, d, *dtypes):
+    """What moves the rows of one call site, by the kernel layer's gate
+    from platform, shapes and dtypes, counted while the site is traced:
+    ``(ops.kernels.moe_rows, its tier as keywords)``, or None for the XLA
+    forms below."""
+    from .kernels import count_traced, dispatch, moe_rows
+    why = moe_rows.rows_supported(n, rows, k, d, *dtypes)
+    path, _ = dispatch("moe_rows", supported=why is None, reason=why)
+    count_traced("MOE_ROW_MOVER", "tier", path)
+    if path == "xla":
+        return None
+    return moe_rows, {"interpret": path == "interpret"}
+
+
 @jax.custom_vjp
 def _dispatch(x, order, place, sizes):
     """``x[order // k]``: the sorted pairs' tokens. Its gradient is a
@@ -230,7 +262,7 @@ def _dispatch(x, order, place, sizes):
 
 
 def _dispatch_fwd(x, order, place, sizes):
-    return _dispatch(x, order, place, sizes), (place, sizes)
+    return _dispatch(x, order, place, sizes), (order, place, sizes)
 
 
 def _pair_rows(rows, place, sizes):
@@ -242,8 +274,15 @@ def _pair_rows(rows, place, sizes):
 
 
 def _dispatch_bwd(res, g):
-    place, sizes = res
-    dx = _pair_rows(g, place, sizes).astype(jnp.float32).sum(axis=1)
+    order, place, sizes = res
+    n, k = place.shape
+    movers = _row_movers(n, g.shape[0], k, g.shape[1], g.dtype)
+    if movers is None:
+        dx = _pair_rows(g, place, sizes).astype(jnp.float32).sum(axis=1)
+        return dx.astype(g.dtype), None, None, None
+    kernels, tier = movers
+    dx = kernels.scatter_sum(g, order, sizes.sum(), k, n,
+                             jnp.ones((n, k), jnp.float32), **tier)
     return dx.astype(g.dtype), None, None, None
 
 
@@ -269,8 +308,13 @@ def moe_combine(y, weights, order, place, sizes):
     ``out[n] = sum_j weights[n, j] * y[place[n, j]]`` over the pairs whose
     expert is held. ``y`` (rows, d) from :func:`moe_experts`. Returns
     (N, d) float32. Gathers forward and backward."""
-    picked = _pair_rows(y, place, sizes).astype(jnp.float32)
-    return jnp.einsum("nk,nkd->nd", weights, picked)
+    n, k = place.shape
+    movers = _row_movers(n, y.shape[0], k, y.shape[1], y.dtype)
+    if movers is None:
+        picked = _pair_rows(y, place, sizes).astype(jnp.float32)
+        return jnp.einsum("nk,nkd->nd", weights, picked)
+    kernels, tier = movers
+    return kernels.scatter_sum(y, order, sizes.sum(), k, n, weights, **tier)
 
 
 def _combine_fwd(y, weights, order, place, sizes):
@@ -280,14 +324,20 @@ def _combine_fwd(y, weights, order, place, sizes):
 
 def _combine_bwd(res, g):
     y, weights, order, place, sizes = res
-    k = place.shape[1]
-    g_pair = g[order // k]                      # (rows, d): each pair's dout
-    dy = _valid_rows((g_pair * weights.reshape(-1)[order][:, None])
-                     .astype(y.dtype), sizes)
+    n, k = place.shape
+    movers = _row_movers(n, y.shape[0], k, y.shape[1], y.dtype, g.dtype)
     # d weights[n, j] = <y[place[n, j]], dout[n]>: taken pair by pair in
     # the sorted list, where dout is gathered already, then one number a
     # pair carried back through ``place``
-    dw_pair = jnp.sum(y.astype(jnp.float32) * g_pair, axis=-1)
+    if movers is None:
+        g_pair = g[order // k]                  # (rows, d): each pair's dout
+        dy = _valid_rows((g_pair * weights.reshape(-1)[order][:, None])
+                         .astype(y.dtype), sizes)
+        dw_pair = jnp.sum(y.astype(jnp.float32) * g_pair, axis=-1)
+    else:
+        kernels, tier = movers
+        dy, dw_pair = kernels.gather_rows(g, order, sizes.sum(), k, weights,
+                                          y, **tier)
     dw = jnp.where(place < sizes.sum(),
                    dw_pair[jnp.minimum(place, y.shape[0] - 1)], 0.0)
     return dy, dw.astype(weights.dtype), None, None, None

@@ -156,6 +156,7 @@ FLASH_ATTENTION_LAYOUT = "mx_flash_attention_layout_total"
 FLASH_ATTENTION_GRID_STEPS = "mx_flash_attention_grid_steps_total"
 ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
+MOE_ROW_MOVER = "mx_moe_row_mover_total"
 
 # ---------------------------------------------------------------------------
 # inference serving engine (serving/batcher.py)
@@ -492,6 +493,15 @@ CATALOG = {
              "dropped token; capacity = the GShard one-hot dispatch of "
              "ops/moe.py moe_ffn, which drops past capacity); one count "
              "a traced layer"),
+    MOE_ROW_MOVER: dict(
+        kind="counter", label="tier",
+        help="the row movements behind the dropless expert layer's "
+             "grouped products (the weighted sum back, its gradient, and "
+             "the gradient of the tokens' gather; ops/moe.py) by the "
+             "tier that took them: pallas / interpret = the kernels of "
+             "ops/kernels/moe_rows.py, whose work follows the pairs "
+             "held; xla = gathers over the whole static list; one count "
+             "a traced call site"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),

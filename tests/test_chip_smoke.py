@@ -126,13 +126,17 @@ def test_phases_rehearse_on_cpu(monkeypatch):
                bert="bert_small_test",
                optimizer=("adam", {"learning_rate": 1e-3}))
     sparse = dict(chip_smoke.SPARSE, batch=2, seq=32, steps=4, model=dict(
-        chip_smoke.SPARSE["model"], hidden_size=64, head_dim=16,
+        chip_smoke.SPARSE["model"], hidden_size=128, head_dim=32,
         moe_ffn_hidden_size=32, sliding_window_size=8, vocab_size=128))
     train = chip_smoke.phase_train(cfg, sparse)
     assert train["kernel_paths"]["flash_attention"] == "interpret"
     assert train["sparse_lm"]["mx_moe_dispatch_total"] == {"grouped": 4}
     assert train["sparse_lm"]["mx_attention_mask_total"] == {
         "causal": 1, "window": 3}
+    # 2 x 32 tokens of 128, top-2: a list of 128 rows, which the row
+    # movers' kernels take
+    movers = train["sparse_lm"]["mx_moe_row_mover_total"]
+    assert set(movers) == {"interpret"} and movers["interpret"] >= 12
     # 32 positions in blocks of 32: a step a program, none dead; the
     # sparse LM's grouped heads take the two-kernel backward
     assert train["flash_grid_steps"]["dead"] == 0 < \
