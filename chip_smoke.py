@@ -78,6 +78,25 @@ SPARSE = dict(batch=2, seq=512, steps=8,
                          moe_router_width=8, moe_num_primary_experts=4,
                          moe_num_active_primary_experts=2, vocab_size=512))
 
+# a small JoyAILM for the train phase: the head widths of the benchmark's
+# configuration (benchmark/grid/configs/joyai-llm-flash.json: keys of 128 +
+# 64 rotary lanes beside values of 128), one dense and one expert layer and
+# the MTP module, everything else cut down
+LATENT = dict(batch=2, seq=512, steps=8,
+              optimizer=("adam", {"learning_rate": 1e-3}),
+              model=dict(hidden_size=256, intermediate_size=512,
+                         q_lora_rank=192, kv_lora_rank=128,
+                         qk_nope_head_dim=128, qk_rope_head_dim=64,
+                         v_head_dim=128, num_attention_heads=4,
+                         rope_theta=32e6, rope_interleave=True,
+                         rms_norm_eps=1e-6, hidden_act="silu",
+                         first_k_dense_replace=1, num_hidden_layers=2,
+                         num_nextn_predict_layers=1,
+                         moe_intermediate_size=256, n_routed_experts=4,
+                         moe_router_width=8, num_experts_per_tok=2,
+                         n_shared_experts=1, scoring_func="sigmoid",
+                         routed_scaling_factor=2.5, vocab_size=512))
+
 # the accelerator sizes of bench.py's decode leg
 SERVE = dict(vocab=256, d_model=128, heads=4, requests=16,
              ladder=(1, 2, 4, 8), page_size=16)
@@ -242,6 +261,19 @@ def kernel_cases(tiny: bool = False):
                 window=window: attention.flash_attention_bsh(
                     q, k, v, heads, causal=True, num_kv_heads=kv_heads,
                     window=window), (0, 1, 2))
+
+        # the JoyAI cell's latent attention: keys 192 wide (128 + 64
+        # rotary lanes) beside values 128 wide, causal, 4096 long; 8 of
+        # its 32 heads (four blocks of two heads on 384 / 256 lanes)
+        seq, heads = (64, 4) if tiny else (4096, 8)
+        add("flash_attention",
+            f"flash_attention bsh 1x{seq} h{heads} keys 192 values 128 "
+            "causal", dtype,
+            lambda rng, seq=seq, heads=heads: (
+                f32(rng, 1, seq, heads * 192), f32(rng, 1, seq, heads * 192),
+                f32(rng, 1, seq, heads * 128)),
+            lambda q, k, v, heads=heads: attention.flash_attention_bsh(
+                q, k, v, heads, causal=True), (0, 1, 2))
 
         # the SmallThinker cell's expert layer, one chip's share: 8192
         # tokens of 2560, top-6 of 64 experts of width 768, 8 held, so an
@@ -539,20 +571,15 @@ def _counter(name: str) -> dict:
             telemetry.registry().counter(name).values().items()}
 
 
-def _sparse_lm(cfg: dict) -> dict:
-    """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
-    fused, traced once, loss falling; → its losses and what the program
-    counted while tracing it (``mx_moe_dispatch_total``,
-    ``mx_attention_mask_total``, ``mx_moe_row_mover_total``,
-    ``mx_flash_attention_grid_steps_total``)."""
-    import numpy as onp
+def _train_small_lm(net, cfg: dict, x, y, tracked: tuple):
+    """A model-zoo LM on seeded ``normal(0, 0.02)`` weights (gains 1)
+    through ``TrainLoop`` under bf16 AMP for ``cfg["steps"]`` steps on one
+    batch; → ``(losses, {counter name: {label: counts while tracing}})``
+    for the ``tracked`` counters, the flash kernels' layouts and their grid
+    steps among them."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
-    from mxnet_tpu.gluon.model_zoo.smallthinker import SmallThinkerLM
     from mxnet_tpu.telemetry import names
-    mx.random.seed(SEED)
-    rng = onp.random.RandomState(SEED)
-    net = SmallThinkerLM(cfg["model"])
     net.initialize(mx.init.Normal(0.02))
     for name, p in net.collect_params().items():
         if name.endswith("gamma"):
@@ -560,14 +587,8 @@ def _sparse_lm(cfg: dict) -> dict:
     trainer = mx.gluon.Trainer(net.collect_params(), *cfg["optimizer"],
                                kvstore="tpu")
     loop = mx.gluon.TrainLoop(net, trainer, SoftmaxCrossEntropyLoss())
-    shape = (cfg["batch"], cfg["seq"])
-    x, y = (mx.nd.array(rng.randint(0, cfg["model"]["vocab_size"],
-                                    size=shape).astype("int32"))
-            for _ in range(2))
-    before = {n: _counter(n) for n in (names.MOE_DISPATCH,
-                                       names.ATTENTION_MASK,
-                                       names.MOE_ROW_MOVER)}
-    steps_before = _grid_steps()
+    before = {n: _counter(n) for n in tracked}
+    layouts_before, steps_before = _flash_layouts(), _grid_steps()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
@@ -576,6 +597,32 @@ def _sparse_lm(cfg: dict) -> dict:
     counted = {n: {k: v - before[n].get(k, 0)
                    for k, v in _counter(n).items()
                    if v > before[n].get(k, 0)} for n in before}
+    counted["flash_layouts"] = {k: n - layouts_before[k]
+                                for k, n in _flash_layouts().items()}
+    counted[names.FLASH_ATTENTION_GRID_STEPS] = _grid_steps(steps_before)
+    return losses, counted
+
+
+def _sparse_lm(cfg: dict) -> dict:
+    """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
+    fused, traced once, loss falling; → its losses and what the program
+    counted while tracing it (``mx_moe_dispatch_total``,
+    ``mx_attention_mask_total``, ``mx_moe_row_mover_total``,
+    ``mx_flash_attention_grid_steps_total``)."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo.smallthinker import SmallThinkerLM
+    from mxnet_tpu.telemetry import names
+    mx.random.seed(SEED)
+    rng = onp.random.RandomState(SEED)
+    shape = (cfg["batch"], cfg["seq"])
+    x, y = (mx.nd.array(rng.randint(0, cfg["model"]["vocab_size"],
+                                    size=shape).astype("int32"))
+            for _ in range(2))
+    losses, counted = _train_small_lm(
+        SmallThinkerLM(cfg["model"]), cfg, x, y,
+        (names.MOE_DISPATCH, names.ATTENTION_MASK, names.MOE_ROW_MOVER))
+    del counted["flash_layouts"]
     layers = cfg["model"]["num_hidden_layers"]
     windowed = sum(cfg["model"]["sliding_window_layout"][:layers])
     if counted[names.MOE_DISPATCH] != {"grouped": layers} or \
@@ -592,12 +639,54 @@ def _sparse_lm(cfg: dict) -> dict:
         raise RuntimeError(f"SmallThinkerLM's row movers took {movers}, "
                            f"expected {_compiled_tier()} alone, {3 * layers} "
                            "or more")
-    counted[names.FLASH_ATTENTION_GRID_STEPS] = _grid_steps(steps_before)
     log(f"  SmallThinkerLM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}
 
 
-def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE) -> dict:
+def _latent_lm(cfg: dict) -> dict:
+    """A small ``JoyAILM`` through ``TrainLoop`` under bf16 AMP: fused,
+    traced once, loss falling; → its losses and what the program counted
+    while tracing it: latent attention in every layer and in the MTP
+    module (``mx_latent_attention_total``), sigmoid routers
+    (``mx_moe_router_total``), one MTP module, and no flash call that
+    pads its 192-lane keys in HBM."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon.model_zoo.joyai import JoyAILM
+    from mxnet_tpu.telemetry import names
+    mx.random.seed(SEED)
+    rng = onp.random.RandomState(SEED)
+    model, seq = cfg["model"], cfg["seq"]
+    t = rng.randint(0, model["vocab_size"],
+                    size=(cfg["batch"], seq + 2)).astype("int32")
+    x = mx.nd.array(t[:, :seq + 1])
+    y = mx.nd.array(onp.concatenate([t[:, 1:seq + 1], t[:, 2:]], 1))
+    losses, counted = _train_small_lm(
+        JoyAILM(model), cfg, x, y,
+        (names.LATENT_ATTENTION, names.MOE_ROUTER, names.MTP_MODULES,
+         names.MOE_DISPATCH, names.MOE_ROW_MOVER))
+    blocks = model["num_hidden_layers"] + model["num_nextn_predict_layers"]
+    routers = blocks - model["first_k_dense_replace"]
+    if counted[names.LATENT_ATTENTION] != {"expanded": blocks} or \
+            counted[names.MOE_ROUTER] != {"sigmoid": routers} or \
+            sum(counted[names.MTP_MODULES].values()) != 1 or \
+            counted[names.MOE_DISPATCH] != {"grouped": routers}:
+        raise RuntimeError(f"JoyAILM traced {counted}, expected {blocks} "
+                           f"latent attentions, {routers} sigmoid routers "
+                           "and one MTP module")
+    layouts = counted["flash_layouts"]
+    if layouts != {"packed": blocks, "unpadded": 0, "padded": 0}:
+        raise RuntimeError(f"JoyAILM's attention layers took {layouts}, "
+                           f"expected {blocks} packed and none padded")
+    if set(counted[names.MOE_ROW_MOVER]) != {_compiled_tier()}:
+        raise RuntimeError("JoyAILM's row movers took "
+                           f"{counted[names.MOE_ROW_MOVER]}")
+    log(f"  JoyAILM: {counted}")
+    return {"loss": [round(l, 4) for l in losses], **counted}
+
+
+def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE,
+                latent: dict = LATENT) -> dict:
     import jax
     import mxnet_tpu as mx
     platform = jax.devices()[0].platform
@@ -627,7 +716,7 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE) -> dict:
                            "expected one call a layer and none padded")
     return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths,
             "flash_layouts": layouts, "flash_grid_steps": grid_steps,
-            "sparse_lm": _sparse_lm(sparse)}
+            "sparse_lm": _sparse_lm(sparse), "latent_lm": _latent_lm(latent)}
 
 
 def phase_kernels(tiny: bool = False) -> dict:

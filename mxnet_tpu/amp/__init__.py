@@ -47,7 +47,7 @@ TARGET_DTYPE_OPS = {
     "fully_connected", "convolution", "deconvolution", "dot", "batch_dot",
     "linalg_gemm2", "flash_attention", "flash_attention_vl",
     "masked_attention", "bert_decoder_proj", "moe_ffn", "moe_experts",
-    "Correlation", "DeformableConvolution",
+    "shared_expert", "Correlation", "DeformableConvolution",
 }
 
 # Norm ops: f32-pinned only for true fp16 (their kernels already compute
@@ -66,6 +66,8 @@ FP32_OPS = NORM_OPS | {
     # the router of the dropless expert layer: logits, top-k and the
     # softmax of the chosen logits (a bf16 logit flips near-tied choices)
     "moe_route",
+    # the two norms of latent attention's compressed q and kv
+    "latent_norm",
 }
 
 _state = {"enabled": False, "dtype": None, "wrapper": None}

@@ -6,6 +6,7 @@ constructed with random init and support ``load_parameters`` from local files.
 from . import vision
 from . import bert
 from . import smallthinker
+from . import joyai
 from .vision import get_model
 
-__all__ = ["vision", "bert", "smallthinker", "get_model"]
+__all__ = ["vision", "bert", "smallthinker", "joyai", "get_model"]

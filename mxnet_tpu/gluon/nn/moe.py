@@ -7,9 +7,11 @@ parallelism as absent); TPU-native extensions backed by ``ops/moe.py``:
   an expert's capacity are DROPPED) over batched two-matrix expert
   einsums;
 - ``SparseMoE``: the dropless layer of today's sparse-expert decoders:
-  top-k of the router's logits, a softmax over the chosen ones, gated
-  (ReGLU) experts, no capacity and no dropped token, and a layer that is
-  told which experts it holds (``held``).
+  top-k of the router's scores by the model's rule (a softmax over the
+  chosen logits; or sigmoid scores, a selection bias, weights normalised
+  over the chosen and scaled), gated experts (ReGLU or SwiGLU), optionally
+  a shared expert every token passes, no capacity and no dropped token,
+  and a layer that is told which experts it holds (``held``).
 """
 from __future__ import annotations
 
@@ -72,10 +74,23 @@ class SparseMoE(HybridBlock):
     """Dropless sparse expert layer: ``out = moe(x)``, x (..., units).
 
     The router (``router_weight``, (num_experts, units)) scores every
-    token against ALL ``num_experts`` experts; each token takes its
-    ``top_k`` best and weighs them by the softmax of those k logits, in
-    float32. Expert e is ``W_down (relu(W_gate x) * (W_up x))`` (sparse
-    ReGLU, width ``hidden``, no bias).
+    token against ALL ``num_experts`` experts, in float32; each token
+    takes its ``top_k`` best and weighs them, by ``score``:
+
+    - ``"softmax"`` (SmallThinker): the k largest logits, weighed by the
+      softmax of those k logits;
+    - ``"sigmoid"`` (the DeepSeek-V3 family): scores sigmoid(logits); the
+      k largest of score + ``router_bias`` ((num_experts,), a parameter
+      that picks and never weighs: its gradient is exactly zero, so an
+      optimizer leaves it where the job's balance rule put it), weighed
+      by ``routed_scale`` * score / the chosen scores' sum.
+
+    Expert e is ``W_down (act(W_gate x) * (W_up x))``, width ``hidden``,
+    no bias; ``activation`` names act: ``"relu"`` (sparse ReGLU) or
+    ``"silu"`` (SwiGLU). With ``shared_hidden`` one more such expert of
+    that width, which every token passes with weight 1, is added to the
+    sum: it is whole on every chip, so the shares of a layer count it
+    once (``shared_expert(x)`` is its term alone).
 
     ``held = (first, count)`` says which experts this layer holds:
     ``(0, num_experts)`` is the whole layer, ``(8 * j, 8)`` chip j's part
@@ -86,7 +101,8 @@ class SparseMoE(HybridBlock):
     ``ops/kernels/moe_rows.py`` walk the held pairs; the XLA tier's
     gathers run over the whole static list, masked), and nothing stands
     in for the other chips. The parts of all the shares
-    add up to the whole layer's output (tests/test_smallthinker.py).
+    add up to the whole layer's output (tests/test_smallthinker.py,
+    tests/test_joyai.py).
 
     ``route(u)`` is the router alone, for a model whose router reads
     another tensor than the experts do (before attention); ``forward(x,
@@ -94,8 +110,15 @@ class SparseMoE(HybridBlock):
     """
 
     def __init__(self, units, hidden, num_experts, top_k, held=None,
-                 dtype="float32", **kwargs):
+                 dtype="float32", score="softmax", routed_scale=1.0,
+                 activation="relu", shared_hidden=0, **kwargs):
         super().__init__(**kwargs)
+        if score not in moe_ops.SCORES:
+            raise MXNetError(f"score {score!r} is none of "
+                             f"{moe_ops.SCORES}")
+        if activation not in moe_ops.ACTIVATIONS:
+            raise MXNetError(f"activation {activation!r} is none of "
+                             f"{sorted(moe_ops.ACTIVATIONS)}")
         first, count = (0, num_experts) if held is None else held
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise MXNetError(f"held={held!r} is no range of "
@@ -104,14 +127,33 @@ class SparseMoE(HybridBlock):
             raise MXNetError(f"top_k {top_k} > {num_experts} experts")
         self._units, self._k = units, top_k
         self._held = (int(first), int(count))
+        self._score, self._scale = score, float(routed_scale)
+        self._activation = activation
         self.router_weight = Parameter(
             "router_weight", shape=(num_experts, units), dtype=dtype)
+        self.router_bias = None
+        if score == "sigmoid":
+            self.router_bias = Parameter(
+                "router_bias", shape=(num_experts,), dtype=dtype,
+                init="zeros")
         self.gate_weight = Parameter(
             "gate_weight", shape=(count, hidden, units), dtype=dtype)
         self.up_weight = Parameter(
             "up_weight", shape=(count, hidden, units), dtype=dtype)
         self.down_weight = Parameter(
             "down_weight", shape=(count, units, hidden), dtype=dtype)
+        self.shared_gate_weight = self.shared_up_weight = \
+            self.shared_down_weight = None
+        if shared_hidden:
+            self.shared_gate_weight = Parameter(
+                "shared_gate_weight", shape=(shared_hidden, units),
+                dtype=dtype)
+            self.shared_up_weight = Parameter(
+                "shared_up_weight", shape=(shared_hidden, units),
+                dtype=dtype)
+            self.shared_down_weight = Parameter(
+                "shared_down_weight", shape=(units, shared_hidden),
+                dtype=dtype)
 
     def _tokens(self, x):
         x = x if isinstance(x, NDArray) else NDArray(x)
@@ -120,31 +162,54 @@ class SparseMoE(HybridBlock):
     def route(self, u):
         """``(weights, order, place, sizes)`` of ``ops.moe.moe_route``
         for the tokens of ``u``."""
-        fn = functools.partial(moe_ops.moe_route, top_k=self._k,
-                               held=self._held)
-        return invoke_raw("moe_route", fn,
-                          [self._tokens(u), self.router_weight.data()],
-                          n_outputs=4)
+        count_traced("MOE_ROUTER", "score", self._score)
+        inputs = [self._tokens(u), self.router_weight.data()]
+        if self.router_bias is not None:
+            inputs.append(self.router_bias.data())
+
+        def fn(x, w, bias=None):
+            return moe_ops.moe_route(x, w, self._k, self._held, self._score,
+                                     bias, self._scale)
+        return invoke_raw("moe_route", fn, inputs, n_outputs=4)
+
+    def shared_expert(self, x):
+        """The shared expert's term alone, ``x``'s shape."""
+        act = moe_ops.ACTIVATIONS[self._activation]
+
+        def fn(x_, gate, up, down):
+            return (act(x_ @ gate.T) * (x_ @ up.T)) @ down.T
+        return invoke_raw("shared_expert", fn,
+                          [x if isinstance(x, NDArray) else NDArray(x),
+                           self.shared_gate_weight.data(),
+                           self.shared_up_weight.data(),
+                           self.shared_down_weight.data()])
 
     def forward(self, x, routing=None):
         shape = x.shape
         tokens = self._tokens(x)
         weights, order, place, sizes = routing or self.route(x)
         count_traced("MOE_DISPATCH", "path", "grouped")
-        y = invoke_raw("moe_experts", moe_ops.moe_experts,
+        experts = functools.partial(moe_ops.moe_experts,
+                                    activation=self._activation)
+        y = invoke_raw("moe_experts", experts,
                        [tokens, order, place, sizes, self.gate_weight.data(),
                         self.up_weight.data(), self.down_weight.data()])
         out = invoke_raw("moe_combine", moe_ops.moe_combine,
-                         [y, weights, order, place, sizes])
-        return out.reshape(shape)
+                         [y, weights, order, place, sizes]).reshape(shape)
+        if self.shared_gate_weight is not None:
+            out = out + self.shared_expert(x)
+        return out
 
     def routing_stats(self, x):
         """Eager, outside any step: ``{"pairs": pairs each held expert is
         given for the tokens of x, "held_share": their share of all
         tokens x top_k pairs}`` as numpy / float."""
         import numpy as onp
+        bias = None if self.router_bias is None else \
+            self.router_bias.data()._data
         sizes, share = moe_ops.routing_counts(
             self._tokens(x)._data, self.router_weight.data()._data,
-            self._k, self._held)
+            self._k, self._held, score=self._score, bias=bias,
+            scale=self._scale)
         return {"pairs": onp.asarray(sizes), "held_share": float(share)}
 

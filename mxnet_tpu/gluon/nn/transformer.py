@@ -18,13 +18,15 @@ from ...base import MXNetError
 from ...ndarray import ops as F
 from ...ndarray.ndarray import NDArray
 from ...ops import attention as ATT
-from ...ops.registry import invoke_raw
+from ...ops import nn as _nn
+from ...ops.kernels import count_traced
+from ...ops.registry import invoke_raw, scope
 from ..block import HybridBlock
 from ..parameter import Parameter
 from .basic_layers import Dense, Dropout, LayerNorm
 
-__all__ = ["MultiHeadAttention", "PositionwiseFFN", "TransformerEncoderCell",
-           "TransformerEncoder"]
+__all__ = ["MultiHeadAttention", "LatentAttention", "PositionwiseFFN",
+           "TransformerEncoderCell", "TransformerEncoder"]
 
 
 def _masked_attention(q, k, v, mask, sm_scale, causal=False,
@@ -165,6 +167,92 @@ class MultiHeadAttention(HybridBlock):
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         (b, s, self._num_heads * d))
         return self.dropout(self.out_proj(out))
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention (MLA, the DeepSeek-V2/V3 family) over
+    (batch, seq, units), causal, in its TRAINING form ("expanded": keys
+    and values are written out a head; the decode form that attends in
+    the latent space over a cached ``c_kv`` is not built here)::
+
+        c_q = RMSNorm(u W_qa)                          units -> q_rank
+        q   = c_q W_qb -> heads x [q_n (nope) | q_r (rope)];  q_r <- RoPE
+        [c_kv | k_r] = u W_kva;  c_kv <- RMSNorm(c_kv);  k_r <- RoPE
+                                                       k_r: ONE head
+        c_kv W_kvb -> heads x [k_n (nope) | v (v_dim)]
+        k = [k_n | k_r], k_r the same for every head
+        out = softmax(q k^T / sqrt(nope + rope) + causal) v  W_o
+
+    No bias. The two latent norms run in float32 (``latent_norm`` is on
+    AMP's float32 list). RoPE turns the ``rope`` lanes behind each
+    head's ``nope`` lanes, neighbouring pairs (2i, 2i+1) with
+    ``rope_interleave``. ``k_r`` is broadcast into every head's key in
+    front of the attention call, whose kernels take keys ``nope + rope``
+    wide beside values ``v_dim`` wide (``ops.attention._Tiles``).
+    Everything in front of the call sits under the ``latent_proj``
+    scope."""
+
+    def __init__(self, units: int, num_heads: int, q_rank: int,
+                 kv_rank: int, nope: int, rope: int, v_dim: int,
+                 rope_theta: float, rope_interleave: bool = True,
+                 epsilon: float = 1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self._heads, self._nope, self._rope_dim = num_heads, nope, rope
+        self._v_dim, self._kv_rank, self._eps = v_dim, kv_rank, epsilon
+        self._theta, self._interleave = float(rope_theta), rope_interleave
+
+        def dense(out, inp):
+            return Dense(out, use_bias=False, flatten=False, in_units=inp)
+        self.q_a_proj = dense(q_rank, units)
+        self.q_a_norm_gamma = Parameter("q_a_norm_gamma", shape=(q_rank,),
+                                        init="ones")
+        self.q_b_proj = dense(num_heads * (nope + rope), q_rank)
+        self.kv_a_proj = dense(kv_rank + rope, units)
+        self.kv_a_norm_gamma = Parameter("kv_a_norm_gamma",
+                                         shape=(kv_rank,), init="ones")
+        self.kv_b_proj = dense(num_heads * (nope + v_dim), kv_rank)
+        self.out_proj = dense(units, num_heads * v_dim)
+
+    def _norm(self, x, gamma):
+        """RMSNorm of the latent in front of ``x``'s lanes (all of c_q;
+        c_kv ahead of k_r), in float32 under AMP."""
+        def fn(x_, g):
+            return _nn.rms_norm(x_[..., :g.shape[0]], g, eps=self._eps)
+        return invoke_raw("latent_norm", fn, [x, gamma.data()])
+
+    def _keys_values(self, kv_a, kv):
+        """From ``[c_kv | k_r]`` (kept for its k_r) and the up-projected
+        heads x [k_n | v]: k (B, S, H * (nope + rope)), every head's
+        rotary lanes the one turned k_r, and v (B, S, H * v_dim)."""
+        heads, nope, v_dim = self._heads, self._nope, self._v_dim
+
+        def fn(kv_a_, kv_):
+            b, s, _ = kv_.shape
+            k_r = ATT.rope(kv_a_[..., self._kv_rank:], 1, self._theta,
+                           interleave=self._interleave)
+            kv_ = kv_.reshape(b, s, heads, nope + v_dim)
+            k_r = jnp.broadcast_to(k_r[:, :, None, :].astype(kv_.dtype),
+                                   (b, s, heads, k_r.shape[-1]))
+            k = jnp.concatenate([kv_[..., :nope], k_r], -1)
+            return (k.reshape(b, s, -1),
+                    kv_[..., nope:].reshape(b, s, heads * v_dim))
+        return invoke_raw("latent_keys_values", fn, [kv_a, kv], n_outputs=2)
+
+    def forward(self, u):
+        heads, d = self._heads, self._nope + self._rope_dim
+        count_traced("LATENT_ATTENTION", "form", "expanded")
+        with scope("latent_proj"):
+            c_q = self._norm(self.q_a_proj(u), self.q_a_norm_gamma)
+            q = invoke_raw("rope", functools.partial(
+                ATT.rope, num_heads=heads, theta=self._theta,
+                lanes=(self._nope, self._rope_dim),
+                interleave=self._interleave), [self.q_b_proj(c_q)])
+            kv_a = self.kv_a_proj(u)
+            c_kv = self._norm(kv_a, self.kv_a_norm_gamma)
+            k, v = self._keys_values(kv_a, self.kv_b_proj(c_kv))
+        fn = functools.partial(ATT.flash_attention_bsh, num_heads=heads,
+                               causal=True, sm_scale=1.0 / math.sqrt(d))
+        return self.out_proj(invoke_raw("flash_attention", fn, [q, k, v]))
 
 
 class PositionwiseFFN(HybridBlock):

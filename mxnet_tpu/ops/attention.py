@@ -77,7 +77,8 @@ def attention_reference(q, k, v, causal: bool = False,
     arbitrary-additive-mask path (XLA fuses the softmax). ``mask`` is an
     additive float mask broadcastable to (B, H, Sq, Sk). ``window`` hides
     key j from query i unless 0 <= i - j < window. k and v may hold fewer
-    heads than q (query head n reads head n // group). Convention shared
+    heads than q (query head n reads head n // group), and v's heads
+    another width than q's and k's (the result's). Convention shared
     by every attention path in this module: a query row with NO valid key
     outputs exactly zero (the flash-kernel convention)."""
     window = _window_of(window, causal)
@@ -105,24 +106,40 @@ def attention_reference(q, k, v, causal: bool = False,
     return out.astype(q.dtype)
 
 
-def rope(x, num_heads: int, theta: float = 10000.0):
+def rope(x, num_heads: int, theta: float = 10000.0, lanes=None,
+         interleave: bool = False):
     """Rotary position embedding of (B, S, H*D) queries or keys, in
-    front of the attention call: position p turns the pair (x[i],
-    x[i + D/2]) of each head by the angle p * theta^(-2i/D)
-    ("rotate-half" over the head's whole width). Angles and the turn in
-    float32, the result in the input's dtype."""
+    front of the attention call. ``lanes = (first, count)`` is the slice
+    of each head's D lanes that is turned (default: all of them; the
+    rest pass through untouched). Position p turns a pair of the slice's
+    R lanes by the angle p * theta^(-2i/R): the pair (x[i], x[i + R/2])
+    ("rotate-half", the default), or with ``interleave`` the neighbours
+    (x[2i], x[2i + 1]). Angles and the turn in float32, the result in
+    the input's dtype. Either pairing keeps scores relative: <rope(q, p),
+    rope(k, p')> depends on p - p' alone."""
     b, s, hd = x.shape
     d = hd // num_heads
-    if hd % num_heads or d % 2:
-        raise MXNetError(f"rope: width {hd} over {num_heads} heads gives "
-                         f"no even head width")
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    first, r = (0, d) if lanes is None else lanes
+    if hd % num_heads or r % 2 or first < 0 or first + r > d:
+        raise MXNetError(f"rope: width {hd} over {num_heads} heads, lanes "
+                         f"{lanes}: no even slice of a head's width")
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(angle)[None, :, None, :]          # (1, S, 1, D/2)
+    cos = jnp.cos(angle)[None, :, None, :]          # (1, S, 1, R/2)
     sin = jnp.sin(angle)[None, :, None, :]
     xf = x.astype(jnp.float32).reshape(b, s, num_heads, d)
-    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    xr = xf if lanes is None else xf[..., first:first + r]
+    if interleave:
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        -1).reshape(b, s, num_heads, r)
+    else:
+        x1, x2 = xr[..., :r // 2], xr[..., r // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              -1)
+    if lanes is not None:
+        out = jnp.concatenate([xf[..., :first], out, xf[..., first + r:]],
+                              -1)
     return out.reshape(b, s, hd).astype(x.dtype)
 
 
@@ -137,10 +154,11 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
     blocks and keeps the matmuls on the MXU. ``valid_length`` is an
     optional (B,) per-sample key length (padding mask); ``window`` the
     sliding window. k, v may hold fewer heads than q: the query heads of
-    a group are folded into the query rows, so nothing is repeated."""
+    a group are folded into the query rows, so nothing is repeated. v's
+    heads may have another width than q's and k's; the result has v's."""
     orig_dtype = q.dtype
     b, hq, sq, d = q.shape
-    h, sk = k.shape[1], k.shape[2]
+    h, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = _kv_group(hq, h)
     if group > 1:       # (B, Hkv, group * Sq, D): row r is query r % Sq
         q = q.reshape(b, h, group * sq, d)
@@ -152,7 +170,7 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     qf = q.astype(jnp.float32) * sm_scale
     kb = jnp.moveaxis(k.reshape(b, h, nk, block_k, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(b, h, nk, block_k, d), 2, 0)
+    vb = jnp.moveaxis(v.reshape(b, h, nk, block_k, dv), 2, 0)
     # align causal diagonal to the end
     q_pos = jnp.tile(jnp.arange(sq) + (sk - sq), group)
     sq = group * sq
@@ -179,14 +197,14 @@ def _attention_xla(q, k, v, causal: bool, sm_scale: float,
             "bhqk,bhkd->bhqd", p, vblk.astype(jnp.float32))
         return (acc, m_new, l), None
 
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, dv), jnp.float32)
     m0 = jnp.full((b, h, sq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     (acc, m, l), _ = lax.scan(body, (acc0, m0, l0),
                               (kb, vb, jnp.arange(nk)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = jnp.where((m > _NEG_INF / 2)[..., None], out, 0.0)  # no-key rows
-    return out.reshape(b, hq, sq // group, d).astype(orig_dtype)
+    return out.reshape(b, hq, sq // group, dv).astype(orig_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -214,6 +232,8 @@ def _default_block(head_dim: int) -> int:
     return 1024 if head_dim >= 128 else _BLOCK_Q
 #: narrowest head the kernels take without padding
 _MIN_LANES = 8
+#: most heads of two widths (q/k beside v) that share one packed block
+_MAX_SHARE = 8
 
 
 class _Tiles(NamedTuple):
@@ -225,9 +245,21 @@ class _Tiles(NamedTuple):
     ``(rows, col_tiles * heads, seq, 8)`` f32 (8 lanes: the narrowest
     block the TPU tiling takes for a row vector).
 
+    q, k and their gradients have heads ``head_dim`` wide in blocks of
+    ``width``; v, o and their gradients heads ``head_dim_v`` wide in
+    blocks of ``width_v``. Most models have one width for both; latent
+    attention (MLA) has keys of 192 = 128 + 64 rotary lanes beside
+    values of 128. A block holds the same ``heads`` heads on both sides.
+
     - ``packed``: the projections' own (B, S, H*D). rows = B, width =
       128 lanes holding 128 // D heads (or D lanes and one head when D
-      is a multiple of 128); nothing is moved around the call.
+      is a multiple of 128); nothing is moved around the call. With two
+      widths a block holds the fewest heads that fill whole lane tiles
+      on both sides (two heads of 192 on 384 lanes beside their 256
+      lanes of values); the kernels reach a head through the lane tiles
+      that hold it (``_Lanes``), so a width of 1.5 tiles is read where
+      it lies, contracted over two tiles with the neighbour's half
+      masked.
     - ``unpadded``: (B, H, S, D) folded to (B*H, S, D), one column tile
       of width D (a block's last dimension may be the array's own).
     - ``padded``: the same fold with D zero-padded to a multiple of 128
@@ -261,16 +293,31 @@ class _Tiles(NamedTuple):
     skp: int
     nq: int
     nk: int
+    head_dim_v: int
+    width_v: int
 
     @property
     def reason(self) -> str:
         """The layout as the dispatch decision words it."""
+        values = "" if self.head_dim_v == self.head_dim else \
+            f" (values D={self.head_dim_v} on {self.width_v})"
         if self.layout == "packed":
             return (f"packed: {self.heads} head{'s' * (self.heads > 1)} "
-                    f"per {self.width} lanes, no pad")
+                    f"per {self.width} lanes, no pad" + values)
         if self.layout == "unpadded":
-            return f"unpadded: D={self.head_dim}, one head per block"
-        return f"padded: D={self.head_dim} → {self.width}"
+            return (f"unpadded: D={self.head_dim}, one head per block"
+                    + values)
+        return f"padded: D={self.head_dim} → {self.width}" + values
+
+    @property
+    def lanes(self) -> "_Lanes":
+        """The heads of a q or k block on its lanes."""
+        return _Lanes(self.heads, self.width)
+
+    @property
+    def lanes_v(self) -> "_Lanes":
+        """The heads of a v or o block on its lanes."""
+        return _Lanes(self.heads, self.width_v)
 
     @property
     def row_group(self) -> int:
@@ -283,38 +330,55 @@ class _Tiles(NamedTuple):
         return self.group if self.layout == "packed" else 1
 
 
+def _fits(d: int) -> bool:
+    """A head width a block takes as it is: whole lane tiles, or a
+    share of one."""
+    return d % 128 == 0 or (128 % d == 0 and d >= _MIN_LANES)
+
+
 def _tiles(q_shape, k_shape, block_q: int, block_k: int,
-           num_heads: Optional[int], window: Optional[int] = None) -> _Tiles:
+           num_heads: Optional[int], window: Optional[int] = None,
+           v_shape=None) -> _Tiles:
     """The tiling for one call, from the shapes alone. ``num_heads``
     None: q, k, v are (B, H, S, D); else they are (B, S, H*D). The
-    key/value head count is read off k's shape."""
+    key/value head count is read off k's shape, the width of the values'
+    heads off ``v_shape`` (None: the keys')."""
     if num_heads is None:
         b, h, sq, d = q_shape
         hkv, sk = k_shape[1], k_shape[2]
+        dv = d if v_shape is None else v_shape[3]
     else:
         b, sq, hd = q_shape
         h, d, sk = num_heads, hd // num_heads, k_shape[1]
         hkv = k_shape[2] // d
+        dv = d if v_shape is None else v_shape[2] // hkv
     group = _kv_group(h, hkv)
     block_q = min(block_q, max(sq, 8))
     block_k = min(block_k, max(sk, 8))
     sqp = -(-sq // block_q) * block_q
     skp = -(-sk // block_k) * block_k
-    fits = d % 128 == 0 or (128 % d == 0 and d >= _MIN_LANES)
+    # the fewest heads that fill whole lane tiles at both widths
+    share = max(128 // math.gcd(d, 128), 128 // math.gcd(dv, 128))
     # heads that share a lane tile share its key/value tile too: with
     # groups, packed is for heads that fill a tile alone
-    if num_heads is not None and fits and (h * d) % 128 == 0 \
-            and (group == 1 or d % 128 == 0):
+    if dv == d and num_heads is not None and _fits(d) \
+            and (h * d) % 128 == 0 and (group == 1 or d % 128 == 0):
         width = max(d, 128)
         layout, rows, cols, heads = "packed", b, h * d // width, width // d
-    elif fits:
+    elif dv != d and num_heads is not None and h % share == 0 \
+            and share <= _MAX_SHARE and (group == 1 or share == 1):
+        layout, rows, cols, heads = "packed", b, h // share, share
+        width = share * d
+    elif _fits(d) and _fits(dv):
         layout, rows, cols, width, heads = "unpadded", b * h, 1, d, 1
     else:
         layout, rows, cols, width, heads = \
             "padded", b * h, 1, -(-d // 128) * 128, 1
+    width_v = width if dv == d else heads * dv if layout == "packed" \
+        else dv if layout == "unpadded" else -(-dv // 128) * 128
     return _Tiles(layout, b, h, d, sq, sk, rows, cols, width, heads, group,
                   window, block_q, block_k, sqp, skp, sqp // block_q,
-                  skp // block_k)
+                  skp // block_k, dv, width_v)
 
 
 def _split_heads(x, num_heads: int):
@@ -339,33 +403,41 @@ def _pad_for_blocks(q, k, v, block_q, block_k, num_heads=None,
     correct when it uses EXACTLY these conventions — keep this the
     single source. Returns ``(qt, kt, vt, to_tiles, from_tiles,
     tiles)``; ``to_tiles(x, seq_to)`` lays any further (…q- or k-shaped)
-    array out the same way and ``from_tiles(y, seq)`` is its inverse."""
+    array out the same way and ``from_tiles(y, seq)`` is its inverse;
+    both take ``values=True`` for an array of v's kind (v, o, do, dv)."""
     default = _default_block(q.shape[-1] // (num_heads or 1))
     t = _tiles(q.shape, k.shape, block_q or default, block_k or default,
-               num_heads, window)
-    b, d = t.batch, t.head_dim
+               num_heads, window, v.shape)
+    b = t.batch
 
-    def to_tiles(x, seq_to):
+    def widths(values):
+        """(a head's width, its block's) of an array of q's or v's kind."""
+        return (t.head_dim_v, t.width_v) if values else \
+            (t.head_dim, t.width)
+
+    def to_tiles(x, seq_to, values=False):
         if t.layout == "packed":
             pad = seq_to - x.shape[1]
             return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+        d, width = widths(values)
         if num_heads is not None:
             x = _split_heads(x, x.shape[-1] // d)
-        pad_s, pad_d = seq_to - x.shape[2], t.width - d
+        pad_s, pad_d = seq_to - x.shape[2], width - d
         if pad_s or pad_d:
             x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_s), (0, pad_d)))
-        return x.reshape(b * x.shape[1], seq_to, t.width)
+        return x.reshape(b * x.shape[1], seq_to, width)
 
-    def from_tiles(y, seq):
+    def from_tiles(y, seq, values=False):
         if t.layout == "packed":
             return y if y.shape[1] == seq else y[:, :seq]
-        y = y.reshape(b, y.shape[0] // b, y.shape[1], t.width)
-        if y.shape[2] != seq or t.width != d:
+        d, width = widths(values)
+        y = y.reshape(b, y.shape[0] // b, y.shape[1], width)
+        if y.shape[2] != seq or width != d:
             y = y[:, :, :seq, :d]
         return y if num_heads is None else _merge_heads(y)
 
-    return (to_tiles(q, t.sqp), to_tiles(k, t.skp), to_tiles(v, t.skp),
-            to_tiles, from_tiles, t)
+    return (to_tiles(q, t.sqp), to_tiles(k, t.skp),
+            to_tiles(v, t.skp, True), to_tiles, from_tiles, t)
 
 
 def _head_group(bh: int, block_q: int, block_k: int,
@@ -416,7 +488,8 @@ def _vmem_limit(g: int, t: _Tiles, itemsize: int, n_blocks: int,
     the BERT-shape forward asked for 16.42 MiB of the 16 MiB a kernel
     gets without a limit. Never below that default."""
     from .kernels import VMEM_SCOPED_DEFAULT_BYTES
-    block = g * max(t.block_q, t.block_k) * (-(-t.width // 128) * 128)
+    block = g * max(t.block_q, t.block_k) \
+        * (-(-max(t.width, t.width_v) // 128) * 128)
     need = (2 * n_blocks * block * itemsize + n_acc * block * 4
             + n_tiles * g * t.heads * t.block_q * t.block_k * 4
             + 2 * n_rows * g * t.heads * t.block_q * 128 * 4)
@@ -450,6 +523,75 @@ def _by_head(parts, masks):
     for part, mask in zip(parts[1:], masks[1:]):
         out = jnp.where(mask, part, out)
     return out
+
+
+class _Lanes(NamedTuple):
+    """Where the ``heads`` heads of one (g, rows, width) block lie: side
+    by side, ``width // heads`` lanes apiece. A kernel reaches head i
+    through its ``span``, the whole lane tiles that hold it: the block
+    itself when that is one head or one tile (every width that divides
+    128 or is a multiple of it), else a 128-aligned slice of it in which
+    a neighbour's lanes are masked (a head of 192 lanes: two tiles, half
+    of one the neighbour's)."""
+    heads: int
+    width: int
+
+    @property
+    def whole(self) -> bool:
+        """Every head's span is the block itself."""
+        return self.heads == 1 or self.width <= 128
+
+    def span(self, i: int) -> tuple:
+        if self.whole:
+            return 0, self.width
+        d = self.width // self.heads
+        return i * d // 128 * 128, -(-(i + 1) * d // 128) * 128
+
+    def cut(self, x, i: int):
+        """Head i's span of a block ``x``."""
+        lo, hi = self.span(i)
+        return x if (lo, hi) == (0, self.width) else x[:, :, lo:hi]
+
+    def masks(self, shape) -> tuple:
+        """One mask per head over its span of a block of ``shape``: True
+        on the head's own lanes, None where the span holds no other."""
+        if self.whole:
+            return _head_masks(shape, self.heads)
+        d, out = self.width // self.heads, []
+        for i in range(self.heads):
+            lo, hi = self.span(i)
+            if (lo, hi) == (i * d, (i + 1) * d):
+                out.append(None)
+                continue
+            lane = lo + lax.broadcasted_iota(
+                jnp.int32, (shape[0], shape[1], hi - lo), 2)
+            out.append((lane >= i * d) & (lane < (i + 1) * d))
+        return tuple(out)
+
+    def join(self, parts, masks):
+        """One (g, rows, width) value from one per head, each over its
+        span (or a per-row scalar (g, rows, 1)), each taken on the
+        head's own lanes; ``masks`` as ``masks()`` gave them."""
+        if self.whole:
+            return _by_head(parts, masks)
+        # lane tile by lane tile: the part of the one head that fills it,
+        # or of the heads that share it, each from its first lane on
+        d, tiles = self.width // self.heads, []
+        for lo_t in range(0, self.width, 128):
+            out = None
+            for i, part in enumerate(parts):
+                if (i + 1) * d <= lo_t or i * d >= lo_t + 128:
+                    continue
+                at = lo_t - self.span(i)[0]
+                if part.shape[2] != 1:
+                    part = part[:, :, at:at + 128]
+                if out is None:
+                    out = jnp.broadcast_to(part, part.shape[:2] + (128,))
+                    continue
+                lane = lo_t + lax.broadcasted_iota(jnp.int32, out.shape, 2)
+                out = jnp.where(lane >= i * d, part, out)
+            tiles.append(out)
+        return jnp.concatenate(tiles, axis=2)
 
 
 def _causal_block_skip(qi, ki, block_q, block_k, seq_q, seq_k,
@@ -592,7 +734,7 @@ def _kv_index(t: _Tiles, walk: _Walk):
 # ---------------------------------------------------------------------------
 
 def _flash_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q, seq_k,
-                  need_mask, heads, window=None):
+                  need_mask, lanes, lanes_v, window=None):
     from jax.experimental import pallas as pl
     *tables, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
     # the grid walks the blocks that hold a pair alone (_walk): every
@@ -610,8 +752,11 @@ def _flash_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q, seq_k,
     # passes
     q = q_ref[...]                                # (G, block_q, width)
     k = k_ref[...]                                # (G, block_k, width)
-    v = v_ref[...]
-    masks = _head_masks(q.shape, heads)
+    v = v_ref[...]                                # (G, block_k, width_v)
+    heads = lanes.heads
+    masks = lanes.masks(q.shape)
+    # one width for q, k and v: the same masks pick a head's lanes of o
+    omasks = masks if lanes_v == lanes else lanes_v.masks(acc_s.shape)
     valid = None
     if need_mask or causal:
         # masking is real VPU work on a (bq, bk) tile — emitted only
@@ -627,7 +772,7 @@ def _flash_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q, seq_k,
                 valid = valid & (k_pos > q_pos - window)
     alphas, pvs = [], []
     for i, mask in enumerate(masks):              # static: heads <= 16
-        s = lax.dot_general(_only(q, mask), k,
+        s = lax.dot_general(_only(lanes.cut(q, i), mask), lanes.cut(k, i),
                             (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * sm_scale
         if valid is not None:
@@ -642,20 +787,21 @@ def _flash_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q, seq_k,
         l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
         alphas.append(alpha)
         pvs.append(lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            p.astype(v.dtype), lanes_v.cut(v, i),
+            (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32))
-    acc_s[...] = acc_s[...] * _by_head(alphas, masks) \
-        + _by_head(pvs, masks)
+    acc_s[...] = acc_s[...] * lanes_v.join(alphas, omasks) \
+        + lanes_v.join(pvs, omasks)
 
     @pl.when(last)
     def _finalize():
-        masks = _head_masks(acc_s.shape, heads)
+        masks = lanes_v.masks(acc_s.shape)
         ms = [m_s[i, :, :, :1] for i in range(heads)]
         ls = [jnp.maximum(l_s[i, :, :, :1], 1e-30) for i in range(heads)]
-        out = acc_s[...] / _by_head(ls, masks)
+        out = acc_s[...] / lanes_v.join(ls, masks)
         # rows that never saw a valid key (m still at init) output zero —
         # the shared convention across every path in this module
-        out = jnp.where(_by_head(ms, masks) > _NEG_INF / 2, out, 0.0)
+        out = jnp.where(lanes_v.join(ms, masks) > _NEG_INF / 2, out, 0.0)
         o_ref[...] = out.astype(o_ref.dtype)
         for i, (m, l) in enumerate(zip(ms, ls)):
             # log-sum-exp per row: the residual the backward kernels need
@@ -687,36 +833,41 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
         q, k, v, block_q, block_k, num_heads, window)
     g = _head_group(t.rows, t.block_q, t.block_k, heads_per_block=t.heads,
                     row_group=t.row_group)
-    w = t.width
+    w, wv = t.width, t.width_v
 
     walk = _walk(_live_blocks(t, causal))
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=t.block_q,
         block_k=t.block_k, seq_q=t.seq_q, seq_k=t.seq_k,
-        need_mask=(t.skp != t.seq_k), heads=t.heads, window=t.window)
+        need_mask=(t.skp != t.seq_k), lanes=t.lanes, lanes_v=t.lanes_v,
+        window=t.window)
     grid = (t.rows // g, t.col_tiles) + walk.axes
     _count_grid_steps(grid)
-    q_spec = pl.BlockSpec((g, t.block_q, w),
-                          lambda r, c, *at: (r, walk.row(*at), c))
+    q_at = lambda r, c, *at: (r, walk.row(*at), c)
+    q_spec = pl.BlockSpec((g, t.block_q, w), q_at)
+    o_spec = q_spec if wv == w else pl.BlockSpec((g, t.block_q, wv), q_at)
     k_spec = pl.BlockSpec((g, t.block_k, w), _kv_index(t, walk))
+    v_spec = k_spec if wv == w else \
+        pl.BlockSpec((g, t.block_k, wv), _kv_index(t, walk))
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=grid,
-            in_specs=[q_spec, k_spec, k_spec],
+            in_specs=[q_spec, k_spec, v_spec],
             out_specs=[
-                q_spec,
+                o_spec,
                 pl.BlockSpec((g, t.heads, t.block_q, 8),
                              lambda r, c, *at: (r, c, walk.row(*at), 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
                 pltpu.VMEM((t.heads, g, t.block_q, 128), jnp.float32),
-                pltpu.VMEM((g, t.block_q, w), jnp.float32),
+                pltpu.VMEM((g, t.block_q, wv), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            jax.ShapeDtypeStruct(qt.shape[:2] + (t.col_tiles * wv,),
+                                 q.dtype),
             jax.ShapeDtypeStruct(
                 (t.rows, t.col_tiles * t.heads, t.sqp, 8), jnp.float32),
         ],
@@ -728,7 +879,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
                                          1 + 3 * t.heads, 2, 1)),
         interpret=interpret,
     )(*walk.tables, qt, kt, vt)
-    return from_tiles(out, t.seq_q), lse
+    return from_tiles(out, t.seq_q, True), lse
 
 
 # ---------------------------------------------------------------------------
@@ -749,11 +900,12 @@ def _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k, window=None):
     return valid
 
 
-def _bwd_head(q, k, v, do, lse, delta, qmask, valid, sm_scale):
+def _bwd_head(q, k, v, do, lse, delta, qmask, omask, valid, sm_scale):
     """One head's softmax block rebuilt from its log-sum-exp, and the
-    score gradient: (p, ds), both f32 (G, bq, bk). ``qmask`` picks the
-    head's lanes of the q-side operands (q, do); k and v need none, the
-    zeroed lanes of the other side cancel theirs."""
+    score gradient: (p, ds), both f32 (G, bq, bk). The operands are the
+    head's spans of their blocks (``_Lanes.cut``); ``qmask`` and
+    ``omask`` pick the head's lanes of the q-side operands (q; do); k
+    and v need none, the zeroed lanes of the other side cancel theirs."""
     # operands keep the input dtype (bf16 under AMP), f32 accumulate
     # — see the forward kernel's MXU-pass note
     s = lax.dot_general(_only(q, qmask), k, (((2,), (2,)), ((0,), (0,))),
@@ -761,13 +913,23 @@ def _bwd_head(q, k, v, do, lse, delta, qmask, valid, sm_scale):
     p = jnp.exp(s - lse)                            # (G, bq, bk)
     if valid is not None:
         p = jnp.where(valid[None], p, 0.0)
-    dp = lax.dot_general(_only(do, qmask), v, (((2,), (2,)), ((0,), (0,))),
+    dp = lax.dot_general(_only(do, omask), v, (((2,), (2,)), ((0,), (0,))),
                          preferred_element_type=jnp.float32)
     return p, p * (dp - delta) * sm_scale
 
 
+def _bwd_masks(lanes, lanes_v, q, k, v, do):
+    """The heads' lane masks of the backward kernels' four kinds of
+    block, (q, do, k, v): with one width on both sides the q block's
+    masks serve do and the k block's serve v."""
+    qmasks, kmasks = lanes.masks(q.shape), lanes.masks(k.shape)
+    if lanes_v == lanes:
+        return qmasks, qmasks, kmasks, kmasks
+    return qmasks, lanes_v.masks(do.shape), kmasks, lanes_v.masks(v.shape)
+
+
 def _flash_bwd_dkv_kernel(*refs, blocks_a_head, sm_scale, causal, block_q,
-                          block_k, seq_q, seq_k, need_mask, heads,
+                          block_k, seq_q, seq_k, need_mask, lanes, lanes_v,
                           window=None):
     from jax.experimental import pallas as pl
     (*tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
@@ -784,25 +946,26 @@ def _flash_bwd_dkv_kernel(*refs, blocks_a_head, sm_scale, causal, block_q,
 
     q = q_ref[...]                                  # (G, bq, width)
     k = k_ref[...]                                  # (G, bk, width)
-    v = v_ref[...]
-    do = do_ref[...]                                # (G, bq, width)
-    qmasks = _head_masks(q.shape, heads)
-    kmasks = _head_masks(k.shape, heads)
+    v = v_ref[...]                                  # (G, bk, width_v)
+    do = do_ref[...]                                # (G, bq, width_v)
+    qmasks, omasks, kmasks, vmasks = _bwd_masks(lanes, lanes_v, q, k, v, do)
     valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
                       window) if need_mask or causal else None
     dks, dvs = [], []
-    for i, qmask in enumerate(qmasks):
-        p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
-                          delta_ref[:, i][:, :, :1], qmask, valid,
+    for i, (qmask, omask) in enumerate(zip(qmasks, omasks)):
+        q_i, do_i = lanes.cut(q, i), lanes_v.cut(do, i)
+        p, ds = _bwd_head(q_i, lanes.cut(k, i), lanes_v.cut(v, i), do_i,
+                          lse_ref[:, i][:, :, :1],
+                          delta_ref[:, i][:, :, :1], qmask, omask, valid,
                           sm_scale)
-        dvs.append(lax.dot_general(p.astype(do.dtype), do,
+        dvs.append(lax.dot_general(p.astype(do.dtype), do_i,
                                    (((1,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-        dks.append(lax.dot_general(ds.astype(q.dtype), q,
+        dks.append(lax.dot_general(ds.astype(q.dtype), q_i,
                                    (((1,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-    dv_s[...] += _by_head(dvs, kmasks)
-    dk_s[...] += _by_head(dks, kmasks)
+    dv_s[...] += lanes_v.join(dvs, vmasks)
+    dk_s[...] += lanes.join(dks, kmasks)
 
     @pl.when(last)
     def _finalize():
@@ -813,7 +976,7 @@ def _flash_bwd_dkv_kernel(*refs, blocks_a_head, sm_scale, causal, block_q,
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                             dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
                             block_q, block_k, seq_q, seq_k, need_mask,
-                            heads, window=None):
+                            lanes, lanes_v, window=None):
     """Single-block backward (nq == nk == 1, the short-seq fast path):
     one program computes dq, dk AND dv, reconstructing the softmax block
     ONCE — the two-kernel general path pays the s = qk^T + exp recompute
@@ -822,33 +985,36 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     XLA pass over dO and O precedes the call."""
     q = q_ref[...]                                  # (G, bq, width)
     k = k_ref[...]                                  # (G, bk, width)
-    v = v_ref[...]
-    do = do_ref[...]
+    v = v_ref[...]                                  # (G, bk, width_v)
+    do = do_ref[...]                                # (G, bq, width_v)
     do_o = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
-    qmasks = _head_masks(q.shape, heads)
-    kmasks = _head_masks(k.shape, heads)
+    qmasks, omasks, kmasks, vmasks = _bwd_masks(lanes, lanes_v, q, k, v, do)
     valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k,
                       window) if need_mask or causal else None
     dqs, dks, dvs = [], [], []
-    for i, qmask in enumerate(qmasks):
-        delta = _only(do_o, qmask).sum(axis=2, keepdims=True)
-        p, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1], delta,
-                          qmask, valid, sm_scale)
+    for i, (qmask, omask) in enumerate(zip(qmasks, omasks)):
+        q_i, k_i, do_i = lanes.cut(q, i), lanes.cut(k, i), \
+            lanes_v.cut(do, i)
+        delta = _only(lanes_v.cut(do_o, i), omask).sum(axis=2,
+                                                       keepdims=True)
+        p, ds = _bwd_head(q_i, k_i, lanes_v.cut(v, i), do_i,
+                          lse_ref[:, i][:, :, :1], delta, qmask, omask,
+                          valid, sm_scale)
         ds = ds.astype(k.dtype)
-        dvs.append(lax.dot_general(p.astype(do.dtype), do,
+        dvs.append(lax.dot_general(p.astype(do.dtype), do_i,
                                    (((1,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-        dqs.append(lax.dot_general(ds, k, (((2,), (1,)), ((0,), (0,))),
+        dqs.append(lax.dot_general(ds, k_i, (((2,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-        dks.append(lax.dot_general(ds, q, (((1,), (1,)), ((0,), (0,))),
+        dks.append(lax.dot_general(ds, q_i, (((1,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-    dq_ref[...] = _by_head(dqs, qmasks).astype(dq_ref.dtype)
-    dk_ref[...] = _by_head(dks, kmasks).astype(dk_ref.dtype)
-    dv_ref[...] = _by_head(dvs, kmasks).astype(dv_ref.dtype)
+    dq_ref[...] = lanes.join(dqs, qmasks).astype(dq_ref.dtype)
+    dk_ref[...] = lanes.join(dks, kmasks).astype(dk_ref.dtype)
+    dv_ref[...] = lanes_v.join(dvs, vmasks).astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q,
-                         seq_k, need_mask, heads, window=None):
+                         seq_k, need_mask, lanes, lanes_v, window=None):
     from jax.experimental import pallas as pl
     (*tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
      dq_s) = refs
@@ -860,20 +1026,23 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q,
 
     q = q_ref[...]                                  # (G, bq, width)
     k = k_ref[...]                                  # (G, bk, width)
-    v = v_ref[...]
-    do = do_ref[...]
-    qmasks = _head_masks(q.shape, heads)
+    v = v_ref[...]                                  # (G, bk, width_v)
+    do = do_ref[...]                                # (G, bq, width_v)
+    qmasks = lanes.masks(q.shape)
+    omasks = qmasks if lanes_v == lanes else lanes_v.masks(do.shape)
     valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
                       window) if need_mask or causal else None
     dqs = []
-    for i, qmask in enumerate(qmasks):
-        _, ds = _bwd_head(q, k, v, do, lse_ref[:, i][:, :, :1],
-                          delta_ref[:, i][:, :, :1], qmask, valid,
+    for i, (qmask, omask) in enumerate(zip(qmasks, omasks)):
+        k_i = lanes.cut(k, i)
+        _, ds = _bwd_head(lanes.cut(q, i), k_i, lanes_v.cut(v, i),
+                          lanes_v.cut(do, i), lse_ref[:, i][:, :, :1],
+                          delta_ref[:, i][:, :, :1], qmask, omask, valid,
                           sm_scale)
-        dqs.append(lax.dot_general(ds.astype(k.dtype), k,
+        dqs.append(lax.dot_general(ds.astype(k.dtype), k_i,
                                    (((2,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
-    dq_s[...] += _by_head(dqs, qmasks)
+    dq_s[...] += lanes.join(dqs, qmasks)
 
     @pl.when(last)
     def _finalize():
@@ -899,31 +1068,34 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         q, k, v, block_q, block_k, num_heads, window)
     sq, sk = t.seq_q, t.seq_k
     do = do.astype(q.dtype)
-    dot = to_tiles(do, t.sqp)
+    dot = to_tiles(do, t.sqp, True)
     g = _head_group(t.rows, t.block_q, t.block_k, n_tiles=4,
                     heads_per_block=t.heads, row_group=t.row_group)
     need_mask = (t.skp != sk) or (t.sqp != sq)
-    w, heads = t.width, t.heads
+    w, wv, heads = t.width, t.width_v, t.heads
     static = dict(sm_scale=sm_scale, causal=causal, block_q=t.block_q,
                   block_k=t.block_k, seq_q=sq, seq_k=sk,
-                  need_mask=need_mask, heads=heads, window=t.window)
+                  need_mask=need_mask, lanes=t.lanes, lanes_v=t.lanes_v,
+                  window=t.window)
     q_shape = jax.ShapeDtypeStruct(qt.shape, q.dtype)
     k_shape = jax.ShapeDtypeStruct(kt.shape, k.dtype)
+    v_shape = jax.ShapeDtypeStruct(vt.shape, v.dtype)
 
     if t.nq == 1 and t.nk == 1 and t.group == 1:
-        bspec = lambda blk: pl.BlockSpec((g, blk, w),
-                                         lambda r, c: (r, 0, c))
+        bspec = lambda blk, width=w: pl.BlockSpec((g, blk, width),
+                                                  lambda r, c: (r, 0, c))
         rspec = pl.BlockSpec((g, heads, t.block_q, 8),
                              lambda r, c: (r, c, 0, 0))
         _count_grid_steps((t.rows // g, t.col_tiles))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_fused_kernel, **static),
             grid=(t.rows // g, t.col_tiles),
-            in_specs=[bspec(t.block_q), bspec(t.block_k), bspec(t.block_k),
-                      bspec(t.block_q), bspec(t.block_q), rspec],
+            in_specs=[bspec(t.block_q), bspec(t.block_k),
+                      bspec(t.block_k, wv), bspec(t.block_q, wv),
+                      bspec(t.block_q, wv), rspec],
             out_specs=[bspec(t.block_q), bspec(t.block_k),
-                       bspec(t.block_k)],
-            out_shape=[q_shape, k_shape, k_shape],
+                       bspec(t.block_k, wv)],
+            out_shape=[q_shape, k_shape, v_shape],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 # q, k, v, o, do in, dq, dk, dv out; dO*O and three
@@ -931,8 +1103,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                 vmem_limit_bytes=_vmem_limit(g, t, q.dtype.itemsize, 8,
                                              1 + 3 * heads, 4, 1)),
             interpret=interpret,
-        )(qt, kt, vt, to_tiles(o, t.sqp), dot, lse)
-        return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
+        )(qt, kt, vt, to_tiles(o, t.sqp, True), dot, lse)
+        return (from_tiles(dq, sq), from_tiles(dk, sk),
+                from_tiles(dv, sk, True))
 
     # delta_i = rowsum(dO_i * O_i), per head, laid out as the lse is
     # (cheap; XLA fuses it into one pass over dO and O)
@@ -942,7 +1115,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     else:
         # reduce first: the transpose then moves one number a head
         delta = delta.reshape(t.batch, sq, t.num_heads,
-                              t.head_dim).sum(-1).transpose(0, 2, 1)
+                              t.head_dim_v).sum(-1).transpose(0, 2, 1)
     delta = delta.reshape(lse.shape[0], lse.shape[1], sq)
     if t.sqp != sq:
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t.sqp - sq)))
@@ -955,16 +1128,20 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     _count_grid_steps(grid)
     q_spec = pl.BlockSpec((g, t.block_q, w),
                           lambda r, c, *at: (r, walk.row(*at), c))
+    do_spec = q_spec if wv == w else pl.BlockSpec(
+        (g, t.block_q, wv), lambda r, c, *at: (r, walk.row(*at), c))
     row_spec = pl.BlockSpec((g, heads, t.block_q, 8),
                             lambda r, c, *at: (r, c, walk.row(*at), 0))
     k_in = pl.BlockSpec((g, t.block_k, w), _kv_index(t, walk))
+    v_in = k_in if wv == w else \
+        pl.BlockSpec((g, t.block_k, wv), _kv_index(t, walk))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=grid,
-            in_specs=[q_spec, k_in, k_in, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, k_in, v_in, do_spec, row_spec, row_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((g, t.block_q, w), jnp.float32)]),
         out_shape=q_shape,
@@ -993,7 +1170,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         return row, col, qi, 0
     k_spec = pl.BlockSpec((g, t.block_k, w),
                           lambda r, c, *at: (r, walk.row(*at), c))
+    v_spec = k_spec if wv == w else pl.BlockSpec(
+        (g, t.block_k, wv), lambda r, c, *at: (r, walk.row(*at), c))
     qrow = pl.BlockSpec((g, t.block_q, w), q_at)
+    dorow = qrow if wv == w else pl.BlockSpec((g, t.block_q, wv), q_at)
     rrow = pl.BlockSpec((g, heads, t.block_q, 8), stat_at)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **static,
@@ -1001,11 +1181,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=grid,
-            in_specs=[qrow, k_spec, k_spec, qrow, rrow, rrow],
-            out_specs=[k_spec, k_spec],
+            in_specs=[qrow, k_spec, v_spec, dorow, rrow, rrow],
+            out_specs=[k_spec, v_spec],
             scratch_shapes=[pltpu.VMEM((g, t.block_k, w), jnp.float32),
-                            pltpu.VMEM((g, t.block_k, w), jnp.float32)]),
-        out_shape=[k_shape, k_shape],
+                            pltpu.VMEM((g, t.block_k, wv), jnp.float32)]),
+        out_shape=[k_shape, v_shape],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_semantics(grid),
             # q, k, v, do in, dk, dv out; dk, dv scratch and two
@@ -1015,7 +1195,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         interpret=interpret,
     )(*walk.tables, qt, kt, vt, dot, lse, delta)
 
-    return from_tiles(dq, sq), from_tiles(dk, sk), from_tiles(dv, sk)
+    return (from_tiles(dq, sq), from_tiles(dk, sk),
+            from_tiles(dv, sk, True))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -1091,7 +1272,8 @@ def _flash_vl_bwd(causal, sm_scale, res, g):
 _flash_vl.defvjp(_flash_vl_fwd, _flash_vl_bwd)
 
 
-def _kernel_tier(q, k, num_heads, use_pallas, window=None) -> Optional[bool]:
+def _kernel_tier(q, k, v, num_heads, use_pallas,
+                 window=None) -> Optional[bool]:
     """Which tier takes this call: None for the XLA reference, else
     whether the kernel bodies run interpreted. ``use_pallas`` None asks
     the shared MXNET_PALLAS three-tier gate (ops/kernels): compiled
@@ -1099,7 +1281,8 @@ def _kernel_tier(q, k, num_heads, use_pallas, window=None) -> Optional[bool]:
     blockwise-XLA reference otherwise. A call the kernels take is
     counted by the layout its shapes gave it, and the gate's recorded
     reason says which."""
-    tiles = _tiles(q.shape, k.shape, _BLOCK_Q, _BLOCK_K, num_heads, window)
+    tiles = _tiles(q.shape, k.shape, _BLOCK_Q, _BLOCK_K, num_heads, window,
+                   v.shape)
     if use_pallas is None:
         from .kernels import dispatch as _kdispatch
         path, _ = _kdispatch("flash_attention", detail=tiles.reason)
@@ -1143,7 +1326,9 @@ def flash_attention(q, k, v, causal: bool = False,
 
     k and v may hold fewer heads than q (``num_kv_heads``, read off their
     shape when not given): query head n reads head n // (H // Hkv), and
-    no tier repeats k or v in memory. ``window`` (with ``causal``) hides
+    no tier repeats k or v in memory. v's heads may have another width
+    than q's and k's (latent attention: 192 beside 128); the result has
+    v's, and no tier pads v to q's. ``window`` (with ``causal``) hides
     key j from query i unless 0 <= i - j < window; the kernels' grids
     hold neither the blocks wholly outside it nor those above the
     diagonal (``_walk``).
@@ -1161,7 +1346,7 @@ def flash_attention(q, k, v, causal: bool = False,
                              "neither a window nor grouped K/V heads")
         vl = jnp.asarray(valid_length, jnp.float32)
         return _flash_vl(q, k, v, vl, causal, float(sm_scale))
-    interpret = _kernel_tier(q, k, None, use_pallas, window)
+    interpret = _kernel_tier(q, k, v, None, use_pallas, window)
     if interpret is None:
         return _flash(q, k, v, causal, float(sm_scale), window)
     # full-Pallas path: flash forward AND FlashAttention-2-style
@@ -1177,7 +1362,9 @@ def flash_attention_bsh(q, k, v, num_heads: int, causal: bool = False,
     """:func:`flash_attention` on (B, S, H*D) tensors, heads side by
     side on the last axis as a projection emits them and as the output
     projection eats them; returns (B, S, H*D). k and v are
-    (B, S, Hkv*D), Hkv = ``num_kv_heads`` (``num_heads`` when None).
+    (B, S, Hkv*D), Hkv = ``num_kv_heads`` (``num_heads`` when None);
+    v may be (B, S, Hkv*Dv) with heads of another width, and the result
+    is then (B, S, H*Dv).
 
     When the head width divides 128 (and H*D is a multiple of 128) or
     is a multiple of it, the Pallas kernels read and write these arrays
@@ -1198,11 +1385,14 @@ def flash_attention_bsh(q, k, v, num_heads: int, causal: bool = False,
                          f"not a multiple of the head width {d}")
     kv_heads = k.shape[-1] // d
     _check_kv_heads(num_kv_heads, kv_heads)
+    if v.shape[-1] % kv_heads:
+        raise MXNetError(f"flash_attention_bsh: V width {v.shape[-1]} not "
+                         f"divisible by its {kv_heads} heads")
     window = _window_of(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     _count_mask(causal, window)
-    interpret = _kernel_tier(q, k, num_heads, None, window)
+    interpret = _kernel_tier(q, k, v, num_heads, None, window)
     if interpret is not None:
         return _flash_tpu(q, k, v, causal, float(sm_scale), interpret,
                           num_heads, window)

@@ -163,13 +163,14 @@ def dispatch(kernel: str, supported: bool = True,
     return out
 
 
-def count_traced(metric: str, label_key: str, label: str,
-                 n: int = 1) -> None:
+def count_traced(metric: str, label_key: Optional[str] = None,
+                 label: Optional[str] = None, n: int = 1) -> None:
     """``n`` more (one, unless said) under ``label`` of the labelled
-    counter ``telemetry.names.<metric>``: what the op layer counts while
-    a call is traced (dispatch path, flash layout and grid steps,
-    attention mask, expert dispatch and row movers). Telemetry must never
-    fail a kernel call."""
+    counter ``telemetry.names.<metric>`` (or of one without labels): what
+    the op layer counts while a call is traced (dispatch path, flash
+    layout and grid steps, attention mask and form, expert dispatch,
+    router rule and row movers, MTP modules). Telemetry must never fail a
+    kernel call."""
     try:
         from ...telemetry import names as tn
         from ...telemetry import registry as treg

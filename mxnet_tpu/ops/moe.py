@@ -24,20 +24,22 @@ router and experts.
 
 The DROPLESS layer (``moe_route`` / ``moe_experts`` / ``moe_combine``,
 behind ``gluon.nn.SparseMoE``) is a different layer, not a second path of
-the one above: no capacity and no dropped token at any imbalance, a
-softmax over the chosen logits only, gated (ReGLU) experts, and a layer
-that is told WHICH experts it holds. It routes over all ``E`` experts and
-computes its own part of the sum:
+the one above: no capacity and no dropped token at any imbalance, weights
+over the chosen experts only, gated experts (ReGLU or SwiGLU), and a
+layer that is told WHICH experts it holds. It routes over all ``E``
+experts and computes its own part of the sum:
 
-- ``moe_route``: router logits, top-k and the softmax of the chosen
-  logits in float32; the token-expert pairs whose expert is held, sorted
-  by expert (a stable argsort; pairs of experts held elsewhere sort behind
-  them), each pair's place in that order, and the held experts' group
-  sizes;
+- ``moe_route``: router logits, the top-k choice and the chosen experts'
+  weights in float32, by the model's score rule (``SCORES``: a softmax
+  of the chosen logits, or sigmoid scores with a bias that picks but
+  never weighs, normalised over the chosen and scaled); the token-expert
+  pairs whose expert is held, sorted by expert (a stable argsort; pairs
+  of experts held elsewhere sort behind them), each pair's place in that
+  order, and the held experts' group sizes;
 - ``moe_experts``: the sorted pairs' tokens gathered, one grouped (ragged)
-  matrix product a projection over the experts held, ReGLU between. The
-  product is ``lax.ragged_dot``, told the group sizes, so rows past the
-  last group are never multiplied;
+  matrix product a projection over the experts held, the gate's
+  activation between. The product is ``lax.ragged_dot``, told the group
+  sizes, so rows past the last group are never multiplied;
 - ``moe_combine``: each token's chosen outputs weighted and summed in
   float32, the sort's inverse.
 
@@ -71,7 +73,14 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["moe_gating", "moe_ffn", "moe_route", "moe_experts",
-           "moe_combine", "routing_counts"]
+           "moe_combine", "routing_counts", "SCORES", "ACTIVATIONS"]
+
+#: the score rules ``moe_route`` knows, as ``mx_moe_router_total`` labels
+#: them
+SCORES = ("softmax", "sigmoid")
+#: the gate activations of ``moe_experts`` by their configs' names: ReGLU
+#: and SwiGLU experts
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
 def moe_gating(x, gate_w, num_experts: int, top_k: int = 2,
@@ -181,12 +190,22 @@ def moe_ffn(x, gate_w, w1, w2, top_k: int = 2, capacity_factor: float = 1.25,
 # the dropless layer: route, grouped experts, combine
 # ---------------------------------------------------------------------------
 
-def moe_route(x, router_w, top_k: int, held):
+def moe_route(x, router_w, top_k: int, held, score: str = "softmax",
+              bias=None, scale: float = 1.0):
     """Router of the dropless layer. ``x`` (N, d); ``router_w`` (E, d),
     all E experts whichever are held; ``held = (first, count)``.
 
-    Returns ``(weights, order, place, sizes)``: ``weights`` (N, k) f32,
-    the softmax over each token's k chosen logits; ``order`` (rows,)
+    ``score`` is the rule that chooses and weighs:
+
+    - ``"softmax"``: the top-k logits, weighed by the softmax over those
+      k logits;
+    - ``"sigmoid"``: scores s = sigmoid(logits); the top-k of s +
+      ``bias`` ((E,), a selection bias that picks and never weighs: no
+      gradient reaches it), weighed by ``scale * s / sum of the chosen
+      s``.
+
+    Returns ``(weights, order, place, sizes)``: ``weights`` (N, k) f32;
+    ``order`` (rows,)
     int32, the pairs (token * k + choice) of held experts sorted by
     expert, rows = N * min(k, count); ``place`` (N, k) int32, where each
     pair stands in that order (pairs of experts held elsewhere stand at
@@ -198,8 +217,19 @@ def moe_route(x, router_w, top_k: int, held):
     logits = jnp.einsum("nd,ed->ne", x.astype(jnp.float32),
                         router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    top_vals, top_idx = lax.top_k(logits, top_k)
-    weights = jax.nn.softmax(top_vals, axis=-1)
+    if score == "softmax":
+        top_vals, top_idx = lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(top_vals, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        picked = scores if bias is None else \
+            scores + lax.stop_gradient(bias.astype(jnp.float32))[None, :]
+        _, top_idx = lax.top_k(lax.stop_gradient(picked), top_k)
+        chosen = jnp.take_along_axis(scores, top_idx, axis=1)
+        weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"moe_route: no score rule {score!r}; "
+                         f"it knows {SCORES}")
     # each pair's sort key: its expert's index among those held, or
     # ``count`` for an expert held elsewhere
     local = top_idx.reshape(-1) - first
@@ -217,10 +247,11 @@ def moe_route(x, router_w, top_k: int, held):
     return weights, order[:n * min(top_k, count)], place, totals[:count]
 
 
-def routing_counts(x, router_w, top_k: int, held):
+def routing_counts(x, router_w, top_k: int, held, **rule):
     """``(pairs per held expert (count,), held share of all N*k pairs)``
-    of one routing, for tests and PERF.md; not part of any step."""
-    _, _, _, sizes = moe_route(x, router_w, top_k, held)
+    of one routing (``rule``: the score rule's keywords), for tests and
+    PERF.md; not part of any step."""
+    _, _, _, sizes = moe_route(x, router_w, top_k, held, **rule)
     return sizes, sizes.sum() / (x.shape[0] * top_k)
 
 
@@ -289,17 +320,19 @@ def _dispatch_bwd(res, g):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def moe_experts(x, order, place, sizes, w_gate, w_up, w_down):
+def moe_experts(x, order, place, sizes, w_gate, w_up, w_down,
+                activation: str = "relu"):
     """The held experts on the sorted pairs. ``x`` (N, d); ``order``,
     ``place``, ``sizes`` from :func:`moe_route`; ``w_gate``, ``w_up``
     (count, f, d) and ``w_down`` (count, d, f), expert e's
-    ``y = W_down (relu(W_gate x) * (W_up x))``. Returns (rows, d), row p
-    the output for pair ``order[p]``; rows past the last group are
-    zero (``moe_combine`` never reads them)."""
+    ``y = W_down (act(W_gate x) * (W_up x))``, ``act`` one of
+    ``ACTIVATIONS`` (``relu``: ReGLU, ``silu``: SwiGLU). Returns (rows,
+    d), row p the output for pair ``order[p]``; rows past the last group
+    are zero (``moe_combine`` never reads them)."""
     xs = _dispatch(x, order, place, sizes)
     gate = _grouped_dot(xs, w_gate, sizes)
     up = _grouped_dot(xs, w_up, sizes)
-    return _grouped_dot(jax.nn.relu(gate) * up, w_down, sizes)
+    return _grouped_dot(ACTIVATIONS[activation](gate) * up, w_down, sizes)
 
 
 @jax.custom_vjp

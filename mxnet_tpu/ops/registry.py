@@ -10,6 +10,7 @@ tape recording (see _tape.py), (c) NaiveEngine synchronous mode.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -120,6 +121,20 @@ def _named_scope_kernel(name: str, fn: Callable) -> Callable:
         with jax.named_scope(safe):
             return fn(*args, **kwargs)
     return wrapped
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """``jax.named_scope(name)`` around SEVERAL ops of a block (the
+    projections in front of latent attention, a whole MTP module), so a
+    device trace can sum them under one name; each op inside keeps its
+    own. As ``_named_scope_kernel``: only while a trace is being built."""
+    if _trace_state_clean():
+        yield
+        return
+    import jax
+    with jax.named_scope(name):
+        yield
 
 
 def invoke_raw(name: str, fn: Callable, inputs: Sequence[Any],

@@ -157,6 +157,9 @@ FLASH_ATTENTION_GRID_STEPS = "mx_flash_attention_grid_steps_total"
 ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
 MOE_ROW_MOVER = "mx_moe_row_mover_total"
+MOE_ROUTER = "mx_moe_router_total"
+LATENT_ATTENTION = "mx_latent_attention_total"
+MTP_MODULES = "mx_mtp_modules_total"
 
 # ---------------------------------------------------------------------------
 # inference serving engine (serving/batcher.py)
@@ -502,6 +505,26 @@ CATALOG = {
              "ops/kernels/moe_rows.py, whose work follows the pairs "
              "held; xla = gathers over the whole static list; one count "
              "a traced call site"),
+    MOE_ROUTER: dict(
+        kind="counter", label="score",
+        help="routers of the dropless expert layer by their score rule "
+             "(softmax = the top-k logits weighed by their softmax; "
+             "sigmoid = the top-k of sigmoid scores plus a selection "
+             "bias, weighed by the normalised, scaled scores; ops/moe.py "
+             "moe_route); one count a traced router"),
+    LATENT_ATTENTION: dict(
+        kind="counter", label="form",
+        help="latent-attention (MLA) layers by the form they run in "
+             "(expanded = keys and values written out a head in front "
+             "of the flash kernels, the training form; absorbed = the "
+             "decode form over a cached latent, not built yet; "
+             "gluon/nn/transformer.py LatentAttention); one count a "
+             "traced layer"),
+    MTP_MODULES: dict(
+        kind="counter", label=None,
+        help="multi-token-prediction modules traced behind a trunk, "
+             "each a further block and a second head over the trunk's "
+             "own embedding and head tables (gluon/model_zoo/joyai.py)"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),

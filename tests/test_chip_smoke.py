@@ -128,7 +128,11 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     sparse = dict(chip_smoke.SPARSE, batch=2, seq=32, steps=4, model=dict(
         chip_smoke.SPARSE["model"], hidden_size=128, head_dim=32,
         moe_ffn_hidden_size=32, sliding_window_size=8, vocab_size=128))
-    train = chip_smoke.phase_train(cfg, sparse)
+    latent = dict(chip_smoke.LATENT, batch=2, seq=32, steps=4, model=dict(
+        chip_smoke.LATENT["model"], hidden_size=128, intermediate_size=64,
+        q_lora_rank=64, kv_lora_rank=32, moe_intermediate_size=32,
+        vocab_size=128))
+    train = chip_smoke.phase_train(cfg, sparse, latent)
     assert train["kernel_paths"]["flash_attention"] == "interpret"
     assert train["sparse_lm"]["mx_moe_dispatch_total"] == {"grouped": 4}
     assert train["sparse_lm"]["mx_attention_mask_total"] == {
@@ -143,6 +147,14 @@ def test_phases_rehearse_on_cpu(monkeypatch):
         train["flash_grid_steps"]["live"]
     sparse_steps = train["sparse_lm"]["mx_flash_attention_grid_steps_total"]
     assert sparse_steps["dead"] == 0 and sparse_steps["live"] % 4 == 0
+    # the small JoyAILM: one dense and one expert layer and the MTP
+    # module's, each behind latent attention with keys of 192 lanes beside
+    # values of 128, which the kernels take where they lie
+    assert train["latent_lm"]["mx_latent_attention_total"] == {"expanded": 3}
+    assert train["latent_lm"]["mx_moe_router_total"] == {"sigmoid": 2}
+    assert train["latent_lm"]["flash_layouts"] == {
+        "packed": 3, "unpadded": 0, "padded": 0}
+    assert train["latent_lm"]["loss"][-1] < train["latent_lm"]["loss"][0]
     dp = chip_smoke.phase_dp(train["loss"], cfg)
     assert dp["devices"] == 8 and dp["collectives"]["all-gather"]
     checked = chip_smoke.phase_kernels(tiny=True)
