@@ -113,8 +113,16 @@ SERVE = dict(vocab=256, d_model=128, heads=4, requests=16,
 #: f32 too (0.0013 to 0.0076, PR 21).
 #: TOL_F32 — f32 elementwise kernels (bias-GELU, LayerNorm, optimizer
 #: update): only the exp/rsqrt/pow expansions differ (at most 9e-7, PR 21).
+#: TOL_F32_PRODUCTS — f32 products at HIGHEST on both sides (the grouped
+#: products of the expert layer): every product is exact to f32, but the
+#: tiers add in another order (a contraction tile, a group's rows a visit)
+#: and the router's gradient is a difference of near-equal sums: 4.2e-07
+#: at the SmallThinker cell's shape, 1.6e-05 at the JoyAI cell's (top-8
+#: of 256, an eighth as many pairs held) where one bf16 pass anywhere
+#: reads 4.4e-03 (PR 33).
 TOL_BF16 = 4 * 2.0 ** -8
 TOL_F32 = 1e-5
+TOL_F32_PRODUCTS = 1e-4
 
 #: dp-vs-one-chip loss trajectory, absolute, on a loss that starts near
 #: ln 2: the same program partitioned four ways sums gradients in
@@ -158,7 +166,8 @@ def kernel_cases(tiny: bool = False):
 
     def add(kernel, label, dtype, make, fn, grad_argnums=()):
         dots = kernel in ("flash_attention", "rnn_scan", "rnn_decode_step")
-        tol = TOL_F32 if dtype == "float32" and not dots else TOL_BF16
+        tol = TOL_BF16 if dtype != "float32" or dots else \
+            TOL_F32_PRODUCTS if kernel == "grouped_dot" else TOL_F32
         cases.append(KernelCase(kernel, f"{label} {dtype}", dtype, tol,
                                 make, fn, grad_argnums))
 
@@ -177,7 +186,7 @@ def kernel_cases(tiny: bool = False):
                 f32(rng, g * h, h, scale=h ** -0.5),
                 f32(rng, g * h, scale=0.1))
 
-    def sparse_experts(top_k, held):
+    def sparse_experts(top_k, held, activation="relu"):
         # float32 products at HIGHEST in the kernel tier too, so that both
         # sides multiply alike and the comparison reads the rows moved:
         # at the default precision a float32 product is one bf16 pass,
@@ -192,9 +201,15 @@ def kernel_cases(tiny: bool = False):
                 weights, order, place, sizes = ops_moe.moe_route(
                     x, router_w, top_k, held)
                 y = ops_moe.moe_experts(x, order, place, sizes, w_gate,
-                                        w_up, w_down)
+                                        w_up, w_down, activation)
                 return ops_moe.moe_combine(y, weights, order, place, sizes)
         return layer
+
+    def expert_weights(rng, n, d, f, e, c):
+        return (f32(rng, n, d), f32(rng, e, d),
+                f32(rng, c, f, d, scale=d ** -0.5),
+                f32(rng, c, f, d, scale=d ** -0.5),
+                f32(rng, c, d, f, scale=f ** -0.5))
 
     for dtype in ("bfloat16", "float32"):
         # BERT-base FFN: 32 x 512 tokens, hidden 3072
@@ -284,12 +299,26 @@ def kernel_cases(tiny: bool = False):
             else (8192, 2560, 768, 64, 6, (0, 8))
         add("moe_rows", f"moe_rows {n}x{d} f{f} top{k}/{e} held {held[1]}",
             dtype,
-            lambda rng, n=n, d=d, f=f, e=e, c=held[1]: (
-                f32(rng, n, d), f32(rng, e, d),
-                f32(rng, c, f, d, scale=d ** -0.5),
-                f32(rng, c, f, d, scale=d ** -0.5),
-                f32(rng, c, d, f, scale=f ** -0.5)),
+            lambda rng, n=n, d=d, f=f, e=e, c=held[1]: expert_weights(
+                rng, n, d, f, e, c),
             sparse_experts(k, held), (0, 1, 2, 3, 4))
+
+        # the grouped products between them, ops/kernels/grouped_dot.py
+        # against lax.ragged_dot, at both MoE cells' shapes: the same
+        # layer (49,152 list rows x 2560 x 768, about 6,144 live, ReGLU),
+        # and the JoyAI cell's (4096 tokens of 2048, top-8 of 256, 8
+        # held: 32,768 rows x 2048 x 768, about 1,024 live in groups of
+        # 128, SwiGLU); judged like the row movers, through the layer
+        for (n, d, f, e, k, held), act in (
+                (((128, 128, 128, 8, 2, (2, 4)), "relu"),
+                 ((128, 256, 128, 16, 4, (4, 4)), "silu")) if tiny else
+                (((8192, 2560, 768, 64, 6, (0, 8)), "relu"),
+                 ((4096, 2048, 768, 256, 8, (0, 8)), "silu"))):
+            add("grouped_dot", f"grouped_dot {n * min(k, held[1])}x{d}x{f} "
+                f"top{k}/{e} held {held[1]} {act}", dtype,
+                lambda rng, n=n, d=d, f=f, e=e, c=held[1]: expert_weights(
+                    rng, n, d, f, e, c),
+                sparse_experts(k, held, act), (0, 1, 2, 3, 4))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -603,6 +632,19 @@ def _train_small_lm(net, cfg: dict, x, y, tracked: tuple):
     return losses, counted
 
 
+def _kernel_products(counted: dict, layers: int, model: str) -> None:
+    """Eight grouped products an expert layer (three forward, five
+    backward), each traced at least once, all by the kernels of
+    ops/kernels/grouped_dot.py; ``lax.ragged_dot`` took none."""
+    from mxnet_tpu.telemetry import names
+    products = counted[names.MOE_GROUPED_DOT]
+    if set(products) != {_compiled_tier()} or \
+            products[_compiled_tier()] < 8 * layers:
+        raise RuntimeError(f"{model}'s grouped products took {products}, "
+                           f"expected {_compiled_tier()} alone, "
+                           f"{8 * layers} or more")
+
+
 def _sparse_lm(cfg: dict) -> dict:
     """A small ``SmallThinkerLM`` through ``TrainLoop`` under bf16 AMP:
     fused, traced once, loss falling; → its losses and what the program
@@ -621,7 +663,8 @@ def _sparse_lm(cfg: dict) -> dict:
             for _ in range(2))
     losses, counted = _train_small_lm(
         SmallThinkerLM(cfg["model"]), cfg, x, y,
-        (names.MOE_DISPATCH, names.ATTENTION_MASK, names.MOE_ROW_MOVER))
+        (names.MOE_DISPATCH, names.ATTENTION_MASK, names.MOE_ROW_MOVER,
+         names.MOE_GROUPED_DOT))
     del counted["flash_layouts"]
     layers = cfg["model"]["num_hidden_layers"]
     windowed = sum(cfg["model"]["sliding_window_layout"][:layers])
@@ -639,6 +682,7 @@ def _sparse_lm(cfg: dict) -> dict:
         raise RuntimeError(f"SmallThinkerLM's row movers took {movers}, "
                            f"expected {_compiled_tier()} alone, {3 * layers} "
                            "or more")
+    _kernel_products(counted, layers, "SmallThinkerLM")
     log(f"  SmallThinkerLM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}
 
@@ -664,7 +708,7 @@ def _latent_lm(cfg: dict) -> dict:
     losses, counted = _train_small_lm(
         JoyAILM(model), cfg, x, y,
         (names.LATENT_ATTENTION, names.MOE_ROUTER, names.MTP_MODULES,
-         names.MOE_DISPATCH, names.MOE_ROW_MOVER))
+         names.MOE_DISPATCH, names.MOE_ROW_MOVER, names.MOE_GROUPED_DOT))
     blocks = model["num_hidden_layers"] + model["num_nextn_predict_layers"]
     routers = blocks - model["first_k_dense_replace"]
     if counted[names.LATENT_ATTENTION] != {"expanded": blocks} or \
@@ -681,6 +725,7 @@ def _latent_lm(cfg: dict) -> dict:
     if set(counted[names.MOE_ROW_MOVER]) != {_compiled_tier()}:
         raise RuntimeError("JoyAILM's row movers took "
                            f"{counted[names.MOE_ROW_MOVER]}")
+    _kernel_products(counted, routers, "JoyAILM")
     log(f"  JoyAILM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}
 

@@ -97,8 +97,10 @@ class SparseMoE(HybridBlock):
     when eight chips share a 64-expert layer. The layer computes ITS
     experts' part of each token's sum and nothing else: pairs whose expert
     is held elsewhere are not multiplied, and on a TPU chip not moved
-    back either (behind the products the row movers of
-    ``ops/kernels/moe_rows.py`` walk the held pairs; the XLA tier's
+    back either (the products are the kernels of
+    ``ops/kernels/grouped_dot.py``, whose grid is as long as the groups,
+    and behind them the row movers of ``ops/kernels/moe_rows.py`` walk
+    the held pairs; the XLA tier multiplies by ``lax.ragged_dot`` and its
     gathers run over the whole static list, masked), and nothing stands
     in for the other chips. The parts of all the shares
     add up to the whole layer's output (tests/test_smallthinker.py,

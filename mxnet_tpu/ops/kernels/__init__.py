@@ -15,6 +15,10 @@ Members (each joins the flash-attention kernels in ops/attention.py):
 - :mod:`.moe_rows` — the row movers around the dropless expert layer's
   grouped products (ops/moe.py), whose work follows the pairs a layer
   holds and not the static length of its sorted list.
+- :mod:`.grouped_dot` — that layer's grouped products themselves (rows
+  x a group's matrix, the same against the transposed matrix, and the
+  per-group rows^T x rows of the matrices' gradient), whose grid is as
+  long as the groups; ``lax.ragged_dot`` is their XLA tier.
 
 Dispatch discipline (shared by every kernel in this package, and by
 ``ops.attention.flash_attention``): one ``MXNET_PALLAS`` gate with
@@ -83,7 +87,7 @@ def vmem_tile_budget() -> int:
 
 #: the kernel names the dispatch gate knows (diagnose/chip_smoke vocabulary)
 KERNELS = ("rnn_scan", "rnn_decode_step", "opt_update", "layernorm",
-           "bias_gelu", "flash_attention", "moe_rows")
+           "bias_gelu", "flash_attention", "moe_rows", "grouped_dot")
 
 # last decision per kernel name: {kernel: (path, reason)}
 _DECISIONS: Dict[str, Tuple[str, str]] = {}
@@ -169,7 +173,7 @@ def count_traced(metric: str, label_key: Optional[str] = None,
     counter ``telemetry.names.<metric>`` (or of one without labels): what
     the op layer counts while a call is traced (dispatch path, flash
     layout and grid steps, attention mask and form, expert dispatch,
-    router rule and row movers, MTP modules). Telemetry must never fail a
+    router rule, row movers and grouped products, MTP modules). Telemetry must never fail a
     kernel call."""
     try:
         from ...telemetry import names as tn

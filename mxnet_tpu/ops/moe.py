@@ -38,8 +38,8 @@ experts and computes its own part of the sum:
   order, and the held experts' group sizes;
 - ``moe_experts``: the sorted pairs' tokens gathered, one grouped (ragged)
   matrix product a projection over the experts held, the gate's
-  activation between. The product is ``lax.ragged_dot``, told the group
-  sizes, so rows past the last group are never multiplied;
+  activation between. The product is told the group sizes, so rows past
+  the last group are never multiplied (two tiers, below);
 - ``moe_combine``: each token's chosen outputs weighted and summed in
   float32, the sort's inverse.
 
@@ -65,8 +65,33 @@ platform and shapes):
 
 ``mx_moe_row_mover_total{tier}`` counts which tier a traced call site
 took.
+
+The grouped products themselves have the same two tiers behind
+``dispatch("grouped_dot")``, from platform, shapes and dtypes:
+
+- on one TPU chip the kernels of ``ops/kernels/grouped_dot.py`` (bf16
+  and float32; float32 in one bf16 pass or at highest, as
+  ``jax.default_matmul_precision`` asked where the layer was called,
+  forward and backward), whose grid is as long as the groups: the three forward
+  products, and a backward written out (``_experts_bwd``) in which the
+  input's gradient is ONE product, the gate's and the up projection's
+  cotangents summed in its accumulator, where autodiff makes two arrays
+  and adds them over the static list. The walk over the groups is made
+  once a layer and shared by its eight products;
+- everywhere else, under a multi-device mesh, for widths that are no
+  multiple of 128, and as the oracle: ``lax.ragged_dot`` and autodiff.
+
+Past the last group the kernels write NOTHING (``ragged_dot`` leaves
+zeros): what ``moe_experts`` returns there, forward and backward, is
+undefined on every tier. Its readers do not read it: ``moe_combine`` and
+``_dispatch_bwd`` select the held pairs' rows (kernels) or mask by
+``place < sizes.sum()`` (``_pair_rows``), and the matrices' gradients
+mask both operands a group. ``mx_moe_grouped_dot_total{tier}`` counts
+the traced product sites by tier.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -262,12 +287,84 @@ def _valid_rows(x, sizes):
 
 
 def _grouped_dot(lhs, rhs, sizes):
-    """One grouped product: row r of ``lhs`` (rows, k) against
-    ``rhs[g]`` (n, k) for the group g that holds r; zero past the last
-    group. ``lax.ragged_dot`` in every dtype and on every backend: XLA
-    is told the group sizes and multiplies no row past them."""
+    """One grouped product of the XLA tier, and the oracle: row r of
+    ``lhs`` (rows, k) against ``rhs[g]`` (n, k) for the group g that
+    holds r; zero past the last group. ``lax.ragged_dot``: XLA is told
+    the group sizes and multiplies no row past them. (One TPU chip takes
+    ``ops/kernels/grouped_dot.py`` instead: :func:`_experts`.)"""
     return lax.ragged_dot(lhs, rhs.swapaxes(1, 2), sizes,
                           preferred_element_type=lhs.dtype)
+
+
+def _products_tier(xs, w_gate, w_up, w_down, precision):
+    """The tier of one layer's grouped products, by the kernel layer's
+    gate from platform, shapes, dtypes and the matmul precision asked
+    for: ``"pallas"`` / ``"interpret"`` (ops/kernels/grouped_dot.py) or
+    ``"xla"`` (``lax.ragged_dot``)."""
+    from .kernels import dispatch, grouped_dot
+    why = grouped_dot.supported(xs.shape[0], xs.shape[1], w_gate.shape[1],
+                                xs.dtype, w_gate.dtype, w_up.dtype,
+                                w_down.dtype, precision=precision)
+    return dispatch("grouped_dot", supported=why is None, reason=why)[0]
+
+
+def _count_products(sites, tier):
+    from .kernels import count_traced
+    count_traced("MOE_GROUPED_DOT", "tier", tier, sites)
+
+
+def _gated(activation):
+    return lambda gate, up: ACTIVATIONS[activation](gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts(activation, kernel, xs, sizes, w_gate, w_up, w_down):
+    """``moe_experts`` behind the tokens' gather on a kernel tier: the
+    three products as ``grouped_dot.gmm`` over one walk of the groups.
+    ``kernel = (tier, precision)``: ``"pallas"`` or ``"interpret"``, and
+    the matmul precision asked for where the layer was called, which its
+    backward keeps (as ``ragged_dot``'s does) though it is traced after
+    that ``with`` block has closed."""
+    return _experts_fwd(activation, kernel, xs, sizes, w_gate, w_up,
+                        w_down)[0]
+
+
+def _kernel_keywords(kernel):
+    tier, precision = kernel
+    return {"precision": precision, "interpret": tier == "interpret"}
+
+
+def _experts_fwd(activation, kernel, xs, sizes, w_gate, w_up, w_down):
+    from .kernels import grouped_dot as kernels
+    _count_products(3, kernel[0])
+    rows = xs.shape[0]
+    walk = kernels.group_metadata(sizes, rows, kernels.row_tile(rows))
+    gmm = functools.partial(kernels.gmm, **_kernel_keywords(kernel))
+    gate = gmm(xs, w_gate, walk)
+    up = gmm(xs, w_up, walk)
+    y = gmm(_gated(activation)(gate, up), w_down, walk)
+    # the activation's product is made again in the backward, in the
+    # pass that takes its gradient, and is no residual of this function
+    # (XLA may still merge the two makings and keep it: PERF.md, PR 33)
+    return y, (xs, gate, up, walk, w_gate, w_up, w_down)
+
+
+def _experts_bwd(activation, kernel, res, dy):
+    from .kernels import grouped_dot as kernels
+    _count_products(5, kernel[0])
+    xs, gate, up, walk, w_gate, w_up, w_down = res
+    gmm_t = functools.partial(kernels.gmm, transposed=True,
+                              **_kernel_keywords(kernel))
+    tgmm = functools.partial(kernels.tgmm, **_kernel_keywords(kernel))
+    h, pull = jax.vjp(_gated(activation), gate, up)
+    d_gate, d_up = pull(gmm_t(dy, w_down, walk))
+    # the input's gradient once: both cotangents in one accumulator
+    dxs = gmm_t((d_gate, d_up), (w_gate, w_up), walk)
+    return (dxs, None, tgmm(d_gate, xs, walk), tgmm(d_up, xs, walk),
+            tgmm(dy, h, walk))
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def _row_movers(n, rows, k, d, *dtypes):
@@ -328,11 +425,19 @@ def moe_experts(x, order, place, sizes, w_gate, w_up, w_down,
     ``y = W_down (act(W_gate x) * (W_up x))``, ``act`` one of
     ``ACTIVATIONS`` (``relu``: ReGLU, ``silu``: SwiGLU). Returns (rows,
     d), row p the output for pair ``order[p]``; rows past the last group
-    are zero (``moe_combine`` never reads them)."""
+    are UNDEFINED, and so is their gradient's row (the kernel tier writes
+    nothing there, ``ragged_dot`` zeros; ``moe_combine`` and
+    ``_dispatch_bwd`` never read them)."""
     xs = _dispatch(x, order, place, sizes)
+    precision = jax.config.jax_default_matmul_precision
+    tier = _products_tier(xs, w_gate, w_up, w_down, precision)
+    if tier != "xla":
+        return _experts(activation, (tier, precision), xs, sizes, w_gate,
+                        w_up, w_down)
+    _count_products(3, tier)
     gate = _grouped_dot(xs, w_gate, sizes)
     up = _grouped_dot(xs, w_up, sizes)
-    return _grouped_dot(ACTIVATIONS[activation](gate) * up, w_down, sizes)
+    return _grouped_dot(_gated(activation)(gate, up), w_down, sizes)
 
 
 @jax.custom_vjp

@@ -157,6 +157,7 @@ FLASH_ATTENTION_GRID_STEPS = "mx_flash_attention_grid_steps_total"
 ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
 MOE_ROW_MOVER = "mx_moe_row_mover_total"
+MOE_GROUPED_DOT = "mx_moe_grouped_dot_total"
 MOE_ROUTER = "mx_moe_router_total"
 LATENT_ATTENTION = "mx_latent_attention_total"
 MTP_MODULES = "mx_mtp_modules_total"
@@ -505,6 +506,16 @@ CATALOG = {
              "ops/kernels/moe_rows.py, whose work follows the pairs "
              "held; xla = gathers over the whole static list; one count "
              "a traced call site"),
+    MOE_GROUPED_DOT: dict(
+        kind="counter", label="tier",
+        help="the grouped products of the dropless expert layer "
+             "(ops/moe.py moe_experts: gate, up and down projections, "
+             "and on the kernel tiers the five products of their "
+             "backward) by the tier that took them: pallas / interpret "
+             "= the kernels of ops/kernels/grouped_dot.py, whose grid "
+             "is as long as the groups; xla = lax.ragged_dot (its "
+             "backward is autodiff's and is not counted); one count a "
+             "traced product site"),
     MOE_ROUTER: dict(
         kind="counter", label="score",
         help="routers of the dropless expert layer by their score rule "

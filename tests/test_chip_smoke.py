@@ -127,10 +127,10 @@ def test_phases_rehearse_on_cpu(monkeypatch):
                optimizer=("adam", {"learning_rate": 1e-3}))
     sparse = dict(chip_smoke.SPARSE, batch=2, seq=32, steps=4, model=dict(
         chip_smoke.SPARSE["model"], hidden_size=128, head_dim=32,
-        moe_ffn_hidden_size=32, sliding_window_size=8, vocab_size=128))
+        moe_ffn_hidden_size=128, sliding_window_size=8, vocab_size=128))
     latent = dict(chip_smoke.LATENT, batch=2, seq=32, steps=4, model=dict(
         chip_smoke.LATENT["model"], hidden_size=128, intermediate_size=64,
-        q_lora_rank=64, kv_lora_rank=32, moe_intermediate_size=32,
+        q_lora_rank=64, kv_lora_rank=32, moe_intermediate_size=128,
         vocab_size=128))
     train = chip_smoke.phase_train(cfg, sparse, latent)
     assert train["kernel_paths"]["flash_attention"] == "interpret"
@@ -141,6 +141,11 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     # movers' kernels take
     movers = train["sparse_lm"]["mx_moe_row_mover_total"]
     assert set(movers) == {"interpret"} and movers["interpret"] >= 12
+    # and so do the grouped products' (widths of 128): eight a layer
+    products = train["sparse_lm"]["mx_moe_grouped_dot_total"]
+    assert set(products) == {"interpret"} and products["interpret"] >= 32
+    assert set(train["latent_lm"]["mx_moe_grouped_dot_total"]) == {
+        "interpret"}
     # 32 positions in blocks of 32: a step a program, none dead; the
     # sparse LM's grouped heads take the two-kernel backward
     assert train["flash_grid_steps"]["dead"] == 0 < \
