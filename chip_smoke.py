@@ -19,6 +19,12 @@ it, through the entry points users call:
   collectives in the compiled program, loss parity with ``train`` (the
   kernels take the XLA tier there: Mosaic does not lower into a
   GSPMD-partitioned program);
+- ``hybrid``  — a small ``NemotronHLM`` (Mamba-2, expert and attention
+  layers, one mixer a layer) through the same ``TrainLoop``: fused, one
+  trace, loss falling; the chunked selective scan (``ops.ssm.ssd_scan``)
+  and its gradients against the recurrence, float32 at ``highest`` and
+  bf16; the counters ``mx_ssd_scan_chunks_total`` and
+  ``mx_mamba_recompute_total``;
 - ``serve``   — ``TinyDecoder`` and ``GQADecoder`` through
   ``serving.run_decode`` (``DecodeEngine.warmup()`` AOT-compiles every
   ladder bucket): all requests finish and the speculative + shared-prefix
@@ -96,6 +102,30 @@ LATENT = dict(batch=2, seq=512, steps=8,
                          moe_router_width=8, num_experts_per_tok=2,
                          n_shared_experts=1, scoring_func="sigmoid",
                          routed_scaling_factor=2.5, vocab_size=512))
+
+# a small NemotronHLM for the hybrid phase: the Mamba-2 head width, state,
+# chunk and conv of the benchmark's configuration (benchmark/grid/configs/
+# nemotron-3-nano-30b-a3b.json), its attention heads of 128 with no
+# position signal and its ungated relu2 experts behind the sigmoid router,
+# everything else cut down; the experts' width is a multiple of 128 lanes
+# here, so their two forward and three backward products are the grouped-
+# product kernels' with nothing padded (the cell's 1856 lanes moe_experts
+# zero-pads to 1920 for them)
+HYBRID = dict(batch=2, seq=512, steps=8,
+              optimizer=("adam", {"learning_rate": 1e-3}),
+              scan=dict(seq=512, heads=8, groups=2),
+              model=dict(hidden_size=256, hybrid_override_pattern="MEM*E",
+                         num_hidden_layers=5, mamba_num_heads=8,
+                         mamba_head_dim=64, ssm_state_size=128, n_groups=2,
+                         conv_kernel=4, chunk_size=128,
+                         layer_norm_epsilon=1e-5, num_attention_heads=4,
+                         num_key_value_heads=2, head_dim=128,
+                         moe_intermediate_size=256,
+                         moe_shared_expert_intermediate_size=512,
+                         n_shared_experts=1, n_routed_experts=4,
+                         moe_router_width=8, num_experts_per_tok=2,
+                         routed_scaling_factor=2.5, mlp_hidden_act="relu2",
+                         vocab_size=512))
 
 # the accelerator sizes of bench.py's decode leg
 SERVE = dict(vocab=256, d_model=128, heads=4, requests=16,
@@ -186,7 +216,7 @@ def kernel_cases(tiny: bool = False):
                 f32(rng, g * h, h, scale=h ** -0.5),
                 f32(rng, g * h, scale=0.1))
 
-    def sparse_experts(top_k, held, activation="relu"):
+    def sparse_experts(top_k, held, activation="relu", gated=True):
         # float32 products at HIGHEST in the kernel tier too, so that both
         # sides multiply alike and the comparison reads the rows moved:
         # at the default precision a float32 product is one bf16 pass,
@@ -195,13 +225,15 @@ def kernel_cases(tiny: bool = False):
         # of its scale at these widths, whatever multiplies: PR 31).
         # bf16 products stay as they are: they are exact in float32, and
         # XLA's own bf16 ragged-dot kernel refuses HIGHEST ("Bad lhs type")
-        def layer(x, router_w, w_gate, w_up, w_down):
+        def layer(x, router_w, *matrices):
+            # without a gate: (w_up, w_down)
             with jax.default_matmul_precision(
                     "highest" if x.dtype == jnp.float32 else "default"):
                 weights, order, place, sizes = ops_moe.moe_route(
                     x, router_w, top_k, held)
-                y = ops_moe.moe_experts(x, order, place, sizes, w_gate,
-                                        w_up, w_down, activation)
+                y = ops_moe.moe_experts(x, order, place, sizes,
+                                        *(() if gated else (None,)),
+                                        *matrices, activation=activation)
                 return ops_moe.moe_combine(y, weights, order, place, sizes)
         return layer
 
@@ -319,6 +351,20 @@ def kernel_cases(tiny: bool = False):
                 lambda rng, n=n, d=d, f=f, e=e, c=held[1]: expert_weights(
                     rng, n, d, f, e, c),
                 sparse_experts(k, held, act), (0, 1, 2, 3, 4))
+
+        # experts WITHOUT a gate, relu^2 (two products forward, three
+        # backward), at the Nemotron cell's shape but for the width: 4096
+        # tokens of 2688, top-6 of 128, 8 held (24,576 rows, about 1,536
+        # live). The cell's own 1856 = 14.5 lane tiles the kernels decline;
+        # 1920 is the next they take, and what moe_experts pads the cell's to
+        n, d, f, e, k, held = (128, 128, 256, 8, 2, (2, 4)) if tiny \
+            else (4096, 2688, 1920, 128, 6, (0, 8))
+        add("grouped_dot", f"grouped_dot {n * min(k, held[1])}x{d}x{f} "
+            f"top{k}/{e} held {held[1]} relu2 ungated", dtype,
+            lambda rng, n=n, d=d, f=f, e=e, c=held[1]: expert_weights(
+                rng, n, d, f, e, c)[:2] + expert_weights(
+                rng, n, d, f, e, c)[3:],
+            sparse_experts(k, held, "relu2", gated=False), (0, 1, 2, 3))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -764,6 +810,104 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE,
             "sparse_lm": _sparse_lm(sparse), "latent_lm": _latent_lm(latent)}
 
 
+def _scan_against_the_recurrence(cfg: dict) -> dict:
+    """``ops.ssm.ssd_scan`` (chunked) and every gradient of it against
+    ``ssd_scan_reference`` (a ``lax.scan`` over time, float32) on the
+    device: float32 at ``highest`` on both sides, and bf16 operands beside
+    float32 step sizes as AMP hands them over (three roundings to bf16
+    along a path: the decayed scores, the decay-weighted x, the entering
+    states; 0.005 on the CPU). → the worst gap a dtype, max |got -
+    oracle| over max |oracle| a tensor, y and its six gradients."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from mxnet_tpu.ops import ssm
+    rng = onp.random.RandomState(SEED)
+    seq, heads, groups = cfg["scan"]["seq"], cfg["scan"]["heads"], \
+        cfg["scan"]["groups"]
+    model = cfg["model"]
+    width, state = model["mamba_head_dim"], model["ssm_state_size"]
+
+    def f32(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    x, B, C = f32(1, seq, heads, width), f32(1, seq, groups, state), \
+        f32(1, seq, groups, state)
+    dt = jax.nn.softplus(f32(1, seq, heads))
+    A, D = -jnp.exp(jnp.asarray(rng.uniform(-1.39, 1.39, heads),
+                                jnp.float32)), f32(heads)
+    weigh = f32(1, seq, heads, width)
+
+    def both(fn, *args):
+        """y and the gradient of sum(y * weigh) by every operand."""
+        grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)
+                                            * weigh), argnums=tuple(range(6)))
+        return jax.jit(lambda *a: (fn(*a),) + grads(*a))(*args)
+
+    def chunked(*a):
+        return ssm.ssd_scan(*a, chunk=model["chunk_size"])
+    gaps = {}
+    with jax.default_matmul_precision("highest"):
+        for dtype, tol in (("float32", TOL_F32_PRODUCTS),
+                           ("bfloat16", TOL_BF16)):
+            low = [a.astype(dtype) for a in (x, B, C)]
+            # the oracle on the same inputs upcast to float32
+            want = both(ssm.ssd_scan_reference, low[0].astype(jnp.float32),
+                        dt, A, *(a.astype(jnp.float32) for a in low[1:]), D)
+            got = both(chunked, low[0], dt, A, low[1], low[2], D)
+            gap = max(float(jnp.max(jnp.abs(g.astype(jnp.float32) - w))
+                            / jnp.maximum(jnp.max(jnp.abs(w)), 1e-30))
+                      for g, w in zip(got, want))
+            gaps[dtype] = gap
+            if not gap <= tol:
+                raise RuntimeError(f"ssd_scan in {dtype} is {gap:.3g} off "
+                                   f"the recurrence (limit {tol:.3g})")
+    return gaps
+
+
+def phase_hybrid(cfg: dict = HYBRID) -> dict:
+    """The module docstring's ``hybrid`` phase."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.gluon.model_zoo.nemotron_h import NemotronHLM
+    from mxnet_tpu.telemetry import names
+    rng = onp.random.RandomState(SEED)
+    model = cfg["model"]
+    t = rng.randint(0, model["vocab_size"],
+                    size=(cfg["batch"], cfg["seq"] + 1)).astype("int32")
+    x, y = mx.nd.array(t[:, :-1]), mx.nd.array(t[:, 1:])
+    layers = model["hybrid_override_pattern"][:model["num_hidden_layers"]]
+    mixers, experts = layers.count("M"), layers.count("E")
+    out = {"scan_gap": _scan_against_the_recurrence(cfg)}
+    mx.random.seed(SEED)
+    chunks = telemetry.value(names.SSD_SCAN_CHUNKS) or 0
+    losses, counted = _train_small_lm(
+        NemotronHLM(model), cfg, x, y,
+        (names.MAMBA_RECOMPUTE, names.MOE_ROUTER, names.MOE_DISPATCH,
+         names.MOE_GROUPED_DOT))
+    counted[names.SSD_SCAN_CHUNKS] = int(
+        telemetry.value(names.SSD_SCAN_CHUNKS) - chunks)
+    per_scan = -(-cfg["seq"] // model["chunk_size"])
+    if counted[names.MAMBA_RECOMPUTE] != {"segment": mixers} or \
+            counted[names.SSD_SCAN_CHUNKS] != mixers * per_scan or \
+            counted[names.MOE_ROUTER] != {"sigmoid": experts} or \
+            counted[names.MOE_DISPATCH] != {"grouped": experts}:
+        raise RuntimeError(
+            f"NemotronHLM traced {counted}, expected {mixers} mixers of "
+            f"{per_scan} chunks, each one checkpointed segment, and "
+            f"{experts} sigmoid-routed expert layers")
+    # experts without a gate: two products forward, three backward
+    products = counted[names.MOE_GROUPED_DOT]
+    if set(products) != {_compiled_tier()} or \
+            products[_compiled_tier()] < 5 * experts:
+        raise RuntimeError(f"the ungated experts' products took "
+                           f"{products}, expected {_compiled_tier()} "
+                           f"alone, {5 * experts} or more")
+    log(f"  NemotronHLM: {counted}")
+    out["lm"] = {"loss": [round(l, 4) for l in losses], **counted}
+    return out
+
+
 def phase_kernels(tiny: bool = False) -> dict:
     """Every case runs, so one chip call names every kernel Mosaic
     refuses; any failed case fails the phase."""
@@ -901,6 +1045,20 @@ def run_phases(phases: dict) -> dict:
     return report
 
 
+def phases() -> dict:
+    """The run's phases in order, each ``fn(report so far)``."""
+    return {
+        "device": lambda _: phase_device(),
+        "train": lambda _: phase_train(),
+        # a failed train phase fails dp too: there is nothing to match.
+        # Before `kernels`, so the dispatch table dp prints is BERT's
+        "dp": lambda r: phase_dp(r["train"]["loss"]),
+        "kernels": lambda _: phase_kernels(),
+        "hybrid": lambda _: phase_hybrid(),
+        "serve": lambda _: phase_serve(),
+    }
+
+
 def main() -> int:
     import jax
     devices = jax.devices()
@@ -909,15 +1067,7 @@ def main() -> int:
               f"{devices[0].platform}); nothing was run", file=sys.stderr)
         return 2
     from mxnet_tpu import _native, runtime
-    report = run_phases({
-        "device": lambda _: phase_device(),
-        "train": lambda _: phase_train(),
-        # a failed train phase fails dp too: there is nothing to match.
-        # Before `kernels`, so the dispatch table dp prints is BERT's
-        "dp": lambda r: phase_dp(r["train"]["loss"]),
-        "kernels": lambda _: phase_kernels(),
-        "serve": lambda _: phase_serve(),
-    })
+    report = run_phases(phases())
     return finish(report, devices, runtime.compile_cache_stats(),
                   _native.built_this_run())
 

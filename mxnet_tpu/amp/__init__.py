@@ -68,6 +68,11 @@ FP32_OPS = NORM_OPS | {
     "moe_route",
     # the two norms of latent attention's compressed q and kv
     "latent_norm",
+    # a state-space mixer's step size (softplus of dt + its bias; the
+    # scan's decays exp(dt A) are built from it in float32) and its gated
+    # group norm; the scan itself (``ssd_scan``) is in neither list: its
+    # x, B, C flow in bf16 and its dt, A_log, D arrive in float32
+    "mamba_dt", "mamba_norm",
 }
 
 _state = {"enabled": False, "dtype": None, "wrapper": None}

@@ -7,6 +7,8 @@ from . import vision
 from . import bert
 from . import smallthinker
 from . import joyai
+from . import nemotron_h
 from .vision import get_model
 
-__all__ = ["vision", "bert", "smallthinker", "joyai", "get_model"]
+__all__ = ["vision", "bert", "smallthinker", "joyai", "nemotron_h",
+           "get_model"]

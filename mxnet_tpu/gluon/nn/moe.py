@@ -9,13 +9,12 @@ parallelism as absent); TPU-native extensions backed by ``ops/moe.py``:
 - ``SparseMoE``: the dropless layer of today's sparse-expert decoders:
   top-k of the router's scores by the model's rule (a softmax over the
   chosen logits; or sigmoid scores, a selection bias, weights normalised
-  over the chosen and scaled), gated experts (ReGLU or SwiGLU), optionally
-  a shared expert every token passes, no capacity and no dropped token,
-  and a layer that is told which experts it holds (``held``).
+  over the chosen and scaled), gated experts (ReGLU or SwiGLU) or
+  experts without a gate (``relu2``), optionally a shared expert every
+  token passes, no capacity and no dropped token, and a layer that is
+  told which experts it holds (``held``).
 """
 from __future__ import annotations
-
-import functools
 
 from ...base import MXNetError
 from ...ndarray.ndarray import NDArray
@@ -87,10 +86,12 @@ class SparseMoE(HybridBlock):
 
     Expert e is ``W_down (act(W_gate x) * (W_up x))``, width ``hidden``,
     no bias; ``activation`` names act: ``"relu"`` (sparse ReGLU) or
-    ``"silu"`` (SwiGLU). With ``shared_hidden`` one more such expert of
-    that width, which every token passes with weight 1, is added to the
-    sum: it is whole on every chip, so the shares of a layer count it
-    once (``shared_expert(x)`` is its term alone).
+    ``"silu"`` (SwiGLU). With ``gated=False`` an expert is ``W_down
+    act(W_up x)`` and has no ``gate_weight`` (Nemotron-H's experts, act
+    ``"relu2"``, relu squared). With ``shared_hidden`` one more expert of
+    the same form and that width, which every token passes with weight
+    1, is added to the sum: it is whole on every chip, so the shares of a
+    layer count it once (``shared_expert(x)`` is its term alone).
 
     ``held = (first, count)`` says which experts this layer holds:
     ``(0, num_experts)`` is the whole layer, ``(8 * j, 8)`` chip j's part
@@ -113,7 +114,8 @@ class SparseMoE(HybridBlock):
 
     def __init__(self, units, hidden, num_experts, top_k, held=None,
                  dtype="float32", score="softmax", routed_scale=1.0,
-                 activation="relu", shared_hidden=0, **kwargs):
+                 activation="relu", shared_hidden=0, gated=True,
+                 **kwargs):
         super().__init__(**kwargs)
         if score not in moe_ops.SCORES:
             raise MXNetError(f"score {score!r} is none of "
@@ -130,7 +132,7 @@ class SparseMoE(HybridBlock):
         self._units, self._k = units, top_k
         self._held = (int(first), int(count))
         self._score, self._scale = score, float(routed_scale)
-        self._activation = activation
+        self._activation, self._gated = activation, bool(gated)
         self.router_weight = Parameter(
             "router_weight", shape=(num_experts, units), dtype=dtype)
         self.router_bias = None
@@ -139,7 +141,8 @@ class SparseMoE(HybridBlock):
                 "router_bias", shape=(num_experts,), dtype=dtype,
                 init="zeros")
         self.gate_weight = Parameter(
-            "gate_weight", shape=(count, hidden, units), dtype=dtype)
+            "gate_weight", shape=(count, hidden, units), dtype=dtype) \
+            if gated else None
         self.up_weight = Parameter(
             "up_weight", shape=(count, hidden, units), dtype=dtype)
         self.down_weight = Parameter(
@@ -147,9 +150,10 @@ class SparseMoE(HybridBlock):
         self.shared_gate_weight = self.shared_up_weight = \
             self.shared_down_weight = None
         if shared_hidden:
-            self.shared_gate_weight = Parameter(
-                "shared_gate_weight", shape=(shared_hidden, units),
-                dtype=dtype)
+            if gated:
+                self.shared_gate_weight = Parameter(
+                    "shared_gate_weight", shape=(shared_hidden, units),
+                    dtype=dtype)
             self.shared_up_weight = Parameter(
                 "shared_up_weight", shape=(shared_hidden, units),
                 dtype=dtype)
@@ -178,27 +182,37 @@ class SparseMoE(HybridBlock):
         """The shared expert's term alone, ``x``'s shape."""
         act = moe_ops.ACTIVATIONS[self._activation]
 
-        def fn(x_, gate, up, down):
+        def gated(x_, gate, up, down):
             return (act(x_ @ gate.T) * (x_ @ up.T)) @ down.T
+
+        def ungated(x_, up, down):
+            return act(x_ @ up.T) @ down.T
+        fn = gated if self._gated else ungated
         return invoke_raw("shared_expert", fn,
-                          [x if isinstance(x, NDArray) else NDArray(x),
-                           self.shared_gate_weight.data(),
-                           self.shared_up_weight.data(),
-                           self.shared_down_weight.data()])
+                          [x if isinstance(x, NDArray) else NDArray(x)]
+                          + self._matrices("shared_"))
+
+    def _matrices(self, prefix=""):
+        """``[gate,] up, down`` of the routed (or the shared) experts."""
+        return [p.data() for p in (
+            getattr(self, f"{prefix}{part}_weight")
+            for part in ("gate", "up", "down")) if p is not None]
 
     def forward(self, x, routing=None):
         shape = x.shape
         tokens = self._tokens(x)
         weights, order, place, sizes = routing or self.route(x)
         count_traced("MOE_DISPATCH", "path", "grouped")
-        experts = functools.partial(moe_ops.moe_experts,
-                                    activation=self._activation)
+        no_gate = () if self._gated else (None,)
+
+        def experts(x_, order_, place_, sizes_, *weights):
+            return moe_ops.moe_experts(x_, order_, place_, sizes_, *no_gate,
+                                       *weights, activation=self._activation)
         y = invoke_raw("moe_experts", experts,
-                       [tokens, order, place, sizes, self.gate_weight.data(),
-                        self.up_weight.data(), self.down_weight.data()])
+                       [tokens, order, place, sizes] + self._matrices())
         out = invoke_raw("moe_combine", moe_ops.moe_combine,
                          [y, weights, order, place, sizes]).reshape(shape)
-        if self.shared_gate_weight is not None:
+        if self.shared_up_weight is not None:
             out = out + self.shared_expert(x)
         return out
 

@@ -25,8 +25,9 @@ router and experts.
 The DROPLESS layer (``moe_route`` / ``moe_experts`` / ``moe_combine``,
 behind ``gluon.nn.SparseMoE``) is a different layer, not a second path of
 the one above: no capacity and no dropped token at any imbalance, weights
-over the chosen experts only, gated experts (ReGLU or SwiGLU), and a
-layer that is told WHICH experts it holds. It routes over all ``E``
+over the chosen experts only, gated experts (ReGLU or SwiGLU) or experts
+without a gate (``W_down relu(W_up x)^2``), and a layer that is told WHICH
+experts it holds. It routes over all ``E``
 experts and computes its own part of the sum:
 
 - ``moe_route``: router logits, the top-k choice and the chosen experts'
@@ -77,9 +78,12 @@ The grouped products themselves have the same two tiers behind
   input's gradient is ONE product, the gate's and the up projection's
   cotangents summed in its accumulator, where autodiff makes two arrays
   and adds them over the static list. The walk over the groups is made
-  once a layer and shared by its eight products;
+  once a layer and shared by its eight products (five without a gate:
+  two forward, three backward);
 - everywhere else, under a multi-device mesh, for widths that are no
-  multiple of 128, and as the oracle: ``lax.ragged_dot`` and autodiff.
+  multiple of 128 (experts without a gate are zero-padded to one on a
+  kernel tier, ``_whole_lane_tiles``), and as the oracle:
+  ``lax.ragged_dot`` and autodiff.
 
 Past the last group the kernels write NOTHING (``ragged_dot`` leaves
 zeros): what ``moe_experts`` returns there, forward and backward, is
@@ -103,9 +107,11 @@ __all__ = ["moe_gating", "moe_ffn", "moe_route", "moe_experts",
 #: the score rules ``moe_route`` knows, as ``mx_moe_router_total`` labels
 #: them
 SCORES = ("softmax", "sigmoid")
-#: the gate activations of ``moe_experts`` by their configs' names: ReGLU
-#: and SwiGLU experts
-ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+#: the activations of ``moe_experts`` by their configs' names: the gate's
+#: in ReGLU and SwiGLU experts, the hidden layer's own in experts without
+#: a gate (``relu2``: the square of relu)
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def moe_gating(x, gate_w, num_experts: int, top_k: int = 2,
@@ -296,16 +302,28 @@ def _grouped_dot(lhs, rhs, sizes):
                           preferred_element_type=lhs.dtype)
 
 
-def _products_tier(xs, w_gate, w_up, w_down, precision):
+def _products_tier(xs, matrices, hidden, precision):
     """The tier of one layer's grouped products, by the kernel layer's
     gate from platform, shapes, dtypes and the matmul precision asked
     for: ``"pallas"`` / ``"interpret"`` (ops/kernels/grouped_dot.py) or
-    ``"xla"`` (``lax.ragged_dot``)."""
+    ``"xla"`` (``lax.ragged_dot``). ``matrices``: ``w_gate`` (None where
+    the experts have no gate), ``w_up``, ``w_down``; ``hidden``: the
+    experts' width as the products would see it."""
     from .kernels import dispatch, grouped_dot
-    why = grouped_dot.supported(xs.shape[0], xs.shape[1], w_gate.shape[1],
-                                xs.dtype, w_gate.dtype, w_up.dtype,
-                                w_down.dtype, precision=precision)
+    why = grouped_dot.supported(
+        xs.shape[0], xs.shape[1], hidden, xs.dtype,
+        *(w.dtype for w in matrices if w is not None), precision=precision)
     return dispatch("grouped_dot", supported=why is None, reason=why)[0]
+
+
+def _whole_lane_tiles(w_up, w_down, hidden):
+    """Experts without a gate at ``hidden`` lanes: ``w_up`` (count, f, d)
+    gains zero rows and ``w_down`` (count, d, f) zero columns. Exact,
+    forward and backward: the new lanes of ``act(W_up x)`` meet zeros of
+    ``W_down`` and their gradients are dropped with the padding."""
+    more = hidden - w_up.shape[1]
+    return (jnp.pad(w_up, ((0, 0), (0, more), (0, 0))),
+            jnp.pad(w_down, ((0, 0), (0, 0), (0, more))))
 
 
 def _count_products(sites, tier):
@@ -314,13 +332,17 @@ def _count_products(sites, tier):
 
 
 def _gated(activation):
-    return lambda gate, up: ACTIVATIONS[activation](gate) * up
+    """``(gate, up) ->`` what the down projection reads: ``act(gate) *
+    up``, or ``act(up)`` where the experts have no gate (``gate`` None)."""
+    act = ACTIVATIONS[activation]
+    return lambda gate, up: act(up) if gate is None else act(gate) * up
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _experts(activation, kernel, xs, sizes, w_gate, w_up, w_down):
     """``moe_experts`` behind the tokens' gather on a kernel tier: the
-    three products as ``grouped_dot.gmm`` over one walk of the groups.
+    three products (two without a gate, ``w_gate`` None) as
+    ``grouped_dot.gmm`` over one walk of the groups.
     ``kernel = (tier, precision)``: ``"pallas"`` or ``"interpret"``, and
     the matmul precision asked for where the layer was called, which its
     backward keeps (as ``ragged_dot``'s does) though it is traced after
@@ -336,11 +358,11 @@ def _kernel_keywords(kernel):
 
 def _experts_fwd(activation, kernel, xs, sizes, w_gate, w_up, w_down):
     from .kernels import grouped_dot as kernels
-    _count_products(3, kernel[0])
+    _count_products(2 if w_gate is None else 3, kernel[0])
     rows = xs.shape[0]
     walk = kernels.group_metadata(sizes, rows, kernels.row_tile(rows))
     gmm = functools.partial(kernels.gmm, **_kernel_keywords(kernel))
-    gate = gmm(xs, w_gate, walk)
+    gate = None if w_gate is None else gmm(xs, w_gate, walk)
     up = gmm(xs, w_up, walk)
     y = gmm(_gated(activation)(gate, up), w_down, walk)
     # the activation's product is made again in the backward, in the
@@ -351,13 +373,16 @@ def _experts_fwd(activation, kernel, xs, sizes, w_gate, w_up, w_down):
 
 def _experts_bwd(activation, kernel, res, dy):
     from .kernels import grouped_dot as kernels
-    _count_products(5, kernel[0])
     xs, gate, up, walk, w_gate, w_up, w_down = res
+    _count_products(3 if gate is None else 5, kernel[0])
     gmm_t = functools.partial(kernels.gmm, transposed=True,
                               **_kernel_keywords(kernel))
     tgmm = functools.partial(kernels.tgmm, **_kernel_keywords(kernel))
     h, pull = jax.vjp(_gated(activation), gate, up)
     d_gate, d_up = pull(gmm_t(dy, w_down, walk))
+    if gate is None:
+        return (gmm_t(d_up, w_up, walk), None, None, tgmm(d_up, xs, walk),
+                tgmm(dy, h, walk))
     # the input's gradient once: both cotangents in one accumulator
     dxs = gmm_t((d_gate, d_up), (w_gate, w_up), walk)
     return (dxs, None, tgmm(d_gate, xs, walk), tgmm(d_up, xs, walk),
@@ -423,19 +448,32 @@ def moe_experts(x, order, place, sizes, w_gate, w_up, w_down,
     ``place``, ``sizes`` from :func:`moe_route`; ``w_gate``, ``w_up``
     (count, f, d) and ``w_down`` (count, d, f), expert e's
     ``y = W_down (act(W_gate x) * (W_up x))``, ``act`` one of
-    ``ACTIVATIONS`` (``relu``: ReGLU, ``silu``: SwiGLU). Returns (rows,
+    ``ACTIVATIONS`` (``relu``: ReGLU, ``silu``: SwiGLU); with ``w_gate``
+    None the experts have no gate, ``y = W_down act(W_up x)`` (``relu2``),
+    two products forward and three backward where the gated form has
+    three and five, and on a kernel tier a width that is no multiple of
+    128 lanes is zero-padded to the next (the gated form at such a width
+    is ``lax.ragged_dot``'s). Returns (rows,
     d), row p the output for pair ``order[p]``; rows past the last group
     are UNDEFINED, and so is their gradient's row (the kernel tier writes
     nothing there, ``ragged_dot`` zeros; ``moe_combine`` and
     ``_dispatch_bwd`` never read them)."""
     xs = _dispatch(x, order, place, sizes)
     precision = jax.config.jax_default_matmul_precision
-    tier = _products_tier(xs, w_gate, w_up, w_down, precision)
+    hidden = w_up.shape[1]
+    if w_gate is None:
+        # the next whole number of lane tiles (Nemotron-H's 1856 = 14.5):
+        # the kernels take that, and a step's time follows the held pairs
+        # a fifth as much as on lax.ragged_dot (PERF.md section 6, PR 35)
+        hidden += -hidden % 128
+    tier = _products_tier(xs, (w_gate, w_up, w_down), hidden, precision)
     if tier != "xla":
+        if hidden != w_up.shape[1]:
+            w_up, w_down = _whole_lane_tiles(w_up, w_down, hidden)
         return _experts(activation, (tier, precision), xs, sizes, w_gate,
                         w_up, w_down)
-    _count_products(3, tier)
-    gate = _grouped_dot(xs, w_gate, sizes)
+    _count_products(2 if w_gate is None else 3, tier)
+    gate = None if w_gate is None else _grouped_dot(xs, w_gate, sizes)
     up = _grouped_dot(xs, w_up, sizes)
     return _grouped_dot(_gated(activation)(gate, up), w_down, sizes)
 

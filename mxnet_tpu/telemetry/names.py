@@ -161,6 +161,8 @@ MOE_GROUPED_DOT = "mx_moe_grouped_dot_total"
 MOE_ROUTER = "mx_moe_router_total"
 LATENT_ATTENTION = "mx_latent_attention_total"
 MTP_MODULES = "mx_mtp_modules_total"
+SSD_SCAN_CHUNKS = "mx_ssd_scan_chunks_total"
+MAMBA_RECOMPUTE = "mx_mamba_recompute_total"
 
 # ---------------------------------------------------------------------------
 # inference serving engine (serving/batcher.py)
@@ -536,6 +538,22 @@ CATALOG = {
         help="multi-token-prediction modules traced behind a trunk, "
              "each a further block and a second head over the trunk's "
              "own embedding and head tables (gluon/model_zoo/joyai.py)"),
+    SSD_SCAN_CHUNKS: dict(
+        kind="counter", label=None,
+        help="chunks of the traced selective scans of a state-space "
+             "mixer (ops/ssm.py ssd_scan, the chunked form of Mamba-2's "
+             "recurrence, XLA's matrix products and a scan over the "
+             "chunk states): ceil(sequence / chunk) a call (32 at 4,096 "
+             "positions and the published chunk of 128)"),
+    MAMBA_RECOMPUTE: dict(
+        kind="counter", label="span",
+        help="state-space mixer layers (gluon.nn.Mamba2Mixer) by what "
+             "their backward makes again: segment = conv, scan and "
+             "gated norm under one jax.checkpoint that keeps the "
+             "projection's output and the chunk-boundary states; none = "
+             "the scan's own checkpoint alone, under the imperative "
+             "tape, which no checkpoint can span; one count a traced "
+             "layer"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),

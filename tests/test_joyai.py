@@ -590,7 +590,7 @@ def test_sparse_moe_refuses_what_it_does_not_know():
     relu = nn.SparseMoE(8, 8, 4, 2)               # SmallThinker's layer
     assert sorted(relu.collect_params()) == [
         "down_weight", "gate_weight", "router_weight", "up_weight"]
-    assert sorted(MOE.ACTIVATIONS) == ["relu", "silu"]
+    assert sorted(MOE.ACTIVATIONS) == ["relu", "relu2", "silu"]
 
 
 def test_gated_ffn_is_swiglu():
