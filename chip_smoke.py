@@ -23,8 +23,9 @@ it, through the entry points users call:
   layers, one mixer a layer) through the same ``TrainLoop``: fused, one
   trace, loss falling; the chunked selective scan (``ops.ssm.ssd_scan``)
   and its gradients against the recurrence, float32 at ``highest`` and
-  bf16; the counters ``mx_ssd_scan_chunks_total`` and
-  ``mx_mamba_recompute_total``;
+  bf16; the counters ``mx_ssd_scan_chunks_total``,
+  ``mx_mamba_recompute_total`` and ``mx_ssd_scan_total``, which must read
+  the compiled tier alone, once a mixer (ops/kernels/ssd_scan.py);
 - ``serve``   — ``TinyDecoder`` and ``GQADecoder`` through
   ``serving.run_decode`` (``DecodeEngine.warmup()`` AOT-compiles every
   ladder bucket): all requests finish and the speculative + shared-prefix
@@ -190,12 +191,14 @@ def kernel_cases(tiny: bool = False):
     from mxnet_tpu.ops import attention, kernels
     from mxnet_tpu.ops import moe as ops_moe
     from mxnet_tpu.ops import nn as ops_nn
+    from mxnet_tpu.ops import ssm as ops_ssm
     from mxnet_tpu.ops.kernels import norm, rnn_scan
 
     cases = []
 
     def add(kernel, label, dtype, make, fn, grad_argnums=()):
-        dots = kernel in ("flash_attention", "rnn_scan", "rnn_decode_step")
+        dots = kernel in ("flash_attention", "rnn_scan", "rnn_decode_step",
+                          "ssd_scan")
         tol = TOL_BF16 if dtype != "float32" or dots else \
             TOL_F32_PRODUCTS if kernel == "grouped_dot" else TOL_F32
         cases.append(KernelCase(kernel, f"{label} {dtype}", dtype, tol,
@@ -365,6 +368,23 @@ def kernel_cases(tiny: bool = False):
                 rng, n, d, f, e, c)[:2] + expert_weights(
                 rng, n, d, f, e, c)[3:],
             sparse_experts(k, held, "relu2", gated=False), (0, 1, 2, 3))
+
+        # the Nemotron cell's selective scan: 1 x 4096, 64 heads of 64 in
+        # 8 groups over a state of 128, chunks of 128; step sizes and
+        # decay rates spread as the cell's weights spread them, forward
+        # and all six gradients against _ssd_chunked
+        seq, heads, groups = (256, 16, 2) if tiny else (4096, 64, 8)
+        add("ssd_scan", f"ssd_scan 1x{seq} h{heads}x64 g{groups} n128",
+            dtype,
+            lambda rng, seq=seq, heads=heads, groups=groups: (
+                f32(rng, 1, seq, heads, 64), f32(rng, 1, seq, heads),
+                (rng.rand(heads) * 2.78 - 1.39).astype("float32"),
+                f32(rng, 1, seq, groups, 128), f32(rng, 1, seq, groups, 128),
+                f32(rng, heads)),
+            lambda x, dt, a_log, b, c, skip: ops_ssm.ssd_scan(
+                x, jax.nn.softplus(dt.astype(jnp.float32)),
+                -jnp.exp(a_log.astype(jnp.float32)), b, c,
+                skip.astype(jnp.float32)), (0, 1, 2, 3, 4, 5))
 
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
@@ -870,6 +890,7 @@ def phase_hybrid(cfg: dict = HYBRID) -> dict:
     import mxnet_tpu as mx
     from mxnet_tpu import telemetry
     from mxnet_tpu.gluon.model_zoo.nemotron_h import NemotronHLM
+    from mxnet_tpu.ops import kernels
     from mxnet_tpu.telemetry import names
     rng = onp.random.RandomState(SEED)
     model = cfg["model"]
@@ -884,7 +905,7 @@ def phase_hybrid(cfg: dict = HYBRID) -> dict:
     losses, counted = _train_small_lm(
         NemotronHLM(model), cfg, x, y,
         (names.MAMBA_RECOMPUTE, names.MOE_ROUTER, names.MOE_DISPATCH,
-         names.MOE_GROUPED_DOT))
+         names.MOE_GROUPED_DOT, names.SSD_SCAN))
     counted[names.SSD_SCAN_CHUNKS] = int(
         telemetry.value(names.SSD_SCAN_CHUNKS) - chunks)
     per_scan = -(-cfg["seq"] // model["chunk_size"])
@@ -896,6 +917,13 @@ def phase_hybrid(cfg: dict = HYBRID) -> dict:
             f"NemotronHLM traced {counted}, expected {mixers} mixers of "
             f"{per_scan} chunks, each one checkpointed segment, and "
             f"{experts} sigmoid-routed expert layers")
+    # the scans are the kernels of ops/kernels/ssd_scan.py, one a mixer
+    path, reason = kernels.decisions()["ssd_scan"]
+    log(f"  decision ssd_scan: {path} ({reason})")
+    if counted[names.SSD_SCAN] != {_compiled_tier(): mixers}:
+        raise RuntimeError(f"the mixers' scans took {counted[names.SSD_SCAN]}"
+                           f", expected {_compiled_tier()} alone, one a "
+                           f"mixer ({mixers}): {reason}")
     # experts without a gate: two products forward, three backward
     products = counted[names.MOE_GROUPED_DOT]
     if set(products) != {_compiled_tier()} or \

@@ -48,11 +48,16 @@ class Mamba2Mixer(HybridBlock):
 
     What the backward makes again instead of keeping: conv, scan and
     gated norm are one ``jax.checkpoint`` (the ``segment``) that keeps
-    ``u W_in`` and the chunk-boundary states; elementwise work and the
-    in-chunk products are made twice, the projections once. No checkpoint
-    can span ops the imperative tape records one by one: under
-    ``autograd.record()`` only the scan is one (``none``: what it never
-    keeps is its (Q x Q) decay matrices).
+    ``u W_in`` and the chunk-boundary states; the conv, the step sizes and
+    the norm's elementwise work are made twice, the projections once. On
+    the scan's kernel tier (one TPU chip, ops/kernels/ssd_scan.py) the
+    segment keeps the scan's y as well, so the forward kernel is launched
+    once and the backward kernel makes a chunk's decays and scores again
+    in VMEM; on the XLA tier the in-chunk products are made forward, again
+    under the segment and backward. No checkpoint can span ops the
+    imperative tape records one by one: under ``autograd.record()`` only
+    the scan is one (``none``: what it never keeps is its (Q x Q) decay
+    matrices, by its own checkpoint or its custom VJP).
     ``mx_mamba_recompute_total{span}`` counts which a traced layer took.
     Scopes: ``mamba_mixer`` around all of it, inside it ``mamba_proj``
     (both projections), ``mamba_conv``, ``ssd_scan``, ``mamba_norm``.
@@ -131,11 +136,13 @@ class Mamba2Mixer(HybridBlock):
                 y = self._core(zxbcdt, True)
             else:
                 # ONE jax.checkpoint: it keeps u W_in, the parameters the
-                # segment reads and the chunk-boundary states
+                # segment reads, the chunk-boundary states and, on the
+                # scan's kernel tier, its y (what the norm's backward
+                # reads: kept, the forward kernel is launched once)
                 segment = jax.checkpoint(
                     lambda data: self._core(NDArray(data), False)._data,
                     policy=jax.checkpoint_policies.save_only_these_names(
-                        ssm_ops.SSD_STATES))
+                        ssm_ops.SSD_STATES, ssm_ops.SSD_OUTPUT))
                 y = NDArray(segment(zxbcdt._data))
             with scope("mamba_proj"):
                 return self.out_proj(y)

@@ -19,6 +19,11 @@ Members (each joins the flash-attention kernels in ops/attention.py):
   x a group's matrix, the same against the transposed matrix, and the
   per-group rows^T x rows of the matrices' gradient), whose grid is as
   long as the groups; ``lax.ragged_dot`` is their XLA tier.
+- :mod:`.ssd_scan` — Mamba-2's chunked selective scan (ops/ssm.py),
+  forward and backward: a chunk's (Q x Q) decay and score matrices live
+  in VMEM and the state of a group's heads rides the grid's chunk axis,
+  where XLA writes those matrices to HBM several times a pass and scans
+  the chunk states in a ``while`` loop.
 
 Dispatch discipline (shared by every kernel in this package, and by
 ``ops.attention.flash_attention``): one ``MXNET_PALLAS`` gate with
@@ -87,7 +92,8 @@ def vmem_tile_budget() -> int:
 
 #: the kernel names the dispatch gate knows (diagnose/chip_smoke vocabulary)
 KERNELS = ("rnn_scan", "rnn_decode_step", "opt_update", "layernorm",
-           "bias_gelu", "flash_attention", "moe_rows", "grouped_dot")
+           "bias_gelu", "flash_attention", "moe_rows", "grouped_dot",
+           "ssd_scan")
 
 # last decision per kernel name: {kernel: (path, reason)}
 _DECISIONS: Dict[str, Tuple[str, str]] = {}
@@ -173,7 +179,8 @@ def count_traced(metric: str, label_key: Optional[str] = None,
     counter ``telemetry.names.<metric>`` (or of one without labels): what
     the op layer counts while a call is traced (dispatch path, flash
     layout and grid steps, attention mask and form, expert dispatch,
-    router rule, row movers and grouped products, MTP modules). Telemetry must never fail a
+    router rule, row movers and grouped products, MTP modules, the
+    selective scan's tier and chunks). Telemetry must never fail a
     kernel call."""
     try:
         from ...telemetry import names as tn

@@ -30,16 +30,29 @@ the same function live here and in the tests:
 - the quadratic form (one S x S ``L`` a head), the benchmark's plain
   reference (benchmark/grid/configs/nemotron-3-nano-30b-a3b.py).
 
-Backward: the chunked form is a ``jax.checkpoint`` that keeps its operands
-and the chunk-boundary states ``H_in`` (named :data:`SSD_STATES`) and
-makes ``cs``, ``L``, ``C B^T`` again; the (Q x Q) decay matrices of every
-chunk and head are never kept from forward to backward. A caller whose own
-``jax.checkpoint`` spans the call (``gluon.nn.Mamba2Mixer``) passes
-``recompute=False`` and names :data:`SSD_STATES` in its policy.
+Two tiers run the chunked form, picked by the kernel layer's gate
+(``kernels.dispatch("ssd_scan")``; ``mx_ssd_scan_total{tier}`` counts the
+traced calls by tier, ``mx_ssd_scan_chunks_total`` their chunks):
 
-XLA runs all of it (no Pallas tier yet: the kernel layer's gate and a
-tier label come with one). ``mx_ssd_scan_chunks_total`` counts the chunks
-of the traced calls.
+- the kernels of ops/kernels/ssd_scan.py on one TPU chip (their bodies
+  under the interpreter with ``MXNET_PALLAS=on`` elsewhere) at chunk 128
+  with the state and a group's head lanes whole multiples of 128 lanes,
+  bf16 or float32: a grid of (batch, group, chunk) that keeps a chunk's
+  (Q x Q) matrices in VMEM and carries the group's state along the chunk
+  axis, forward and, last chunk to first, backward. A ``jax.custom_vjp``
+  keeps the operands and the states ``H_in`` entering the chunks (named
+  :data:`SSD_STATES`) and makes ``cs``, ``L``, ``C B^T`` again in VMEM; y
+  is named :data:`SSD_OUTPUT`;
+- :func:`_ssd_chunked`, XLA's, everywhere else (other backends, a GSPMD
+  mesh, ``MXNET_PALLAS=off``, shapes the kernels decline, with the reason
+  in ``kernels.decisions()``) and as the oracle beside the recurrence: a
+  ``jax.checkpoint`` that keeps its operands and ``H_in`` (the same name)
+  and makes ``cs``, ``L``, ``C B^T`` again, so the (Q x Q) decay matrices
+  of every chunk and head are never kept from forward to backward, though
+  XLA writes them to HBM inside a pass. A caller whose own
+  ``jax.checkpoint`` spans the call (``gluon.nn.Mamba2Mixer``) passes
+  ``recompute=False`` and names :data:`SSD_STATES` (and
+  :data:`SSD_OUTPUT`) in its policy.
 """
 from __future__ import annotations
 
@@ -51,11 +64,15 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["causal_conv1d", "ssd_scan", "ssd_scan_reference",
-           "gated_group_rms_norm", "SSD_STATES"]
+           "gated_group_rms_norm", "SSD_STATES", "SSD_OUTPUT"]
 
 #: the name the chunk-boundary states carry for a ``jax.checkpoint``
 #: policy (``jax.checkpoint_policies.save_only_these_names``)
 SSD_STATES = "ssd_chunk_states"
+#: the name the kernel tier's y carries: a caller's checkpoint that keeps
+#: it with the states launches the forward kernel once (the two are its
+#: only outputs); the XLA tier names no such value
+SSD_OUTPUT = "ssd_scan_output"
 
 _F32 = jnp.float32
 
@@ -168,6 +185,58 @@ def _ssd_chunked(x, dt, A, B, C, D, chunk: int):
     return y.astype(x.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _ssd_kernels(groups, kernel, x, dt, A, B, C, D):
+    """The chunked form on a kernel tier (ops/kernels/ssd_scan.py): ``x``
+    (B, S, H P), ``B`` and ``C`` (B, S, G N), S whole chunks. ``kernel =
+    (tier, precision)`` as ``ops.moe._experts`` has it: the matmul
+    precision asked for where the scan was called, which its backward
+    keeps though it is traced after that ``with`` block has closed."""
+    return _ssd_kernels_fwd(groups, kernel, x, dt, A, B, C, D)[0]
+
+
+def _kernel_keywords(groups, kernel):
+    tier, precision = kernel
+    return {"groups": groups, "precision": precision,
+            "interpret": tier == "interpret"}
+
+
+def _ssd_kernels_fwd(groups, kernel, x, dt, A, B, C, D):
+    from .kernels import ssd_scan as kernels
+    y, entering = kernels.forward(x, dt, A, B, C, D,
+                                  **_kernel_keywords(groups, kernel))
+    # kept from forward to backward: the operands and the states entering
+    # the chunks, the latter by the name a caller's checkpoint knows
+    return (checkpoint_name(y, SSD_OUTPUT),
+            (x, dt, A, B, C, D, checkpoint_name(entering, SSD_STATES)))
+
+
+def _ssd_kernels_bwd(groups, kernel, kept, dy):
+    from .kernels import ssd_scan as kernels
+    operands = kept[:6]
+    grads = kernels.backward(*kept, dy, **_kernel_keywords(groups, kernel))
+    return tuple(g.astype(a.dtype) for g, a in zip(grads, operands))
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
+def _ssd_on_kernels(x, dt, A, B, C, D, chunk, kernel):
+    """:func:`_ssd_kernels` behind what lays its operands out: the heads'
+    and groups' lanes side by side (no copy), the tail padded to a whole
+    chunk with ``dt = 0``, a skip of zero where there is none."""
+    batch, seq, heads, width = x.shape
+    groups = B.shape[2]
+    flat = [a.reshape(batch, seq, -1) for a in (x, B, C)] + [dt]
+    pad = -seq % chunk
+    if pad:
+        flat = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in flat]
+    x_, B_, C_, dt_ = flat
+    y = _ssd_kernels(groups, kernel, x_, dt_, A, B_, C_,
+                     jnp.zeros_like(A) if D is None else D)
+    return y[:, :seq].reshape(x.shape)
+
+
 def ssd_scan(x, dt, A, B, C, D=None, chunk: int = 128,
              recompute: bool = True):
     """The selective scan of a state-space mixer, chunked. ``x`` (B, S,
@@ -176,11 +245,26 @@ def ssd_scan(x, dt, A, B, C, D=None, chunk: int = 128,
     (H / G); ``D`` (H,) the skip's weight or None; S need be no multiple
     of ``chunk``. Returns y (B, S, H, P) in x's dtype.
 
-    ``recompute`` (default): the call is its own ``jax.checkpoint`` that
-    keeps the operands and the chunk-boundary states. False where the
-    caller's checkpoint spans the call."""
-    from .kernels import count_traced
+    The kernel layer's gate picks the tier (``"ssd_scan"`` in
+    ``kernels.decisions()``, ``mx_ssd_scan_total{tier}``): the kernels of
+    ops/kernels/ssd_scan.py on one TPU chip at shapes they take,
+    :func:`_ssd_chunked` everywhere else.
+
+    ``recompute`` (default): the XLA tier is its own ``jax.checkpoint``
+    that keeps the operands and the chunk-boundary states. False where the
+    caller's checkpoint spans the call. The kernel tier's custom VJP keeps
+    just those either way."""
+    from .kernels import count_traced, dispatch
+    from .kernels import ssd_scan as kernels
     count_traced("SSD_SCAN_CHUNKS", n=-(-x.shape[1] // chunk))
+    precision = jax.config.jax_default_matmul_precision
+    why = kernels.supported(x.shape[2], x.shape[3], B.shape[2], B.shape[3],
+                            chunk, x.dtype, B.dtype, C.dtype,
+                            precision=precision)
+    tier = dispatch("ssd_scan", supported=why is None, reason=why)[0]
+    count_traced("SSD_SCAN", "tier", tier)
+    if tier != "xla":
+        return _ssd_on_kernels(x, dt, A, B, C, D, chunk, (tier, precision))
     scan = functools.partial(_ssd_chunked, chunk=chunk)
     if recompute:
         scan = jax.checkpoint(

@@ -162,6 +162,7 @@ MOE_ROUTER = "mx_moe_router_total"
 LATENT_ATTENTION = "mx_latent_attention_total"
 MTP_MODULES = "mx_mtp_modules_total"
 SSD_SCAN_CHUNKS = "mx_ssd_scan_chunks_total"
+SSD_SCAN = "mx_ssd_scan_total"
 MAMBA_RECOMPUTE = "mx_mamba_recompute_total"
 
 # ---------------------------------------------------------------------------
@@ -545,6 +546,15 @@ CATALOG = {
              "recurrence, XLA's matrix products and a scan over the "
              "chunk states): ceil(sequence / chunk) a call (32 at 4,096 "
              "positions and the published chunk of 128)"),
+    SSD_SCAN: dict(
+        kind="counter", label="tier",
+        help="traced selective scans of a state-space mixer (ops/ssm.py "
+             "ssd_scan) by tier: pallas = the kernels of "
+             "ops/kernels/ssd_scan.py, compiled (one TPU chip, chunk 128, "
+             "state and a group's head lanes whole multiples of 128); "
+             "interpret = their bodies under the interpreter "
+             "(MXNET_PALLAS=on off the chip); xla = _ssd_chunked, XLA's "
+             "products and a lax.scan over the chunk states"),
     MAMBA_RECOMPUTE: dict(
         kind="counter", label="span",
         help="state-space mixer layers (gluon.nn.Mamba2Mixer) by what "

@@ -123,18 +123,20 @@ def test_hybrid_phase_is_listed_and_its_dry_path_runs(monkeypatch):
     assert list(chip_smoke.phases()) == ["device", "train", "dp", "kernels",
                                          "hybrid", "serve"]
     monkeypatch.setenv("MXNET_PALLAS", "on")
-    cfg = dict(chip_smoke.HYBRID, batch=2, seq=32, steps=4,
-               scan=dict(seq=40, heads=4, groups=2), model=dict(
+    # heads of 64 lanes over a state of 128 in chunks of 128, as the
+    # phase's own: shapes the scan's kernels take
+    cfg = dict(chip_smoke.HYBRID, batch=2, seq=160, steps=4,
+               scan=dict(seq=300, heads=4, groups=2), model=dict(
                    chip_smoke.HYBRID["model"], hidden_size=128,
-                   mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16,
-                   chunk_size=8, head_dim=32, moe_intermediate_size=128,
+                   mamba_num_heads=4, head_dim=32, moe_intermediate_size=128,
                    moe_shared_expert_intermediate_size=128, vocab_size=128))
     out = chip_smoke.phase_hybrid(cfg)
     assert set(out) == {"scan_gap", "lm"}
     assert out["scan_gap"]["float32"] < 1e-5 < out["scan_gap"]["bfloat16"]
     got = out["lm"]
     assert got["loss"][-1] < got["loss"][0]
-    assert got["mx_ssd_scan_chunks_total"] == 2 * 4
+    assert got["mx_ssd_scan_chunks_total"] == 2 * 2
+    assert got["mx_ssd_scan_total"] == {"interpret": 2}
     assert got["mx_mamba_recompute_total"] == {"segment": 2}
     assert got["mx_moe_router_total"] == {"sigmoid": 2}
     # widths of 128 and a list of 128 rows: the kernels' bodies, two

@@ -171,24 +171,45 @@ def test_scan_keeps_its_operands_dtype_and_float32_decays():
     before = telemetry.value(tnames.SSD_SCAN_CHUNKS) or 0
     SSM.ssd_scan(x, dt, A, B, C, chunk=5)
     assert telemetry.value(tnames.SSD_SCAN_CHUNKS) == before + 7
-    # no Pallas tier yet, so no gate to decide and no tier to label
-    assert "ssd_scan" not in kernels.KERNELS
-    assert "ssd_scan" not in kernels.decisions()
+    # the kernel layer's gate decides the tier and says why: chunks of 5
+    # are not the kernels' (tests/test_ssd_scan_kernel.py has the rest)
+    assert "ssd_scan" in kernels.KERNELS
+    assert kernels.decisions()["ssd_scan"][0] == "xla"
+    assert "chunks of 5" in kernels.decisions()["ssd_scan"][1]
 
 
-def test_scan_backward_keeps_the_chunk_states_and_no_decay_matrix():
-    """What ``ssd_scan`` saves from forward to backward: its operands and
-    the chunk-boundary states, nothing of a chunk's (Q x Q) size."""
-    args = _scan_inputs(32, 2)
+@pytest.mark.parametrize("tier", ["xla", "interpret"])
+def test_scan_backward_keeps_the_chunk_states_and_no_decay_matrix(
+        tier, monkeypatch):
+    """What ``ssd_scan`` saves from forward to backward on either tier: its
+    operands and the chunk-boundary states, nothing of a chunk's (Q x Q)
+    size. The XLA tier's ``jax.checkpoint`` sees to it, the kernel tier's
+    custom VJP (heads of 64 lanes, state 128, chunks of 128: shapes the
+    kernels take, their bodies under the interpreter)."""
+    monkeypatch.setenv("MXNET_PALLAS", "on" if tier == "interpret" else "off")
+    chunk, groups = (128, 2) if tier == "interpret" else (8, 2)
+    args = _scan_inputs(32, 2) if tier == "xla" else _scan_inputs(
+        256, groups, batch=1, heads=8, width=64, state=128)
     batch, seq, heads, width = args[0].shape
-    chunk, state = 8, args[3].shape[-1]
+    state = args[3].shape[-1]
     def kept(fn):
         # the leaves of a vjp function are what the forward kept for it
         _, pull = jax.vjp(fn, *args)
         return [tuple(a.shape) for a in jax.tree_util.tree_leaves(pull)]
     shapes = kept(lambda *a: SSM.ssd_scan(*a, chunk=chunk))
-    assert (batch, seq // chunk, 2, heads // 2, width, state) in shapes
+    assert kernels.decisions()["ssd_scan"][0] == tier
+    per_group = heads // groups
+    entering = (batch, seq // chunk, groups, per_group, width, state) \
+        if tier == "xla" else \
+        (batch, seq // chunk, groups, state, per_group * width)
+    assert entering in shapes
     assert not [s for s in shapes if s[-2:] == (chunk, chunk)]
+    if tier == "interpret":
+        # the operands as the kernels read them, the states, and no more
+        operands = [(batch, seq, heads * width), (batch, seq, heads),
+                    (heads,), (batch, seq, groups * state),
+                    (batch, seq, groups * state), (heads,)]
+        assert sorted(shapes) == sorted(operands + [entering])
     assert [s for s in kept(lambda *a: SSM._ssd_chunked(*a, chunk))
             if s[-2:] == (chunk, chunk)]
 
