@@ -191,11 +191,12 @@ class DispatchWindow:
     def push(self, payload, tag=None, aux=None):
         """Record one dispatched async result; returns immediately unless
         the window is over capacity, in which case the OLDEST entry
-        retires (blocks until that step completed). ``aux`` is an
-        optional numerics record (``telemetry.StepNumerics``) riding
-        alongside the payload: its on-device statistics are read at
-        this entry's retire — inside the same blessed sync, after the
-        step's program has completed — so numerics stay sync-free."""
+        retires (blocks until that step completed). ``aux`` is the
+        step's optional ``telemetry.StepAux`` riding alongside the
+        payload (a numerics record, the device counters its ops
+        emitted): what the device computed is read at this entry's
+        retire — inside the same blessed sync, after the step's program
+        has completed — so both stay sync-free."""
         st = self.stats
         with self._mu:
             st["pushes"] += 1
@@ -267,18 +268,21 @@ class DispatchWindow:
         blocking sync itself, recorded around it) + watchdog feed for
         one retire — gated on MXNET_TELEMETRY / an active profiler; must
         never kill a run.
-        The numerics aux (when the step was compiled with numerics
-        instrumentation) is consumed FIRST and regardless of the
-        telemetry gate — MXNET_NUMERICS is its own opt-in."""
+        The aux's numerics part (when the step was compiled with
+        numerics instrumentation) is consumed FIRST and regardless of
+        the telemetry gate — MXNET_NUMERICS is its own opt-in. Its
+        device counters are read only past the gate, onto the ``window``
+        span; with telemetry off they are dropped unread."""
         t = _telemetry()
         try:
-            if aux is not None:
-                t.numerics.monitor().observe_retire(tag, aux)
+            if aux is not None and aux.numerics is not None:
+                t.numerics.monitor().observe_retire(tag, aux.numerics)
             if not t.active():
                 self._last_retire_t = None
                 return
             t_done = time.perf_counter()
-            t.timeline().record("window", t_push, t_done, step=tag)
+            t.timeline().record("window", t_push, t_done, step=tag,
+                                counters=t.device_counters.observe(aux))
             dt = None if self._last_retire_t is None \
                 else t_done - self._last_retire_t
             self._last_retire_t = t_done

@@ -59,6 +59,13 @@ Contracts:
   numerics=off, and under ZeRO the reductions are psum-composed from
   the flat shards so every replica reports true global norms
   (telemetry/numerics.py; docs/OBSERVABILITY.md "numerics").
+- **Device counters.** What an op emits while the loss function is
+  traced (``telemetry/device_counters.py``: the pairs a router gave its
+  held experts) leaves every step program the same way, numerics on or
+  off: the program's LAST output is one aux pytree with a ``numerics``
+  and a ``counters`` part, each only where there is one. A program with
+  neither returns an empty pytree there, no output of the lowered
+  program, so its text is what it was without the channel.
 """
 from __future__ import annotations
 
@@ -78,6 +85,7 @@ from ..analysis import guard as _tguard
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from ..ndarray.random import next_key, push_trace_key, pop_trace_key
+from ..telemetry import device_counters as _dcounters
 from ..testing.faults import fault_point
 from .block import UNTRACEABLE_ERRORS, ParamBinding, _TRACED
 
@@ -387,6 +395,12 @@ class _ZeroShardPlan:
         return total
 
 
+def _aux_outputs(numerics, counters) -> dict:
+    """A step program's last output: the parts it has, none empty."""
+    parts = {"numerics": numerics, "counters": counters}
+    return {k: v for k, v in parts.items() if v}
+
+
 def _infer_batch_size(traced) -> int:
     for leaf in traced:
         d = leaf._data if isinstance(leaf, NDArray) else leaf
@@ -429,7 +443,7 @@ class CompiledTrainStep:
         # MXNET_NUMERICS. Part of the bucket signature — switching mode
         # compiles a fresh instrumented program.
         self._numerics = _telemetry().numerics.mode(numerics)
-        self._pending_numerics = None
+        self._pending_aux = None    # the last step's StepAux
         # ZeRO-1 sharded update: None = auto (on when a mesh with a
         # `zero_axis` axis is active), True = required, False = off
         self._zero_requested = zero_shard
@@ -491,15 +505,24 @@ class CompiledTrainStep:
         shape bucket; existing buckets stay cached."""
         self._numerics = _telemetry().numerics.mode(mode or "off")
 
+    def take_aux(self):
+        """Pop the :class:`~mxnet_tpu.telemetry.StepAux` of the most
+        recent step: its numerics record and the counters its ops
+        emitted, either part None; None where the program returned
+        neither. The TrainLoop pushes this into the dispatch window
+        alongside the loss, so both are read sync-free at the blessed
+        retire."""
+        aux, self._pending_aux = self._pending_aux, None
+        return aux
+
     def take_numerics(self):
         """Pop the :class:`~mxnet_tpu.telemetry.StepNumerics` record of
-        the most recent step (None when numerics is off). The TrainLoop
-        pushes this into the dispatch window alongside the loss so the
-        statistics are read sync-free at the blessed retire; windowless
-        callers can hand it to ``telemetry.numerics.monitor()`` or read
-        :meth:`numerics_values` directly."""
-        rec, self._pending_numerics = self._pending_numerics, None
-        return rec
+        the most recent step (None when numerics is off), for windowless
+        callers: hand it to ``telemetry.numerics.monitor()`` or read
+        :meth:`numerics_values` directly. The step's device counters go
+        with it, unread."""
+        aux = self.take_aux()
+        return None if aux is None else aux.numerics
 
     def numerics_values(self) -> Optional[dict]:
         """Convenience synchronous read of the last step's numerics:
@@ -962,7 +985,7 @@ class CompiledTrainStep:
             prev_s = _tape.set_taping_suspended(True)
             prev_t = _tape.set_training(train)
             try:
-                with binding:
+                with binding, _dcounters.collect() as emitted:
                     out = loss_fn(*args, **kwargs)
             finally:
                 _tape.set_recording(prev_r)
@@ -972,14 +995,19 @@ class CompiledTrainStep:
             l = out._data if isinstance(out, NDArray) else jnp.asarray(out)
             # differentiate the SUM: identical to loss.backward() seeding
             # ones over the per-sample loss vector
-            return jnp.sum(l), (l, binding.state)
+            return jnp.sum(l), (l, binding.state, emitted)
+
+        def stack_counters(emitted):
+            # under the numerics phase, which the trace readers know
+            with jax.named_scope(PHASE_NUMERICS):
+                return _dcounters.stacked(emitted)
 
         def grad_part(pds, traced_leaves, key):
             with jax.named_scope(PHASE_LOSS_AND_GRAD):
-                (_, (l, state)), grads = jax.value_and_grad(
+                (_, (l, state, emitted)), grads = jax.value_and_grad(
                     run_loss, has_aux=True)(tuple(pds), traced_leaves, key)
             gs = tuple(grads[i] for i in t_pos)
-            return l, state, gs
+            return l, state, gs, stack_counters(emitted)
 
         if self._zero is not None:
             # ZeRO-1 sharded update: grads constrained to the flat
@@ -1103,8 +1131,8 @@ class CompiledTrainStep:
                 with jax.named_scope(PHASE_GRAD_REDUCE):
                     packed = pack_buckets(pds)
                 with jax.named_scope(PHASE_LOSS_AND_GRAD):
-                    (_, (l, state)), grad_bufs = jax.value_and_grad(
-                        run_loss_bufs, has_aux=True)(
+                    (_, (l, state, emitted)), grad_bufs = \
+                        jax.value_and_grad(run_loss_bufs, has_aux=True)(
                             packed, pds, traced_leaves, key)
                 n_units = len(units)
                 ws_u = [None] * n_units
@@ -1186,7 +1214,7 @@ class CompiledTrainStep:
                     # out
                     new_sts = tuple(tuple(wsc(s, shard) for s in st)
                                     for st in new_sts_u)
-                out = (tuple(new_pds), new_sts, tuple(new_masters), l)
+                numerics_aux = None
                 if numerics:
                     with jax.named_scope(PHASE_NUMERICS):
                         gs_log = ()
@@ -1202,9 +1230,10 @@ class CompiledTrainStep:
                                                             idx)):
                                     _scatter_members(gs_log, k, flat,
                                                      to_pds=False)
-                        out = out + (zero_aux(ws_u, gs_u, new_ws, gs_log,
-                                              rescale),)
-                return out
+                        numerics_aux = zero_aux(ws_u, gs_u, new_ws, gs_log,
+                                                rescale)
+                return (tuple(new_pds), new_sts, tuple(new_masters), l,
+                        _aux_outputs(numerics_aux, stack_counters(emitted)))
 
             def zero_aux(ws_u, gs_u, new_ws, gs, rescale):
                 """Numerics aux from the flat 1/N-per-replica unit
@@ -1303,7 +1332,7 @@ class CompiledTrainStep:
         def fused_step(pds, sts, traced_leaves, lrs, wds, ts, rescale,
                        clip, key):
             step_self._n_traces += 1
-            l, state, gs = grad_part(pds, traced_leaves, key)
+            l, state, gs, counters = grad_part(pds, traced_leaves, key)
             ws = tuple(pds[i] for i in t_pos)
             with jax.named_scope(PHASE_OPTIMIZER_UPDATE):
                 new_ws, new_sts = opt_fn(ws, gs, lrs, wds, ts, rescale,
@@ -1311,11 +1340,12 @@ class CompiledTrainStep:
             new_pds = list(state)   # BN-stat rebinds + identity for rest
             for j, i in enumerate(t_pos):
                 new_pds[i] = new_ws[j]
-            out = (tuple(new_pds), new_sts, l)
+            numerics_aux = None
             if numerics:
                 with jax.named_scope(PHASE_NUMERICS):
-                    out = out + (fused_aux(ws, gs, new_ws, rescale),)
-            return out
+                    numerics_aux = fused_aux(ws, gs, new_ws, rescale)
+            return (tuple(new_pds), new_sts, l,
+                    _aux_outputs(numerics_aux, counters))
 
         return {"kind": "fused",
                 "fn": jax.jit(fused_step, donate_argnums=donate),
@@ -1363,14 +1393,9 @@ class CompiledTrainStep:
         ulrs, uwds, uts = plan.pack_hparams(self._trainer._optimizer,
                                             lrs, wds, ts)
         key = next_key()
-        outs = entry["fn"](
+        new_pds, new_sts, new_masters, l, auxd = entry["fn"](
             pds, sts, masters, leaf_datas, ulrs, uwds, uts, rescale, clip,
             key)
-        if entry.get("numerics"):
-            new_pds, new_sts, new_masters, l, auxd = outs
-        else:
-            new_pds, new_sts, new_masters, l = outs
-            auxd = None
         # writeback: same handles, new buffers (donation contract); the
         # state/master handles stay sharded across steps
         for p, nw in zip(self._all_params, new_pds):
@@ -1380,9 +1405,7 @@ class CompiledTrainStep:
                 s._data = n
         for m, nm in zip(plan.masters, new_masters):
             m._data = nm
-        if auxd is not None:
-            self._stash_numerics(entry, auxd, leaf_datas, batch_size,
-                                 key)
+        self._stash_aux(entry, auxd, leaf_datas, batch_size, key)
         return NDArray(l)
 
     def _fused_call(self, args, kwargs, batch_size):
@@ -1420,7 +1443,8 @@ class CompiledTrainStep:
         key = next_key()
 
         if entry["kind"] == "split":
-            l, state, gs = entry["grad"](pds, leaf_datas, key)
+            l, state, gs, counters = entry["grad"](pds, leaf_datas, key)
+            auxd = _aux_outputs(None, counters)
             # land gradients on the Parameter grad handles and reuse the
             # Trainer's own reduction machinery (bucketed pushpull_list)
             tr = self._trainer
@@ -1436,12 +1460,8 @@ class CompiledTrainStep:
                 new_pds[i] = new_ws[j]
         else:
             fn = entry["exe"] or entry["fn"]
-            outs = fn(pds, sts, leaf_datas, lrs, wds, ts,
-                      rescale, clip, key)
-            if entry.get("numerics"):
-                new_pds, new_sts, l, auxd = outs
-            else:
-                (new_pds, new_sts, l), auxd = outs, None
+            new_pds, new_sts, l, auxd = fn(
+                pds, sts, leaf_datas, lrs, wds, ts, rescale, clip, key)
 
         # writeback: same handles, new buffers (donation contract)
         for p, nw in zip(self._all_params, new_pds):
@@ -1449,30 +1469,35 @@ class CompiledTrainStep:
         for st, ns in zip(states, new_sts):
             for s, n in zip(st, ns):
                 s._data = n
-        if entry["kind"] != "split" and auxd is not None:
-            self._stash_numerics(entry, auxd, leaf_datas, batch_size,
-                                 key)
+        self._stash_aux(entry, auxd, leaf_datas, batch_size, key)
         return NDArray(l)
 
-    # ---------------- numerics plumbing ----------------
-    def _stash_numerics(self, entry, auxd, leaf_datas, batch_size, key):
-        """Wrap this step's on-device aux in a StepNumerics record for
-        the dispatch window: small device scalars (still async), the
-        host-side lr/loss-scale context, and the one-shot NaN-origin
-        forensic closure over the CAPTURED input batch + RNG key.
-        Holding the leaf refs keeps at most window-depth input batches
-        alive — the price of being able to replay the faulting batch.
-        Must never kill a step."""
+    # ---------------- aux plumbing ----------------
+    def _stash_aux(self, entry, auxd, leaf_datas, batch_size, key):
+        """Wrap the program's aux output in this step's ``StepAux`` for
+        the dispatch window, or leave None where the program has neither
+        part. The numerics part becomes a StepNumerics record: small
+        device scalars (still async), the host-side lr/loss-scale
+        context, and the one-shot NaN-origin forensic closure over the
+        CAPTURED input batch + RNG key. Holding the leaf refs keeps at
+        most window-depth input batches alive — the price of being able
+        to replay the faulting batch. The counters part stays the device
+        arrays it is. Must never kill a step."""
+        self._pending_aux = None
+        if not auxd:
+            return
         t = _telemetry()
         try:
-            rec = t.numerics.StepNumerics(
-                mode=entry["numerics"], raw=auxd,
-                param_names=self._numerics_param_names(),
-                context=self._numerics_context(batch_size),
-                forensic=self._make_forensic(entry, leaf_datas, key))
-            self._pending_numerics = rec
+            rec = None
+            if "numerics" in auxd:
+                rec = t.numerics.StepNumerics(
+                    mode=entry["numerics"], raw=auxd["numerics"],
+                    param_names=self._numerics_param_names(),
+                    context=self._numerics_context(batch_size),
+                    forensic=self._make_forensic(entry, leaf_datas, key))
+            self._pending_aux = t.StepAux(rec, auxd.get("counters"))
         except Exception:        # pragma: no cover - defensive
-            _LOG.warning("numerics stash failed", exc_info=True)
+            _LOG.warning("aux stash failed", exc_info=True)
 
     def _numerics_param_names(self):
         """UNIQUE trainable-parameter names in trainer._params order:
@@ -1526,7 +1551,7 @@ class CompiledTrainStep:
         info["offending_op"] = t.numerics.localize_nonfinite(
             lambda: probe(pds, leaf_datas, key))
         try:
-            l, _state, gs = jax.jit(probe)(pds, leaf_datas, key)
+            l, _state, gs, _ = jax.jit(probe)(pds, leaf_datas, key)
             lv = onp.asarray(l, dtype="float64")
             info["loss"] = float(lv.mean())
             layers = []
@@ -1760,42 +1785,6 @@ class CompiledTrainStep:
             entry["flops"] = None
         return entry["flops"]
 
-    # ---------------- telemetry (mx.telemetry MFU gauge) ----------------
-    def step_flops(self, *args, batch_size: Optional[int] = None,
-                   **kwargs):
-        """FLOPs of THIS batch bucket's compiled program, from XLA's
-        ``cost_analysis()`` — the numerator of the live MFU gauge
-        (docs/OBSERVABILITY.md). Reuses the AOT executable's count when
-        :meth:`aot_compile` ran; otherwise lowers+compiles the bucket
-        once via the cached :meth:`lower_entry` analysis artifact and
-        caches the count. Returns None on the eager path (no program)
-        or where cost_analysis is unavailable. For the split (dist
-        store) mode the count covers the grad program only — the update
-        program's FLOPs are negligible next to fwd+bwd."""
-        if self._mode is None:
-            self._mode = self._decide_mode()
-        if self._mode != "fused":
-            return None
-        entry, _ = self._entry_for(args, kwargs)
-        if entry.get("flops") is not None:
-            return entry["flops"]
-        if "flops_cost" in entry:
-            return entry["flops_cost"]
-        flops = None
-        try:
-            info = self.lower_entry(*args, batch_size=batch_size,
-                                    **kwargs)
-            if info is not None:
-                ca = info["lowered"].compile().cost_analysis()
-                ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-                f = float(ca.get("flops", 0.0))
-                flops = f if f > 0 else None
-        except Exception as e:   # pragma: no cover - platform-dependent
-            _LOG.warning("step_flops: cost_analysis unavailable "
-                         "(%s: %s)", type(e).__name__, e)
-        entry["flops_cost"] = flops
-        return flops
-
 
 class TrainLoop:
     """Convenience wrapper for the canonical (net, loss, trainer) triple:
@@ -1928,10 +1917,11 @@ class TrainLoop:
             self._global_step = step_no
             self._m_steps.inc()
             d = loss._data if isinstance(loss, NDArray) else loss
-            # the numerics aux (MXNET_NUMERICS) rides the window with
-            # the loss and is read at the blessed retire — sync-free
+            # the step's aux (numerics record, device counters) rides
+            # the window with the loss and is read at the blessed
+            # retire — sync-free
             self._window.push(d, tag=self._global_step,
-                              aux=self._step.take_numerics())
+                              aux=self._step.take_aux())
             if self._manager is not None and self._every and \
                     self._global_step % self._every == 0:
                 with _tguard.allow_transfers("checkpoint snapshot"):
@@ -1997,25 +1987,6 @@ class TrainLoop:
         self._prefetcher = DevicePrefetcher(
             batches, depth=depth, place=self._step.input_placement())
         return self._prefetcher
-
-    def arm_mfu(self, *batch, peak_flops: Optional[float] = None,
-                batch_size: Optional[int] = None) -> Optional[float]:
-        """Arm the live MFU gauge (``mx_model_mfu_ratio``): read this
-        batch bucket's FLOPs from XLA ``cost_analysis()``
-        (:meth:`CompiledTrainStep.step_flops`) into the telemetry
-        watchdog; ``peak_flops`` (FLOP/s — bench's measured roofline or
-        the chip's spec peak) arms the denominator. The watchdog then
-        updates flops/s and MFU on every window retire. Call OUTSIDE
-        the timed loop: the first call per bucket may pay one
-        lower+compile. Returns the per-step FLOPs (None where no
-        compiled program / cost model exists)."""
-        flops = self._step.step_flops(*batch, batch_size=batch_size)
-        wd = _telemetry().watchdog()
-        if flops:
-            wd.set_model_flops(flops)
-        if peak_flops:
-            wd.set_peak_flops(peak_flops)
-        return flops
 
     def engine_stats(self) -> dict:
         """Dispatch/prefetch observability: the in-flight window size and

@@ -21,6 +21,7 @@ from ...ndarray.ndarray import NDArray
 from ...ops.registry import invoke_raw
 from ...ops import moe as moe_ops
 from ...ops.kernels import count_traced
+from ...telemetry import device_counters, names as _names
 from ..block import HybridBlock
 from ..parameter import Parameter
 
@@ -203,6 +204,8 @@ class SparseMoE(HybridBlock):
         tokens = self._tokens(x)
         weights, order, place, sizes = routing or self.route(x)
         count_traced("MOE_DISPATCH", "path", "grouped")
+        # the pairs each held expert was given, out of the compiled step
+        device_counters.emit(_names.COUNTER_MOE_HELD_PAIRS, sizes)
         no_gate = () if self._gated else (None,)
 
         def experts(x_, order_, place_, sizes_, *weights):
@@ -219,7 +222,10 @@ class SparseMoE(HybridBlock):
     def routing_stats(self, x):
         """Eager, outside any step: ``{"pairs": pairs each held expert is
         given for the tokens of x, "held_share": their share of all
-        tokens x top_k pairs}`` as numpy / float."""
+        tokens x top_k pairs}`` as numpy / float. Inside a compiled
+        train step the same pairs are the device counter
+        ``moe_held_pairs``, a row a layer on the step's ``window`` span
+        (docs/OBSERVABILITY.md "Device counters")."""
         import numpy as onp
         bias = None if self.router_bias is None else \
             self.router_bias.data()._data

@@ -16,15 +16,17 @@ hand-rolled bench plumbing) with one subsystem:
    the catalog in :mod:`.names`, behind pluggable exporters
    (:mod:`.exporters`): JSON :func:`snapshot`, Prometheus text file,
    periodic structured-log heartbeat.
-3. **MFU gauge + anomaly watchdog** (:mod:`.watchdog`): per-bucket
-   FLOPs from XLA ``cost_analysis()`` over measured step time, plus
-   NaN/inf-loss and step-time-stall detection piggybacked on window
-   retires.
+3. **Anomaly watchdog** (:mod:`.watchdog`): the retire-to-retire step
+   time, NaN/inf-loss and step-time-stall detection piggybacked on
+   window retires.
 
 Two further domains build on these: device memory (:mod:`.memory` —
 HBM accounting, buffer census, OOM forensics) and training numerics
 (:mod:`.numerics` — in-program grad/param health threaded through the
-compiled step, divergence watchdog, NaN-origin forensics).
+compiled step, divergence watchdog, NaN-origin forensics). What the
+device computes about a step's own work (:mod:`.device_counters`: the
+pairs a router gave its held experts) leaves the program the same way
+and lands on the step's ``window`` span.
 
 Cost model: registry counters/gauges are ALWAYS on (one uncontended
 lock + float update per event, no host syncs — the transfer guard is
@@ -48,6 +50,8 @@ from . import memory
 from .memory import BufferCensus, MemoryReport, census
 from . import numerics
 from .numerics import NumericsMonitor, StepNumerics
+from . import device_counters
+from .device_counters import StepAux
 from .exporters import (SCHEMA_VERSION, Heartbeat, heartbeat_interval,
                         prometheus_file, prometheus_text, snapshot,
                         start_heartbeat, stop_heartbeat,
@@ -61,7 +65,7 @@ __all__ = ["names", "registry", "MetricsRegistry", "Counter", "Gauge",
            "heartbeat_interval", "SCHEMA_VERSION", "enabled", "enable",
            "span", "value", "reset", "memory", "census", "BufferCensus",
            "MemoryReport", "numerics", "NumericsMonitor",
-           "StepNumerics"]
+           "StepNumerics", "device_counters", "StepAux"]
 
 # every catalog series exists from import time: an exporter always shows
 # the full schema (zero is information; absence is a question)

@@ -197,8 +197,7 @@ def _heartbeat_payload() -> dict:
         if m is None:
             continue
         out[k] = _metric_values(m)
-    for k in (names.STEP_TIME_EWMA, names.MFU,
-              names.MODEL_FLOPS_PER_SEC, names.NUMERICS_GRAD_NORM,
+    for k in (names.STEP_TIME_EWMA, names.NUMERICS_GRAD_NORM,
               names.NUMERICS_PARAM_NORM):
         g = reg.get(k)
         v = g.value() if g is not None else None

@@ -96,11 +96,8 @@ STEP_PHASE_SECONDS = "mx_step_phase_seconds"
 STEP_TIME_SECONDS = "mx_step_time_seconds"
 
 # ---------------------------------------------------------------------------
-# MFU gauge + anomaly watchdog (telemetry/watchdog.py)
+# step-time + anomaly watchdog (telemetry/watchdog.py)
 # ---------------------------------------------------------------------------
-MODEL_FLOPS_PER_STEP = "mx_model_flops_per_step"
-MODEL_FLOPS_PER_SEC = "mx_model_flops_per_sec"
-MFU = "mx_model_mfu_ratio"
 STEP_TIME_EWMA = "mx_watchdog_step_time_ewma_seconds"
 ANOMALIES = "mx_anomalies_total"
 
@@ -159,11 +156,29 @@ MOE_DISPATCH = "mx_moe_dispatch_total"
 MOE_ROW_MOVER = "mx_moe_row_mover_total"
 MOE_GROUPED_DOT = "mx_moe_grouped_dot_total"
 MOE_ROUTER = "mx_moe_router_total"
+MOE_HELD_PAIRS = "mx_moe_held_pairs"
+MOE_EXPERT_LOAD_MAX_RATIO = "mx_moe_expert_load_max_ratio"
 LATENT_ATTENTION = "mx_latent_attention_total"
 MTP_MODULES = "mx_mtp_modules_total"
 SSD_SCAN_CHUNKS = "mx_ssd_scan_chunks_total"
 SSD_SCAN = "mx_ssd_scan_total"
 MAMBA_RECOMPUTE = "mx_mamba_recompute_total"
+
+# ---------------------------------------------------------------------------
+# device counters (telemetry/device_counters.py): numbers the DEVICE
+# computes inside a compiled train step, returned with its outputs and
+# read at the window retire. The names below key the ``counters`` of a
+# ``window`` span; they are no registry series (no ``mx_`` prefix)
+# ---------------------------------------------------------------------------
+DEVICE_COUNTER_DROPPED = "mx_device_counter_dropped_total"
+COUNTER_MOE_HELD_PAIRS = "moe_held_pairs"
+DEVICE_COUNTERS = {
+    COUNTER_MOE_HELD_PAIRS:
+        "int32[expert layers in trace order, held experts]: the "
+        "token-expert pairs each held expert of a dropless expert layer "
+        "was given this step (gluon.nn.SparseMoE.forward; the group "
+        "sizes of ops/moe.py moe_route)",
+}
 
 # ---------------------------------------------------------------------------
 # inference serving engine (serving/batcher.py)
@@ -328,15 +343,6 @@ CATALOG = {
     STEP_TIME_SECONDS: dict(
         kind="histogram", label=None,
         help="retire-to-retire step wall time (pipelined steady state)"),
-    MODEL_FLOPS_PER_STEP: dict(
-        kind="gauge", label=None,
-        help="XLA cost_analysis FLOPs of one compiled train step"),
-    MODEL_FLOPS_PER_SEC: dict(
-        kind="gauge", label=None,
-        help="flops_per_step / measured step time"),
-    MFU: dict(
-        kind="gauge", label=None,
-        help="model FLOPs utilization vs the configured roofline"),
     STEP_TIME_EWMA: dict(
         kind="gauge", label=None,
         help="exponentially-weighted mean step time the stall detector "
@@ -526,6 +532,25 @@ CATALOG = {
              "sigmoid = the top-k of sigmoid scores plus a selection "
              "bias, weighed by the normalised, scaled scores; ops/moe.py "
              "moe_route); one count a traced router"),
+    MOE_HELD_PAIRS: dict(
+        kind="gauge", label="layer",
+        help="token-expert pairs the held experts of one dropless "
+             "expert layer (trace order) were given in the last step "
+             "observed at its window retire: the device counter "
+             "moe_held_pairs summed over the layer's experts; set only "
+             "while telemetry is active"),
+    MOE_EXPERT_LOAD_MAX_RATIO: dict(
+        kind="gauge", label="layer",
+        help="the most loaded held expert's pairs over the mean of the "
+             "layer's held experts, same step and layer as "
+             "mx_moe_held_pairs: 1 is balance"),
+    DEVICE_COUNTER_DROPPED: dict(
+        kind="counter", label="name",
+        help="device-counter values dropped while a step was traced: "
+             "emitted under a transformation the step's collector "
+             "cannot see out of (an inner jax.checkpoint, lax.scan, "
+             "custom_vjp rule or jit), or sites of one name whose "
+             "shapes differ; one count a traced site"),
     LATENT_ATTENTION: dict(
         kind="counter", label="form",
         help="latent-attention (MLA) layers by the form they run in "

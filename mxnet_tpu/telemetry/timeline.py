@@ -5,7 +5,8 @@ One pipelined train step passes through five host-observable phases —
     batch_fetch   producer pulls + stages the batch (prefetcher thread)
     h2d_wait      consumer wait on the staged device-resident batch
     dispatch      host time inside the compiled step call (enqueue)
-    window        residency in the in-flight dispatch window (push->done)
+    window        residency in the in-flight dispatch window (push->done);
+                  carries the step's device counters (``counters``)
     retire        the blocking wait at the window boundary (FIFO oldest)
 
 plus ``checkpoint`` for snapshot captures. Each instrumentation point
@@ -97,7 +98,9 @@ class _Span:
 class StepTimeline:
     """Bounded ring of step-phase spans + the phase-duration histogram."""
 
-    def __init__(self, capacity: int = 2048):
+    def __init__(self, capacity: int = 8192):
+        # five spans a step: room for a 20 s window of 12 ms steps, so a
+        # reader of the window's spans (the benchmark's) sees every step
         self._events: "deque[dict]" = deque(maxlen=capacity)
         # bare on purpose: telemetry substrate: the audit's metrics path runs under it
         self._lock = threading.Lock()  # mx-lint: allow=MXA009
@@ -117,28 +120,36 @@ class StepTimeline:
         return _Span(self, phase, step, annotation)
 
     def record(self, phase: str, t0: float, t1: float,
-               step: Optional[int] = None):
+               step: Optional[int] = None,
+               counters: Optional[dict] = None):
         """Record one span: ``t0``/``t1`` are ``time.perf_counter()``
         stamps; ``step`` is the global step number where the
         instrumentation point knows it (prefetcher spans use their own
-        batch ordinal). Also mirrors the span into the profiler's
+        batch ordinal); ``counters`` is what the device computed about
+        the step's own work (``telemetry.device_counters``; the
+        ``window`` span of a retired step carries them, ``{}`` where its
+        program emitted none). Also mirrors the span into the profiler's
         Chrome-trace stream when it is running."""
         _check_phase(phase)
         dur = max(0.0, t1 - t0)
         self._hist.observe(dur, label=phase)
+        event = {"phase": phase, "step": step, "t0": t0, "t1": t1,
+                 "dur": dur}
+        if counters is not None:
+            event["counters"] = counters
         with self._lock:
-            self._events.append(
-                {"phase": phase, "step": step, "t0": t0, "t1": t1,
-                 "dur": dur})
-        self._emit_trace(phase, t0, t1, step)
+            self._events.append(event)
+        self._emit_trace(event)
 
     @staticmethod
-    def _emit_trace(phase, t0, t1, step):
+    def _emit_trace(event):
         from ..profiler import Profiler
         prof = Profiler.get()
         if prof.running and not prof.paused:
-            prof.record(f"step:{phase}", t0, t1, cat="step",
-                        args={"step": step, "phase": phase})
+            args = {k: event[k] for k in ("step", "phase", "counters")
+                    if k in event}
+            prof.record(f"step:{event['phase']}", event["t0"], event["t1"],
+                        cat="step", args=args)
 
     # ---------------- queries ----------------
     def events(self, n: Optional[int] = None) -> List[dict]:

@@ -1,4 +1,4 @@
-"""Live MFU gauge + anomaly watchdog, piggybacking on window retires.
+"""Step-time + anomaly watchdog, piggybacking on window retires.
 
 The watchdog is fed from exactly one hot-path site — the dispatch
 window's FIFO retire (engine.py), which is already the pipelined loop's
@@ -7,11 +7,6 @@ ONE blessed host sync — so it adds no sync of its own:
 - **step time**: retire-to-retire wall time is the steady-state step
   time of a pipelined run; it feeds the ``mx_step_time_seconds``
   histogram and an EWMA gauge.
-- **MFU gauge**: per-bucket FLOPs from XLA ``cost_analysis()`` on the
-  already-compiled train step (``CompiledTrainStep.step_flops`` /
-  ``TrainLoop.arm_mfu``) divided by measured step time, against the
-  configured roofline (bench's measured or spec peak) —
-  ``mx_model_mfu_ratio``.
 - **NaN/inf-loss detection**: the retired payload IS the step's loss;
   once the retire has blocked for completion, reading the small loss
   buffer is one cheap device->host copy inside the already-blessed
@@ -79,7 +74,7 @@ def stall_factor(default: float = 4.0) -> float:
 
 
 class Watchdog:
-    """Process-global MFU gauge + NaN/stall anomaly detector."""
+    """Process-global step-time gauge + NaN/stall anomaly detector."""
 
     def __init__(self, max_events: int = 256):
         # bare on purpose: telemetry substrate: the deadlock episode fires under it
@@ -93,37 +88,10 @@ class Watchdog:
         self._episode_active: dict = {}
         # anomaly-channel subscribers: callback(event_dict)
         self._subscribers: list = []
-        self._flops: Optional[float] = None
-        self._peak: Optional[float] = None
         reg = _default_registry()
         self._c_anom = reg.counter(names.ANOMALIES, label_key="kind")
         self._h_step = reg.histogram(names.STEP_TIME_SECONDS)
         self._g_ewma = reg.gauge(names.STEP_TIME_EWMA)
-        self._g_flops = reg.gauge(names.MODEL_FLOPS_PER_STEP)
-        self._g_fps = reg.gauge(names.MODEL_FLOPS_PER_SEC)
-        self._g_mfu = reg.gauge(names.MFU)
-
-    # ---------------- configuration ----------------
-    def set_model_flops(self, flops_per_step: float):
-        """Arm the MFU numerator: XLA cost_analysis FLOPs of the ONE
-        compiled program the chip runs per step."""
-        with self._lock:
-            self._flops = float(flops_per_step)
-        self._g_flops.set(float(flops_per_step))
-
-    def set_peak_flops(self, peak_flops_per_sec: float):
-        """Arm the MFU denominator: the roofline in FLOP/s (bench's
-        measured matmul roofline, or the chip's spec peak)."""
-        with self._lock:
-            self._peak = float(peak_flops_per_sec)
-
-    @property
-    def model_flops(self) -> Optional[float]:
-        return self._flops
-
-    @property
-    def peak_flops(self) -> Optional[float]:
-        return self._peak
 
     # ---------------- the retire hook ----------------
     def observe_retire(self, step, payload=None,
@@ -163,13 +131,7 @@ class Watchdog:
                     (1 - _ALPHA) * self._ewma + _ALPHA * dt
                 self._samples += 1
                 ewma = self._ewma
-                flops, peak = self._flops, self._peak
             self._g_ewma.set(ewma)
-            if flops:
-                fps = flops / dt
-                self._g_fps.set(fps)
-                if peak:
-                    self._g_mfu.set(fps / peak)
 
     def _check_finite(self, step, payload):
         arr = getattr(payload, "_data", payload)   # NDArray -> jax.Array
@@ -268,8 +230,6 @@ class Watchdog:
             self._stall_active = False
             self._episode_active.clear()
             self._subscribers.clear()
-            self._flops = None
-            self._peak = None
 
 
 _watchdog = Watchdog()

@@ -347,7 +347,7 @@ def test_injected_inf_grad_one_anomaly_and_dump(tmp_path, monkeypatch):
     w = engine.DispatchWindow(max_inflight=2)
     for i in range(1, 9):
         l = step(xinf if i == 5 else x, y)
-        w.push(l._data, tag=i, aux=step.take_numerics())
+        w.push(l._data, tag=i, aux=step.take_aux())
     w.drain()
 
     events = telemetry.watchdog().anomalies("nonfinite_grad")

@@ -1,5 +1,5 @@
 """Unified runtime telemetry (ISSUE 6): step-timeline tracing, metrics
-registry + exporters, MFU gauge, anomaly watchdog.
+registry + exporters, anomaly watchdog.
 
 Acceptance bar:
 
@@ -11,7 +11,6 @@ Acceptance bar:
 - the Chrome trace merges per-op events (phase-tagged dispatch/sync)
   and per-step phase spans (window/retire stamped from the
   DispatchWindow's retire timestamps) in one stream;
-- the MFU gauge is nonzero and derived from XLA cost_analysis();
 - an injected NaN loss and an artificial stall each raise exactly ONE
   structured anomaly event attributed to the correct step number;
 - exporters: Prometheus text-format golden output, JSON snapshot schema
@@ -359,17 +358,6 @@ def test_nan_anomaly_fires_once_per_episode():
     assert len(wd.anomalies()) == 2
 
 
-def test_mfu_gauges_from_flops_and_step_time():
-    wd = telemetry.watchdog()
-    wd.set_model_flops(1e6)
-    wd.set_peak_flops(1e9)
-    wd.observe_retire(1, dt=0.01)
-    wd.observe_retire(2, dt=0.01)
-    assert telemetry.value(names.MODEL_FLOPS_PER_STEP) == 1e6
-    assert abs(telemetry.value(names.MODEL_FLOPS_PER_SEC) - 1e8) < 1e6
-    assert abs(telemetry.value(names.MFU) - 0.1) < 1e-3
-
-
 # ---------------------------------------------------------------------------
 # timeline
 # ---------------------------------------------------------------------------
@@ -449,42 +437,6 @@ def test_pipelined_telemetry_zero_unblessed_syncs(tmp_path, monkeypatch):
     text = telemetry.prometheus_text()
     assert 'mx_guard_host_syncs_total{kind="window_retire"} 12' in text
     assert "mx_engine_window_occupancy 0" in text
-
-
-def test_mfu_gauge_nonzero_from_cost_analysis(monkeypatch):
-    monkeypatch.setenv("MXNET_TELEMETRY", "1")
-    loop = _loop()
-    x, y = _batch()
-    flops = loop.arm_mfu(x, y, peak_flops=1e12)
-    assert flops and flops > 0, "cost_analysis returned no flops"
-    assert telemetry.value(names.MODEL_FLOPS_PER_STEP) == flops
-    for _ in range(8):
-        loop.step(x, y)
-    loop.synchronize()
-    mfu = telemetry.value(names.MFU)
-    fps = telemetry.value(names.MODEL_FLOPS_PER_SEC)
-    assert fps and fps > 0
-    assert mfu and 0 < mfu < 1
-    assert abs(mfu - fps / 1e12) < 1e-12
-
-
-def test_step_flops_eager_mode_is_none(monkeypatch):
-    """No compiled program -> no MFU numerator (and no crash)."""
-    net = _build()
-    loss_blk = gloss.SoftmaxCrossEntropyLoss()
-    trainer = Trainer(net.collect_params(), "sgd",
-                      {"learning_rate": 0.1})
-
-    def hostile(a, b):
-        out = net(a)
-        _ = out.asnumpy().sum()          # untraceable: eager fallback
-        return loss_blk(out, b)
-
-    step = trainer.compile_step(hostile)
-    x, y = _batch()
-    step(x, y)
-    assert step.mode == "eager"
-    assert step.step_flops(x, y) is None
 
 
 def test_injected_nan_loss_one_anomaly_at_correct_step(monkeypatch):

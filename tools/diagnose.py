@@ -235,16 +235,11 @@ def check_elastic():
 def check_telemetry():
     """Runtime-telemetry health: run a tiny pipelined MLP TrainLoop with
     telemetry forced on and print (a) a metrics-registry snapshot of the
-    headline series, (b) a 10-step timeline summary — p50/p99 duration
-    per step phase — and (c) the live MFU estimate: cost_analysis FLOPs
-    of the compiled step over measured step time, against a quickly
-    measured matmul roofline (docs/OBSERVABILITY.md)."""
+    headline series and (b) a 10-step timeline summary — p50/p99
+    duration per step phase (docs/OBSERVABILITY.md)."""
     print("----------Runtime Telemetry----------")
     try:
-        import time
         import numpy as onp
-        import jax
-        import jax.numpy as jnp
         import mxnet_tpu as mx
         from mxnet_tpu import telemetry
         from mxnet_tpu.gluon import Trainer, TrainLoop, nn
@@ -266,19 +261,7 @@ def check_telemetry():
         telemetry.enable(True)
         loop.step(x, y)          # compile outside the measured region
         loop.synchronize()
-        # quick measured roofline: achieved f32 matmul FLOP/s here
-        m = 512
-        a = jnp.asarray(onp.random.randn(m, m).astype("float32"))
-        f = jax.jit(lambda a: a @ a)
-        float(f(a).sum())
-        t0 = time.perf_counter()
-        for _ in range(5):
-            c = f(a)
-        float(c.sum())
-        roofline = 5 * 2 * m ** 3 / (time.perf_counter() - t0)
-        flops = loop.arm_mfu(x, y, peak_flops=roofline)
         telemetry.reset()
-        loop.arm_mfu(x, y, peak_flops=roofline)   # re-arm post-reset
         for bx, by in loop.prefetch((x, y) for _ in range(steps)):
             loop.step(bx, by)
         loop.synchronize()
@@ -299,16 +282,6 @@ def check_telemetry():
         for phase, s in summary.items():
             print(f"{phase:<12s}{s['count']:>6d}{s['p50_ms']:>10.3f}"
                   f"{s['p99_ms']:>10.3f}{s['max_ms']:>10.3f}")
-        print("-- MFU estimate --")
-        print("step flops   :", flops, "(XLA cost_analysis)")
-        print(f"roofline     : {roofline/1e9:.1f} GFLOP/s (measured "
-              f"{m}^3 matmul)")
-        fps = telemetry.value(names.MODEL_FLOPS_PER_SEC)
-        mfu = telemetry.value(names.MFU)
-        print("flops/sec    :",
-              f"{fps/1e9:.3f} GFLOP/s" if fps else "n/a")
-        print("mfu          :", f"{mfu:.6f}" if mfu else "n/a",
-              "(tiny MLP: expect ~0; the gauge matters on real models)")
         wd = telemetry.watchdog()
         print("anomalies    :", len(wd.anomalies()) or "none")
         telemetry.enable(None)
@@ -1232,7 +1205,7 @@ def main(argv=None):
                         help="also run a tiny pipelined TrainLoop with "
                         "telemetry on and print the metrics-registry "
                         "snapshot, a 10-step phase-timeline summary "
-                        "(p50/p99), and the MFU estimate")
+                        "(p50/p99)")
     parser.add_argument("--memory", action="store_true",
                         help="also compile a tiny train step and print "
                         "its memory report, the live-buffer census by "
