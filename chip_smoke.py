@@ -659,6 +659,17 @@ def _grid_steps(before: dict = None) -> dict:
     return steps
 
 
+def _bwd_forms(before: dict = None) -> dict:
+    """``mx_flash_attention_bwd_total`` as {form: calls since
+    ``before``}: the flash backward's one-block, fused multi-block or
+    split (dq and dk/dv kernels) form."""
+    from mxnet_tpu.telemetry import names
+    now = {"one_block": 0, "fused": 0, "split": 0,
+           **_counter(names.FLASH_ATTENTION_BWD)}
+    return now if before is None else \
+        {k: n - before[k] for k, n in now.items()}
+
+
 def _counter(name: str) -> dict:
     """A labelled counter of the program as {label: count so far}."""
     from mxnet_tpu import telemetry
@@ -670,8 +681,8 @@ def _train_small_lm(net, cfg: dict, x, y, tracked: tuple):
     """A model-zoo LM on seeded ``normal(0, 0.02)`` weights (gains 1)
     through ``TrainLoop`` under bf16 AMP for ``cfg["steps"]`` steps on one
     batch; → ``(losses, {counter name: {label: counts while tracing}})``
-    for the ``tracked`` counters, the flash kernels' layouts and their grid
-    steps among them."""
+    for the ``tracked`` counters, the flash kernels' layouts, their grid
+    steps and the backward's forms among them."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
     from mxnet_tpu.telemetry import names
@@ -684,6 +695,7 @@ def _train_small_lm(net, cfg: dict, x, y, tracked: tuple):
     loop = mx.gluon.TrainLoop(net, trainer, SoftmaxCrossEntropyLoss())
     before = {n: _counter(n) for n in tracked}
     layouts_before, steps_before = _flash_layouts(), _grid_steps()
+    forms_before = _bwd_forms()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
@@ -695,6 +707,7 @@ def _train_small_lm(net, cfg: dict, x, y, tracked: tuple):
     counted["flash_layouts"] = {k: n - layouts_before[k]
                                 for k, n in _flash_layouts().items()}
     counted[names.FLASH_ATTENTION_GRID_STEPS] = _grid_steps(steps_before)
+    counted[names.FLASH_ATTENTION_BWD] = _bwd_forms(forms_before)
     return losses, counted
 
 
@@ -716,7 +729,8 @@ def _sparse_lm(cfg: dict) -> dict:
     fused, traced once, loss falling; → its losses and what the program
     counted while tracing it (``mx_moe_dispatch_total``,
     ``mx_attention_mask_total``, ``mx_moe_row_mover_total``,
-    ``mx_flash_attention_grid_steps_total``)."""
+    ``mx_flash_attention_grid_steps_total``, and
+    ``mx_flash_attention_bwd_total``, which must read ``fused`` alone)."""
     import numpy as onp
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.smallthinker import SmallThinkerLM
@@ -749,6 +763,12 @@ def _sparse_lm(cfg: dict) -> dict:
                            f"expected {_compiled_tier()} alone, {3 * layers} "
                            "or more")
     _kernel_products(counted, layers, "SmallThinkerLM")
+    # grouped heads: every layer's backward one kernel, dq, dk and dv
+    # resident in VMEM
+    forms = counted[names.FLASH_ATTENTION_BWD]
+    if forms["split"] or forms["fused"] < layers:
+        raise RuntimeError(f"SmallThinkerLM's flash backward took {forms}, "
+                           f"expected {layers} or more fused and none split")
     log(f"  SmallThinkerLM: {counted}")
     return {"loss": [round(l, 4) for l in losses], **counted}
 
@@ -803,6 +823,7 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE,
     platform = jax.devices()[0].platform
     net, loop, x, y = _bert_loop(cfg)
     before, steps_before = _flash_layouts(), _grid_steps()
+    forms_before = _bwd_forms()
     mx.amp.init()
     try:
         losses = _run_steps(loop, x, y, cfg["steps"])
@@ -810,6 +831,7 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE,
         mx.amp.uninit()
     layouts = {k: n - before[k] for k, n in _flash_layouts().items()}
     grid_steps = _grid_steps(steps_before)
+    bwd_forms = _bwd_forms(forms_before)
     arrays = [p.data()._data for p in net.collect_params().values()]
     placed = {d.platform for a in arrays + [x._data, y._data]
               for d in a.devices()}
@@ -827,6 +849,7 @@ def phase_train(cfg: dict = TRAIN, sparse: dict = SPARSE,
                            "expected one call a layer and none padded")
     return {"loss": [round(l, 4) for l in losses], "kernel_paths": paths,
             "flash_layouts": layouts, "flash_grid_steps": grid_steps,
+            "flash_bwd_forms": bwd_forms,
             "sparse_lm": _sparse_lm(sparse), "latent_lm": _latent_lm(latent)}
 
 

@@ -8,10 +8,12 @@ batch_dot+softmax. This module is the TPU-idiomatic superset the build plan
 
 - ``flash_attention``: O(S) memory online-softmax attention. On TPU both
   the forward AND the backward are Pallas kernels (FlashAttention-2 style:
-  the forward saves a per-row log-sum-exp residual; the backward's dq and
-  dk/dv kernels reconstruct softmax blocks from it — no S×S residual is
-  ever materialized). Elsewhere a blockwise ``lax.scan`` XLA implementation
-  with identical math and a recompute-based backward.
+  the forward saves a per-row log-sum-exp residual; the backward rebuilds
+  each softmax block from it once and makes dq, dk and dv there, or,
+  where a sequence is too long for its buffers to stay in VMEM, in a dq
+  and a dk/dv kernel — no S×S residual is ever materialized). Elsewhere a
+  blockwise ``lax.scan`` XLA implementation with identical math and a
+  recompute-based backward.
 - ``flash_attention_bsh``: the same op on (batch, seq, heads*head_dim),
   what a projection emits and the output projection eats. The kernels
   address those arrays where they lie, at the head's own width (``_Tiles``:
@@ -39,6 +41,7 @@ import numpy as onp
 from jax import lax
 
 from ..base import MXNetError
+from .kernels import VMEM_BYTES_PER_CORE
 
 __all__ = ["flash_attention", "flash_attention_bsh", "rope",
            "paged_decode_attention", "ring_attention",
@@ -228,7 +231,10 @@ def _default_block(head_dim: int) -> int:
     of 128, ms a call at 512 -> 1024, PR 28: forward 10.51 -> 5.21,
     backward 17.86 -> 15.68; behind a 4096 window 9.14 -> 4.83 and
     16.71 -> 14.59; on PR 29's grids of live blocks 9.16 -> 4.57 and
-    14.04 -> 13.61, behind the window 7.36 -> 3.96 and 11.76 -> 12.11)."""
+    14.04 -> 13.61, behind the window 7.36 -> 3.96 and 11.76 -> 12.11;
+    the fused multi-block backward at 1024 on those grids, PR 38: 9.43,
+    behind the window 8.40, where the dq and dk/dv kernels take 13.61 and
+    12.11)."""
     return 1024 if head_dim >= 128 else _BLOCK_Q
 #: narrowest head the kernels take without padding
 _MIN_LANES = 8
@@ -455,11 +461,14 @@ def _head_group(bh: int, block_q: int, block_k: int,
     the rnn_scan timestep-block sizer accounts against the same
     number). ``n_tiles`` is how many such score-shaped tiles the kernel
     holds live at once: 1 for the forward (s; p overwrites it), 4 for
-    the fused backward (s, p, dp, ds) — budgeting the backward as a
-    single tile oversizes G and fails Mosaic lowering at large
-    blocks. ``row_group`` > 1 (fewer key/value rows than query rows):
-    one row a program, so that a program's rows read one key/value
-    row."""
+    every form of the backward (s, p, dp, ds) — budgeting the backward as
+    a single tile oversizes G and fails Mosaic lowering at large
+    blocks. The fused multi-block backward keeps, besides, g rows' whole
+    sequences of dq, dk and dv in VMEM (``_resident_bytes``), which
+    ``_flash_bwd_pallas`` weighs against ``_VMEM_ASK_BYTES`` with the G
+    this gives. ``row_group`` > 1 (fewer key/value rows than query
+    rows): one row a program, so that a program's rows read one
+    key/value row."""
     from .kernels import vmem_tile_budget
     if row_group > 1:
         return 1
@@ -472,8 +481,14 @@ def _head_group(bh: int, block_q: int, block_k: int,
     return g
 
 
+def _lane_tiles(width: int) -> int:
+    """Lanes a block ``width`` wide fills in VMEM: whole tiles of 128."""
+    return -(-width // 128) * 128
+
+
 def _vmem_limit(g: int, t: _Tiles, itemsize: int, n_blocks: int,
-                n_acc: int, n_tiles: int, n_rows: int) -> int:
+                n_acc: int, n_tiles: int, n_rows: int,
+                resident: int = 0) -> int:
     """``vmem_limit_bytes`` for one flash kernel, counted from what it
     keeps in VMEM: ``n_blocks`` (g, block, width) operand/result blocks
     in the input dtype (Pallas double-buffers each; a block narrower
@@ -481,19 +496,35 @@ def _vmem_limit(g: int, t: _Tiles, itemsize: int, n_blocks: int,
     values of that shape (scratch accumulators, the per-head statistics
     and partial results), ``n_tiles`` live f32 (g, bq, bk) score tiles
     for each head of the block (Mosaic gives every head of the unrolled
-    loop its own) and ``n_rows`` (g, heads, bq, 8 -> 128 lanes) f32
-    statistic blocks, plus a quarter for Mosaic's own temporaries.
+    loop its own), ``n_rows`` (g, heads, bq, 8 -> 128 lanes) f32
+    statistic blocks and ``resident`` bytes of whole-sequence buffers
+    (``_resident_bytes``), plus a quarter for Mosaic's own temporaries.
     ``_head_group`` budgets
     the score tiles only; at f32 the operand blocks alone double, and
     the BERT-shape forward asked for 16.42 MiB of the 16 MiB a kernel
     gets without a limit. Never below that default."""
     from .kernels import VMEM_SCOPED_DEFAULT_BYTES
     block = g * max(t.block_q, t.block_k) \
-        * (-(-max(t.width, t.width_v) // 128) * 128)
+        * _lane_tiles(max(t.width, t.width_v))
     need = (2 * n_blocks * block * itemsize + n_acc * block * 4
             + n_tiles * g * t.heads * t.block_q * t.block_k * 4
-            + 2 * n_rows * g * t.heads * t.block_q * 128 * 4)
+            + 2 * n_rows * g * t.heads * t.block_q * 128 * 4 + resident)
     return max(VMEM_SCOPED_DEFAULT_BYTES, need + need // 4)
+
+
+#: the most VMEM a flash kernel asks Mosaic for: the fused multi-block
+#: backward is taken where its ``_vmem_limit`` is no more, the dq and
+#: dk/dv kernels elsewhere
+_VMEM_ASK_BYTES = VMEM_BYTES_PER_CORE
+
+
+def _resident_bytes(g: int, t: _Tiles, itemsize: int) -> int:
+    """What the fused multi-block backward keeps for a whole sequence:
+    one query head's dq and the key/value head's dk, dv, each as an f32
+    scratch accumulator and as a (double-buffered) output block in the
+    input dtype."""
+    w, wv = _lane_tiles(t.width), _lane_tiles(t.width_v)
+    return g * (t.sqp * w + t.skp * (w + wv)) * (4 + 2 * itemsize)
 
 
 def _head_masks(shape, heads: int):
@@ -683,6 +714,20 @@ def _walk(live, group: int = 1) -> _Walk:
                  lambda row, s: s // n_blocks, lambda row, s: s % n_blocks)
 
 
+def _pair_walk(live, group: int) -> _Walk:
+    """The fused backward's walk: for each query head of the ``group``
+    in turn, the (k block, q block) pairs of ``live`` (nq, nk) that hold
+    a valid pair, k-major (``_walk`` over one row of the flattened pairs a
+    head, so ``_grid_step`` in the kernel reads (head, pair, its head's
+    first, its head's last)). ``row`` gives the step's k block, ``head``
+    its query head, ``block`` its q block."""
+    n_q = live.shape[0]
+    walk = _walk(onp.tile(live.T.reshape(1, -1), (group, 1)))
+    return _Walk(walk.axes, walk.tables,
+                 lambda *at: walk.block(*at) // n_q, walk.row,
+                 lambda *at: walk.block(*at) % n_q)
+
+
 def _grid_step(tables, blocks_a_head=None):
     """Inside a kernel, this grid step as (row of blocks, block, first
     of its row, last of its row), from ``_walk``'s tables or, on the
@@ -712,10 +757,29 @@ def _count_grid_steps(grid) -> None:
     count_traced("FLASH_ATTENTION_GRID_STEPS", "kind", "dead", 0)
 
 
+def _count_bwd_form(form: str) -> None:
+    """``mx_flash_attention_bwd_total{form}`` for one traced Pallas flash
+    backward: ``one_block``, ``fused`` or ``split``
+    (``_flash_bwd_pallas``)."""
+    from .kernels import count_traced
+    count_traced("FLASH_ATTENTION_BWD", "form", form)
+
+
 def _semantics(grid) -> tuple:
     """``dimension_semantics`` of a flash kernel's grid: its last axis
     accumulates, the others are independent."""
     return ("parallel",) * (len(grid) - 1) + ("arbitrary",)
+
+
+def _q_index(t: _Tiles, walk: _Walk):
+    """Index map of a q-side block on a grid over key/value rows and
+    column tiles (the dk/dv kernel's, the fused backward's): the q block
+    ``walk.block`` names, of the query head of the group ``walk.head``
+    names."""
+    rg, cg = t.row_group, t.col_group
+    return lambda r, c, *at: (
+        r * rg + (walk.head(*at) if rg > 1 else 0), walk.block(*at),
+        c * cg + (walk.head(*at) if cg > 1 else 0))
 
 
 def _kv_index(t: _Tiles, walk: _Walk):
@@ -973,30 +1037,19 @@ def _flash_bwd_dkv_kernel(*refs, blocks_a_head, sm_scale, causal, block_q,
         dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                            dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                            block_q, block_k, seq_q, seq_k, need_mask,
-                            lanes, lanes_v, window=None):
-    """Single-block backward (nq == nk == 1, the short-seq fast path):
-    one program computes dq, dk AND dv, reconstructing the softmax block
-    ONCE — the two-kernel general path pays the s = qk^T + exp recompute
-    twice, and that VPU work dominates short-seq attention (r5). delta_i
-    = rowsum(dO_i * O_i) is taken here from the o block, per head, so no
-    XLA pass over dO and O precedes the call."""
-    q = q_ref[...]                                  # (G, bq, width)
-    k = k_ref[...]                                  # (G, bk, width)
-    v = v_ref[...]                                  # (G, bk, width_v)
-    do = do_ref[...]                                # (G, bq, width_v)
-    do_o = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
-    qmasks, omasks, kmasks, vmasks = _bwd_masks(lanes, lanes_v, q, k, v, do)
-    valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k,
-                      window) if need_mask or causal else None
+def _bwd_products(q, k, v, do, lse_ref, deltas, masks, valid, lanes,
+                  lanes_v, sm_scale):
+    """The three gradients of one visit to a (q block, k block) pair,
+    each head's softmax block rebuilt ONCE: lists of one f32 product a
+    head, (dqs, dks, dvs), each over the head's span of its block.
+    ``deltas(i, omask)`` is head i's (G, bq, 1) delta and ``masks`` what
+    ``_bwd_masks`` gave."""
+    qmasks, omasks = masks[:2]
     dqs, dks, dvs = [], [], []
     for i, (qmask, omask) in enumerate(zip(qmasks, omasks)):
         q_i, k_i, do_i = lanes.cut(q, i), lanes.cut(k, i), \
             lanes_v.cut(do, i)
-        delta = _only(lanes_v.cut(do_o, i), omask).sum(axis=2,
-                                                       keepdims=True)
+        delta = deltas(i, omask)
         p, ds = _bwd_head(q_i, k_i, lanes_v.cut(v, i), do_i,
                           lse_ref[:, i][:, :, :1], delta, qmask, omask,
                           valid, sm_scale)
@@ -1008,9 +1061,89 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                                    preferred_element_type=jnp.float32))
         dks.append(lax.dot_general(ds, q_i, (((1,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32))
+    return dqs, dks, dvs
+
+
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                            dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
+                            block_q, block_k, seq_q, seq_k, need_mask,
+                            lanes, lanes_v, window=None):
+    """Single-block backward (nq == nk == 1, no shared key/value head:
+    the short-seq fast path): one program computes dq, dk AND dv from
+    the softmax block rebuilt ONCE, as ``_flash_bwd_walk_kernel`` does a
+    visit. delta_i = rowsum(dO_i * O_i) is taken here from the o block,
+    per head, so no XLA pass over dO and O precedes the call."""
+    q = q_ref[...]                                  # (G, bq, width)
+    k = k_ref[...]                                  # (G, bk, width)
+    v = v_ref[...]                                  # (G, bk, width_v)
+    do = do_ref[...]                                # (G, bq, width_v)
+    do_o = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
+    masks = _bwd_masks(lanes, lanes_v, q, k, v, do)
+    qmasks, _, kmasks, vmasks = masks
+    valid = _bwd_mask(0, 0, block_q, block_k, causal, seq_q, seq_k,
+                      window) if need_mask or causal else None
+    dqs, dks, dvs = _bwd_products(
+        q, k, v, do, lse_ref,
+        lambda i, omask: _only(lanes_v.cut(do_o, i), omask).sum(
+            axis=2, keepdims=True),
+        masks, valid, lanes, lanes_v, sm_scale)
     dq_ref[...] = lanes.join(dqs, qmasks).astype(dq_ref.dtype)
     dk_ref[...] = lanes.join(dks, kmasks).astype(dk_ref.dtype)
     dv_ref[...] = lanes_v.join(dvs, vmasks).astype(dv_ref.dtype)
+
+
+def _flash_bwd_walk_kernel(*refs, group, n_q, sm_scale, causal, block_q,
+                           block_k, seq_q, seq_k, need_mask, lanes, lanes_v,
+                           window=None):
+    """The multi-block backward as ONE kernel: the grid walks, for each
+    query head of the group in turn, k block after k block, the q blocks
+    that hold a pair with it (``_walk`` over the flattened (k block, q
+    block) pairs, one row a head), and every visit rebuilds the softmax
+    block once and makes dq, dk and dv from it. dk and dv accumulate for
+    the key/value head's whole sequence, summed over the group, dq for
+    the query head's, all three in f32 VMEM scratch: dq is written when
+    its head's last visit is made, dk and dv after the group's."""
+    from jax.experimental import pallas as pl
+    (*tables, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+     dk_ref, dv_ref, dq_s, dk_s, dv_s) = refs
+    head, pair, first, last = _grid_step(tables)
+    ki, qi = pair // n_q, pair % n_q
+
+    @pl.when(first & (head == 0))
+    def _init_kv():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    @pl.when(first)
+    def _init_q():
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    q = q_ref[...]                                  # (G, bq, width)
+    k = k_ref[...]                                  # (G, bk, width)
+    v = v_ref[...]                                  # (G, bk, width_v)
+    do = do_ref[...]                                # (G, bq, width_v)
+    masks = _bwd_masks(lanes, lanes_v, q, k, v, do)
+    qmasks, _, kmasks, vmasks = masks
+    valid = _bwd_mask(qi, ki, block_q, block_k, causal, seq_q, seq_k,
+                      window) if need_mask or causal else None
+    dqs, dks, dvs = _bwd_products(
+        q, k, v, do, lse_ref,
+        lambda i, omask: delta_ref[:, i][:, :, :1],
+        masks, valid, lanes, lanes_v, sm_scale)
+    at_q = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    at_k = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+    dv_s[:, at_k] += lanes_v.join(dvs, vmasks)
+    dk_s[:, at_k] += lanes.join(dks, kmasks)
+    dq_s[:, at_q] += lanes.join(dqs, qmasks)
+
+    @pl.when(last)
+    def _write_q():
+        dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
+
+    @pl.when(last & (head == group - 1))
+    def _write_kv():
+        dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q,
@@ -1052,15 +1185,26 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, seq_q,
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                       block_q: int = None, block_k: int = None,
                       interpret: bool = False, num_heads=None, window=None):
-    """Pallas flash attention backward: one fused kernel when the whole
-    sequence is one block, else dq via a kernel that walks each q
-    block's live k blocks and dk/dv via one that walks each k block's
-    live q blocks (``_walk``: a block with no valid pair is not in
-    either grid). ``lse`` is what ``_flash_fwd_pallas`` returned for the
-    same blocks. With fewer key/value heads than query heads the dk/dv
-    kernel's inner axis walks the q blocks of each query head of the
-    group in turn, so a key/value head's gradient is summed where it is
-    made, and the one-block case takes the two kernels too."""
+    """Pallas flash attention backward, in one of three forms
+    (``mx_flash_attention_bwd_total{form}``):
+
+    - ``one_block``: the whole sequence is one block and no key/value
+      head is shared: one kernel, one step a program, delta taken inside;
+    - ``fused``: one kernel (``_flash_bwd_walk_kernel``) walks the live
+      blocks (``_walk``: a block with no valid pair is not in the grid)
+      head by head of the group, k block by k block, and makes dq, dk and
+      dv from each visit's ONE rebuilt softmax block. dq accumulates for
+      the query head's whole sequence and dk, dv for the key/value head's
+      in VMEM, so the call takes this form where those buffers fit the
+      VMEM a kernel may ask for (``_resident_bytes``, ``_VMEM_ASK_BYTES``);
+    - ``split``: longer sequences: dq via a kernel that walks each q
+      block's live k blocks and dk/dv via one that walks each k block's
+      live q blocks, once for each query head of the group, so a
+      key/value head's gradient is summed where it is made. Each rebuilds
+      the softmax block: seven products a visit where the fused form
+      makes five.
+
+    ``lse`` is what ``_flash_fwd_pallas`` returned for the same blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1087,6 +1231,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         rspec = pl.BlockSpec((g, heads, t.block_q, 8),
                              lambda r, c: (r, c, 0, 0))
         _count_grid_steps((t.rows // g, t.col_tiles))
+        _count_bwd_form("one_block")
         dq, dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_fused_kernel, **static),
             grid=(t.rows // g, t.col_tiles),
@@ -1123,15 +1268,79 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     delta = jnp.broadcast_to(delta[..., None], lse.shape)
 
     live = _live_blocks(t, causal)
+    kv_grid = (kt.shape[0] // g, kt.shape[2] // w)
+
+    def kv_specs(walk):
+        """The k and v blocks of a grid over key/value rows and column
+        tiles, at the walk's k block."""
+        at = lambda r, c, *a: (r, walk.row(*a), c)
+        k_spec = pl.BlockSpec((g, t.block_k, w), at)
+        return k_spec, (k_spec if wv == w else
+                        pl.BlockSpec((g, t.block_k, wv), at))
+
+    def q_specs(walk):
+        """The q, do, lse and delta blocks of such a grid: the walk's q
+        block of the query head of the group it names."""
+        at = _q_index(t, walk)
+
+        def stat_at(*a):
+            row, qi, col = at(*a)
+            return row, col, qi, 0
+        q_spec = pl.BlockSpec((g, t.block_q, w), at)
+        return (q_spec, q_spec if wv == w else
+                pl.BlockSpec((g, t.block_q, wv), at),
+                pl.BlockSpec((g, heads, t.block_q, 8), stat_at))
+
+    resident = _resident_bytes(g, t, q.dtype.itemsize)
+    # q, k, v, do in; three products a head; 4 score tiles; lse and
+    # delta blocks; dq, dk, dv whole
+    limit = _vmem_limit(g, t, q.dtype.itemsize, 4, 3 * heads, 4, 2,
+                        resident)
+    if limit <= _VMEM_ASK_BYTES:
+        _count_bwd_form("fused")
+        walk = _pair_walk(live, t.group)
+        grid = kv_grid + walk.axes
+        _count_grid_steps(grid)
+        q_in, do_in, row_in = q_specs(walk)
+        k_in, v_in = kv_specs(walk)
+
+        def whole_q(r, c, *at):
+            row, _, col = _q_index(t, walk)(r, c, *at)
+            return row, 0, col
+        whole_kv = lambda r, c, *at: (r, 0, c)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_walk_kernel, **static,
+                              group=t.group, n_q=t.nq),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(walk.tables),
+                grid=grid,
+                in_specs=[q_in, k_in, v_in, do_in, row_in, row_in],
+                out_specs=[pl.BlockSpec((g, t.sqp, w), whole_q),
+                           pl.BlockSpec((g, t.skp, w), whole_kv),
+                           pl.BlockSpec((g, t.skp, wv), whole_kv)],
+                scratch_shapes=[pltpu.VMEM((g, t.sqp, w), jnp.float32),
+                                pltpu.VMEM((g, t.skp, w), jnp.float32),
+                                pltpu.VMEM((g, t.skp, wv), jnp.float32)]),
+            out_shape=[q_shape, k_shape, v_shape],
+            compiler_params=pltpu.CompilerParams(
+                # every step of a (row, column tile) adds to its buffers
+                dimension_semantics=("parallel", "parallel")
+                + ("arbitrary",) * len(walk.axes),
+                vmem_limit_bytes=limit),
+            interpret=interpret,
+        )(*walk.tables, qt, kt, vt, dot, lse, delta)
+        return (from_tiles(dq, sq), from_tiles(dk, sk),
+                from_tiles(dv, sk, True))
+
+    _count_bwd_form("split")
     walk = _walk(live)
     grid = (t.rows // g, t.col_tiles) + walk.axes
     _count_grid_steps(grid)
-    q_spec = pl.BlockSpec((g, t.block_q, w),
-                          lambda r, c, *at: (r, walk.row(*at), c))
-    do_spec = q_spec if wv == w else pl.BlockSpec(
-        (g, t.block_q, wv), lambda r, c, *at: (r, walk.row(*at), c))
-    row_spec = pl.BlockSpec((g, heads, t.block_q, 8),
-                            lambda r, c, *at: (r, c, walk.row(*at), 0))
+    at_q = lambda r, c, *at: (r, walk.row(*at), c)
+    q_in = pl.BlockSpec((g, t.block_q, w), at_q)
+    do_in = q_in if wv == w else pl.BlockSpec((g, t.block_q, wv), at_q)
+    row_in = pl.BlockSpec((g, heads, t.block_q, 8),
+                          lambda r, c, *at: (r, c, walk.row(*at), 0))
     k_in = pl.BlockSpec((g, t.block_k, w), _kv_index(t, walk))
     v_in = k_in if wv == w else \
         pl.BlockSpec((g, t.block_k, wv), _kv_index(t, walk))
@@ -1141,8 +1350,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=grid,
-            in_specs=[q_spec, k_in, v_in, do_spec, row_spec, row_spec],
-            out_specs=q_spec,
+            in_specs=[q_in, k_in, v_in, do_in, row_in, row_in],
+            out_specs=q_in,
             scratch_shapes=[pltpu.VMEM((g, t.block_q, w), jnp.float32)]),
         out_shape=q_shape,
         compiler_params=pltpu.CompilerParams(
@@ -1154,34 +1363,18 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         interpret=interpret,
     )(*walk.tables, qt, kt, vt, dot, lse, delta)
 
-    # the dk/dv grid is over key/value rows and column tiles; a step
-    # reads a q block of the query head of the group that the walk names
     walk = _walk(live.T, t.group)
-    grid = (kt.shape[0] // g, kt.shape[2] // w) + walk.axes
+    grid = kv_grid + walk.axes
     _count_grid_steps(grid)
-    rg, cg = t.row_group, t.col_group
-
-    def q_at(r, c, *at):
-        return (r * rg + (walk.head(*at) if rg > 1 else 0), walk.block(*at),
-                c * cg + (walk.head(*at) if cg > 1 else 0))
-
-    def stat_at(*at):
-        row, qi, col = q_at(*at)
-        return row, col, qi, 0
-    k_spec = pl.BlockSpec((g, t.block_k, w),
-                          lambda r, c, *at: (r, walk.row(*at), c))
-    v_spec = k_spec if wv == w else pl.BlockSpec(
-        (g, t.block_k, wv), lambda r, c, *at: (r, walk.row(*at), c))
-    qrow = pl.BlockSpec((g, t.block_q, w), q_at)
-    dorow = qrow if wv == w else pl.BlockSpec((g, t.block_q, wv), q_at)
-    rrow = pl.BlockSpec((g, heads, t.block_q, 8), stat_at)
+    q_in, do_in, row_in = q_specs(walk)
+    k_spec, v_spec = kv_specs(walk)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **static,
                           blocks_a_head=t.nq if t.group > 1 else None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk.tables),
             grid=grid,
-            in_specs=[qrow, k_spec, v_spec, dorow, rrow, rrow],
+            in_specs=[q_in, k_spec, v_spec, do_in, row_in, row_in],
             out_specs=[k_spec, v_spec],
             scratch_shapes=[pltpu.VMEM((g, t.block_k, w), jnp.float32),
                             pltpu.VMEM((g, t.block_k, wv), jnp.float32)]),

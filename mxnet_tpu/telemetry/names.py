@@ -151,6 +151,7 @@ OVERLAP_FRACTION = "mx_overlap_fraction"
 KERNEL_DISPATCH = "mx_kernel_dispatch_total"
 FLASH_ATTENTION_LAYOUT = "mx_flash_attention_layout_total"
 FLASH_ATTENTION_GRID_STEPS = "mx_flash_attention_grid_steps_total"
+FLASH_ATTENTION_BWD = "mx_flash_attention_bwd_total"
 ATTENTION_MASK = "mx_attention_mask_total"
 MOE_DISPATCH = "mx_moe_dispatch_total"
 MOE_ROW_MOVER = "mx_moe_row_mover_total"
@@ -486,12 +487,21 @@ CATALOG = {
     FLASH_ATTENTION_GRID_STEPS: dict(
         kind="counter", label="kind",
         help="grid steps of the Pallas flash-attention calls (forward, "
-             "dq, dk/dv, the one-block fused backward), summed over a "
+             "the backward in whichever form it took), summed over a "
              "call's programs: live = steps whose body computes a block, "
              "dead = steps in the grid whose body is skipped. The grids "
              "hold the blocks with a valid pair under the mask and no "
              "other (ops/attention.py _walk), so dead reads 0; counted "
              "while a call is traced"),
+    FLASH_ATTENTION_BWD: dict(
+        kind="counter", label="form",
+        help="Pallas flash-attention backward calls by form (one_block = "
+             "the whole sequence one block, one kernel; fused = one "
+             "kernel that walks the live blocks and keeps dq, dk, dv in "
+             "VMEM; split = a dq kernel and a dk/dv kernel, where the "
+             "fused form's resident buffers exceed the VMEM a kernel may "
+             "ask for; ops/attention.py _flash_bwd_pallas); one count a "
+             "traced call"),
     ATTENTION_MASK: dict(
         kind="counter", label="kind",
         help="flash-attention calls by the mask they asked for, whatever "

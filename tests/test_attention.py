@@ -245,12 +245,25 @@ _MULTIBLOCK = {
 }
 
 
+def _bwd_forms():
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.telemetry import names as tnames
+    return {form: telemetry.value(tnames.FLASH_ATTENTION_BWD, form) or 0
+            for form in ("one_block", "fused", "split")}
+
+
+@pytest.mark.parametrize("form", ["fused", "split"])
 @pytest.mark.parametrize("case", sorted(_MULTIBLOCK))
-def test_flash_attention_pallas_backward_multiblock(case):
+def test_flash_attention_pallas_backward_multiblock(case, form, monkeypatch):
     # Small explicit blocks force a multi-block grid: exercises the
     # accumulators across a row of live blocks and the table of live
-    # blocks in the forward and both backward kernels (not reachable
-    # with default 512 blocks on CI sizes), against the unfused oracle.
+    # blocks in the forward and in both forms of the backward (not
+    # reachable with default 512 blocks on CI sizes), against the unfused
+    # oracle: the one kernel with dq, dk, dv resident in VMEM, and the dq
+    # and dk/dv kernels, which a call takes where no VMEM is left to ask
+    # for them.
+    if form == "split":
+        monkeypatch.setattr(A, "_VMEM_ASK_BYTES", 0)
     hq, hkv, d, (sq, sk), (bq, bk), causal, window, bsh = _MULTIBLOCK[case]
     rng = onp.random.RandomState(5)
     q, k, v, do = (jnp.asarray(rng.randn(1, h, s, d).astype("float32"))
@@ -264,8 +277,11 @@ def test_flash_attention_pallas_backward_multiblock(case):
     heads = hq if bsh else None
     o, lse = A._flash_fwd_pallas(to(q), to(k), to(v), causal, sm, bq, bk,
                                  True, heads, window)
+    before = _bwd_forms()
     dq, dk, dv = A._flash_bwd_pallas(to(q), to(k), to(v), o, lse, to(do),
                                      causal, sm, bq, bk, True, heads, window)
+    assert {f: n - before[f] for f, n in _bwd_forms().items()} == \
+        {"one_block": 0, "fused": 0, "split": 0, form: 1}
     t = A._tiles(to(q).shape, to(k).shape, bq, bk, heads, window)
     assert t.nq > 1 and t.nk > 1
     if bsh:
@@ -364,6 +380,20 @@ def test_live_block_table_is_the_brute_force_mask(mask, seqs, blocks, group):
                  for s in range(g * n_blocks)]
         assert dense == list(zip(*(x.tolist()
                                    for x in A._live_steps(lv, g)[:3])))
+    # the fused backward's one walk: query head after query head, the
+    # live (k block, q block) pairs k-major, each head's first and last
+    # step marked
+    walk = A._pair_walk(live, group)
+    grid = [(s, *walk.tables) for s in range(walk.axes[0])] \
+        if walk.tables else [(h, s) for h in range(walk.axes[0])
+                             for s in range(walk.axes[1])]
+    pairs = list(zip(*onp.nonzero(want.T)))
+    assert [(walk.head(*at), walk.row(*at), walk.block(*at))
+            for at in grid] == [(h, kb, qb) for h in range(group)
+                                for kb, qb in pairs]
+    if walk.tables:
+        _check_rows(*walk.tables, onp.tile(want.T.reshape(1, -1),
+                                           (group, 1)), 1)
 
 
 def test_live_steps_of_the_smallthinker_cell():
@@ -401,18 +431,60 @@ def test_flash_grid_steps_are_counted_and_none_is_dead(monkeypatch):
     q = jax.ShapeDtypeStruct((1, 4096, 28 * 128), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((1, 4096, 4 * 128), jnp.bfloat16)
     # 4 x 4 blocks of 1024 a head: 10 live when causal, 9 behind 2048;
-    # forward, dq and dk/dv each walk them once for each of 28 heads
-    assert trace(q, k, 28, causal=True) == {"live": 3 * 28 * 10, "dead": 0}
+    # the forward and the fused backward each walk them once for each of
+    # 28 heads
+    before = _bwd_forms()
+    assert trace(q, k, 28, causal=True) == {"live": 2 * 28 * 10, "dead": 0}
     counted = [trace(q, k, 28, causal=True, window=2048) for _ in range(3)]
-    assert counted == [{"live": 3 * 28 * 9, "dead": 0}] * 3
+    assert counted == [{"live": 2 * 28 * 9, "dead": 0}] * 3
+    assert _bwd_forms()["fused"] - before["fused"] == 4
     # BERT-base, 32 x 512: one block a sequence, two heads a lane tile
     # (6 column tiles), a few rows a program: one step a program in the
-    # forward and in the fused backward
+    # forward and in the one-block backward
     x = jax.ShapeDtypeStruct((32, 512, 768), jnp.bfloat16)
     programs = sum(32 // A._head_group(32, 512, 512, n_tiles=n,
                                        heads_per_block=2) * 6
                    for n in (1, 4))
+    before = _bwd_forms()
     assert trace(x, x, 12) == {"live": programs, "dead": 0}
+    assert {f: n - before[f] for f, n in _bwd_forms().items()} == \
+        {"one_block": 1, "fused": 0, "split": 0}
+
+
+# (batch, seq, query heads, key/value heads, head width, value width,
+# causal, window) of the cells' attention calls, bf16, and the form of
+# their backward
+_BWD_FORMS = {
+    "smallthinker-8192": ((1, 8192, 28, 4, 128, 128, True, None), "fused"),
+    "smallthinker-8192-window": ((1, 8192, 28, 4, 128, 128, True, 4096),
+                                 "fused"),
+    "joyai-4096": ((1, 4096, 32, 32, 192, 128, True, None), "fused"),
+    "nemotron-4096": ((1, 4096, 32, 2, 128, 128, True, None), "fused"),
+    # the resident dq, dk, dv of a 32k sequence (100 MB) and the rest of
+    # the kernel's VMEM are more than a core has: the dq and dk/dv kernels
+    "smallthinker-32768": ((1, 32768, 28, 4, 128, 128, True, None),
+                           "split"),
+    "bert-512": ((32, 512, 12, 12, 64, 64, False, None), "one_block"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BWD_FORMS))
+def test_flash_backward_form_follows_what_fits(case, monkeypatch):
+    """``mx_flash_attention_bwd_total{form}``: the fused multi-block
+    backward wherever what it keeps for a whole sequence fits the VMEM a
+    kernel may ask for, at the cells' own shapes (traced only)."""
+    monkeypatch.setenv("MXNET_PALLAS", "on")
+    (b, s, hq, hkv, d, dv, causal, window), form = _BWD_FORMS[case]
+    q, k, v = (jax.ShapeDtypeStruct((b, s, h * w), jnp.bfloat16)
+               for h, w in ((hq, d), (hkv, d), (hkv, dv)))
+    o = jax.ShapeDtypeStruct((b, s, hq * dv), jnp.bfloat16)
+    before = _bwd_forms()
+    jax.eval_shape(lambda q_, k_, v_, do: jax.vjp(
+        lambda *a: A.flash_attention_bsh(*a, hq, causal=causal,
+                                         window=window, num_kv_heads=hkv),
+        q_, k_, v_)[1](do), q, k, v, o)
+    assert {f: n - before[f] for f, n in _bwd_forms().items()} == \
+        {"one_block": 0, "fused": 0, "split": 0, form: 1}
 
 
 def test_flash_pallas_bf16_interpret():

@@ -177,12 +177,17 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     assert set(products) == {"interpret"} and products["interpret"] >= 32
     assert set(train["latent_lm"]["mx_moe_grouped_dot_total"]) == {
         "interpret"}
-    # 32 positions in blocks of 32: a step a program, none dead; the
-    # sparse LM's grouped heads take the two-kernel backward
+    # 32 positions in blocks of 32: a step a program, none dead, BERT's
+    # backward one block; the sparse LM's grouped heads take the fused
+    # backward, one kernel a layer
     assert train["flash_grid_steps"]["dead"] == 0 < \
         train["flash_grid_steps"]["live"]
+    forms = train["flash_bwd_forms"]
+    assert forms["one_block"] > 0 and forms["fused"] == forms["split"] == 0
     sparse_steps = train["sparse_lm"]["mx_flash_attention_grid_steps_total"]
     assert sparse_steps["dead"] == 0 and sparse_steps["live"] % 4 == 0
+    sparse_forms = train["sparse_lm"]["mx_flash_attention_bwd_total"]
+    assert sparse_forms["split"] == 0 and sparse_forms["fused"] >= 4
     # the small JoyAILM: one dense and one expert layer and the MTP
     # module's, each behind latent attention with keys of 192 lanes beside
     # values of 128, which the kernels take where they lie
