@@ -66,8 +66,9 @@ FP32_OPS = NORM_OPS | {
     # the router of the dropless expert layer: logits, top-k and the
     # softmax of the chosen logits (a bf16 logit flips near-tied choices)
     "moe_route",
-    # the two norms of latent attention's compressed q and kv
-    "latent_norm",
+    # the two norms of latent attention's compressed q and kv, and the
+    # per-head norms of q and k (MultiHeadAttention ``qk_norm``)
+    "latent_norm", "qk_norm",
     # a state-space mixer's step size (softplus of dt + its bias; the
     # scan's decays exp(dt A) are built from it in float32) and its gated
     # group norm; the scan itself (``ssd_scan``) is in neither list: its

@@ -8,7 +8,8 @@ from . import bert
 from . import smallthinker
 from . import joyai
 from . import nemotron_h
+from . import lfm2
 from .vision import get_model
 
-__all__ = ["vision", "bert", "smallthinker", "joyai", "nemotron_h",
+__all__ = ["vision", "bert", "smallthinker", "joyai", "nemotron_h", "lfm2",
            "get_model"]

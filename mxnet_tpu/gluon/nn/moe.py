@@ -79,11 +79,12 @@ class SparseMoE(HybridBlock):
 
     - ``"softmax"`` (SmallThinker): the k largest logits, weighed by the
       softmax of those k logits;
-    - ``"sigmoid"`` (the DeepSeek-V3 family): scores sigmoid(logits); the
-      k largest of score + ``router_bias`` ((num_experts,), a parameter
-      that picks and never weighs: its gradient is exactly zero, so an
-      optimizer leaves it where the job's balance rule put it), weighed
-      by ``routed_scale`` * score / the chosen scores' sum.
+    - ``"sigmoid"`` (the DeepSeek-V3 family, LFM2): scores
+      sigmoid(logits); the k largest of score + ``router_bias``
+      ((num_experts,), a parameter that picks and never weighs: its
+      gradient is exactly zero, so an optimizer leaves it where the job's
+      balance rule put it), weighed by ``routed_scale`` * score / (the
+      chosen scores' sum + ``norm_eps``).
 
     Expert e is ``W_down (act(W_gate x) * (W_up x))``, width ``hidden``,
     no bias; ``activation`` names act: ``"relu"`` (sparse ReGLU) or
@@ -116,7 +117,7 @@ class SparseMoE(HybridBlock):
     def __init__(self, units, hidden, num_experts, top_k, held=None,
                  dtype="float32", score="softmax", routed_scale=1.0,
                  activation="relu", shared_hidden=0, gated=True,
-                 **kwargs):
+                 norm_eps=0.0, **kwargs):
         super().__init__(**kwargs)
         if score not in moe_ops.SCORES:
             raise MXNetError(f"score {score!r} is none of "
@@ -133,6 +134,7 @@ class SparseMoE(HybridBlock):
         self._units, self._k = units, top_k
         self._held = (int(first), int(count))
         self._score, self._scale = score, float(routed_scale)
+        self._norm_eps = float(norm_eps)
         self._activation, self._gated = activation, bool(gated)
         self.router_weight = Parameter(
             "router_weight", shape=(num_experts, units), dtype=dtype)
@@ -176,7 +178,7 @@ class SparseMoE(HybridBlock):
 
         def fn(x, w, bias=None):
             return moe_ops.moe_route(x, w, self._k, self._held, self._score,
-                                     bias, self._scale)
+                                     bias, self._scale, self._norm_eps)
         return invoke_raw("moe_route", fn, inputs, n_outputs=4)
 
     def shared_expert(self, x):
@@ -232,6 +234,6 @@ class SparseMoE(HybridBlock):
         sizes, share = moe_ops.routing_counts(
             self._tokens(x)._data, self.router_weight.data()._data,
             self._k, self._held, score=self._score, bias=bias,
-            scale=self._scale)
+            scale=self._scale, norm_eps=self._norm_eps)
         return {"pairs": onp.asarray(sizes), "held_share": float(share)}
 

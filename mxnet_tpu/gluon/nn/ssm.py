@@ -1,7 +1,8 @@
 """State-space mixer layers.
 
 No reference analog (the reference's recurrent layers are gluon/rnn);
-``Mamba2Mixer`` is the mixer of the Mamba-2 / Nemotron-H family over
+``Mamba2Mixer`` is the mixer of the Mamba-2 / Nemotron-H family and
+``ShortConvMixer`` LFM2's gated short convolution, both over
 ``ops/ssm.py``.
 """
 from __future__ import annotations
@@ -15,11 +16,42 @@ from ...ndarray.ndarray import NDArray
 from ...ops import ssm as ssm_ops
 from ...ops.kernels import count_traced
 from ...ops.registry import invoke_raw, scope
+from ...telemetry.names import SCOPE_CONV_MIXER
 from ..block import HybridBlock
 from ..parameter import Parameter
 from .basic_layers import Dense
 
-__all__ = ["Mamba2Mixer"]
+__all__ = ["Mamba2Mixer", "ShortConvMixer"]
+
+
+class ShortConvMixer(HybridBlock):
+    """LFM2's gated short-convolution mixer: ``out = mixer(u)``, u (B, S,
+    units), no bias anywhere::
+
+        [B | C | x] = u W_in               units -> 3 units
+        v   = conv(B * x)                  causal, depthwise, ``taps`` taps
+        out = (C * v) W_out                units -> units
+
+    No state and no activation. The gates and the conv are
+    ``ops.ssm.gated_short_conv`` (float32 inside, the projection's dtype
+    out: bf16 under AMP); everything, projections included, sits under
+    the ``conv_mixer`` scope, the gates and the conv alone under
+    ``short_conv``. ``mx_short_conv_total`` counts the traced calls."""
+
+    def __init__(self, units: int, taps: int = 3, **kwargs):
+        super().__init__(**kwargs)
+        self.conv_weight = Parameter("conv_weight", shape=(units, taps))
+        self.in_proj = Dense(3 * units, use_bias=False, flatten=False,
+                             in_units=units)
+        self.out_proj = Dense(units, use_bias=False, flatten=False,
+                              in_units=units)
+
+    def forward(self, u):
+        count_traced("SHORT_CONV")
+        with scope(SCOPE_CONV_MIXER):
+            y = invoke_raw("gated_short_conv", ssm_ops.gated_short_conv,
+                           [self.in_proj(u), self.conv_weight.data()])
+            return self.out_proj(y)
 
 
 class Mamba2Mixer(HybridBlock):

@@ -61,7 +61,11 @@ class MultiHeadAttention(HybridBlock):
     are projected to that many heads and never repeated), ``window`` (with
     ``causal``: key j is seen by query i iff 0 <= i - j < window),
     ``rope_theta`` (rotary position embedding of q and k in front of the
-    call; None: no position signal at all).
+    call; None: no position signal at all), ``qk_norm`` (the epsilon of
+    an RMSNorm of each head of q and of k, with a gain of head width
+    each, ``q_norm_gamma`` and ``k_norm_gamma``, after the projections
+    and before RoPE, in float32 under the ``qk_norm`` scope; None: no
+    such norm).
     """
 
     def __init__(self, units: int, num_heads: int, dropout: float = 0.0,
@@ -69,7 +73,8 @@ class MultiHeadAttention(HybridBlock):
                  head_dim: Optional[int] = None,
                  num_kv_heads: Optional[int] = None,
                  window: Optional[int] = None,
-                 rope_theta: Optional[float] = None, **kwargs):
+                 rope_theta: Optional[float] = None,
+                 qk_norm: Optional[float] = None, **kwargs):
         super().__init__(**kwargs)
         if head_dim is None:
             if units % num_heads:
@@ -89,6 +94,12 @@ class MultiHeadAttention(HybridBlock):
         self._causal = causal
         self._window = window
         self._rope_theta = rope_theta
+        self._qk_norm = qk_norm
+        if qk_norm is not None:
+            self.q_norm_gamma = Parameter("q_norm_gamma", shape=(head_dim,),
+                                          init="ones")
+            self.k_norm_gamma = Parameter("k_norm_gamma", shape=(head_dim,),
+                                          init="ones")
         width = num_heads * head_dim
         self.query_proj = Dense(width, use_bias=use_bias, flatten=False,
                                 in_units=units)
@@ -111,10 +122,21 @@ class MultiHeadAttention(HybridBlock):
                                theta=self._rope_theta)
         return invoke_raw("rope", fn, [x])
 
+    def _norm_heads(self, x, gamma, heads):
+        """RMSNorm of each of ``heads`` heads of (B, S, H*D) ``x``."""
+        def fn(x_, g):
+            b, s, hd = x_.shape
+            return _nn.rms_norm(x_.reshape(b, s, heads, hd // heads), g,
+                                eps=self._qk_norm).reshape(b, s, hd)
+        return invoke_raw("qk_norm", fn, [x, gamma.data()])
+
     def forward(self, q, k=None, v=None, mask=None, valid_length=None):
         k = q if k is None else k
         v = k if v is None else v
         qp, kp, vp = self.query_proj(q), self.key_proj(k), self.value_proj(v)
+        if self._qk_norm is not None:
+            qp = self._norm_heads(qp, self.q_norm_gamma, self._num_heads)
+            kp = self._norm_heads(kp, self.k_norm_gamma, self._kv_heads)
         d = self._head_dim
         scale = 1.0 / math.sqrt(d)
         if mask is None and valid_length is None:

@@ -222,7 +222,7 @@ def moe_ffn(x, gate_w, w1, w2, top_k: int = 2, capacity_factor: float = 1.25,
 # ---------------------------------------------------------------------------
 
 def moe_route(x, router_w, top_k: int, held, score: str = "softmax",
-              bias=None, scale: float = 1.0):
+              bias=None, scale: float = 1.0, norm_eps: float = 0.0):
     """Router of the dropless layer. ``x`` (N, d); ``router_w`` (E, d),
     all E experts whichever are held; ``held = (first, count)``.
 
@@ -232,8 +232,8 @@ def moe_route(x, router_w, top_k: int, held, score: str = "softmax",
       k logits;
     - ``"sigmoid"``: scores s = sigmoid(logits); the top-k of s +
       ``bias`` ((E,), a selection bias that picks and never weighs: no
-      gradient reaches it), weighed by ``scale * s / sum of the chosen
-      s``.
+      gradient reaches it), weighed by ``scale * s / (sum of the chosen
+      s + norm_eps)`` (LFM2 adds 1e-6; the DeepSeek-V3 family nothing).
 
     Returns ``(weights, order, place, sizes)``: ``weights`` (N, k) f32;
     ``order`` (rows,)
@@ -257,7 +257,11 @@ def moe_route(x, router_w, top_k: int, held, score: str = "softmax",
             scores + lax.stop_gradient(bias.astype(jnp.float32))[None, :]
         _, top_idx = lax.top_k(lax.stop_gradient(picked), top_k)
         chosen = jnp.take_along_axis(scores, top_idx, axis=1)
-        weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        # the product before the sum, and no add where norm_eps is 0: the
+        # lowered step text of the cells without an epsilon rests on both
+        weights = scale * chosen
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
     else:
         raise ValueError(f"moe_route: no score rule {score!r}; "
                          f"it knows {SCORES}")

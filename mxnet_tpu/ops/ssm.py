@@ -1,6 +1,8 @@
 """The core of a state-space mixer (Mamba-2, "SSD": Dao & Gu 2024,
 arXiv:2405.21060): a causal depthwise conv, the selective scan in its
-chunked form, and the gated group RMSNorm behind it.
+chunked form, and the gated group RMSNorm behind it; and the gated short
+convolution of LFM2's conv mixers (:func:`gated_short_conv`), the same
+conv between two multiplicative gates and nothing else.
 
 No reference analog (the reference's one recurrence is the fused RNN of
 ops/rnn.py). A head h of width P carries a state ``H`` (P x N) along the
@@ -63,8 +65,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["causal_conv1d", "ssd_scan", "ssd_scan_reference",
-           "gated_group_rms_norm", "SSD_STATES", "SSD_OUTPUT"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssd_scan",
+           "ssd_scan_reference", "gated_group_rms_norm", "SSD_STATES",
+           "SSD_OUTPUT"]
 
 #: the name the chunk-boundary states carry for a ``jax.checkpoint``
 #: policy (``jax.checkpoint_policies.save_only_these_names``)
@@ -90,6 +93,21 @@ def causal_conv1d(x, weight, bias=None):
     if bias is not None:
         out = out + bias.astype(_F32)
     return out.astype(x.dtype)
+
+
+def gated_short_conv(bcx, weight):
+    """The gated short convolution of LFM2's conv mixers, between their
+    two projections: ``bcx`` (B, S, 3C) is ``[B | C | x]``, ``weight``
+    (C, K) a causal depthwise conv without bias; returns ``C *
+    causal_conv1d(B * x, weight)`` (B, S, C). No state and no activation.
+    Gates and conv summed in float32, returned in bcx's dtype, all of it
+    (and its backward) under the ``short_conv`` scope."""
+    from ..telemetry.names import SCOPE_SHORT_CONV
+    with jax.named_scope(SCOPE_SHORT_CONV):
+        c = bcx.shape[-1] // 3
+        b, gate, x = (bcx[..., i * c:(i + 1) * c].astype(_F32)
+                      for i in range(3))
+        return (gate * causal_conv1d(b * x, weight)).astype(bcx.dtype)
 
 
 def gated_group_rms_norm(y, z, gain, groups: int, eps: float = 1e-5):

@@ -164,6 +164,20 @@ MTP_MODULES = "mx_mtp_modules_total"
 SSD_SCAN_CHUNKS = "mx_ssd_scan_chunks_total"
 SSD_SCAN = "mx_ssd_scan_total"
 MAMBA_RECOMPUTE = "mx_mamba_recompute_total"
+SHORT_CONV = "mx_short_conv_total"
+
+# ---------------------------------------------------------------------------
+# named scopes (``jax.named_scope``) that the benchmark's readers find in a
+# step's HLO ``op_name``s (docs/OBSERVABILITY.md "Phase scopes"); no
+# registry series
+# ---------------------------------------------------------------------------
+#: a gated short-convolution mixer whole (gluon.nn.ShortConvMixer)
+SCOPE_CONV_MIXER = "conv_mixer"
+#: its gates and conv, between the projections (ops/ssm.py gated_short_conv)
+SCOPE_SHORT_CONV = "short_conv"
+#: the per-head RMSNorm of q and k (gluon.nn.MultiHeadAttention qk_norm):
+#: the op's own invoke-funnel name, which AMP's float32 list names too
+SCOPE_QK_NORM = "qk_norm"
 
 # ---------------------------------------------------------------------------
 # device counters (telemetry/device_counters.py): numbers the DEVICE
@@ -599,6 +613,12 @@ CATALOG = {
              "the scan's own checkpoint alone, under the imperative "
              "tape, which no checkpoint can span; one count a traced "
              "layer"),
+    SHORT_CONV: dict(
+        kind="counter", label=None,
+        help="gated short-convolution mixers traced (gluon.nn."
+             "ShortConvMixer, LFM2's conv layers: [B | C | x] = u W_in, "
+             "C * conv(B * x), W_out; ops/ssm.py gated_short_conv): one "
+             "count a traced call"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),
