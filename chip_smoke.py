@@ -386,6 +386,16 @@ def kernel_cases(tiny: bool = False):
                 -jnp.exp(a_log.astype(jnp.float32)), b, c,
                 skip.astype(jnp.float32)), (0, 1, 2, 3, 4, 5))
 
+        # the SmallThinker cell's embedding: 8,192 ids into its share of
+        # the vocabulary, 18,992 rows of 2560, and the table's gradient
+        # (tiny: 200 ids, no multiple of 128, some past either end)
+        n, rows, d = (200, 512, 128) if tiny else (8192, 18992, 2560)
+        add("embedding_grad", f"embedding {n} ids x {rows}x{d}", dtype,
+            lambda rng, n=n, rows=rows, d=d: (
+                rng.randint(-8, rows + 8, size=n).astype("int32"),
+                f32(rng, rows, d)),
+            ops_nn.embedding, (1,))
+
         # LSTM LM: bptt 35, bs 64, hidden 650 (pads to 768); both layers
         # run this recurrence shape (embed = hidden = 650)
         t, n, h = (5, 4, 50) if tiny else (35, 64, 650)
@@ -729,8 +739,9 @@ def _sparse_lm(cfg: dict) -> dict:
     fused, traced once, loss falling; → its losses and what the program
     counted while tracing it (``mx_moe_dispatch_total``,
     ``mx_attention_mask_total``, ``mx_moe_row_mover_total``,
-    ``mx_flash_attention_grid_steps_total``, and
-    ``mx_flash_attention_bwd_total``, which must read ``fused`` alone)."""
+    ``mx_embedding_grad_total``, ``mx_flash_attention_grid_steps_total``,
+    and ``mx_flash_attention_bwd_total``, which must read ``fused``
+    alone)."""
     import numpy as onp
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.smallthinker import SmallThinkerLM
@@ -744,7 +755,7 @@ def _sparse_lm(cfg: dict) -> dict:
     losses, counted = _train_small_lm(
         SmallThinkerLM(cfg["model"]), cfg, x, y,
         (names.MOE_DISPATCH, names.ATTENTION_MASK, names.MOE_ROW_MOVER,
-         names.MOE_GROUPED_DOT))
+         names.MOE_GROUPED_DOT, names.EMBEDDING_GRAD))
     del counted["flash_layouts"]
     layers = cfg["model"]["num_hidden_layers"]
     windowed = sum(cfg["model"]["sliding_window_layout"][:layers])
@@ -763,6 +774,11 @@ def _sparse_lm(cfg: dict) -> dict:
                            f"expected {_compiled_tier()} alone, {3 * layers} "
                            "or more")
     _kernel_products(counted, layers, "SmallThinkerLM")
+    # the table's gradient: the row-scatter kernel, not XLA's scatter-add
+    if set(counted[names.EMBEDDING_GRAD]) != {_compiled_tier()}:
+        raise RuntimeError("SmallThinkerLM's embedding gradient took "
+                           f"{counted[names.EMBEDDING_GRAD]}, expected "
+                           f"{_compiled_tier()} alone")
     # grouped heads: every layer's backward one kernel, dq, dk and dv
     # resident in VMEM
     forms = counted[names.FLASH_ATTENTION_BWD]

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError, jx_dtype
+from ..ops import nn as ops_nn
 from ..ops.registry import invoke_raw, register
 from .ndarray import NDArray, _norm_axis
 
@@ -525,8 +526,8 @@ def embedding(data, weight, input_dim=None, output_dim=None, dtype=None,
     # default fill-with-NaN poisons gradients silently — clamping is the
     # deterministic TPU-native choice (documented deviation).
     return invoke_raw("embedding",
-                      lambda idx, w: jnp.take(w, idx.astype(jnp.int32),
-                                              axis=0, mode="clip"),
+                      lambda idx, w: ops_nn.embedding(idx.astype(jnp.int32),
+                                                      w),
                       [data, weight])
 
 

@@ -14,7 +14,9 @@ Members (each joins the flash-attention kernels in ops/attention.py):
   the transformer/BERT leg.
 - :mod:`.moe_rows` — the row movers around the dropless expert layer's
   grouped products (ops/moe.py), whose work follows the pairs a layer
-  holds and not the static length of its sorted list.
+  holds and not the static length of its sorted list; its
+  ``scatter_sum`` is also an embedding table's gradient (ops/nn.py
+  ``embedding``, the gate's ``embedding_grad``).
 - :mod:`.grouped_dot` — that layer's grouped products themselves (rows
   x a group's matrix, the same against the transposed matrix, and the
   per-group rows^T x rows of the matrices' gradient), whose grid is as
@@ -93,7 +95,7 @@ def vmem_tile_budget() -> int:
 #: the kernel names the dispatch gate knows (diagnose/chip_smoke vocabulary)
 KERNELS = ("rnn_scan", "rnn_decode_step", "opt_update", "layernorm",
            "bias_gelu", "flash_attention", "moe_rows", "grouped_dot",
-           "ssd_scan")
+           "ssd_scan", "embedding_grad")
 
 # last decision per kernel name: {kernel: (path, reason)}
 _DECISIONS: Dict[str, Tuple[str, str]] = {}

@@ -12,7 +12,10 @@ kernels read ``total`` from SMEM and walk the live prefix:
   src[r]`` over the live rows r of token n, in float32; a token without
   a live row reads zero. ``moe_combine`` forward and, with weights of
   one, ``_dispatch`` backward. A token's pairs go to different experts,
-  so its rows are added in the experts' order.
+  so its rows are added in the experts' order. It is also the gradient
+  of an embedding table (ops/nn.py ``embedding``): ``k = 1``, unit
+  weights, the ids as ``order`` and the table's rows as the tokens,
+  under ``name="embedding_grad"``.
 - :func:`gather_rows` (pair-major): ``out[r] = w[r] * src[token(r)]`` for
   ``r < total``, zero up to the end of the last live row block, and in
   the same pass ``<y[r], src[token(r)]>`` in float32, a pair's weight
@@ -243,12 +246,13 @@ def _scatter_kernel(held_ref, token_ref, order_ref, w_ref, src_ref, out_ref,
         _live_rows(i, r, total, row)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n", "interpret"))
-def scatter_sum(src, order, total, k, n, weights, *, interpret=False):
+@functools.partial(jax.jit, static_argnames=("k", "n", "interpret", "name"))
+def scatter_sum(src, order, total, k, n, weights, *, interpret=False,
+                name="moe_scatter_sum"):
     """``out[t] = sum over r < total with order[r] // k == t of w[r] *
     src[r]`` in float32: ``src`` (rows, d), ``order`` (rows,) int32,
     ``total`` () int32, ``weights`` (n, k) float32. Returns (n, d)
-    float32."""
+    float32. ``name`` is the kernel's in the program and its trace."""
     rows, d = src.shape
     dc, r, n_col, n_blk = _geometry(n, rows, d)
     stage = [] if src.dtype == jnp.float32 else \
@@ -261,6 +265,5 @@ def scatter_sum(src, order, total, k, n, weights, *, interpret=False):
             in_specs=[pl.BlockSpec((r, dc), _live_block)],
             out_specs=_token_long(n, dc),
             scratch_shapes=stage),
-        compiler_params=_params(), interpret=interpret,
-        name="moe_scatter_sum",
+        compiler_params=_params(), interpret=interpret, name=name,
     )(*_scalars(total, order, k, weights, r), src)

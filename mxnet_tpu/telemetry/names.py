@@ -165,6 +165,7 @@ SSD_SCAN_CHUNKS = "mx_ssd_scan_chunks_total"
 SSD_SCAN = "mx_ssd_scan_total"
 MAMBA_RECOMPUTE = "mx_mamba_recompute_total"
 SHORT_CONV = "mx_short_conv_total"
+EMBEDDING_GRAD = "mx_embedding_grad_total"
 
 # ---------------------------------------------------------------------------
 # named scopes (``jax.named_scope``) that the benchmark's readers find in a
@@ -619,6 +620,16 @@ CATALOG = {
              "ShortConvMixer, LFM2's conv layers: [B | C | x] = u W_in, "
              "C * conv(B * x), W_out; ops/ssm.py gated_short_conv): one "
              "count a traced call"),
+    EMBEDDING_GRAD: dict(
+        kind="counter", label="tier",
+        help="traced gradients of an embedding table (ops/nn.py "
+             "embedding: the cotangent's rows summed into the ids' rows) "
+             "by tier: pallas = ops/kernels/moe_rows.py scatter_sum, "
+             "compiled (one TPU chip, a table of a multiple of 16 rows "
+             "and 128 lanes, ids and rows within SMEM); interpret = its "
+             "body under the interpreter (MXNET_PALLAS=on off the chip); "
+             "xla = the transpose of XLA's gather, a scatter-add; one "
+             "count a traced table-gradient site"),
     SERVING_REQUESTS: dict(
         kind="counter", label=None,
         help="inference requests submitted to any DynamicBatcher"),

@@ -172,6 +172,9 @@ def test_phases_rehearse_on_cpu(monkeypatch):
     # movers' kernels take
     movers = train["sparse_lm"]["mx_moe_row_mover_total"]
     assert set(movers) == {"interpret"} and movers["interpret"] >= 12
+    # and the embedding table's gradient (128 rows of 128, 64 ids)
+    assert set(train["sparse_lm"]["mx_embedding_grad_total"]) == {
+        "interpret"}
     # and so do the grouped products' (widths of 128): eight a layer
     products = train["sparse_lm"]["mx_moe_grouped_dot_total"]
     assert set(products) == {"interpret"} and products["interpret"] >= 32
